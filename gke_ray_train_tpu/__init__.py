@@ -21,7 +21,7 @@ __version__ = "0.1.0"
 # Sharding-invariant init is a correctness contract here (meshed init ==
 # plain init == init on any elastic topology): every init path wraps
 # itself in parallel.sharding.sharding_invariant_rng (partitionable
-# threefry, scoped — the global flag costs ~15% wall on CPU suites).
+# threefry — the default of the installed jax, pinned there in code).
 
 # The package re-exports are LAZY (PEP 562): parallel.mesh imports jax
 # at module level, but the obs/ CLI surface (`python -m

@@ -31,12 +31,12 @@ from typing import Any, Callable, Dict, List, Optional
 
 logger = logging.getLogger(__name__)
 
-# the jax_log_compiles line pxla emits per compile:
-#   Compiling <name> with global shapes and types [ShapedArray(...)].
-#   Argument mapping: (<shardings>).
+# the jax_log_compiles line pxla emits per compile (jax 0.9):
+#   Compiling jit(<name>) with global shapes and types
+#   (ShapedArray(...),). Argument mapping: (<shardings>).
 _COMPILE_LOG_RE = re.compile(
-    r"Compiling ([^\s]+) with global shapes and types "
-    r"(\[.*?\])\. Argument mapping: (\(.*\))", re.DOTALL)
+    r"Compiling jit\(([^\s]+)\) with global shapes and types "
+    r"(\(.*?\))\. Argument mapping: (\(.*\))", re.DOTALL)
 _PXLA_LOGGER = "jax._src.interpreters.pxla"
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

@@ -317,11 +317,11 @@ def _low_precision(dtype) -> bool:
 
 
 def _sub_jaxprs(params: Mapping[str, Any]):
-    import jax
+    from jax.extend import core as jex_core
     for v in params.values():
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for item in vals:
-            if isinstance(item, jax.core.ClosedJaxpr):
+            if isinstance(item, jex_core.ClosedJaxpr):
                 yield item.jaxpr
             elif hasattr(item, "eqns") and hasattr(item, "invars"):
                 yield item           # raw Jaxpr (pallas_call)
@@ -338,7 +338,7 @@ def _eqn_where(eqn) -> str:
 
 def _walk_jaxpr(jaxpr, label: str, top: bool,
                 findings: List[KernelFinding]) -> None:
-    import jax
+    from jax.extend import core as jex_core
 
     producers = {}
     for eqn in jaxpr.eqns:
@@ -356,7 +356,7 @@ def _walk_jaxpr(jaxpr, label: str, top: bool,
         seen = set()
         while stack:
             v, d = stack.pop()
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, jex_core.Literal):
                 continue     # a literal operand is a constant, not data
             if id(v) in seen or d > _ANCESTRY_DEPTH:
                 continue
@@ -380,7 +380,7 @@ def _walk_jaxpr(jaxpr, label: str, top: bool,
         seen = set()
         while stack:
             v, d = stack.pop()
-            if isinstance(v, jax.core.Literal) or id(v) in seen \
+            if isinstance(v, jex_core.Literal) or id(v) in seen \
                     or d > _ANCESTRY_DEPTH:
                 continue
             seen.add(id(v))

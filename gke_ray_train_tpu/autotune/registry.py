@@ -25,8 +25,8 @@ columns beside the modeled ones. :func:`ingest_observed` matches a run
 dir's :func:`gke_ray_train_tpu.obs.observe.observed_runs` rows against
 entries by plan fingerprint (base arm / tuned arm), refusing rows the
 same way ``apply`` refuses entries — fingerprint drift, version drift,
-and the backend gate (a ``cpu-fallback`` measurement can NEVER
-calibrate a non-CPU ChipSpec). ``autotune/calibrate.py`` fits
+and the backend gate (a CPU measurement can NEVER calibrate a
+non-CPU ChipSpec). ``autotune/calibrate.py`` fits
 per-chip-spec correction factors over those rows, and when a
 calibration exists ingest grows teeth: an arm whose corrected
 prediction misses the measured value by more than
@@ -339,10 +339,6 @@ def maybe_apply(plan, *, config: Optional[Mapping[str, Any]] = None,
 # dir appends nothing (the bitwise-idempotency contract)
 _ROW_KEY = ("run_id", "attempt", "arm", "plan_fingerprint", "source")
 
-# backends whose measurements describe host CPUs, never a TPU ChipSpec
-_CPU_BACKENDS = ("cpu", "cpu-fallback")
-
-
 def drift_band(config: Optional[Mapping[str, Any]] = None) -> float:
     """``AUTOTUNE_DRIFT_BAND`` (config key wins over env, like every
     knob); unparsable values fall back to the default rather than
@@ -451,7 +447,7 @@ def _row_refusal(row: Mapping[str, Any],
     """Why a fingerprint-matched row must NOT become an observed column
     of this entry (None = ingest it). The backend gate is the critical
     one: measurements are only evidence against the ChipSpec they ran
-    on — a ``cpu-fallback`` step time must never calibrate a TPU."""
+    on — a CPU step time must never calibrate a TPU."""
     from gke_ray_train_tpu.perf.costs import CHIP_SPECS
     fi = entry.get("fingerprint_inputs") or {}
     chip = fi.get("chip")
@@ -472,11 +468,11 @@ def _row_refusal(row: Mapping[str, Any],
     if not backend:
         return ("row carries no backend stamp — refusing an "
                 "unattributable measurement")
-    if backend in _CPU_BACKENDS and chip != "cpu":
+    if backend == "cpu" and chip != "cpu":
         return (f"backend {backend!r} measurement can NEVER calibrate "
-                f"ChipSpec {chip!r} — fallback numbers describe the "
+                f"ChipSpec {chip!r} — CPU numbers describe the "
                 "host, not the declared hardware")
-    if backend not in _CPU_BACKENDS and chip == "cpu":
+    if backend != "cpu" and chip == "cpu":
         return (f"backend {backend!r} measurement does not describe the "
                 "CPU ChipSpec this entry scores against")
     return None

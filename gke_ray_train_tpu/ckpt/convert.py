@@ -96,16 +96,10 @@ def convert(orbax_dir: str, out_dir: str, *, step: int = None,
         import orbax.checkpoint as ocp
         sds = jax.ShapeDtypeStruct(leaves[i][1].shape,
                                    leaves[i][1].dtype, sharding=sh)
-        if hasattr(ocp, "PLACEHOLDER"):
-            flat = [ocp.PLACEHOLDER] * len(leaves)
-            flat[i] = sds
-            out = mgr.restore_partial(
-                jax.tree_util.tree_unflatten(treedef, flat), step)
-        else:  # pre-PLACEHOLDER orbax: partial item tree + transforms={}
-            sub = sds
-            for part in reversed(_path_parts(leaves[i][0])):
-                sub = {part: sub}
-            out = mgr.restore_partial(sub, step)
+        flat = [ocp.PLACEHOLDER] * len(leaves)
+        flat[i] = sds
+        out = mgr.restore_partial(
+            jax.tree_util.tree_unflatten(treedef, flat), step)
         (leaf,) = [x for x in jax.tree.leaves(out) if x is not ...]
         return np.asarray(jax.device_get(leaf))
 

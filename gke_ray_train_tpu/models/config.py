@@ -140,8 +140,8 @@ class ModelConfig:
     def resolved_attn_impl(self) -> str:
         if self.attn_impl != "auto":
             return self.attn_impl
-        import jax
-        return "flash" if jax.default_backend() == "tpu" else "xla"
+        from gke_ray_train_tpu.parallel.mesh import on_tpu
+        return "flash" if on_tpu() else "xla"
 
     @property
     def n_repeats(self) -> int:

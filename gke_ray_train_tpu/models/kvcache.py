@@ -99,7 +99,8 @@ def _warn_dense_prefill(T: int, max_len: int) -> None:
 def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
                  cache: Cache, lens: jnp.ndarray, *,
                  lora: Optional[Params] = None,
-                 lora_scale: float = 1.0) -> Tuple[jnp.ndarray, Cache]:
+                 lora_scale: float = 1.0,
+                 mesh=None) -> Tuple[jnp.ndarray, Cache]:
     """tokens [B, T] at per-row absolute positions lens + arange(T) →
     (logits [B, T, vocab] fp32, updated cache).
 
@@ -107,15 +108,17 @@ def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     K/V read from + written to the cache. Supports every family the
     trainer supports (GQA, RoPE/sinusoidal, sliding-window patterns,
     softcaps, QTensor bases, LoRA adapters).
+
+    ``mesh``: the mesh the params live on when they span several
+    devices. The cache and the tokens stay replicated; only the flash
+    prefill needs it, because a compiled Mosaic kernel cannot be
+    partitioned by GSPMD and must sit inside a ``shard_map``.
     """
-    if lora is not None and "aslot" in lora:
-        # multi-tenant serving: ``lora`` is {"aslot": [B] int32,
-        # "blocks": stacked pool with adapter axis 1} — gather each
-        # row's adapter ONCE here (not per layer) so the block scan
-        # sees ordinary per-row [B, d_in, r] entries and ``_proj``
-        # takes the batched-einsum path (ops/lora_batched.py)
-        from gke_ray_train_tpu.ops.lora_batched import gather_pool
-        lora = {"blocks": gather_pool(lora["blocks"], lora["aslot"])}
+    # multi-tenant serving: ``lora`` is {"aslot": [B] int32, "blocks":
+    # stacked pool with adapter axis 1}. Each row's adapter is gathered
+    # per repeat inside the scan (ops/lora_batched.py says why), where
+    # ``_proj`` then sees per-row [B, d_in, r] entries
+    aslot = lora.get("aslot") if lora is not None else None
 
     B, T = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
@@ -145,8 +148,8 @@ def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     # path materializes [B, H, T, max_len] logits — the O(S²) memory
     # wall at long prompts); single-token decode steps (T=1) and odd
     # widths keep the cheap dense mask, and attn_impl="xla" forces it.
-    # ring/a2a are training-time context-parallel strategies — decode is
-    # mesh-local, so they resolve to plain flash here.
+    # ring/a2a are training-time context-parallel strategies — decode
+    # never shards the sequence, so they resolve to plain flash here.
     use_flash = (cfg.resolved_attn_impl != "xla" and T > 1
                  and T % 128 == 0 and max_len % 128 == 0)
     if not use_flash and cfg.resolved_attn_impl != "xla" and T > 1:
@@ -166,6 +169,9 @@ def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
         layer_slice = xs_slice[0]
         cache_slice = xs_slice[1]
         lora_slice = xs_slice[2] if lora is not None else None
+        if aslot is not None:
+            from gke_ray_train_tpu.ops.lora_batched import gather_pool
+            lora_slice = gather_pool(lora_slice, aslot)
         new_cache = []
         for p, kind in enumerate(cfg.block_pattern):
             lp = layer_slice[p]
@@ -193,7 +199,8 @@ def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
             window = cfg.sliding_window if kind == "sliding" else None
             if use_flash:
                 # single kernel entry point for the whole repo
-                # (ops/dispatch.py); mesh=None — decode is mesh-local
+                # (ops/dispatch.py); batch_axes=None — the request
+                # batch is replicated, whatever shards the weights
                 from gke_ray_train_tpu.ops.dispatch import (
                     attention_dispatch)
                 out = attention_dispatch(
@@ -201,7 +208,8 @@ def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
                     v_cache.astype(dtype),
                     q_positions=positions, kv_positions=kv_positions,
                     causal=True, sliding_window=window,
-                    scale=cfg.attn_scale, logit_softcap=cfg.attn_softcap)
+                    scale=cfg.attn_scale, logit_softcap=cfg.attn_softcap,
+                    mesh=mesh, batch_axes=None)
             else:
                 out = dot_product_attention(
                     q, k_cache.astype(dtype), v_cache.astype(dtype),

@@ -23,8 +23,8 @@ Measurement discipline:
   ``modeled_per_token_s`` predicts;
 - ``backend`` comes from the run's own record (the ``first_step``
   event / the bench record's ``backend`` tag), NEVER inferred — a
-  ``cpu-fallback`` measurement must be refusable at ingest so it can
-  never calibrate a TPU ChipSpec;
+  CPU measurement must be refusable at ingest so it can never
+  calibrate a TPU ChipSpec;
 - every float is rounded once, here, so re-extracting the same
   artifacts is bitwise-identical (the ingest idempotency contract).
 

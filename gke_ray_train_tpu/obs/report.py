@@ -150,9 +150,9 @@ def build_report(run_dir: str) -> Dict[str, Any]:
                 for e in evs if e["kind"] not in ("step",)],
             "steps_logged": sum(1 for e in evs if e["kind"] == "step"),
             # the backend the attempt ACTUALLY ran on (first_step
-            # stamps it): `autotune ingest` filters on this so a
-            # cpu-fallback measurement can never calibrate a TPU
-            # ChipSpec — the report carries it through
+            # stamps it): `autotune ingest` filters on this so a CPU
+            # measurement can never calibrate a TPU ChipSpec — the
+            # report carries it through
             "backend": next((e.get("backend") for e in evs
                              if e["kind"] == "first_step"
                              and e.get("backend")), None),

@@ -98,20 +98,16 @@ def resolve_obs_dir(plan=None, config: Optional[dict] = None
 
 def current_backend() -> Optional[str]:
     """The backend tag observed-row producers stamp (ISSUE 16): the
-    honest answer to "what hardware produced this measurement".
-    ``cpu-fallback`` when the run itself declared it is a fallback
-    (bench.py's BENCH_CPU_FALLBACK contract), else the live jax
-    backend name — lazy-imported so the stdlib-only driver side can
-    call this and get None rather than an import error. The point of
-    the stamp: a cpu-fallback number must be REFUSABLE at autotune
-    ingest, so it can never calibrate a TPU ChipSpec."""
-    if os.environ.get("BENCH_CPU_FALLBACK") == "1":
-        return "cpu-fallback"
+    live jax backend name, the honest answer to "what hardware produced
+    this measurement" — lazy-imported so the stdlib-only driver side
+    can call this and get None rather than an import error. The point
+    of the stamp: a CPU number must be REFUSABLE at autotune ingest, so
+    it can never calibrate a TPU ChipSpec."""
     try:
         import jax
-        return str(jax.default_backend())
-    except Exception:  # noqa: BLE001 - no jax / dead backend = no tag
+    except ImportError:
         return None
+    return str(jax.default_backend())
 
 
 class ObsRun:

@@ -23,7 +23,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax.numpy as jnp
-from gke_ray_train_tpu.ops.smap import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from gke_ray_train_tpu.parallel.mesh import (

@@ -31,15 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas < 0.5 spells it TPUCompilerParams; alias locally, never mutate
-# the third-party module
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported pallas version")
-
 from gke_ray_train_tpu.ops.attention import NEG_INF
 
 # tuned on v5e (8x2048x16h/8kv/128dh bf16 fwd+bwd sweep: 13.1 ms vs
@@ -66,7 +57,8 @@ def interpret_default(interpret: "Optional[bool]") -> bool:
     the Mosaic kernels can't compile, so the same kernel runs under the
     interpreter. One rule for every kernel module."""
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        from gke_ray_train_tpu.parallel.mesh import on_tpu
+        return not on_tpu()
     return interpret
 
 
@@ -120,6 +112,13 @@ def _block_live(q_pos, kv_pos, q_seg, kv_seg, causal, window):
 
 
 FULL_BLOCK_LIMIT = 2048  # max seq to load as one VMEM block
+
+# Mosaic's default scoped-VMEM limit is 16 MiB of a v5e core's 128. At
+# Llama-3.1-8B widths the fused epilogue kernels (ops/fused_norm_rope.py,
+# ops/fused_ce.py) need more for their default blocks — 22 MiB for rope
+# over 32+8 heads of 128, 39 MiB for the cross-entropy dx kernel at
+# d_model 4096 — so they ask for this much instead.
+FUSED_VMEM_LIMIT_BYTES = 48 * 2**20
 
 
 def estimate_vmem_bytes(block_q: int, block_kv: int, head_dim: int,
@@ -268,7 +267,7 @@ def _fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, *, scale, causal, window,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -423,7 +422,7 @@ def _bwd(res, g, *, scale, causal, window, softcap, block_q, block_kv,
                                lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, dh), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -469,7 +468,7 @@ def _bwd(res, g, *, scale, causal, window, softcap, block_q, block_kv,
             pltpu.VMEM((block_kv, dh), jnp.float32),
             pltpu.VMEM((block_kv, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -34,14 +34,14 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from gke_ray_train_tpu.ops.attention import NEG_INF
 from gke_ray_train_tpu.ops.flash_attention import (
-    _block_env, interpret_default, pick_block)
-from gke_ray_train_tpu.ops.smap import shard_map
+    FUSED_VMEM_LIMIT_BYTES, _block_env, interpret_default, pick_block)
 from gke_ray_train_tpu.parallel.mesh import AXIS_CONTEXT, BATCH_AXES
 
 
@@ -192,6 +192,8 @@ def _row_stats(x, head, targets, *, block_r, block_v, interpret):
             pltpu.VMEM((br, 128), jnp.float32),
             pltpu.VMEM((br, 128), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(targets.astype(jnp.int32)[None, :], x[None], head)
     return lse[0], tgt[0]
@@ -221,6 +223,8 @@ def _grads(x, head, targets, wg, lse, *, block_r, block_v, interpret):
         out_specs=pl.BlockSpec((1, br, D), lambda b, i, j: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((1, N, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((br, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(t2, wg2, lse2, x[None], head)[0]
 
@@ -237,6 +241,8 @@ def _grads(x, head, targets, wg, lse, *, block_r, block_v, interpret):
         out_specs=pl.BlockSpec((D, bv), lambda j, b, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((D, V), head.dtype),
         scratch_shapes=[pltpu.VMEM((D, bv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(t2, wg2, lse2, x[None], head)
     return dx, dhead

@@ -41,12 +41,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from gke_ray_train_tpu.ops.flash_attention import (
-    _block_env, interpret_default, pick_block)
-from gke_ray_train_tpu.ops.smap import shard_map
+    FUSED_VMEM_LIMIT_BYTES, _block_env, interpret_default, pick_block)
 from gke_ray_train_tpu.parallel.mesh import AXIS_CONTEXT, BATCH_AXES
 
 
@@ -101,7 +102,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps, scale_plus_one):
 
 
 def _rope_qk_kernel(pos_ref, f_ref, q_ref, k_ref, oq_ref, ok_ref):
-    pos = pos_ref[0]
+    pos = pos_ref[0, 0]
     freqs = f_ref[0]
     oq_ref[0] = _rot_block(q_ref[0].astype(jnp.float32), pos, freqs
                            ).astype(oq_ref.dtype)
@@ -114,7 +115,7 @@ def _rmsnorm_rope_kernel(pos_ref, f_ref, s_ref, x_ref, o_ref, *,
     x32 = x_ref[0].astype(jnp.float32)
     y = _norm_block(x32, s_ref[0].astype(jnp.float32),
                     eps=eps, scale_plus_one=scale_plus_one)
-    o_ref[0] = _rot_block(y, pos_ref[0], f_ref[0]).astype(o_ref.dtype)
+    o_ref[0] = _rot_block(y, pos_ref[0, 0], f_ref[0]).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -153,6 +154,8 @@ def fused_rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, *,
                 ],
                 out_specs=pl.BlockSpec((1, bs, D), lambda b, i: (b, i, 0)),
                 out_shape=jax.ShapeDtypeStruct((B, S, D), x.dtype),
+                compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
                 interpret=interpret,
             )(x, scale[None, :])
 
@@ -206,7 +209,10 @@ def fused_rope_qk(q: jnp.ndarray, k: jnp.ndarray, positions: jnp.ndarray,
                 _rope_qk_kernel,
                 grid=grid,
                 in_specs=[
-                    pl.BlockSpec((1, bs), lambda b, i: (b, i)),
+                    # [B, 1, S] positions: a (1, 1, bs) block is
+                    # Mosaic-legal where (1, bs) of [B, S] is not (the
+                    # flash kernel's layout, for the same reason)
+                    pl.BlockSpec((1, 1, bs), lambda b, i: (b, 0, i)),
                     pl.BlockSpec((1, dh // 2), lambda b, i: (0, 0)),
                     pl.BlockSpec((1, bs, H, dh), lambda b, i: (b, i, 0, 0)),
                     pl.BlockSpec((1, bs, K, dh), lambda b, i: (b, i, 0, 0)),
@@ -219,8 +225,11 @@ def fused_rope_qk(q: jnp.ndarray, k: jnp.ndarray, positions: jnp.ndarray,
                     jax.ShapeDtypeStruct((B, S, H, dh), q.dtype),
                     jax.ShapeDtypeStruct((B, S, K, dh), k.dtype),
                 ],
+                compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
                 interpret=interpret,
-            )(positions.astype(jnp.int32), freqs[None, :], q, k)
+            )(positions.astype(jnp.int32)[:, None, :], freqs[None, :],
+              q, k)
 
         # positions/freqs ride as custom_vjp ARGS (None cotangents) —
         # closing over tracers would leak them across the fwd/bwd
@@ -273,7 +282,7 @@ def fused_rmsnorm_rope(x: jnp.ndarray, scale: jnp.ndarray,
             kernel,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bs), lambda b, i: (b, i)),
+                pl.BlockSpec((1, 1, bs), lambda b, i: (b, 0, i)),
                 pl.BlockSpec((1, dh // 2), lambda b, i: (0, 0)),
                 pl.BlockSpec((1, dh), lambda b, i: (0, 0)),
                 pl.BlockSpec((1, bs, H, dh), lambda b, i: (b, i, 0, 0)),
@@ -281,8 +290,10 @@ def fused_rmsnorm_rope(x: jnp.ndarray, scale: jnp.ndarray,
             out_specs=pl.BlockSpec((1, bs, H, dh),
                                    lambda b, i: (b, i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((B, S, H, dh), x.dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
             interpret=interpret,
-        )(positions.astype(jnp.int32), inv_freqs[None, :],
+        )(positions.astype(jnp.int32)[:, None, :], inv_freqs[None, :],
           scale[None, :], x)
 
     @jax.custom_vjp

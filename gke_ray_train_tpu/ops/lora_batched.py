@@ -14,9 +14,12 @@ Layout contract (mirrors ``train/lora.py`` single-adapter trees):
   so the scanned-block axis stays leading and a per-repeat ``lax.scan``
   slice is ``[A, d_in, r]`` with the adapter axis leading (the layout
   ``ops/registry.py``'s ``lora_batched`` kernel spec pins);
-- ``gather_pool`` selects per-row adapters BEFORE the block scan
-  (one gather for all layers: ``[n_repeats, B, d_in, r]``), so inside
-  the scan ``_proj`` sees a 3-D per-row entry and runs ``bgmv``.
+- ``gather_pool`` selects per-row adapters from one repeat's slice
+  INSIDE the block scan (``[A, d_in, r]`` → ``[B, d_in, r]``), so
+  ``_proj`` sees a 3-D per-row entry and runs ``bgmv``. Gathering all
+  layers at once outside the scan would hold ``B`` full adapter copies
+  live for the whole forward — 8 rows of an r=64 Llama-3.1-8B adapter
+  do not fit one 16 GB chip beside the base weights.
 
 Reference path is pure einsum — exact on the CPU mesh, and the oracle
 ledger (tests/tolerances/lora_batched.json) pins it at 0.0 against the
@@ -37,16 +40,15 @@ import jax
 import jax.numpy as jnp
 
 
-def gather_pool(pool_blocks: Any, aslot: jnp.ndarray) -> Any:
-    """Select each batch row's adapter from a stacked pool.
+def gather_pool(pool_slice: Any, aslot: jnp.ndarray) -> Any:
+    """Select each batch row's adapter from one repeat's pool slice.
 
-    ``pool_blocks``: pytree of ``[n_repeats, A, ...]`` leaves (adapter
-    axis 1); ``aslot``: ``[B]`` int32 slot indices. Returns the same
-    tree with leaves ``[n_repeats, B, ...]`` — row ``b`` carries adapter
-    ``aslot[b]``. Hoisted outside the block scan so the gather happens
-    once per forward, not once per layer.
+    ``pool_slice``: pytree of ``[A, ...]`` leaves (the per-repeat scan
+    slice of the stacked pool, adapter axis leading); ``aslot``: ``[B]``
+    int32 slot indices. Returns the same tree with leaves ``[B, ...]`` —
+    row ``b`` carries adapter ``aslot[b]``.
     """
-    return jax.tree.map(lambda p: jnp.take(p, aslot, axis=1), pool_blocks)
+    return jax.tree.map(lambda p: jnp.take(p, aslot, axis=0), pool_slice)
 
 
 def bgmv(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray, *,

@@ -12,8 +12,9 @@ it to *kernels*: each accelerated op (`ops/flash_attention.py`,
   differential claim rather than a per-test hand-rolled comparison;
 - its **domain** — the shape/dtype/sharding cases it supports, each a
   named :class:`KernelCase` (sharded cases carry the mesh axes they
-  run under on the canonical fake-8 CPU mesh, Pallas in interpret
-  mode);
+  run under on the canonical fake-8 CPU mesh; Pallas kernels compile
+  on a TPU and run in interpret mode elsewhere —
+  ``interpret=None``, resolved by ``interpret_default``);
 - whether its **gradients** are part of the contract (custom-VJP
   kernels: yes; frozen-base quant codecs and cache plumbing: no);
 - optional **traced bodies** for the numerics lint (kernelcheck
@@ -153,7 +154,7 @@ def _dispatch_kernel(impl: str):
             q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
             causal=True, sliding_window=kw.get("sliding_window"),
             logit_softcap=kw.get("logit_softcap"), mesh=mesh,
-            interpret=True)
+            interpret=None)
         return _mask_padding_rows(out, segment_ids)
     return run
 
@@ -168,7 +169,7 @@ def _flash_numerics_targets() -> List[tuple]:
     sd = jax.ShapeDtypeStruct((1, 128, 2, 32), jnp.bfloat16)
 
     def body(q, k, v):
-        return flash_attention(q, k, v, interpret=True).sum()
+        return flash_attention(q, k, v, interpret=None).sum()
 
     return [("flash_attention/bfloat16",
              jax.grad(body, argnums=(0, 1, 2)), (sd, sd, sd))]
@@ -590,15 +591,15 @@ def _fnr_kernel(case: KernelCase, mesh, *args):
     mode = case.kw().get("mode", "composed")
     if mode == "norm":
         x, scale = args
-        return fused_rmsnorm(x, scale, interpret=True, mesh=mesh)
+        return fused_rmsnorm(x, scale, interpret=None, mesh=mesh)
     if mode == "rope_qk":
         q, k, positions = args
         qr, kr = fused_rope_qk(q, k, positions, _fnr_freqs(q.shape[-1]),
-                               interpret=True, mesh=mesh)
+                               interpret=None, mesh=mesh)
         return {"q": qr, "k": kr}
     x, scale, positions = args
     return fused_rmsnorm_rope(x, scale, positions,
-                              _fnr_freqs(x.shape[-1]), interpret=True)
+                              _fnr_freqs(x.shape[-1]), interpret=None)
 
 
 def _fnr_oracle(case: KernelCase, mesh, *args):
@@ -628,12 +629,12 @@ def _fnr_numerics_targets() -> List[tuple]:
     bf = jnp.bfloat16
     return [
         ("fused_rmsnorm/bfloat16",
-         lambda x, s: fused_rmsnorm(x, s, interpret=True),
+         lambda x, s: fused_rmsnorm(x, s, interpret=None),
          (jax.ShapeDtypeStruct((2, 128, 32), bf),
           jax.ShapeDtypeStruct((32,), jnp.float32))),
         ("fused_rmsnorm_rope/bfloat16",
          lambda x, s, p: fused_rmsnorm_rope(
-             x, s, p, _fnr_freqs(32), interpret=True),
+             x, s, p, _fnr_freqs(32), interpret=None),
          (jax.ShapeDtypeStruct((2, 128, 2, 32), bf),
           jax.ShapeDtypeStruct((32,), jnp.float32),
           jax.ShapeDtypeStruct((2, 128), jnp.int32))),
@@ -675,7 +676,7 @@ def _fce_inputs(case: KernelCase, key: jax.Array, B=2, S=128, D=64,
 def _fce_kernel(case: KernelCase, mesh, x, head, targets, weights):
     from gke_ray_train_tpu.ops.fused_ce import fused_cross_entropy
     nll, w = fused_cross_entropy(
-        x, head, targets, weights, interpret=True, mesh=mesh,
+        x, head, targets, weights, interpret=None, mesh=mesh,
         block_v=case.kw().get("block_v", 2048))
     return {"nll": nll, "w": w}
 
@@ -704,7 +705,7 @@ def _fce_numerics_targets() -> List[tuple]:
     def body(x, h, t, w):
         return jax.grad(
             lambda a, b: fused_cross_entropy(a, b, t, w,
-                                             interpret=True)[0],
+                                             interpret=None)[0],
             argnums=(0, 1))(x, h)
 
     return [("fused_cross_entropy/bfloat16", body, args)]
@@ -755,7 +756,7 @@ def _hier_kernel(case: KernelCase, mesh, x):
     chain)."""
     from jax.sharding import PartitionSpec as P
 
-    from gke_ray_train_tpu.ops.smap import shard_map
+    from jax import shard_map
     from gke_ray_train_tpu.parallel.hierarchical import (
         compressed_cross_psum, hier_psum, intra_reduce_shard)
     topo = _hier_topo(case)

@@ -28,7 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from gke_ray_train_tpu.ops.smap import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from gke_ray_train_tpu.ops import flash_attention as fa
@@ -158,8 +158,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     if mesh is None:
         raise ValueError("ring attention needs a mesh with a context axis")
     B, S, H, dh = q.shape
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = fa.interpret_default(interpret)
 
     if q_positions is None:
         q_positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),

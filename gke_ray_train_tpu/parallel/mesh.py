@@ -55,6 +55,17 @@ MESH_AXES = (AXIS_DATA, AXIS_FSDP, AXIS_MODEL, AXIS_CONTEXT, AXIS_PIPE)
 BATCH_AXES = (AXIS_DATA, AXIS_FSDP)
 
 
+def on_tpu() -> bool:
+    """Whether the attached backend is a TPU — the ONE test behind every
+    device-dependent choice: flash vs the XLA oracle
+    (``ModelConfig.resolved_attn_impl``), compiled vs interpreted Pallas
+    (``ops.flash_attention.interpret_default``, shared by ring/a2a) and
+    whether ``OVERLAP=xla`` adds its compiler options (``plan.py``).
+    A backend that fails to initialize raises here; nothing degrades to
+    the CPU behind the caller's back."""
+    return jax.default_backend() == "tpu"
+
+
 @dataclasses.dataclass(frozen=True)
 class MeshConfig:
     """Logical mesh shape. Any axis may be -1 ("fill with what remains").
@@ -152,13 +163,16 @@ def build_mesh(config: MeshConfig | None = None,
                 "emulating the %d-slice hybrid mesh row-major",
                 config.num_slices)
             dev_array = np.asarray(devices).reshape(config.shape)
+    elif devices[0].platform == "cpu":
+        # fake CPU devices have no physical topology to honor
+        dev_array = np.asarray(devices).reshape(config.shape)
     else:
-        try:
-            dev_array = mesh_utils.create_device_mesh(
-                config.shape, devices=devices)
-        except (ValueError, NotImplementedError):
-            # Fake/CPU devices or odd topologies: plain row-major layout.
-            dev_array = np.asarray(devices).reshape(config.shape)
+        # a topology create_device_mesh cannot lay out raises: a silent
+        # row-major order would put logical neighbors off ICI neighbors
+        dev_array = mesh_utils.create_device_mesh(
+            config.shape, devices=devices)
+        logger.info("mesh %s device order: %s", config.shape,
+                    [d.id for d in dev_array.flat])
     return Mesh(dev_array, MESH_AXES)
 
 

@@ -20,15 +20,15 @@ def sharding_invariant_rng():
     """Partitionable threefry for the duration: random draws made inside
     are IDENTICAL however — and whether — their outputs are sharded.
 
-    On jaxlib 0.4.x the default (non-partitionable) threefry makes a
-    jitted draw's VALUES depend on its ``out_shardings`` (kernelcheck's
-    differential sweeps caught meshed ``init_params`` diverging from
-    the plain oracle by ~3 init-stds). Every init path wraps itself in
-    this context, making meshed init == plain init == init on ANY
-    topology (the elastic same-seed-any-pool contract, PR 8) a real
-    invariant. Scoped rather than set globally: partitionable
-    generation costs ~15% wall on CPU-heavy suites, and init is where
-    sharding-invariance is a *correctness* contract."""
+    Under non-partitionable threefry a jitted draw's VALUES depend on
+    its ``out_shardings`` (kernelcheck's differential sweeps caught
+    meshed ``init_params`` diverging from the plain oracle by ~3
+    init-stds). Every init path wraps itself in this context, making
+    meshed init == plain init == init on ANY topology (the elastic
+    same-seed-any-pool contract, PR 8) an invariant of the code and not
+    of a global flag. The installed jax 0.9 already defaults the flag
+    to True, so the scope changes nothing today; it stays because
+    ``tests/test_kernelcheck.py`` pins it."""
     old = bool(jax.config.jax_threefry_partitionable)
     jax.config.update("jax_threefry_partitionable", True)
     try:

@@ -3,14 +3,16 @@
 Two mechanisms make compilation a one-time cost across restarts:
 
 1. **Persistent compilation cache** (:func:`enable_persistent_cache`):
-   JAX's file cache pointed at shared storage (``COMPILE_CACHE_DIR``,
-   default ``/mnt/pvc/xla_cache``) so every retry/resume — and every
-   *other worker* of the slice — reuses the XLA binary instead of
-   recompiling (minutes at 8B scale; the MaxText practice for GSPMD
-   programs). Entries are namespaced by a topology fingerprint subdir
-   so v5e and v5p slices never share a directory; JAX's own cache key
-   already encodes the program + platform, the subdir adds operational
-   hygiene (per-topology GC, never a correctness mechanism).
+   JAX's file cache, so every retry/resume — and every *other worker*
+   of the slice — reuses the XLA binary instead of recompiling (minutes
+   at 8B scale; the MaxText practice for GSPMD programs). The directory
+   is placed from outside with ``JAX_COMPILATION_CACHE_DIR``, which JAX
+   reads itself; only without it does the program name one
+   (``COMPILE_CACHE_DIR``, else ``<checkout>/.jax_cache``) — always a
+   fixed path, never one built from a temporary name, a pid or the
+   time, because a directory that moves between runs never hits.
+   Entries sit directly in it: JAX's own cache key already encodes
+   program, platform and topology.
 
 2. **AOT executables** (:func:`build_or_load_step`): the train/eval
    step is built ahead-of-time via ``jit(...).lower(...).compile()``
@@ -20,10 +22,9 @@ Two mechanisms make compilation a one-time cost across restarts:
    the persistent cache saves compile time, the sidecar saves trace
    + lowering time too.
 
-Both paths are fail-open: an unwritable cache dir falls back to a
-local directory (then to disabled), a stale/mismatched sidecar falls
-back to the jitted path — a performance layer must never turn a
-recoverable restart into a crash.
+A stale/mismatched sidecar falls back to a fresh compile. Nothing
+else degrades: a cache directory that cannot be written, or a step
+that fails to compile, is an error.
 
 Gotcha this module owns so callers don't have to: JAX memoizes "is the
 cache usable" at the FIRST compile of the process
@@ -46,9 +47,11 @@ import jax
 
 logger = logging.getLogger(__name__)
 
-DEFAULT_CACHE_DIR = "/mnt/pvc/xla_cache"
-_LOCAL_FALLBACK = os.path.join(
-    os.path.expanduser("~"), ".cache", "gke_ray_train_tpu", "xla_cache")
+# where the cache lives when nothing names a directory: a fixed path
+# inside the checkout (listed in .gitignore)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 # hit/miss counters fed by jax.monitoring events — the same counters
 # the cache-hit tests assert on (ISSUE 4 satellite).
@@ -76,19 +79,16 @@ def _install_listener() -> None:
     global _LISTENER_INSTALLED
     if _LISTENER_INSTALLED:
         return
-    try:
-        from jax._src import monitoring
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        _LISTENER_INSTALLED = True
-    except Exception as e:  # noqa: BLE001 - private API; counters stay 0
-        logger.warning("compilation-cache counters unavailable (%s: %s)",
-                       type(e).__name__, e)
+    from jax._src import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    _LISTENER_INSTALLED = True
 
 
-def cache_stats() -> Dict[str, float]:
-    """Process-wide persistent-cache counters (hits/misses/seconds)."""
-    return dict(_STATS)
+def cache_stats() -> Dict[str, Any]:
+    """Process-wide persistent-cache counters (hits/misses/seconds) and
+    the directory in use (``dir``; None while disabled)."""
+    return {**_STATS, "dir": _ENABLED_DIR}
 
 
 def log_cache_summary(log: logging.Logger = logger) -> None:
@@ -108,8 +108,8 @@ def cpu_mesh_env(n_devices: int = 8, **extra: str) -> Dict[str, str]:
     """os.environ copy that forces an ``n_devices`` virtual CPU platform
     in a CHILD process (XLA_FLAGS must land before backend init, hence
     re-exec rather than in-process switching). The one canonical recipe
-    shared by the bench's dead-accelerator fallback and the budget CLI —
-    keep it here so the two cannot drift."""
+    shared by the bench's CPU-mesh arms and the budget/analysis CLIs —
+    keep it here so they cannot drift."""
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]
@@ -157,28 +157,41 @@ def topology_fingerprint() -> Tuple[str, Dict[str, Any]]:
     return digest, facts
 
 
+def resolve_cache_dir(cache_dir: Optional[str] = None,
+                      plan=None) -> Tuple[str, bool]:
+    """(directory, placed_from_outside) of the persistent compile cache.
+
+    ``JAX_COMPILATION_CACHE_DIR`` wins over everything — JAX reads it
+    itself, so the program must not name another. Without it: explicit
+    arg → ``plan.compile_cache_dir`` → ``$COMPILE_CACHE_DIR`` →
+    ``<checkout>/.jax_cache``. Pure: no backend, no filesystem, so the
+    answer is the same before and after backend init."""
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if outside:
+        return outside, True
+    return (cache_dir
+            or (plan.compile_cache_dir if plan is not None else None)
+            or os.environ.get("COMPILE_CACHE_DIR")
+            or DEFAULT_CACHE_DIR), False
+
+
 def enable_persistent_cache(cache_dir: Optional[str] = None,
-                            plan=None,
-                            surface: str = "train") -> Optional[str]:
-    """Point JAX's persistent compilation cache at shared storage.
+                            plan=None) -> Optional[str]:
+    """Turn on JAX's persistent compilation cache in the directory
+    :func:`resolve_cache_dir` names, with no subdirectory under it.
 
-    Resolution: explicit arg → ``plan.compile_cache_dir`` →
-    ``$COMPILE_CACHE_DIR`` → the PVC default ``/mnt/pvc/xla_cache``;
-    the actual cache lives in a topology-fingerprint subdir (suffixed
-    with the ExecutionPlan's COMPILE fingerprint when a plan is given —
-    the plan identity subsumes the bare topology fingerprint, so two
-    runs share a subdir only when both the hardware AND the declared
-    compiled program agree; operational knobs like prefetch depth or a
-    guard do not split the cache).
-    ``COMPILE_CACHE=0`` (or ``plan.compile_cache=False``) disables.
-    Unwritable dirs fall back to ``~/.cache/gke_ray_train_tpu`` and
-    then to disabled — never raise.
+    With ``JAX_COMPILATION_CACHE_DIR`` set the directory is JAX's own
+    business and this sets none; it still drops the entry-size and
+    compile-time floors, installs the hit/miss listener and un-memoizes
+    JAX's "is the cache used" verdict. ``COMPILE_CACHE=0`` (or
+    ``plan.compile_cache=False``) makes the program configure nothing.
+    A directory that cannot be created or written raises.
 
-    Safe to call more than once: the entry scripts re-enable after
-    ``distributed_init`` so the fingerprint gains real device facts;
-    a repeat call that resolves to the current dir is a no-op.
+    Safe to call more than once (the trainer enables before the entry
+    script does): a repeat call that resolves to the current dir is a
+    no-op.
 
-    Returns the resolved cache dir, or None when disabled.
+    Returns the cache dir, or None when disabled.
     """
     global _ENABLED_DIR
     if plan is not None and not plan.compile_cache:
@@ -188,38 +201,23 @@ def enable_persistent_cache(cache_dir: Optional[str] = None,
     if os.environ.get("COMPILE_CACHE", "1").lower() in ("0", "false"):
         logger.info("compile cache disabled via COMPILE_CACHE=0")
         return None
-    base = cache_dir \
-        or (plan.compile_cache_dir if plan is not None else None) \
-        or os.environ.get("COMPILE_CACHE_DIR", DEFAULT_CACHE_DIR)
-    digest, facts = topology_fingerprint()
-    if plan is not None:
-        # per-surface compile identity (plan.py): a serving replica's
-        # cache subdir is keyed on the serve fields, a trainer's on the
-        # train fields — retuning one surface's knobs never cold-starts
-        # the other's cache
-        digest = f"{digest}-{plan.compile_fingerprint(surface)[:8]}"
-    resolved = None
-    for candidate in (os.path.join(base, digest),
-                      os.path.join(_LOCAL_FALLBACK, digest)):
-        try:
-            os.makedirs(candidate, exist_ok=True)
-            probe = os.path.join(candidate, ".writable")
-            with open(probe, "w") as f:
-                f.write("1")
-            os.remove(probe)
-            resolved = candidate
-            break
-        except OSError as e:
-            logger.warning("compile cache dir %s unusable (%s); %s",
-                           candidate, e,
-                           "falling back to local cache"
-                           if candidate.startswith(base) else "disabling")
-    if resolved is None:
-        return None
+    resolved, outside = resolve_cache_dir(cache_dir, plan)
     if resolved == _ENABLED_DIR:
         return resolved
+    try:
+        os.makedirs(resolved, exist_ok=True)
+        probe = os.path.join(resolved, f".writable.{os.getpid()}")
+        with open(probe, "w") as f:
+            f.write("1")
+        os.remove(probe)
+    except OSError as e:
+        raise RuntimeError(
+            f"compile cache dir {resolved} is unusable ({e}); name a "
+            "writable one (JAX_COMPILATION_CACHE_DIR / COMPILE_CACHE_DIR) "
+            "or set COMPILE_CACHE=0") from e
 
-    jax.config.update("jax_compilation_cache_dir", resolved)
+    if not outside:
+        jax.config.update("jax_compilation_cache_dir", resolved)
     # persist everything: the whole point is that the NEXT process
     # skips the compile, so entry-size/compile-time floors are off
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
@@ -227,19 +225,14 @@ def enable_persistent_cache(cache_dir: Optional[str] = None,
                       float(os.environ.get("COMPILE_CACHE_MIN_COMPILE_S",
                                            "0")))
     _install_listener()
-    try:
-        # un-memoize is_cache_used: any compile that already ran this
-        # process (state init, a probe) froze the "no cache dir"
-        # verdict; without this reset, late enabling silently no-ops
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception as e:  # noqa: BLE001 - private API drift
-        logger.warning("compilation_cache.reset_cache unavailable (%s); "
-                       "cache may stay off if jit already ran", e)
+    # un-memoize is_cache_used: any compile that already ran this
+    # process (state init, a probe) froze the "no cache dir" verdict;
+    # without this reset, late enabling silently no-ops
+    from jax._src import compilation_cache
+    compilation_cache.reset_cache()
     _ENABLED_DIR = resolved
-    logger.info("persistent compile cache at %s (topology %s)",
-                resolved, facts.get("device_kind")
-                or facts.get("accelerator_type") or "pre-init")
+    logger.info("persistent compile cache at %s%s", resolved,
+                " (JAX_COMPILATION_CACHE_DIR)" if outside else "")
     return resolved
 
 
@@ -325,8 +318,16 @@ def save_executable(compiled, path: str, key: str) -> bool:
     try:
         from jax.experimental import serialize_executable
         payload, in_tree, out_tree = serialize_executable.serialize(compiled)
-        blob = pickle.dumps({"key": key, "payload": payload,
-                             "in_tree": in_tree, "out_tree": out_tree})
+        blob = pickle.dumps({
+            "key": key, "payload": payload,
+            "in_tree": in_tree, "out_tree": out_tree,
+            # the devices the executable runs on, in its own order: a
+            # load without them targets EVERY device of the backend, so
+            # a one-device serve executable on an 8-device host (or a
+            # mesh whose order is not jax.devices() order) would reject
+            # its first call
+            "device_ids": [d.id for d in
+                           compiled.runtime_executable().local_devices()]})
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         tmp = f"{path}.tmp.{os.getpid()}"
         with open(tmp, "wb") as f:
@@ -352,8 +353,10 @@ def load_executable(path: str, key: str):
                         "changed); recompiling", path)
             return None
         from jax.experimental import serialize_executable
+        by_id = {d.id: d for d in jax.devices()}
         return serialize_executable.deserialize_and_load(
-            blob["payload"], blob["in_tree"], blob["out_tree"])
+            blob["payload"], blob["in_tree"], blob["out_tree"],
+            execution_devices=[by_id[i] for i in blob["device_ids"]])
     except Exception as e:  # noqa: BLE001 - fall back to compile
         logger.warning("AOT sidecar %s unusable (%s: %s); recompiling",
                        path, type(e).__name__, e)
@@ -368,17 +371,18 @@ class GuardedStep:
     permanently falls back to the jitted function — a stale sidecar
     costs one retrace, never a crash. ``info`` records the build source
     ("deserialized" | "compiled") and seconds, for the loop's
-    compile-time metrics.
+    compile-time metrics; ``fell_back`` says whether a call ever left
+    the executable for the jitted path.
     """
 
     def __init__(self, compiled, jitted_fn: Callable, info: Dict[str, Any]):
         self._compiled = compiled
         self._jitted = jitted_fn
         self.info = info
-        self._fell_back = compiled is None
+        self.fell_back = compiled is None
 
     def __call__(self, *args):
-        if not self._fell_back:
+        if not self.fell_back:
             try:
                 return self._compiled(*args)
             except Exception as e:  # noqa: BLE001 - classified below
@@ -391,7 +395,7 @@ class GuardedStep:
                 if any(getattr(x, "is_deleted", lambda: False)()
                        for x in jax.tree.leaves(args)):
                     raise
-                self._fell_back = True
+                self.fell_back = True
                 logger.warning(
                     "AOT executable rejected the call (%s: %s); falling "
                     "back to the jitted step (one retrace)",
@@ -456,13 +460,10 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
             _note_cost_report(loaded, plan)
             return GuardedStep(loaded, jitted_fn, info)
     t0 = time.perf_counter()
-    try:
-        compiled = jitted_fn.lower(*args).compile()
-    except Exception as e:  # noqa: BLE001 - abstract args may mismatch
-        logger.warning("%s: AOT build failed (%s: %s); using the plain "
-                       "jitted step", label, type(e).__name__, e)
-        info.update(source="jit-fallback", build_s=0.0)
-        return GuardedStep(None, jitted_fn, info)
+    # a step that cannot be lowered or compiled is an error, not a
+    # reason to fall back: the jitted path would hit the same wall at
+    # its first call, later and with less context
+    compiled = jitted_fn.lower(*args).compile()
     info.update(source="compiled", build_s=time.perf_counter() - t0)
     logger.info("%s: AOT compiled in %.2fs", label, info["build_s"])
     _note_cost_report(compiled, plan)

@@ -65,12 +65,16 @@ _KIND_TO_SPEC = {
 }
 
 
-def chip_spec_for_devices(default: str = "v5e") -> ChipSpec:
+def chip_spec_for_devices() -> ChipSpec:
+    """Spec of the attached device; an unknown ``device_kind`` raises
+    (same rule as ``train.metrics.peak_flops_per_device``)."""
     kind = jax.devices()[0].device_kind.lower()
     for k, spec in sorted(_KIND_TO_SPEC.items(), key=lambda kv: -len(kv[0])):
         if k in kind:
             return CHIP_SPECS[spec]
-    return CHIP_SPECS[default]
+    raise ValueError(
+        f"device_kind {kind!r} matches no chip spec "
+        f"({sorted(_KIND_TO_SPEC)}); add it to {__name__}")
 
 
 COLLECTIVE_KINDS = ("all-reduce", "all-gather", "reduce-scatter",
@@ -769,8 +773,6 @@ def step_cost_report(compiled, *, tokens_per_step: Optional[int] = None,
     report = StepCostReport(n_devices=max(len(jax.devices()), 1),
                             tokens_per_step=tokens_per_step)
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # older jaxlib: one dict per... module
-        ca = ca[0] if ca else {}
     if ca:
         report.flops = float(ca.get("flops", 0.0))
         report.bytes_accessed = float(ca.get("bytes accessed", 0.0))

@@ -45,10 +45,9 @@ class PlanError(ValueError):
     """An ExecutionPlan field failed validation."""
 
 
-# chip counts of the topology presets plancheck verifies against. The
-# real accelerator backend being dark (ROADMAP preamble), these are
-# *declared* shapes — the point is that every one of them is checkable
-# via shape/divisibility arithmetic with zero hardware. cpu-N are the
+# chip counts of the topology presets plancheck verifies against. These
+# are *declared* shapes — every one of them is checkable via
+# shape/divisibility arithmetic with zero hardware. cpu-N are the
 # fake-device CI meshes (save-on-8 → restore-on-4/16 is the static half
 # of elastic resume, ROADMAP #1).
 CHIP_COUNTS: Dict[str, int] = {
@@ -84,8 +83,9 @@ SPEC_DRAFT_MODES = ("none", "self", "distilled")
 # grad reduces into async start/done pairs and schedules independent
 # compute into their windows — the budget fields overlap_stats pins.
 # TPU-only: other backends reject the flag names outright, so
-# overlap_compiler_options() gates on the attached backend and the
-# compile falls back to plain flags when a backend refuses them.
+# overlap_compiler_options() gates on the attached backend. A name the
+# installed TPU compiler rejects fails the compile; it is removed from
+# this table, never caught.
 # python bools, NOT "true" strings: jaxlib's option parser accepts
 # bool values / "True" but rejects lowercase "true" with
 # INVALID_ARGUMENT at compile time
@@ -105,14 +105,8 @@ def overlap_compiler_options(plan: "ExecutionPlan"
     attached backend is not a TPU (the flags are TPU-scheduler knobs;
     XLA:CPU rejects unknown option names, and the CPU-mesh program is
     the bitwise baseline either way)."""
-    if plan.overlap != "xla":
-        return None
-    import jax
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 - dead backend: plain compile
-        return None
-    if backend != "tpu":
+    from gke_ray_train_tpu.parallel.mesh import on_tpu
+    if plan.overlap != "xla" or not on_tpu():
         return None
     return dict(XLA_OVERLAP_OPTIONS)
 
@@ -1058,10 +1052,8 @@ def compile_step_with_plan(plan: ExecutionPlan, mesh, fn: Callable,
         if opts is not None:
             # overlap="xla" on a TPU backend: the latency-hiding
             # scheduler flags ride the jit params into every
-            # lower().compile() of this step. A backend that refuses a
-            # flag fails at compile time — fall back to plain flags
-            # there rather than here (the refusal message names the
-            # flag; swallowing it pre-compile would hide WHICH one).
+            # lower().compile() of this step. A compiler that refuses a
+            # flag fails the compile, naming it.
             kw["compiler_options"] = opts
         fn = jax.jit(fn, donate_argnums=argnums, **kw)
         try:

@@ -270,8 +270,7 @@ def _run_worker(fn: Callable, config: dict, env: Dict[str, str],
     # static — no backend is touched before distributed_init. Under an
     # elastic pool override the plan is re-resolved on the survivors
     # (the entry/worker fn does the same via rayint/elastic.py), so the
-    # logged identity — and the compile-cache namespace — match what
-    # the attempt actually compiles.
+    # logged identity matches what the attempt actually compiles.
     # snapshot the tuned-overlay env keys BEFORE maybe_apply below can
     # export an entry's flash blocks — the finally must restore the
     # PRE-attempt values, or a dropped overlay's env leaks into a later
@@ -298,8 +297,7 @@ def _run_worker(fn: Callable, config: dict, env: Dict[str, str],
         # so the registry lookup keys on the topology this attempt
         # actually runs — a reshard re-keys (usually a miss) instead of
         # a stale 8-device tune riding a 4-device attempt. Loud apply,
-        # loud refusal; the cache enable below then namespaces by the
-        # TUNED plan's compile fingerprint.
+        # loud refusal.
         if plan.autotune:
             from gke_ray_train_tpu.autotune.registry import maybe_apply
             plan, _ = maybe_apply(plan, config=config, log=logger)
@@ -323,9 +321,8 @@ def _run_worker(fn: Callable, config: dict, env: Dict[str, str],
                  pool=os.environ.get("ELASTIC_N_DEVICES"))
     # compile-once across restarts: every attempt (and every retry of a
     # preempted worker) reuses the persistent XLA cache instead of
-    # paying a full recompile. Config-only here — the backend must not
-    # initialize before distributed_init; the entry scripts re-enable
-    # after it so the cache dir gains the real topology fingerprint.
+    # paying a full recompile. Config-only — the backend must not
+    # initialize before distributed_init.
     enable_persistent_cache(plan=plan)
     # KERNELCHECK (config key wins over env, like every knob): export
     # the resolved value so run_training's attempt-start probe sees it

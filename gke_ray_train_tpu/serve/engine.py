@@ -136,8 +136,22 @@ def _resolve_lora(state: Dict[str, Any], lora: Any, pool: bool) -> Any:
     return {"aslot": state["aslot"], "blocks": lora}
 
 
+def params_mesh(params: Any):
+    """The mesh ``params`` are sharded over, or None when every leaf
+    sits on one device — read off the arrays, so an engine handed
+    mesh-placed weights (the post-train smoke on a multi-chip host)
+    needs no extra argument."""
+    from jax.sharding import NamedSharding
+    for leaf in jax.tree.leaves(params):
+        sharding = getattr(leaf, "sharding", None)
+        if isinstance(sharding, NamedSharding) and sharding.mesh.size > 1:
+            return sharding.mesh
+    return None
+
+
 def make_prefill_fn(cfg: ModelConfig, *, lora_scale: float = 1.0,
-                    draft_cfg: Optional[ModelConfig] = None) -> Callable:
+                    draft_cfg: Optional[ModelConfig] = None,
+                    mesh=None) -> Callable:
     """``prefill_step(params, prompt[1, L], prompt_len[1], lora) ->
     (first_tok[1], cache_row)`` — full-bucket-width prefill with lens=0:
     garbage K/V past the prompt sit at positions strictly above every
@@ -152,7 +166,7 @@ def make_prefill_fn(cfg: ModelConfig, *, lora_scale: float = 1.0,
         cache = init_cache(cfg, B, L)
         logits, cache = forward_step(
             params, prompt, cfg, cache, jnp.zeros((B,), jnp.int32),
-            lora=lora, lora_scale=lora_scale)
+            lora=lora, lora_scale=lora_scale, mesh=mesh)
         idx = jnp.clip(prompt_len - 1, 0, L - 1)
         first = jnp.argmax(
             jnp.take_along_axis(logits, idx[:, None, None],
@@ -173,7 +187,7 @@ def make_prefill_fn(cfg: ModelConfig, *, lora_scale: float = 1.0,
         # target disposes; only its K/V matter here
         _, dcache = forward_step(
             draft_params, prompt, draft_cfg, dcache,
-            jnp.zeros((B,), jnp.int32))
+            jnp.zeros((B,), jnp.int32), mesh=mesh)
         return first, cache, dcache
     return spec_prefill_step
 
@@ -448,7 +462,8 @@ class BatchEngine:
         self._heartbeat = heartbeat_fn
         dcfg = self._draft[1] if self._draft else None
         self._prefill_fn = make_prefill_fn(cfg, lora_scale=lora_scale,
-                                           draft_cfg=dcfg)
+                                           draft_cfg=dcfg,
+                                           mesh=params_mesh(self.params))
         if self._draft is not None:
             self._decode_fn = make_spec_decode_fn(
                 cfg, dcfg, self.eos_ids, self.plan.spec_k,
@@ -998,8 +1013,9 @@ def post_train_smoke(params: Any, cfg: ModelConfig,
     continuous-batching engine on the just-trained weights (train →
     serve on the same process, ROADMAP #2's loop closed end to end).
     Returns (completions, stats), or None — with a loud warning — when
-    no declared bucket fits the model or no prompt is usable; a smoke
-    must degrade, not kill a finished training run."""
+    no declared bucket fits the model or no prompt is usable. A failure
+    inside the engine raises: a job whose serving path is broken must
+    not exit 0."""
     usable = [b for b in plan.bucket_list() if b <= cfg.max_seq_len]
     if not usable:
         logger.warning(
@@ -1030,29 +1046,23 @@ def post_train_smoke(params: Any, cfg: ModelConfig,
     lora_kw: Dict[str, Any] = {"lora": lora, "lora_scale": lora_scale}
     if lora is not None and any(t is not None for t in tags):
         from gke_ray_train_tpu.serve.adapters import AdapterPool
-        pool = AdapterPool.from_template(
-            lora, max_adapters=max(plan.max_adapters,
-                                   len({t for t in tags if t})))
-        for aid in sorted({t for t in tags if t}):
+        tenants = sorted({t for t in tags if t})
+        # sized to the tenants this smoke registers, not to
+        # plan.max_adapters: the trained state is still resident, and
+        # 8 spare slots of an r=64 Llama-3.1-8B adapter are 5 GB
+        pool = AdapterPool.from_template(lora, max_adapters=len(tenants))
+        for aid in tenants:
             pool.register(aid, lora)
         lora_kw = {"adapters": pool, "lora_scale": lora_scale}
     elif lora is None:
         tags = [None] * len(prompts)
     t0 = time.perf_counter()
-    try:
-        engine = BatchEngine(params, cfg, plan=plan, eos_ids=eos_ids,
-                             **lora_kw)
-        comps = engine.run_until_drained([
-            Request(rid=f"smoke{i}", token_ids=p,
-                    max_new_tokens=max_new_tokens, adapter_id=tags[i])
-            for i, p in enumerate(prompts)])
-    except Exception:  # noqa: BLE001 - the degrade contract below
-        # the whole point of this hook is "degrade, not kill": the
-        # training run already SUCCEEDED — a serving-smoke failure is
-        # loud telemetry, never a job failure
-        logger.warning("SERVE_AFTER_TRAIN failed; training output is "
-                       "unaffected", exc_info=True)
-        return None
+    engine = BatchEngine(params, cfg, plan=plan, eos_ids=eos_ids,
+                         **lora_kw)
+    comps = engine.run_until_drained([
+        Request(rid=f"smoke{i}", token_ids=p,
+                max_new_tokens=max_new_tokens, adapter_id=tags[i])
+        for i, p in enumerate(prompts)])
     stats = engine.stats()
     stats["wall_s"] = round(time.perf_counter() - t0, 3)
     stats["generated_tokens"] = int(

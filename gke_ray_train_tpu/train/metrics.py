@@ -1,7 +1,7 @@
 """Throughput & MFU accounting — first-class, not derived offline.
 
 The reference logs only loss/epoch/LR/step (ray-jobs/pytorch_llm_ray.py:
-287-292) and publishes no perf numbers (BASELINE.md); tokens/sec/chip and
+287-292) and publishes no perf numbers; tokens/sec/chip and
 MFU are this framework's north-star metrics (BASELINE.json) so they are
 computed in the loop from the model's exact FLOP count.
 """
@@ -167,22 +167,17 @@ PEAK_FLOPS = {
 }
 
 
-def peak_flops_per_device(default: float = 197e12) -> float:
+def peak_flops_per_device() -> float:
+    """Peak of the attached device. A ``device_kind`` the table does not
+    know raises: MFU against a guessed roofline is a wrong number that
+    looks like a measurement."""
     kind = jax.devices()[0].device_kind.lower()
     for k, v in sorted(PEAK_FLOPS.items(), key=lambda kv: -len(kv[0])):
         if k in kind:
             return v
-    # an unrecognized device_kind (a future "TPU v7 lite", a GPU) would
-    # silently misreport MFU against the default roofline — say so once
-    # (VERDICT r4 weak #7)
-    import logging
-
-    from gke_ray_train_tpu.logging_utils import warn_once
-    warn_once(logging.getLogger(__name__), ("peak_flops", kind),
-              "device_kind %r matches no PEAK_FLOPS entry; MFU uses the "
-              "default %.0f TFLOP/s roofline and may be wrong — extend "
-              "PEAK_FLOPS in %s", kind, default / 1e12, __name__)
-    return default
+    raise ValueError(
+        f"device_kind {kind!r} matches no PEAK_FLOPS entry "
+        f"({sorted(PEAK_FLOPS)}); add its peak to {__name__}")
 
 
 def train_flops_per_token(cfg: ModelConfig, seq_len: int, *,

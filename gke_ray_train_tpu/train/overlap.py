@@ -53,6 +53,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import jax.tree_util as jtu
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import ModelConfig
@@ -60,7 +61,6 @@ from gke_ray_train_tpu.models.transformer import (
     Params, param_specs, pre_unembed, resolve_seq_impl, run_block_stack,
     unembed_head, _unembed, make_attention_mask)
 from gke_ray_train_tpu.ops.rope import rope_frequencies, sinusoidal_positions
-from gke_ray_train_tpu.ops.smap import shard_map
 
 _DP_AXES = ("data", "fsdp")
 
@@ -99,10 +99,12 @@ def check_manual_support(cfg: ModelConfig, mesh: Optional[Mesh], *,
 
 @jax.custom_vjp
 def _pin(args):
-    """``optimization_barrier`` with a (trivial) VJP — jax 0.4.x defines
-    no AD rule for the primitive. Forward pins the schedule (the
-    prefetched gather is issued before the compute that the barrier
-    releases); the cotangent passes through untouched."""
+    """``optimization_barrier`` with a trivial VJP. Forward pins the
+    schedule (the prefetched gather is issued before the compute that
+    the barrier releases); the cotangent passes through untouched, with
+    no barrier in the backward. jax 0.9 has its own AD rule for the
+    primitive, but that rule barriers the cotangents too — a different
+    backward program from the one the bitwise off/manual tests pin."""
     return jax.lax.optimization_barrier(args)
 
 

@@ -108,16 +108,13 @@ def make_train_state(cfg: ModelConfig, optimizer: optax.GradientTransformation,
 
     Meshed init is SHARDING-INVARIANT: the meshed and plain paths — and
     any two elastic topologies — produce IDENTICAL values from the same
-    key (the pipeline/moe matches-plain oracles rely on it; on jaxlib
-    0.4.x non-partitionable threefry, a jitted draw's values otherwise
-    CHANGE with its out_shardings — the seed-failure kernelcheck's
-    sweeps ran down). Small trees init eagerly and are placed with
-    ``device_put`` — plain-path-identical by construction, and no init
-    program to compile; trees past ``_EAGER_INIT_LIMIT`` (an 8B fp32
-    init must never materialize on one host) take the jitted sharded
-    path under ``sharding_invariant_rng`` (partitionable threefry,
-    scoped — the flag's ~15% generation cost is paid only at a scale
-    where it is noise next to the init itself)."""
+    key (the pipeline/moe matches-plain oracles rely on it; under
+    non-partitionable threefry a jitted draw's values otherwise CHANGE
+    with its out_shardings). Small trees init eagerly and are placed
+    with ``device_put`` — plain-path-identical by construction, and no
+    init program to compile; trees past ``_EAGER_INIT_LIMIT`` (an 8B
+    fp32 init must never materialize on one host) take the jitted
+    sharded path under ``sharding_invariant_rng``."""
     from gke_ray_train_tpu.parallel.sharding import (
         shard_tree, sharding_invariant_rng)
 
@@ -425,8 +422,20 @@ def make_eval_step(cfg: ModelConfig, *, mesh: Optional[Mesh] = None,
 
     if batch_shardings is not None:
         # None = leave the state's shardings to propagate from the args
-        return jax.jit(eval_step,
-                       in_shardings=(None, dict(batch_shardings)))
+        jitted = jax.jit(eval_step,
+                         in_shardings=(None, dict(batch_shardings)))
+
+        def placed_eval_step(state: TrainState, batch: Batch):
+            # host rows are placed before the call: a numpy leaf and an
+            # array on the mesh have different avals (the mesh's axis
+            # types ride in the type), so they would trace twice
+            return jitted(state, {
+                k: v if isinstance(v, jax.Array)
+                else jax.device_put(v, batch_shardings[k])
+                for k, v in batch.items()})
+
+        placed_eval_step.lower = jitted.lower
+        return placed_eval_step
     return jax.jit(eval_step)
 
 

@@ -23,6 +23,7 @@ import json
 import logging
 import os
 import sys
+import types
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -33,12 +34,25 @@ logger = logging.getLogger("fine_tune")
 
 def train_loop_per_worker(config: dict):
     """Runs on every TPU host (same shape as the reference's worker fn,
-    fine_tune_llama_ray.py:198)."""
+    fine_tune_llama_ray.py:198): train/eval/save, then the final
+    export, the optional inference comparison and the optional serving
+    smoke. The four parts are separate functions over one ``run``
+    namespace so a caller with less host RAM or time than the 8B export
+    needs (chip_smoke.py) can run the parts it can afford."""
+    run = train_eval_save(config)
+    export_final(run)
+    compare_inference(run)
+    serve_after_train(run)
+    return run.metrics
+
+
+def train_eval_save(config: dict) -> types.SimpleNamespace:
+    """Everything up to and including ``run_training`` (prefetch, eval,
+    checkpoints). Returns the run's live objects for the parts below."""
     import jax
     import numpy as np
 
-    from gke_ray_train_tpu.ckpt import (
-        CheckpointManager, load_hf_checkpoint, save_hf_checkpoint)
+    from gke_ray_train_tpu.ckpt import CheckpointManager, load_hf_checkpoint
     from gke_ray_train_tpu.data import (
         ByteTokenizer, downsample, load_hf_tokenizer, pad_sft_rows,
         pack_examples, sft_epoch_batches, synthetic_sql_rows,
@@ -52,7 +66,7 @@ def train_loop_per_worker(config: dict):
     from gke_ray_train_tpu.rayint import get_context
     from gke_ray_train_tpu.train import (
         LoraConfig, ThroughputMeter, make_train_state, make_train_step,
-        make_eval_step, merge_lora)
+        make_eval_step)
     from gke_ray_train_tpu.train.loop import run_training
     from gke_ray_train_tpu.train.profiling import (
         apply_debug_flags, profiler_from_config)
@@ -79,9 +93,7 @@ def train_loop_per_worker(config: dict):
     # pool re-resolves the plan on the survivors (data/fsdp reflowed,
     # global batch preserved, budget pin dropped) and the mesh is built
     # on exactly those devices; the checkpoint restore below reshards
-    # from the logical spec. A no-op when ELASTIC is off. Replan BEFORE
-    # enabling the cache — the cache subdir is namespaced by the plan's
-    # compile fingerprint, which must be the survivors'.
+    # from the logical spec. A no-op when ELASTIC is off.
     from gke_ray_train_tpu.rayint.elastic import maybe_replan
     plan, devices = maybe_replan(plan, config=config, log=logger)
     # tuned-plan overlay (autotune/registry.py): with AUTOTUNE=1,
@@ -92,8 +104,8 @@ def train_loop_per_worker(config: dict):
     from gke_ray_train_tpu.autotune.registry import maybe_apply
     plan, _ = maybe_apply(plan, config=config, log=logger)
     # persistent XLA compile cache (perf/cache.py): restarts and peer
-    # hosts reuse the compiled binary; re-enabled post-init so the
-    # cache dir carries the real device-topology fingerprint
+    # hosts reuse the compiled binary. The trainer already enabled it;
+    # the repeat covers a bare call of this function and is a no-op
     from gke_ray_train_tpu.perf.cache import enable_persistent_cache
     enable_persistent_cache(plan=plan)
     mesh = plan.build_mesh(devices)
@@ -426,8 +438,26 @@ def train_loop_per_worker(config: dict):
             config, os.path.join(out_base, "tensorboard"),
             is_host0=ctx.is_host0()),
         is_host0=ctx.is_host0())
+    return types.SimpleNamespace(
+        config=config, plan=plan, mesh=mesh, cfg=cfg, tokenizer=tokenizer,
+        ds_test=ds_test, state=state, metrics=metrics, step_fn=step_fn,
+        ckpt_manager=mgr, ckpt_view=ckpt_view, use_lora=use_lora,
+        lora_cfg=lora_cfg, out_base=out_base, ctx=ctx, n_hosts=n_hosts,
+        have_local=have_local, ckpt_dir=ckpt_dir)
 
-    # ---- save final artifacts (HF layout, §5.4) ----------------------
+
+def export_final(run: types.SimpleNamespace) -> None:
+    """Save the final artifacts in HF layout (§5.4). Single-host LoRA
+    runs merge on the HOST: at 8B that is a ~30 GB fp32 tree plus
+    dequantization temporaries (about 49 GB of host RAM, CHANGES.md
+    PR 21)."""
+    import jax
+
+    from gke_ray_train_tpu.ckpt import CheckpointManager, save_hf_checkpoint
+    from gke_ray_train_tpu.train import merge_lora
+    config, state, cfg, ctx = run.config, run.state, run.cfg, run.ctx
+    use_lora, lora_cfg, out_base = run.use_lora, run.lora_cfg, run.out_base
+    tokenizer, n_hosts = run.tokenizer, run.n_hosts
     if use_lora:
         final_dir = os.path.join(
             out_base, config.get("MERGED_MODEL_SUBDIR_NAME", "merged"))
@@ -484,12 +514,15 @@ def train_loop_per_worker(config: dict):
             from gke_ray_train_tpu.data import save_tokenizer
             save_tokenizer(tokenizer,
                            os.path.join(final_dir + "_orbax", "tokenizer"))
-    if use_lora:
-        # LoRA-mode inference below uses base + adapters, never the
-        # merged tree — release it (the 8B host merge holds ~32 GB)
-        merged = None
 
-    # ---- optional inference comparison (§3.4) ------------------------
+
+def compare_inference(run: types.SimpleNamespace) -> None:
+    """Optional base-vs-tuned inference comparison (§3.4)."""
+    from gke_ray_train_tpu.ckpt import load_hf_checkpoint
+    config, state, cfg, ctx = run.config, run.state, run.cfg, run.ctx
+    use_lora, lora_cfg, out_base = run.use_lora, run.lora_cfg, run.out_base
+    tokenizer, ds_test, mesh = run.tokenizer, run.ds_test, run.mesh
+    have_local, ckpt_dir = run.have_local, run.ckpt_dir
     # COLLECTIVE: every host enters the comparison — the params are
     # mesh-sharded global arrays, so a host-0-only generate would
     # diverge the SPMD program (the reference's rank-0 gate at :381-395
@@ -510,13 +543,13 @@ def train_loop_per_worker(config: dict):
             base_params = tuned_params = state.params
         elif have_local:
             base_params = load_hf_checkpoint(str(ckpt_dir), cfg, mesh=mesh)
-            tuned_params = merged
+            tuned_params = state.params
         else:
             if ctx.is_host0():
                 logger.warning(
                     "full-FT smoke without a pretrained checkpoint: "
                     "comparing tuned model against itself")
-            base_params = tuned_params = merged
+            base_params = tuned_params = state.params
         run_inference_comparison(
             base_params, tuned_params, cfg, tokenizer, ds_test,
             num_samples=int(config.get("NUM_EVAL_SAMPLES_INFERENCE", 2)),
@@ -529,7 +562,20 @@ def train_loop_per_worker(config: dict):
             tuned_lora=state.lora if use_lora else None,
             lora_scale=lora_cfg.scale if use_lora else 1.0)
 
-    # ---- optional post-train serving smoke (serve/, ROADMAP #2) ------
+
+def serve_after_train(run: types.SimpleNamespace):
+    """Optional post-train serving smoke (serve/, ROADMAP #2). Returns
+    what ``post_train_smoke`` returned — (completions, stats) — or None
+    when the smoke is off or was skipped."""
+    import jax
+
+    from gke_ray_train_tpu.data import format_gretel_sql_example
+    from gke_ray_train_tpu.train.tb import writer_from_config
+    config, state, cfg, ctx = run.config, run.state, run.cfg, run.ctx
+    use_lora, lora_cfg, out_base = run.use_lora, run.lora_cfg, run.out_base
+    tokenizer, ds_test, plan = run.tokenizer, run.ds_test, run.plan
+    n_hosts = run.n_hosts
+    out = None
     # train → serve in the same process: the comparison prompts run
     # through the continuous-batching engine on the just-trained
     # weights (LoRA runs serve base + adapters, never a merged tree).
@@ -601,7 +647,7 @@ def train_loop_per_worker(config: dict):
                         w.log_registry(int(jax.device_get(state.step)),
                                        obs_runtime.registry())
                         w.close()
-    return metrics
+    return out
 
 
 if __name__ == "__main__":

@@ -68,8 +68,6 @@ def train_loop_per_worker(config: dict):
     # survivors (data/fsdp reflowed, global batch preserved) and build
     # the mesh on exactly those devices; restore below reshards from
     # the logical spec. A no-op when ELASTIC is off or the pool is full.
-    # Replan BEFORE enabling the cache — the cache subdir is namespaced
-    # by the plan's compile fingerprint, which must be the survivors'.
     from gke_ray_train_tpu.rayint.elastic import maybe_replan
     plan, devices = maybe_replan(plan, config=config, log=logger)
     # tuned-plan overlay (autotune/registry.py): AUTOTUNE=1 overlays a
@@ -79,10 +77,10 @@ def train_loop_per_worker(config: dict):
     # usually misses — the hook logs that loudly rather than guessing.
     from gke_ray_train_tpu.autotune.registry import maybe_apply
     plan, _ = maybe_apply(plan, config=config, log=logger)
-    # persistent XLA compile cache on the shared PVC: the first worker
-    # to compile pays; every restart (and every other host) reuses the
-    # binary. Re-enabled here (the trainer already enabled it pre-init)
-    # so the cache dir carries the real device-topology fingerprint.
+    # persistent XLA compile cache (perf/cache.py): the first worker to
+    # compile pays; every restart (and every other host) reuses the
+    # binary. The trainer already enabled it; the repeat covers a bare
+    # call of this function and is a no-op.
     from gke_ray_train_tpu.perf.cache import enable_persistent_cache
     enable_persistent_cache(plan=plan)
     mesh = plan.build_mesh(devices)

@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
-# Record the BASELINE.md measurement set on the attached TPU chip.
-# Each line of bench output is one JSON record; copy the numbers into
-# BASELINE.md with the exact command that produced them.
+# Run the bench.py measurement set on the attached TPU chip; each line
+# of bench output is one JSON record. Not run on the current
+# installation (CHANGES.md, PR 21); ROADMAP D1 shrinks this to the one
+# benchmark command.
 #
 # Usage: bash scripts/record_baselines.sh [outfile]
 set -uo pipefail
@@ -109,7 +110,7 @@ run obs-diff       python -m gke_ray_train_tpu.obs diff "$OBS_ELASTIC_DIR" \
 # close the loop (ISSUE 16): fold the elastic drill's observed
 # telemetry back into the autotune registry. `ingest` matches each
 # bench/goodput record to a registry arm by plan fingerprint under the
-# surface/chip/backend refusal gates (a cpu-fallback run can NEVER
+# surface/chip/backend refusal gates (a CPU run can NEVER
 # calibrate a TPU entry; rc=3 just means nothing matched this dir —
 # not a failure on a fresh registry), then `calibrate` re-fits the
 # per-chip correction factors from everything observed so far. A

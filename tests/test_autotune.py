@@ -703,10 +703,7 @@ def test_ingest_refusal_matrix(fixture_registry):
         {**row, "topology": "cpu-4"}, entry)
     assert "no backend stamp" in registry._row_refusal(
         {**row, "backend": None}, entry)
-    # cpu-fallback measurements are fine against the CPU ChipSpec...
-    assert registry._row_refusal(
-        {**row, "backend": "cpu-fallback"}, entry) is None
-    # ...but a real-backend number is not evidence about the CPU spec
+    # a real-backend number is not evidence about the CPU spec
     assert "does not describe" in registry._row_refusal(
         {**row, "backend": "tpu"}, entry)
     # THE gate, inverted: host numbers can never calibrate a TPU entry
@@ -714,7 +711,7 @@ def test_ingest_refusal_matrix(fixture_registry):
     v5e["topology"] = "v5e-8"
     v5e["fingerprint_inputs"]["chip"] = "v5e"
     tpu_row = {"surface": "train", "topology": "v5e-8",
-               "chip_family": "v5e", "backend": "cpu-fallback"}
+               "chip_family": "v5e", "backend": "cpu"}
     assert "can NEVER calibrate" in registry._row_refusal(tpu_row, v5e)
     # an unknown chip family is host evidence (scored as cpu), so it
     # is chip-family-refused against the v5e entry too
@@ -741,12 +738,12 @@ def test_ingest_refusal_matrix(fixture_registry):
     assert main(["calibrate", "--dir", fixture_registry]) == 3
 
 
-def test_cpu_fallback_never_calibrates_tpu_entry(fixture_registry,
-                                                 tmp_path, capsys):
+def test_cpu_rows_never_calibrate_tpu_entry(fixture_registry,
+                                            tmp_path, capsys):
     """The satellite-3 regression, full-ingest path: re-key the
     fixture entry as a v5e tune, measure the SAME fingerprints on a
-    cpu-fallback host — ingest must refuse every row (rc 4) and the
-    entry must gain zero observed columns."""
+    CPU host — ingest must refuse every row (rc 4) and the entry must
+    gain zero observed columns."""
     from gke_ray_train_tpu.autotune.__main__ import main
     path, entry = _one_entry(fixture_registry)
     entry["topology"] = "v5e-8"
@@ -756,9 +753,9 @@ def test_cpu_fallback_never_calibrates_tpu_entry(fixture_registry,
     _rewrite_entry(path.replace("cpu-8", "v5e-8"), entry)
     with open(os.path.join(OBS_GOOD, "bench_records.jsonl")) as f:
         rec = json.loads(f.readline())
-    rec["backend"] = "cpu-fallback"
+    rec["backend"] = "cpu"
     rec["topology"] = "v5e-8"
-    obs = tmp_path / "obs_fallback"
+    obs = tmp_path / "obs_cpu"
     obs.mkdir()
     (obs / "bench_records.jsonl").write_text(json.dumps(rec) + "\n")
     assert main(["ingest", str(obs), "--dir", fixture_registry]) == 4

@@ -151,7 +151,7 @@ def test_hier_psum_vjp_identity():
     reduction (which would cost the bitwise contract)."""
     from jax.sharding import PartitionSpec as P
 
-    from gke_ray_train_tpu.ops.smap import shard_map
+    from jax import shard_map
     from gke_ray_train_tpu.parallel.hierarchical import (
         SliceTopology, hier_psum)
 

@@ -57,7 +57,7 @@ def test_shard_tree(tp_mesh):
 
 def test_psum_over_mesh(dp_mesh):
     """A real collective on the fake mesh: mean over data axis."""
-    from gke_ray_train_tpu.ops.smap import shard_map
+    from jax import shard_map
 
     def f(x):
         return jax.lax.pmean(x, "data")

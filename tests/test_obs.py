@@ -981,7 +981,7 @@ def test_observed_runs_extraction_and_determinism(tmp_path):
 def test_observed_backend_never_inferred(tmp_path):
     """No first_step backend stamp -> backend stays None. The
     extraction NEVER guesses: ingest refuses None-backend rows, which
-    is the first half of the cpu-fallback-never-calibrates-a-TPU
+    is the first half of the CPU-rows-never-calibrate-a-TPU
     guarantee (the other half is the registry's backend gate)."""
     from gke_ray_train_tpu.obs.observe import observed_runs
     _synthetic_session(str(tmp_path), backend=None)
@@ -999,7 +999,7 @@ def test_report_backend_and_autotune_drift_section(tmp_path):
     from gke_ray_train_tpu.obs.report import build_report, render_text
     log = EventLog(events_path(str(tmp_path), 0), run_id="r",
                    attempt=1, rank=0)
-    log.emit("first_step", compile_s=1.0, backend="cpu-fallback")
+    log.emit("first_step", compile_s=1.0, backend="cpu")
     log.emit("worker_exit", status="ok",
              goodput={"compile_s": 1.0, "step_s": 3.0, "wall_s": 4.0})
     log.emit("autotune_drift", key="train-cpu-8-abc", arm="tuned",
@@ -1008,13 +1008,13 @@ def test_report_backend_and_autotune_drift_section(tmp_path):
              stale=True)
     log.close()
     rep = build_report(str(tmp_path))
-    assert rep["backend"] == "cpu-fallback"
+    assert rep["backend"] == "cpu"
     at = rep["autotune"]
     assert at["drift_events"] == 1 and at["drift_stale"] == 1
     assert at["drift_max_rel_err"] == 0.8 and at["drift_band"] == 0.25
     assert at["drift_keys"] == ["train-cpu-8-abc"]
     txt = render_text(rep)
-    assert "backend: cpu-fallback" in txt
+    assert "backend: cpu" in txt
     assert "1 STALE" in txt
     flat = flatten_report(rep)
     assert flat["autotune_drift_events"] == 1.0

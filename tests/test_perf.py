@@ -43,12 +43,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture
 def cache_sandbox(tmp_path, monkeypatch):
-    """Route the persistent cache (and its local fallback) into tmp and
-    restore JAX's global cache config afterwards — these tests mutate
-    process-wide state the rest of the suite must not inherit."""
-    monkeypatch.setattr(perf_cache, "_LOCAL_FALLBACK",
-                        str(tmp_path / "local_fallback"))
+    """Route the persistent cache into tmp and restore JAX's global
+    cache config afterwards — these tests mutate process-wide state the
+    rest of the suite must not inherit."""
     monkeypatch.setattr(perf_cache, "_ENABLED_DIR", None)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("COMPILE_CACHE_DIR", raising=False)
     # conftest disables the cache suite-wide (no persistent writes from
     # ordinary tests); these tests opt back in, sandboxed
     monkeypatch.setenv("COMPILE_CACHE", "1")
@@ -81,8 +81,8 @@ def test_cache_hit_second_build_compiles_nothing(cache_sandbox, fsdp_mesh):
     config costs zero new XLA compilations — every compile is a
     persistent-cache hit (JAX's own miss counters are the witness)."""
     enabled = enable_persistent_cache(str(cache_sandbox / "cache"))
-    assert enabled is not None and enabled.startswith(
-        str(cache_sandbox / "cache"))
+    # the directory itself, no fingerprint subdirectory under it
+    assert enabled == str(cache_sandbox / "cache")
     # drop in-memory executables BEFORE the cold build: helpers compiled
     # by earlier tests would otherwise be reused (and never persisted to
     # this fresh cache dir), then MISS on the rebuild below
@@ -98,15 +98,62 @@ def test_cache_hit_second_build_compiles_nothing(cache_sandbox, fsdp_mesh):
         "identical rebuild performed NEW compilations — persistent cache "
         f"missed ({s2['misses'] - s1['misses']} misses)")
     assert s2["hits"] > s1["hits"]
+    assert s2["dir"] == enabled and os.listdir(enabled)
     # and the cache-built executable actually runs
     _, m = c2(state, batch)
     assert np.isfinite(float(jax.device_get(m["loss"])))
 
 
-def test_enable_falls_back_to_local_dir_when_unwritable(cache_sandbox):
-    got = enable_persistent_cache("/proc/definitely/not/writable")
-    assert got is not None
-    assert got.startswith(str(cache_sandbox / "local_fallback"))
+def test_unusable_cache_dir_raises(cache_sandbox):
+    """No fallback to a home directory and no silent disable: a cache
+    directory that cannot be written is an error."""
+    with pytest.raises(RuntimeError, match="unusable"):
+        enable_persistent_cache("/proc/definitely/not/writable")
+    assert cache_stats()["dir"] is None
+
+
+def test_cache_dir_from_outside_wins_and_is_not_set_in_code(
+        cache_sandbox, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR is JAX's to read: it beats the explicit
+    argument, the plan's COMPILE_CACHE_DIR and the env key, gets no
+    subdirectory, and the program never names a directory itself."""
+    from gke_ray_train_tpu.plan import ExecutionPlan
+    outside = str(cache_sandbox / "placed_from_outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    monkeypatch.setenv("COMPILE_CACHE_DIR", str(cache_sandbox / "env_key"))
+    plan = ExecutionPlan.from_config(
+        {"COMPILE_CACHE_DIR": str(cache_sandbox / "config_key")})
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1])
+    got = enable_persistent_cache(str(cache_sandbox / "argument"),
+                                  plan=plan)
+    assert got == outside and os.path.isdir(outside)
+    assert "jax_compilation_cache_dir" not in updates
+    # the floors are still dropped and the counters still installed
+    assert "jax_persistent_cache_min_entry_size_bytes" in updates
+    assert cache_stats()["dir"] == outside
+    assert not os.path.exists(cache_sandbox / "argument")
+
+
+def test_default_cache_dir_is_fixed_inside_the_checkout(cache_sandbox,
+                                                        monkeypatch):
+    """With nothing named anywhere the cache lives at <checkout>/
+    .jax_cache, and the answer does not move when the backend comes up
+    (it used to carry a topology digest that did)."""
+    want = os.path.join(REPO, ".jax_cache")
+    for backend_up in (False, True):
+        monkeypatch.setattr(perf_cache, "_backend_initialized",
+                            lambda up=backend_up: up)
+        assert perf_cache.resolve_cache_dir() == (want, False)
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+    # resolution order below the outside variable
+    monkeypatch.setenv("COMPILE_CACHE_DIR", "/env/key")
+    assert perf_cache.resolve_cache_dir() == ("/env/key", False)
+    assert perf_cache.resolve_cache_dir("/argument") == ("/argument", False)
 
 
 def test_enable_respects_kill_switch(cache_sandbox, monkeypatch):
@@ -390,17 +437,17 @@ def test_run_training_reports_compile_metrics():
 
 
 @pytest.mark.slow
-def test_bench_compile_mode_and_cpu_fallback():
-    """Acceptance gate: BENCH_MODE=compile with a DEAD accelerator still
-    exits 0 with one valid JSON record tagged cpu-fallback, warm-cache
-    (or AOT) build under 30% of cold, and a bitwise-equal AOT step."""
+def test_bench_compile_mode_stamps_the_platform_it_ran_on():
+    """BENCH_MODE=compile on the CPU mesh: one valid JSON record whose
+    backend is the platform jax attached (no fallback tag, no fallback
+    reason), warm-cache (or AOT) build under 30% of cold, and a
+    bitwise-equal AOT step."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("BENCH_")}
     # conftest's suite-wide COMPILE_CACHE=0 must not leak into the
     # cache-measuring child
     env.pop("COMPILE_CACHE", None)
-    env.update(GRAFT_FORCE_PROBE="hang", BENCH_MODE="compile",
-               PYTHONPATH=REPO)
+    env.update(BENCH_MODE="compile", PYTHONPATH=REPO)
     r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
                        capture_output=True, text=True, cwd=REPO,
                        timeout=600, env=env)
@@ -409,8 +456,8 @@ def test_bench_compile_mode_and_cpu_fallback():
     assert len(lines) == 1, lines
     rec = json.loads(lines[0])
     assert rec["unit"] != "error" and rec["value"] > 0
-    assert rec["backend"] == "cpu-fallback"
-    assert "fallback_reason" in rec
+    assert rec["backend"] == "cpu"
+    assert "fallback_reason" not in rec
     assert min(rec["warm_frac_of_cold"],
                rec.get("aot_frac_of_cold", 1.0)) < 0.3
     assert rec["aot_loss_bitwise_equal"] is True

@@ -387,7 +387,50 @@ def test_serve_shape_splits_compile_fingerprint():
     assert a.compile_fingerprint("serve") != e.compile_fingerprint("serve")
 
 
-def test_post_train_smoke_runs_and_degrades(setup, caplog):
+def test_flash_prefill_on_mesh_placed_params(setup, fsdp_mesh):
+    """The post-train smoke on a multi-chip host hands the engine
+    weights that are sharded over the training mesh. The engine reads
+    the mesh off the arrays and runs the flash prefill inside a
+    shard_map on it (a compiled Mosaic kernel cannot be partitioned by
+    GSPMD — the first four-chip compile failed on exactly that), and
+    serves the same tokens as with the weights on one device."""
+    import dataclasses
+
+    from gke_ray_train_tpu.models import param_specs
+    from gke_ray_train_tpu.parallel.sharding import shard_tree
+    from gke_ray_train_tpu.serve.engine import params_mesh
+    cfg, params = setup
+    cfg = dataclasses.replace(cfg, attn_impl="flash")
+    placed = shard_tree(params, fsdp_mesh, param_specs(cfg))
+    assert params_mesh(params) is None
+    assert params_mesh(placed) is fsdp_mesh
+    reqs = _requests(cfg, [(9, 6), (40, 5)], seed=3)
+    plan = _plan(max_batch=2)
+    want = BatchEngine(params, cfg, plan=plan,
+                       eos_ids=(EOS,)).run_until_drained(reqs)
+    got = BatchEngine(placed, cfg, plan=plan, eos_ids=(EOS,)
+                      ).run_until_drained(
+        [dataclasses.replace(r) for r in reqs])
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a.tokens, b.tokens)
+
+
+def test_post_train_smoke_reraises_an_engine_failure(setup, monkeypatch):
+    """A failure inside the engine is the job's failure: the smoke used
+    to log it and return None, and the job exited 0."""
+    cfg, params = setup
+
+    def boom(self, requests=()):
+        raise RuntimeError("decode executable failed to compile")
+
+    monkeypatch.setattr(BatchEngine, "run_until_drained", boom)
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        post_train_smoke(params, cfg, _plan(),
+                         [np.arange(1, 9, dtype=np.int32)],
+                         eos_ids=(EOS,), max_new_tokens=4)
+
+
+def test_post_train_smoke_runs_and_skips_loudly(setup, caplog):
     cfg, params = setup
     out = post_train_smoke(
         params, cfg, _plan(),

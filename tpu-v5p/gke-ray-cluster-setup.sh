@@ -3,7 +3,7 @@
 # generation dir, mirroring the reference's a3-ultra variant of the same
 # runbook (reference: a3-ultra/gke-ray-cluster-setup.sh). v5p is the
 # high-HBM generation (95 GB/chip): the target here is the Llama-3-70B
-# GSPMD TP+DP fine-tune (BASELINE.md config 3), which needs tensor
+# GSPMD TP+DP fine-tune (BASELINE.json config 3), which needs tensor
 # parallelism across chips — MESH_MODEL>1 in fine_tune_config.json.
 #
 # Topology 2x2x4 = 16 chips on ct5p-hightpu-4t hosts (4 chips each) →
