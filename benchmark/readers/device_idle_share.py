@@ -1,0 +1,9 @@
+"""1 - (union of device-op intervals) / traced window, averaged over the
+chips used."""
+
+
+def read(facts):
+    trace = facts.get("trace")
+    if not trace or not trace.get("window_s") or not trace.get("busy_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
