@@ -18,8 +18,8 @@ import logging
 
 import optax
 
-from gke_ray_train_tpu.train.optim import make_optimizer, \
-    warmup_cosine_schedule
+from gke_ray_train_tpu.train.optim import (
+    clip_by_global_norm, make_optimizer, warmup_cosine_schedule)
 
 logger = logging.getLogger(__name__)
 
@@ -214,11 +214,11 @@ def optimizer_from_config(config: dict, schedule) -> \
     if "adamw" in name or name == "adam":
         return make_optimizer(schedule, weight_decay=wd, clip_norm=clip)
     if "adafactor" in name:
-        return optax.chain(optax.clip_by_global_norm(clip),
+        return optax.chain(clip_by_global_norm(clip),
                            optax.adafactor(learning_rate=schedule,
                                            weight_decay_rate=wd or None))
     if name == "sgd":
-        return optax.chain(optax.clip_by_global_norm(clip),
+        return optax.chain(clip_by_global_norm(clip),
                            optax.sgd(schedule, momentum=0.9))
     logger.warning("OPTIM=%r not recognized; using adamw", name)
     return make_optimizer(schedule, weight_decay=wd, clip_norm=clip)

@@ -52,6 +52,11 @@ Shared contract of :class:`Prefetcher` and :class:`SyncBatchSource`
 - **clean shutdown**: ``close()`` stops the workers and joins them;
   epoch-boundary exhaustion drains and joins automatically.
 
+Both stages are regions of obs/trace.py (``batch_next``: the epoch
+iterator's ``next``; ``batch_place``: ``place_fn``), on whichever thread
+runs them, so a profiler window shows what the input pipeline did while
+the device waited.
+
 Determinism: ticket-ordered delivery means a prefetched run consumes
 the identical batch stream — losses are bitwise identical to the
 synchronous path (pinned by tests/test_prefetch.py).
@@ -62,6 +67,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Callable, Dict, Iterable, Optional
+
+from gke_ray_train_tpu.obs import trace
 
 
 class _Failure:
@@ -95,13 +102,15 @@ class SyncBatchSource:
         t0 = time.perf_counter()
         try:
             while True:
-                batch = next(self._it)
+                with trace.region("batch_next"):
+                    batch = next(self._it)
                 self.yielded += 1
                 if self.skipped < self._skip:
                     self.skipped += 1
                     continue
                 if self._place is not None:
-                    batch = self._place(batch)
+                    with trace.region("batch_place"):
+                        batch = self._place(batch)
                 return batch
         finally:
             self._wait += time.perf_counter() - t0
@@ -161,7 +170,8 @@ class Prefetcher:
                 if self._exhausted:
                     return
                 try:
-                    batch = next(self._it)
+                    with trace.region("batch_next"):
+                        batch = next(self._it)
                 except StopIteration:
                     self._exhausted = True
                     self._finish(self._next_ticket)
@@ -188,8 +198,11 @@ class Prefetcher:
             if self._stop.is_set():
                 return
             try:
-                item = self._place(batch) if self._place is not None \
-                    else batch
+                if self._place is not None:
+                    with trace.region("batch_place"):
+                        item = self._place(batch)
+                else:
+                    item = batch
             except BaseException as e:  # noqa: BLE001 - consumer raises
                 item = _Failure(e)
             self._deliver(ticket, item)

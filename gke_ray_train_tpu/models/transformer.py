@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.obs.trace import scope
 from gke_ray_train_tpu.ops.attention import (
     dot_product_attention, make_attention_mask)
 from gke_ray_train_tpu.ops.norms import rms_norm
@@ -192,28 +193,34 @@ def _proj(x, w, lora_p, lora_scale, dtype, drop_rng=None, drop_rate=0.0,
     # local import: ops.quant -> train.lora -> models.transformer is a
     # module-level chain, so this reverse edge must stay deferred
     from gke_ray_train_tpu.ops.quant import maybe_dequantize
-    y = jnp.einsum("bsd,dh->bsh", x, maybe_dequantize(w, dtype))
+    with scope("base"):
+        y = jnp.einsum("bsd,dh->bsh", x, maybe_dequantize(w, dtype))
     if lora_p is not None:
-        xl = x
-        if drop_rng is not None and drop_rate > 0.0:
-            keep = 1.0 - drop_rate
-            mask = jax.random.bernoulli(drop_rng, keep, x.shape)
-            xl = jnp.where(mask, x / keep, jnp.zeros((), dtype)).astype(dtype)
-        if lora_p["a"].ndim == 3:
-            # per-row adapters, already gathered from a stacked
-            # multi-tenant pool ([B, d_in, r] / [B, r, d_out]) — the
-            # serving engine's batched multi-LoRA path
-            from gke_ray_train_tpu.ops.lora_batched import bgmv
-            y = y + bgmv(xl, lora_p["a"], lora_p["b"],
-                         scale=lora_scale, dtype=dtype)
-        else:
-            xa = jnp.einsum("bsd,dr->bsr", xl, lora_p["a"].astype(dtype))
-            y = y + jnp.einsum("bsr,rh->bsh", xa,
-                               lora_p["b"].astype(dtype)) \
-                * jnp.asarray(lora_scale, dtype)
+        with scope("lora"):
+            y = y + _lora_bypass(x, lora_p, lora_scale, dtype, drop_rng,
+                                 drop_rate)
     if bias is not None:
         y = y + bias.astype(dtype)
     return y
+
+
+def _lora_bypass(x, lora_p, lora_scale, dtype, drop_rng, drop_rate):
+    """The adapter branch of :func:`_proj`: ``scale * (drop(x) @ a) @ b``."""
+    xl = x
+    if drop_rng is not None and drop_rate > 0.0:
+        keep = 1.0 - drop_rate
+        mask = jax.random.bernoulli(drop_rng, keep, x.shape)
+        xl = jnp.where(mask, x / keep, jnp.zeros((), dtype)).astype(dtype)
+    if lora_p["a"].ndim == 3:
+        # per-row adapters, already gathered from a stacked
+        # multi-tenant pool ([B, d_in, r] / [B, r, d_out]) — the
+        # serving engine's batched multi-LoRA path
+        from gke_ray_train_tpu.ops.lora_batched import bgmv
+        return bgmv(xl, lora_p["a"], lora_p["b"], scale=lora_scale,
+                    dtype=dtype)
+    xa = jnp.einsum("bsd,dr->bsr", xl, lora_p["a"].astype(dtype))
+    return jnp.einsum("bsr,rh->bsh", xa, lora_p["b"].astype(dtype)) \
+        * jnp.asarray(lora_scale, dtype)
 
 
 def _lora_entry(lora_p, name):
@@ -253,18 +260,21 @@ def _mlp(x, lp, cfg: ModelConfig, dtype, lora_p=None, lora_scale=1.0,
          drop_rng=None, drop_rate=0.0):
     def lr(name):
         return _lora_entry(lora_p, name)
-    gate = _proj(x, lp["w_gate"], lr("w_gate"), lora_scale, dtype,
-                 _drop_key(drop_rng, 4), drop_rate)
-    up = _proj(x, lp["w_up"], lr("w_up"), lora_scale, dtype,
-               _drop_key(drop_rng, 5), drop_rate)
-    if cfg.activation == "silu":
-        act = jax.nn.silu(gate)
-    elif cfg.activation == "gelu_tanh":
-        act = jax.nn.gelu(gate, approximate=True)
-    else:
-        raise ValueError(f"unknown activation {cfg.activation}")
-    return _proj(act * up, lp["w_down"], lr("w_down"), lora_scale, dtype,
-                 _drop_key(drop_rng, 6), drop_rate)
+    with scope("mlp/gate_up"):
+        gate = _proj(x, lp["w_gate"], lr("w_gate"), lora_scale, dtype,
+                     _drop_key(drop_rng, 4), drop_rate)
+        up = _proj(x, lp["w_up"], lr("w_up"), lora_scale, dtype,
+                   _drop_key(drop_rng, 5), drop_rate)
+        if cfg.activation == "silu":
+            act = jax.nn.silu(gate)
+        elif cfg.activation == "gelu_tanh":
+            act = jax.nn.gelu(gate, approximate=True)
+        else:
+            raise ValueError(f"unknown activation {cfg.activation}")
+        h = act * up
+    with scope("mlp/down"):
+        return _proj(h, lp["w_down"], lr("w_down"), lora_scale, dtype,
+                     _drop_key(drop_rng, 6), drop_rate)
 
 
 def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
@@ -276,37 +286,41 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
 
     def lr(name):
         return _lora_entry(lora_p, name)
-    q = _proj(x, lp["wq"], lr("wq"), lora_scale, dtype,
-              _drop_key(drop_rng, 0), drop_rate, bias=lp.get("bq"))
-    k = _proj(x, lp["wk"], lr("wk"), lora_scale, dtype,
-              _drop_key(drop_rng, 1), drop_rate, bias=lp.get("bk"))
-    v = _proj(x, lp["wv"], lr("wv"), lora_scale, dtype,
-              _drop_key(drop_rng, 2), drop_rate, bias=lp.get("bv"))
-    q = q.reshape(B, S, H, hd)
-    k = k.reshape(B, S, K, hd)
-    v = v.reshape(B, S, K, hd)
-    q = _constrain(q, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
-    k = _constrain(k, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
+    with scope("attn/qkv"):
+        q = _proj(x, lp["wq"], lr("wq"), lora_scale, dtype,
+                  _drop_key(drop_rng, 0), drop_rate, bias=lp.get("bq"))
+        k = _proj(x, lp["wk"], lr("wk"), lora_scale, dtype,
+                  _drop_key(drop_rng, 1), drop_rate, bias=lp.get("bk"))
+        v = _proj(x, lp["wv"], lr("wv"), lora_scale, dtype,
+                  _drop_key(drop_rng, 2), drop_rate, bias=lp.get("bv"))
+        q = q.reshape(B, S, H, hd)
+        k = k.reshape(B, S, K, hd)
+        v = v.reshape(B, S, K, hd)
+        q = _constrain(q, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
+        k = _constrain(k, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
     if rope is not None:
-        q, k = _apply_rope_qk(q, k, positions, rope,
-                              fused_ops=fused_ops, mesh=mesh)
-    if impl == "xla":
-        out = dot_product_attention(
-            q, k, v, mask, scale=cfg.attn_scale,
-            logit_softcap=cfg.attn_softcap)
-    else:
-        # flash (pallas) / ring (context-parallel) kernels take the mask
-        # *inputs*, never a materialized [S, S] mask
-        from gke_ray_train_tpu.ops.dispatch import attention_dispatch
-        out = attention_dispatch(
-            impl, q, k, v,
-            q_positions=positions, kv_positions=positions,
-            q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
-            causal=True, sliding_window=window, scale=cfg.attn_scale,
-            logit_softcap=cfg.attn_softcap, mesh=mesh)
-    out = out.reshape(B, S, H * hd)
-    return _proj(out, lp["wo"], lr("wo"), lora_scale, dtype,
-                 _drop_key(drop_rng, 3), drop_rate)
+        with scope("attn/rope"):
+            q, k = _apply_rope_qk(q, k, positions, rope,
+                                  fused_ops=fused_ops, mesh=mesh)
+    with scope("attn/core"):
+        if impl == "xla":
+            out = dot_product_attention(
+                q, k, v, mask, scale=cfg.attn_scale,
+                logit_softcap=cfg.attn_softcap)
+        else:
+            # flash (pallas) / ring (context-parallel) kernels take the
+            # mask *inputs*, never a materialized [S, S] mask
+            from gke_ray_train_tpu.ops.dispatch import attention_dispatch
+            out = attention_dispatch(
+                impl, q, k, v,
+                q_positions=positions, kv_positions=positions,
+                q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
+                causal=True, sliding_window=window, scale=cfg.attn_scale,
+                logit_softcap=cfg.attn_softcap, mesh=mesh)
+        out = out.reshape(B, S, H * hd)
+    with scope("attn/out"):
+        return _proj(out, lp["wo"], lr("wo"), lora_scale, dtype,
+                     _drop_key(drop_rng, 3), drop_rate)
 
 
 def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
@@ -326,22 +340,27 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
         lo = lora_slice[p] if lora_slice is not None else None
         drng = (jax.random.fold_in(rep_rng, p)
                 if rep_rng is not None else None)
-        h = _rms_norm(x, lp["attn_norm"], eps=eps, scale_plus_one=sp1,
-                      fused_ops=fused_ops, mesh=mesh)
+        with scope("attn_norm"):
+            h = _rms_norm(x, lp["attn_norm"], eps=eps, scale_plus_one=sp1,
+                          fused_ops=fused_ops, mesh=mesh)
         h = _attn(h, lp, cfg, impl, dtype, rope, positions,
                   masks[kind],
                   cfg.sliding_window if kind == "sliding" else None,
                   segment_ids, mesh, lora_p=lo, lora_scale=lora_scale,
                   drop_rng=_drop_key(drng, 0), drop_rate=lora_dropout,
                   fused_ops=fused_ops)
-        if cfg.post_block_norm:
-            h = _rms_norm(h, lp["attn_post_norm"], eps=eps,
-                          scale_plus_one=sp1, fused_ops=fused_ops,
-                          mesh=mesh)
-        x = x + h
-        x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
-        h = _rms_norm(x, lp["mlp_norm"], eps=eps, scale_plus_one=sp1,
-                      fused_ops=fused_ops, mesh=mesh)
+        with scope("attn/out"):
+            # the post-norm and the residual add belong to the output
+            # projection they finish (XLA fuses them into it)
+            if cfg.post_block_norm:
+                h = _rms_norm(h, lp["attn_post_norm"], eps=eps,
+                              scale_plus_one=sp1, fused_ops=fused_ops,
+                              mesh=mesh)
+            x = x + h
+            x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
+        with scope("mlp_norm"):
+            h = _rms_norm(x, lp["mlp_norm"], eps=eps, scale_plus_one=sp1,
+                          fused_ops=fused_ops, mesh=mesh)
         if moe:
             # MoE MLP (ops/moe.py). LoRA adapts attention only on
             # MoE models — there is no single delta-W an adapter
@@ -356,12 +375,13 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
                      lora_scale=lora_scale,
                      drop_rng=_drop_key(drng, 1),
                      drop_rate=lora_dropout)
-        if cfg.post_block_norm:
-            h = _rms_norm(h, lp["mlp_post_norm"], eps=eps,
-                          scale_plus_one=sp1, fused_ops=fused_ops,
-                          mesh=mesh)
-        x = x + h
-        x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
+        with scope("moe/experts" if moe else "mlp/down"):
+            if cfg.post_block_norm:
+                h = _rms_norm(h, lp["mlp_post_norm"], eps=eps,
+                              scale_plus_one=sp1, fused_ops=fused_ops,
+                              mesh=mesh)
+            x = x + h
+            x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
     return x, aux
 
 
@@ -439,18 +459,20 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
     # (~0.1% of an 8B step). The alternatives are worse: replicating the
     # table costs ~1 GB of ICI per step at 8B, and a one-hot-matmul
     # embedding materializes [B,S,V]. Benign — do not "fix" blindly.
-    x = params["embed"].astype(dtype)[tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
-    if cfg.positional == "sinusoidal":
-        table = jnp.asarray(sinusoidal_positions(cfg.max_seq_len, cfg.d_model))
-        x = x + table.astype(dtype)[positions]
-        rope = None
-    else:
-        rope = jnp.asarray(rope_frequencies(
-            cfg.resolved_head_dim, theta=cfg.rope_theta,
-            llama3_scaling=cfg.rope_scaling))
-    x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
+    rope = None
+    with scope("embed"):
+        x = params["embed"].astype(dtype)[tokens]
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
+        if cfg.positional == "sinusoidal":
+            table = jnp.asarray(
+                sinusoidal_positions(cfg.max_seq_len, cfg.d_model))
+            x = x + table.astype(dtype)[positions]
+        else:
+            rope = jnp.asarray(rope_frequencies(
+                cfg.resolved_head_dim, theta=cfg.rope_theta,
+                llama3_scaling=cfg.rope_scaling))
+        x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
 
     pipe_n = 1
     if mesh is not None and "pipe" in mesh.shape:
@@ -545,9 +567,10 @@ def pre_unembed(x, params: Params, cfg: ModelConfig, mesh):
     (but not including) the vocab matmul. The fused cross-entropy path
     (ops/fused_ce.py) takes it together with :func:`unembed_head` so
     the [B, S, vocab] logits never materialize in HBM."""
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
-                 scale_plus_one=cfg.norm_scale_plus_one)
-    return _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
+    with scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
+                     scale_plus_one=cfg.norm_scale_plus_one)
+        return _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
 
 
 def unembed_head(params: Params, cfg: ModelConfig):
@@ -557,11 +580,14 @@ def unembed_head(params: Params, cfg: ModelConfig):
 
 def _unembed(x, params: Params, cfg: ModelConfig, dtype, mesh):
     """Shared tail: final norm → (tied) unembedding → logit softcap."""
-    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
-                 scale_plus_one=cfg.norm_scale_plus_one)
-    logits = jnp.einsum("bsd,dv->bsv", x, unembed_head(params, cfg
-                                                       ).astype(dtype),
-                        preferred_element_type=jnp.float32)
-    if cfg.logit_softcap is not None:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return _constrain(logits, mesh, BATCH_AXES, AXIS_CONTEXT, "model")
+    with scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
+                     scale_plus_one=cfg.norm_scale_plus_one)
+    with scope("unembed"):
+        logits = jnp.einsum("bsd,dv->bsv", x, unembed_head(params, cfg
+                                                           ).astype(dtype),
+                            preferred_element_type=jnp.float32)
+        if cfg.logit_softcap is not None:
+            logits = jnp.tanh(logits / cfg.logit_softcap) \
+                * cfg.logit_softcap
+        return _constrain(logits, mesh, BATCH_AXES, AXIS_CONTEXT, "model")

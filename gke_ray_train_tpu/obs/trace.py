@@ -31,24 +31,45 @@ The span-name vocabulary is CLOSED like the event vocabulary:
 unknown name or stray attribute raises instead of silently orphaning
 ``obs/critical.py``'s term mapping.
 
-Hot-path contract (the obs/ discipline): spans are emitted at the
-boundaries the ledger already times, from host floats the caller
+Hot-path contract (the obs/ discipline): JSONL spans are emitted at
+the boundaries the ledger already times, from host floats the caller
 already measured — never per step (step windows aggregate at the log
 cadence), never with a device fetch of their own. The loss stream with
 TRACE=1 is asserted BITWISE-identical to obs-off.
 
-Stdlib-only (the report/critical-path side runs with no jax).
+The profiler's clock (ISSUE 24). :func:`region` is the ONE way a region
+is opened: it always enters a ``jax.profiler.TraceAnnotation`` named
+``grt:<name>`` (a flag check while no profiler runs), so whatever
+window ``TraceProfiler`` / ``obs/capture.py`` / a benchmark opens holds
+the program's phases on its host plane, and it stamps
+``time.perf_counter()`` endpoints. A finished region goes to the
+bounded in-memory :data:`RECORD` while a profiler is attached to the
+loop or an obs session is active (per-step names live ONLY there), and
+to the JSONL stream under the rule above. The device side of the same
+window is named by :data:`SCOPE_NAMES` (``jax.named_scope`` in the
+model and the step) and :data:`KERNEL_NAMES` (``pl.pallas_call(name=)``);
+:func:`scope_table` reduces a compiled step's HLO text to
+``{instruction name: op_name}`` so a device event can be joined to them.
+
+Stdlib-only at import (the report/critical-path side runs with no
+jax); :func:`region` and :func:`scope` import jax on first use.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import hashlib
+import itertools
 import json
 import logging
 import os
+import re
+import threading
 import time
 import uuid
-from typing import Any, Dict, Iterable, Iterator, List, Optional, Union
+from typing import (
+    Any, Deque, Dict, Iterable, Iterator, List, Optional, Union)
 
 logger = logging.getLogger(__name__)
 
@@ -100,7 +121,61 @@ SPAN_NAMES: Dict[str, tuple] = {
     "serve_enqueue": ("rid",),
     "serve_prefill": ("rid",),
     "serve_decode": ("rid", "iterations"),
+    # loop and build phases on the profiler's clock (:func:`region`).
+    # One iteration of run_training's inner loop and its children, and
+    # the prefetch thread's two stages: PER_STEP_SPANS, in-memory only
+    "step_iter": (),
+    "data_wait": (),
+    "step_dispatch": (),
+    "metrics_fetch": (),
+    "log_emit": (),
+    "batch_next": (),
+    "batch_place": (),
+    # perf/cache.py::build_or_load_step: a handful a process, recorded
+    # always; `source` is "deserialized" | "compiled"
+    "step_build": ("source",),
+    "step_lower": (),
+    "step_compile": (),
 }
+
+# names that never reach the JSONL stream: they end once a step (or
+# once a batch on the prefetch thread), and the stream's contract is
+# the log cadence
+PER_STEP_SPANS = frozenset({
+    "step_iter", "data_wait", "step_dispatch", "metrics_fetch",
+    "log_emit", "batch_next", "batch_place"})
+# names recorded in memory whether or not anything listens: outside
+# every hot path, and the build they time is over before a profiler
+# could be attached
+ALWAYS_RECORDED = frozenset({"step_build", "step_lower", "step_compile"})
+
+# the closed vocabulary of jax.named_scope names on the device ops of
+# the train step (models/transformer.py, ops/moe.py, train/step.py,
+# train/optim.py). A "/" is two nested scopes. `base` / `lora` are the
+# leaf scopes inside `_proj`: the frozen (maybe dequantized) projection
+# against the adapter bypass, whatever module calls it. The phase costs
+# no name: jax writes `jvp(` (forward), `transpose(` (backward) and
+# `rematted_computation` (recomputed forward) into every op_name.
+SCOPE_NAMES = (
+    "embed", "attn_norm", "attn/qkv", "attn/rope", "attn/core",
+    "attn/out", "mlp_norm", "mlp/gate_up", "mlp/down", "moe/route",
+    "moe/experts", "final_norm", "unembed", "loss", "clip", "optimizer",
+    "base", "lora")
+
+# bumped when a scope MOVES without the vocabulary changing: it rides
+# the compile cache's key beside the names (perf/cache.py::names_salt),
+# so that an executable compiled under the old placement is not served
+SCOPE_VERSION = 1
+
+# pl.pallas_call(name=...) of every kernel (ops/flash_attention.py,
+# ops/fused_ce.py, ops/fused_norm_rope.py)
+KERNEL_NAMES = (
+    "flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd", "fused_ce_dx",
+    "fused_ce_dhead", "fused_rmsnorm", "fused_rope_qk",
+    "fused_rmsnorm_rope")
+
+# the profiler's host plane shows a region under this prefix
+ANNOTATION_PREFIX = "grt:"
 
 
 class SpanError(ValueError):
@@ -267,4 +342,220 @@ def check_schema() -> List[str]:
         if tuple(names[k]) != tuple(SPAN_NAMES[k]):
             findings.append(f"schema name {k!r} attrs {names[k]} != "
                             f"code {list(SPAN_NAMES[k])}")
+    for key, code in (("scopes", SCOPE_NAMES), ("kernels", KERNEL_NAMES),
+                      ("per_step", sorted(PER_STEP_SPANS))):
+        if list(doc.get(key, ())) != list(code):
+            findings.append(f"schema {key} {doc.get(key)} != code "
+                            f"{list(code)}")
     return findings
+
+
+# ---------------------------------------------------------------------------
+# regions: the profiler's host plane + the in-memory record
+# ---------------------------------------------------------------------------
+
+class Region:
+    """One open region. ``dur_s`` (settable inside the block) is what
+    the JSONL span carries — the ledger-timed sites set it to the exact
+    float the GoodputLedger booked; unset, it is ``t1 - t0``. ``step``
+    and ``attrs`` may be filled in while the region is open; ``drop``
+    discards it (an iteration that found the stream exhausted)."""
+
+    __slots__ = ("name", "id", "parent", "t0", "t1", "step", "attrs",
+                 "dur_s", "drop")
+
+    def __init__(self, name: str, step: Optional[int],
+                 attrs: Dict[str, Any], parent: Optional[int]):
+        self.name, self.step, self.attrs = name, step, attrs
+        self.id = next(_ids)
+        self.parent = parent
+        self.t0 = self.t1 = 0.0
+        self.dur_s: Optional[float] = None
+        self.drop = False
+
+
+class MemoryRecord:
+    """Finished regions kept in memory ("keep spans in memory and write
+    them out when the benchmark ends"): bounded, appended to from the
+    loop's thread and the prefetch threads (a deque append is atomic),
+    read after the loop. ``attach`` / ``detach`` bracket a
+    ``run_training`` call that was given a profiler object. A span is
+    ``{"name", "id", "parent", "t0", "t1", "step", **attrs}`` with
+    ``time.perf_counter()`` endpoints and the id of the region that was
+    open on the same thread when it opened."""
+
+    def __init__(self, maxlen: int = 65536):
+        self.spans: Deque[Dict[str, Any]] = collections.deque(
+            maxlen=maxlen)
+        # {step label: {instruction name: op_name}} (:func:`scope_table`)
+        self.scope_tables: Dict[str, Dict[str, str]] = {}
+        self.scope_table_s: Dict[str, float] = {}
+        self._attached = 0
+
+    def attach(self) -> None:
+        self._attached += 1
+
+    def detach(self) -> None:
+        self._attached = max(self._attached - 1, 0)
+
+    @property
+    def attached(self) -> bool:
+        return self._attached > 0
+
+    def clear(self) -> None:
+        self.spans.clear()
+        self.scope_tables.clear()
+        self.scope_table_s.clear()
+
+
+# one per process, like the profiler it mirrors
+RECORD = MemoryRecord()
+
+_ids = itertools.count(1)
+_open = threading.local()        # .stack: this thread's open regions
+_annotation = None               # jax.profiler.TraceAnnotation, lazily
+_runtime = None                  # obs.runtime (it imports this module)
+
+
+@contextlib.contextmanager
+def region(name: str, *, step: Optional[int] = None,
+           **attrs: Any) -> Iterator[Region]:
+    """Open one region of the closed vocabulary; the only way one is
+    opened. With no profiler running, none attached and no session, it
+    costs the annotation's flag check and one branch."""
+    global _annotation, _runtime
+    validate_span(name, attrs)
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        from gke_ray_train_tpu.obs import runtime
+        _annotation, _runtime = TraceAnnotation, runtime
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    r = Region(name, step, attrs, stack[-1].id if stack else None)
+    stack.append(r)
+    r.t0 = time.perf_counter()
+    try:
+        with _annotation(ANNOTATION_PREFIX + name):
+            yield r
+    finally:
+        r.t1 = time.perf_counter()
+        stack.pop()
+        run = _runtime.active()
+        if not r.drop and (run is not None or RECORD.attached
+                           or name in ALWAYS_RECORDED):
+            _finish(r, run)
+
+
+def _finish(r: Region, run) -> None:
+    # again: the name may have changed and attributes been added since
+    validate_span(r.name, r.attrs)
+    RECORD.spans.append({"name": r.name, "id": r.id, "parent": r.parent,
+                         "t0": r.t0, "t1": r.t1, "step": r.step,
+                         **r.attrs})
+    if run is not None and r.name not in PER_STEP_SPANS:
+        run.span_add(r.name,
+                     r.t1 - r.t0 if r.dur_s is None else r.dur_s,
+                     step=r.step, **r.attrs)
+
+
+# ---------------------------------------------------------------------------
+# scopes: the device side of the same window
+# ---------------------------------------------------------------------------
+
+def scope(name: str):
+    """``jax.named_scope`` for one name of :data:`SCOPE_NAMES`."""
+    if name not in SCOPE_NAMES:
+        raise SpanError(f"unknown scope name {name!r}; known: "
+                        f"{list(SCOPE_NAMES)}")
+    import jax
+    return jax.named_scope(name)
+
+
+_TRANSFORM = re.compile(r"\w+\(|\)")
+
+
+def scope_path(op_name: str) -> Optional[str]:
+    """The vocabulary names an ``op_name`` lies under, outermost first
+    and joined by ``/`` (``attn/qkv/base``), or None when it holds
+    none. A name counts only as whole path segments; jax wraps the
+    scopes that were open where a transform was applied into it
+    (``transpose(jvp(loss))``), so the wrappers are peeled first."""
+    segs = [x for x in _TRANSFORM.sub("", op_name).split("/") if x]
+    found = []
+    i = 0
+    while i < len(segs):
+        two = "/".join(segs[i:i + 2])
+        if two in SCOPE_NAMES:
+            found.append(two)
+            i += 2
+        else:
+            if segs[i] in SCOPE_NAMES:
+                found.append(segs[i])
+            i += 1
+    return "/".join(found) or None
+
+
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) .*\{\s*$")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_HLO_MATMUL = re.compile(r" (?:convolution|dot)\(")
+
+
+def scope_table(hlo_text: str) -> Dict[str, str]:
+    """``{instruction name: op_name}`` of a compiled executable's
+    optimised HLO text (``compiled.as_text()``), for every instruction
+    outside a fused computation — the names a device event carries. A
+    fusion takes the ``op_name`` of the matmul it holds (``dot`` /
+    ``convolution``) when it holds one, else its own (its root's): the
+    frozen projection fused with the adapter's add is the projection."""
+    own: Dict[str, str] = {}         # instruction -> its own op_name
+    calls: Dict[str, str] = {}       # fusion -> fused computation
+    matmul: Dict[str, str] = {}      # computation -> its first matmul's
+    fused: set = set()               # instructions of fused computations
+    comp = None
+    members: Dict[str, List[str]] = {}
+    for line in hlo_text.splitlines():
+        if comp is None or not line.startswith(" "):
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                comp = m.group(1)
+                members[comp] = []
+                continue
+        m = _HLO_INSTR.match(line)
+        if not m or comp is None:
+            continue
+        name = m.group(1)
+        members[comp].append(name)
+        op = _HLO_OP_NAME.search(line)
+        own[name] = op.group(1) if op else ""
+        if op and comp not in matmul and _HLO_MATMUL.search(line):
+            matmul[comp] = op.group(1)
+        c = _HLO_CALLS.search(line)
+        if c and " fusion(" in line:
+            calls[name] = c.group(1)
+    for target in calls.values():
+        fused.update(members.get(target, ()))
+    return {name: matmul.get(calls.get(name, ""), op) or op
+            for name, op in own.items() if name not in fused}
+
+
+def note_scope_table(label: str, compiled) -> Optional[Dict[str, str]]:
+    """Reduce ``compiled`` (anything with ``as_text()``) to its scope
+    table and keep it, with the seconds it took, in :data:`RECORD`
+    under the step's label. None for an executable that cannot print
+    itself (a deserialized one may not)."""
+    t0 = time.perf_counter()
+    try:
+        text = compiled.as_text()
+    except Exception as e:  # noqa: BLE001 - the profile survives without
+        logger.warning("scope table of %s skipped: %s: %s", label,
+                       type(e).__name__, e)
+        return None
+    table = scope_table(text)
+    RECORD.scope_tables[label] = table
+    RECORD.scope_table_s[label] = time.perf_counter() - t0
+    logger.info("%s: scope table of %d instructions in %.2fs", label,
+                len(table), RECORD.scope_table_s[label])
+    return table

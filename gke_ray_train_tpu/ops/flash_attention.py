@@ -271,6 +271,7 @@ def _fwd(q, k, v, q_pos, kv_pos, q_seg, kv_seg, *, scale, causal, window,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q_pos, kv_pos, q_seg, kv_seg, q, k, v)
     return out, lse
 
@@ -426,6 +427,7 @@ def _bwd(res, g, *, scale, causal, window, softcap, block_q, block_kv,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(*args)
 
     # dk/dv are computed per *query* head ([B, H, T, dh]) so grid programs
@@ -472,6 +474,7 @@ def _bwd(res, g, *, scale, causal, window, softcap, block_q, block_kv,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(*args)
 
     dk = dk_per_h.reshape(B, K, G, T, dh).sum(axis=2).astype(k.dtype)

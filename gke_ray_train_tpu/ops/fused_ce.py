@@ -195,6 +195,7 @@ def _row_stats(x, head, targets, *, block_r, block_v, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_ce_fwd",
     )(targets.astype(jnp.int32)[None, :], x[None], head)
     return lse[0], tgt[0]
 
@@ -226,6 +227,7 @@ def _grads(x, head, targets, wg, lse, *, block_r, block_v, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_ce_dx",
     )(t2, wg2, lse2, x[None], head)[0]
 
     dhead = pl.pallas_call(
@@ -244,6 +246,7 @@ def _grads(x, head, targets, wg, lse, *, block_r, block_v, interpret):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_ce_dhead",
     )(t2, wg2, lse2, x[None], head)
     return dx, dhead
 

@@ -157,6 +157,7 @@ def fused_rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, *,
                 compiler_params=pltpu.CompilerParams(
                     vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
                 interpret=interpret,
+                name="fused_rmsnorm",
             )(x, scale[None, :])
 
         def fwd(x, scale):
@@ -228,6 +229,7 @@ def fused_rope_qk(q: jnp.ndarray, k: jnp.ndarray, positions: jnp.ndarray,
                 compiler_params=pltpu.CompilerParams(
                     vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
                 interpret=interpret,
+                name="fused_rope_qk",
             )(positions.astype(jnp.int32)[:, None, :], freqs[None, :],
               q, k)
 
@@ -293,6 +295,7 @@ def fused_rmsnorm_rope(x: jnp.ndarray, scale: jnp.ndarray,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=FUSED_VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name="fused_rmsnorm_rope",
         )(positions.astype(jnp.int32)[:, None, :], inv_freqs[None, :],
           scale[None, :], x)
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.obs.trace import scope
 
 
 def expert_capacity(cfg: ModelConfig, seq_len: int) -> int:
@@ -57,6 +58,16 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
     r4 weak #4). Router numerics (softmax, top-k, gate renorm, aux) stay
     fp32; only the per-slot gate value rounds once to ``dtype``.
     """
+    with scope("moe/route"):
+        combine, aux = _route(x, router_w, cfg, dtype, weights)
+    with scope("moe/experts"):
+        return _experts(x, combine, w_gate, w_up, w_down, cfg,
+                        dtype), aux
+
+
+def _route(x, router_w, cfg: ModelConfig, dtype, weights):
+    """Router softmax, top-k, aux loss and the static-capacity combine
+    tensor [B, S, E, C] (gate value at each token's expert slot)."""
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.expert_top_k
     C = expert_capacity(cfg, S)
@@ -95,7 +106,11 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
                               dtype=dtype)                        # [B,S,E,C]
         combine = combine \
             + slot * (keep * gate_k[..., k:k + 1]).astype(dtype)[..., None]
+    return combine, aux
 
+
+def _experts(x, combine, w_gate, w_up, w_down, cfg: ModelConfig, dtype):
+    """Dispatch, the batched expert FFN and the weighted combine."""
     # deferred import (ops.quant registers a pytree class; only needed
     # when the expert bank is a quantized QLoRA base)
     from gke_ray_train_tpu.ops.quant import maybe_dequantize
@@ -126,4 +141,4 @@ def moe_mlp(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
                    preferred_element_type=f32).astype(dtype)
     y = jnp.einsum("bsec,ebcd->bsd", combine, h,
                    preferred_element_type=f32)
-    return y.astype(dtype), aux
+    return y.astype(dtype)

@@ -26,15 +26,26 @@ A stale/mismatched sidecar falls back to a fresh compile. Nothing
 else degrades: a cache directory that cannot be written, or a step
 that fails to compile, is an error.
 
-Gotcha this module owns so callers don't have to: JAX memoizes "is the
-cache usable" at the FIRST compile of the process
-(``compilation_cache.is_cache_used``). Enabling the cache after any
-jit has run silently no-ops unless the check is reset —
-:func:`enable_persistent_cache` always resets it.
+Gotchas this module owns so callers don't have to:
+
+- JAX memoizes "is the cache usable" at the FIRST compile of the
+  process (``compilation_cache.is_cache_used``). Enabling the cache
+  after any jit has run silently no-ops unless the check is reset —
+  :func:`enable_persistent_cache` always resets it.
+- JAX leaves an instruction's metadata (``op_name``: the
+  ``jax.named_scope`` path) out of the cache key, so an executable
+  compiled before a scope existed would be served to the code that has
+  it, and its HLO text would name nothing (``obs/trace.py::
+  scope_table``). :func:`build_or_load_step` compiles under
+  :func:`names_salt`, a digest of the names vocabulary that rides the
+  key through JAX's own ``cache_key.custom_hook`` — the steps whose
+  executables are kept and read, not every program of the process (a
+  capped cache directory would hold two copies of everything).
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import logging
@@ -44,6 +55,8 @@ import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+
+from gke_ray_train_tpu.obs import trace
 
 logger = logging.getLogger(__name__)
 
@@ -118,6 +131,32 @@ def cpu_mesh_env(n_devices: int = 8, **extra: str) -> Dict[str, str]:
     env["JAX_PLATFORMS"] = "cpu"
     env.update(extra)
     return env
+
+
+def names_salt() -> str:
+    """A digest of the scope and kernel names the program writes into
+    its executables (obs/trace.py), for the persistent cache's key."""
+    names = (trace.SCOPE_VERSION, trace.SCOPE_NAMES, trace.KERNEL_NAMES)
+    return "grt-names:" + hashlib.sha256(
+        repr(names).encode()).hexdigest()[:16]
+
+
+@contextlib.contextmanager
+def salted_cache_key():
+    """Compiles inside carry :func:`names_salt` in their persistent
+    cache key, beside JAX's own parts: an entry compiled under another
+    vocabulary — or before there was one — is never served. Not
+    ``jax_compilation_cache_include_metadata_in_key``: that keys on
+    source lines too, so every edit of a traced file would recompile.
+    The hook is the process's, so a compile on another thread meanwhile
+    is salted too: a miss, never a wrong executable."""
+    from jax._src import cache_key
+    unsalted = cache_key.custom_hook
+    cache_key.custom_hook = lambda: unsalted() + names_salt()
+    try:
+        yield
+    finally:
+        cache_key.custom_hook = unsalted
 
 
 def _backend_initialized() -> bool:
@@ -405,6 +444,16 @@ class GuardedStep:
     def lower(self, *args, **kw):  # pragma: no cover - passthrough
         return self._jitted.lower(*args, **kw)
 
+    def note_scope_table(self) -> None:
+        """Keep this executable's ``{instruction: op_name}`` table in
+        the in-memory trace record under the step's label, so that the
+        device events of a profiler window can be joined to the
+        program's scopes. The loop calls it on its way out when a
+        profiler was attached; a step that fell back runs another
+        executable, whose instructions this one does not name."""
+        if not self.fell_back:
+            trace.note_scope_table(self.info["label"], self._compiled)
+
 
 def _note_cost_report(compiled, plan) -> None:
     """Feed the obs network gauges (grt_ici_bytes / grt_dcn_bytes)
@@ -446,27 +495,29 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
     info: Dict[str, Any] = {"label": label, "sidecar": sidecar}
     if plan is not None:
         info["plan_fingerprint"] = plan.fingerprint()
-    if sidecar:
-        t0 = time.perf_counter()
-        loaded = load_executable(sidecar, key)
-        if loaded is not None:
-            info.update(source="deserialized",
-                        build_s=time.perf_counter() - t0)
-            logger.info("%s: deserialized AOT executable in %.2fs (%s)",
-                        label, info["build_s"], sidecar)
-            # a warm-restart attempt must feed the obs network gauges
-            # too — the note guards internally against a deserialized
-            # executable that cannot re-serve its analyses
-            _note_cost_report(loaded, plan)
-            return GuardedStep(loaded, jitted_fn, info)
-    t0 = time.perf_counter()
-    # a step that cannot be lowered or compiled is an error, not a
-    # reason to fall back: the jitted path would hit the same wall at
-    # its first call, later and with less context
-    compiled = jitted_fn.lower(*args).compile()
-    info.update(source="compiled", build_s=time.perf_counter() - t0)
-    logger.info("%s: AOT compiled in %.2fs", label, info["build_s"])
+    with trace.region("step_build") as build:
+        compiled = load_executable(sidecar, key) if sidecar else None
+        if compiled is not None:
+            build.attrs["source"] = "deserialized"
+        else:
+            # a step that cannot be lowered or compiled is an error,
+            # not a reason to fall back: the jitted path would hit the
+            # same wall at its first call, later and with less context
+            with trace.region("step_lower"):
+                lowered = jitted_fn.lower(*args)
+            with trace.region("step_compile"), salted_cache_key():
+                compiled = lowered.compile()
+            build.attrs["source"] = "compiled"
+    info.update(source=build.attrs["source"], build_s=build.t1 - build.t0)
+    logger.info("%s: %s AOT executable in %.2fs%s", label, info["source"],
+                info["build_s"],
+                f" ({sidecar})" if info["source"] == "deserialized" else "")
+    # a warm-restart attempt must feed the obs network gauges too — the
+    # note guards internally against a deserialized executable that
+    # cannot re-serve its analyses
     _note_cost_report(compiled, plan)
+    if info["source"] == "deserialized":
+        return GuardedStep(compiled, jitted_fn, info)
     if sidecar:
         is_writer = True
         if _backend_initialized():

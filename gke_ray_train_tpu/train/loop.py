@@ -29,6 +29,7 @@ import numpy as np
 from gke_ray_train_tpu.analysis.guards import RuntimeGuards, allow_transfers
 from gke_ray_train_tpu.data.prefetch import make_batch_source
 from gke_ray_train_tpu.obs import runtime as obs_runtime
+from gke_ray_train_tpu.obs import trace
 from gke_ray_train_tpu.train import preempt
 from gke_ray_train_tpu.train.metrics import (
     GoodputLedger, ThroughputMeter, paused)
@@ -43,9 +44,32 @@ def _fetch_metrics(m: dict) -> dict:
     The pre-shardlint form — ``float(jax.device_get(v))`` per key —
     paid one device round-trip per metric every log step (TPU001);
     ``jax.device_get`` on the dict transfers every leaf in a single
-    fetch, inside the transfer guard's explicit allow-list."""
-    with allow_transfers():
+    fetch, inside the transfer guard's explicit allow-list. It is also
+    where a job that logs every step waits for the device, hence the
+    region."""
+    with trace.region("metrics_fetch"), allow_transfers():
         return {k: float(v) for k, v in jax.device_get(m).items()}
+
+
+_END = object()
+
+
+def _iterations(source):
+    """``(batch, region)`` for each batch of ``source``, yielded inside
+    an open ``step_iter`` region (obs/trace.py) whose first child,
+    ``data_wait``, is the hand-over that ``source.consume_wait`` times.
+    The region closes when the loop asks for the next batch, or when
+    the generator is closed; the wait that finds the stream exhausted
+    belongs to no iteration and is dropped."""
+    it = iter(source)
+    while True:
+        with trace.region("step_iter") as span:
+            with trace.region("data_wait") as wait:
+                batch = next(it, _END)
+                wait.drop = span.drop = batch is _END
+            if batch is _END:
+                return
+            yield batch, span
 
 
 def run_training(state: TrainState,
@@ -146,6 +170,11 @@ def run_training(state: TrainState,
         # collide with the config-gated trace window
         obs.capture._conflict = lambda: bool(
             getattr(profiler, "active", False))
+    if profiler is not None:
+        # a profiler object on the loop is what turns the in-memory
+        # span record on (with an obs session, which does so itself):
+        # what lands in its window can then be read back by name
+        trace.RECORD.attach()
     _obs_prev = [t_loop0, 0.0]   # [last note time, last eval/ckpt total]
     # step-window span accumulator (obs/trace.py): [step_s,
     # data_stall_s, steps] since the last flush. Spans aggregate at the
@@ -160,6 +189,35 @@ def run_training(state: TrainState,
             obs.span_add("step_window", _win[0] + _win[1], step=step,
                          steps=_win[2], data_stall_s=_win[1])
         _win[:] = [0.0, 0.0, 0]
+
+    @contextlib.contextmanager
+    def _ledgered(name, term, **kw):
+        """A region whose JSONL duration is what the ledger booked to
+        ``term`` while it was open (the delta, not a re-measurement:
+        obs/critical.py reconciles the two streams) — on the exception
+        path too, because paused() books on __exit__ regardless."""
+        before = getattr(ledger, term)
+        with trace.region(name, **kw) as r:
+            try:
+                yield r
+            finally:
+                r.dur_s = getattr(ledger, term) - before
+
+    def _snapshot_save(step, state, m_host) -> float:
+        """The async-commit save's blocking part (snapshot + enqueue),
+        booked as ckpt_async_s — on the exception path too. The span
+        carries the same float."""
+        with trace.region("ckpt_snapshot", step=step,
+                          forced=False) as s_span:
+            t_save0 = time.perf_counter()
+            try:
+                with allow_transfers():
+                    ckpt_manager.save(step, save_view(state),
+                                      metrics=m_host)
+            finally:
+                s_span.dur_s = snap_dt = time.perf_counter() - t_save0
+                ledger.note("ckpt_async_s", snap_dt)
+        return snap_dt
     if guards is None:
         guards = RuntimeGuards.from_config()
     # KERNELCHECK=1 (analysis/kernelcheck.py): before anything trains,
@@ -182,52 +240,53 @@ def run_training(state: TrainState,
         fault_injector.bind_ckpt(ckpt_manager)
     resumed_step = None
     if ckpt_manager is not None:
-        t_restore0 = time.perf_counter()
-        try:
-            view, resumed = ckpt_manager.restore_if_available(
-                save_view(state))
-            if resumed is not None:
-                state = load_view(state, view)
-        except Exception as e:  # noqa: BLE001 - layout-mismatch fallback
-            if ckpt_view is None:
-                raise
-            # a checkpoint written before the view existed stores the
-            # FULL state (ADVICE r1: pre-view LoRA checkpoints must stay
-            # restorable) — retry against the full-state template
-            logger.warning(
-                "ckpt_view restore failed (%s: %s); retrying as a "
-                "full-state checkpoint (pre-view layout)",
-                type(e).__name__, e)
-            full, resumed = ckpt_manager.restore_if_available(state)
-            if resumed is not None:
-                state = full
-        restore_dt = time.perf_counter() - t_restore0
-        # a resume served from the peer slice's hot state (ckpt/peer.py)
-        # books peer_restore_s, not restore_s — the ledger says which
-        # recovery path paid for the attempt's start
-        peer_served = getattr(ckpt_manager, "last_restore_source",
-                              None) == "peer"
-        ledger.note("peer_restore_s" if peer_served else "restore_s",
-                    restore_dt)
-        if resumed is not None and is_host0:
-            logger.info("resumed at step %d", resumed)
-        resumed_step = resumed
-        if obs is not None:
+        with trace.region("restore") as r_span:
+            t_restore0 = time.perf_counter()
+            try:
+                view, resumed = ckpt_manager.restore_if_available(
+                    save_view(state))
+                if resumed is not None:
+                    state = load_view(state, view)
+            except Exception as e:  # noqa: BLE001 - layout mismatch
+                if ckpt_view is None:
+                    raise
+                # a checkpoint written before the view existed stores
+                # the FULL state (ADVICE r1: pre-view LoRA checkpoints
+                # must stay restorable) — retry against the full-state
+                # template
+                logger.warning(
+                    "ckpt_view restore failed (%s: %s); retrying as a "
+                    "full-state checkpoint (pre-view layout)",
+                    type(e).__name__, e)
+                full, resumed = ckpt_manager.restore_if_available(state)
+                if resumed is not None:
+                    state = full
+            restore_dt = time.perf_counter() - t_restore0
+            # a resume served from the peer slice's hot state
+            # (ckpt/peer.py) books peer_restore_s, not restore_s — the
+            # ledger says which recovery path paid for the attempt's start
+            peer_served = getattr(ckpt_manager, "last_restore_source",
+                                  None) == "peer"
+            ledger.note("peer_restore_s" if peer_served else "restore_s",
+                        restore_dt)
+            if resumed is not None and is_host0:
+                logger.info("resumed at step %d", resumed)
+            resumed_step = resumed
             # span duration is the EXACT float the ledger booked — the
             # critical-path reconciliation (obs/critical.py) depends on
-            # the two streams agreeing bitwise, not approximately
+            # the two streams agreeing bitwise, not approximately. The
+            # profiler's plane shows `grt:restore` for both sources.
+            r_span.dur_s, r_span.step = restore_dt, resumed
+            r_span.attrs["resumed_step"] = resumed
             if peer_served:
-                obs.span_add("peer_restore", restore_dt, step=resumed,
-                             resumed_step=resumed)
-                _pmeta = getattr(ckpt_manager, "last_peer_restore",
-                                 None) or {}
-                obs.emit("peer_restore", step=resumed,
-                         restore_s=restore_dt,
-                         bytes=_pmeta.get("bytes"),
-                         from_slice=_pmeta.get("from_slice"))
-            else:
-                obs.span_add("restore", restore_dt, step=resumed,
-                             resumed_step=resumed)
+                r_span.name = "peer_restore"
+        if obs is not None and peer_served:
+            _pmeta = getattr(ckpt_manager, "last_peer_restore",
+                             None) or {}
+            obs.emit("peer_restore", step=resumed,
+                     restore_s=restore_dt,
+                     bytes=_pmeta.get("bytes"),
+                     from_slice=_pmeta.get("from_slice"))
         if obs is not None and resumed is not None:
             obs.emit("resume", step=resumed, resumed_step=resumed)
         # attempt metadata for Result.attempt_log (rayint/trainer.py);
@@ -282,14 +341,16 @@ def run_training(state: TrainState,
             tb_writer.flush()
         save_s = None
         if ckpt_manager is not None:
-            t0 = time.perf_counter()
-            with allow_transfers():
-                if m is not None and ckpt_manager.latest_step() != step:
-                    ckpt_manager.save(step, save_view(state),
-                                      metrics=_fetch_metrics(m),
-                                      force=True)
-                ckpt_manager.wait()
-            save_s = time.perf_counter() - t0
+            with trace.region("preempt_save", step=step) as p_span:
+                t0 = time.perf_counter()
+                with allow_transfers():
+                    if m is not None and \
+                            ckpt_manager.latest_step() != step:
+                        ckpt_manager.save(step, save_view(state),
+                                          metrics=_fetch_metrics(m),
+                                          force=True)
+                    ckpt_manager.wait()
+                p_span.dur_s = save_s = time.perf_counter() - t0
             ledger.note("eval_ckpt_stall_s", save_s)
             kept = ckpt_manager.latest_step()
             if kept != step:
@@ -315,8 +376,6 @@ def run_training(state: TrainState,
         # trainer's elastic re-form
         ledger.close(time.perf_counter() - t_loop0)
         if obs is not None:
-            if save_s is not None:
-                obs.span_add("preempt_save", save_s, step=step)
             obs.emit("preempt_exit", step=step, save_s=save_s,
                      grace_remaining_s=preempt.remaining_grace_s(),
                      pool=preempt.pool_target())
@@ -357,8 +416,9 @@ def run_training(state: TrainState,
         source = make_batch_source(epoch_batches(epoch),
                                    place_fn=place_batch,
                                    depth=prefetch, skip=to_skip)
+        iterations = _iterations(source)
         try:
-          for batch in source:
+          for batch, it_span in iterations:
             if _preempt_requested():
                 _preempt_exit(state, m, global_step)
             wait_s = source.consume_wait()
@@ -387,16 +447,20 @@ def run_training(state: TrainState,
                 # must have lowered the SAME step program before the
                 # first collective dispatch wedges on a mismatch
                 guards.check_divergence(train_step, state, batch)
-                t_step0 = time.perf_counter()
-                state, m = train_step(state, batch)
-                # block: the first call's wall time must cover the
-                # compile it triggered, not just the async dispatch
-                jax.block_until_ready(m["loss"])
-                now = time.perf_counter()
-                loop_timing = {
-                    "compile_s": now - t_step0,
-                    "restart_to_first_step_s": now - t_loop0,
-                }
+                with trace.region("compile",
+                                  step=global_step + 1) as c_span:
+                    t_step0 = time.perf_counter()
+                    with trace.region("step_dispatch"):
+                        state, m = train_step(state, batch)
+                    # block: the first call's wall time must cover the
+                    # compile it triggered, not just the async dispatch
+                    jax.block_until_ready(m["loss"])
+                    now = time.perf_counter()
+                    loop_timing = {
+                        "compile_s": now - t_step0,
+                        "restart_to_first_step_s": now - t_loop0,
+                    }
+                    c_span.dur_s = loop_timing["compile_s"]
                 # ledger decomposition of the restart window: restore
                 # was timed directly; the first step call is compile;
                 # on a RESUMED attempt everything else between entry
@@ -405,9 +469,6 @@ def run_training(state: TrainState,
                 # fresh start fast-forwarded nothing — its warmup stays
                 # in step_s rather than fabricating resume time.
                 ledger.note("compile_s", loop_timing["compile_s"])
-                if obs is not None:
-                    obs.span_add("compile", loop_timing["compile_s"],
-                                 step=global_step + 1)
                 if resumed_step is not None:
                     ff_dt = (loop_timing["restart_to_first_step_s"]
                              - loop_timing["compile_s"]
@@ -429,8 +490,10 @@ def run_training(state: TrainState,
                              fast_forward_s=ledger.fast_forward_s,
                              backend=_obs_runtime.current_backend())
             else:
-                state, m = train_step(state, batch)
+                with trace.region("step_dispatch"):
+                    state, m = train_step(state, batch)
             global_step += 1
+            it_span.step = global_step
             if heartbeat_fn is not None:
                 # step-granular liveness: the metric the supervisor
                 # watches is "this rank completed another step"
@@ -465,28 +528,31 @@ def run_training(state: TrainState,
                 meter.update(int(np.prod(batch["inputs"].shape)))
             if log_every and global_step % log_every == 0:
                 m_host = _fetch_metrics(m)
-                last_metrics = {"epoch": epoch, "step": global_step,
-                                **loop_timing, **m_host}
-                if meter is not None:
-                    last_metrics.update(meter.snapshot())
-                if tb_writer is not None:
-                    tb_writer.log(global_step, last_metrics)
-                if obs is not None:
-                    # log-cadence telemetry sink: gauges + ONE `step`
-                    # event + file export, from the host dict already
-                    # fetched above — obs adds no device traffic
-                    obs.log_metrics(global_step, last_metrics,
-                                    epoch=epoch)
-                    _flush_window(global_step)
-                if is_host0:
-                    logger.info(
-                        "epoch %d step %d loss %.4f lr %.3g%s",
-                        epoch, global_step, m_host.get("loss", float("nan")),
-                        m_host.get("learning_rate", float("nan")),
-                        (f" tok/s/chip {last_metrics['tokens_per_sec_per_chip']:.0f}"
-                         f" mfu {last_metrics['mfu']:.1%}"
-                         f" stall {last_metrics['data_stall_frac']:.1%}"
-                         if meter is not None else ""))
+                with trace.region("log_emit"):
+                    last_metrics = {"epoch": epoch, "step": global_step,
+                                    **loop_timing, **m_host}
+                    if meter is not None:
+                        last_metrics.update(meter.snapshot())
+                    if tb_writer is not None:
+                        tb_writer.log(global_step, last_metrics)
+                    if obs is not None:
+                        # log-cadence telemetry sink: gauges + ONE
+                        # `step` event + file export, from the host
+                        # dict already fetched above — obs adds no
+                        # device traffic
+                        obs.log_metrics(global_step, last_metrics,
+                                        epoch=epoch)
+                        _flush_window(global_step)
+                    if is_host0:
+                        logger.info(
+                            "epoch %d step %d loss %.4f lr %.3g%s",
+                            epoch, global_step,
+                            m_host.get("loss", float("nan")),
+                            m_host.get("learning_rate", float("nan")),
+                            (f" tok/s/chip {last_metrics['tokens_per_sec_per_chip']:.0f}"
+                             f" mfu {last_metrics['mfu']:.1%}"
+                             f" stall {last_metrics['data_stall_frac']:.1%}"
+                             if meter is not None else ""))
             if eval_fn is not None and eval_every and \
                     global_step % eval_every == 0:
                 # eval/ckpt stalls are excluded from the meter's
@@ -496,22 +562,10 @@ def run_training(state: TrainState,
                 # compute is booked as training, not stall
                 if meter is not None:
                     jax.block_until_ready(m)
-                _ev0 = ledger.eval_ckpt_stall_s
-                try:
-                    with paused(meter), paused(ledger), \
-                            allow_transfers():
-                        eval_metrics = eval_fn(state)
-                finally:
-                    # span duration = exactly what the ledger booked
-                    # for this pause (the delta, not a re-measurement)
-                    # — emitted on the exception path too, because
-                    # paused() books on __exit__ regardless and the
-                    # two streams must agree for the crashed attempt's
-                    # report to reconcile
-                    if obs is not None:
-                        obs.span_add("eval",
-                                     ledger.eval_ckpt_stall_s - _ev0,
-                                     step=global_step)
+                with _ledgered("eval", "eval_ckpt_stall_s",
+                               step=global_step), \
+                        paused(meter), paused(ledger), allow_transfers():
+                    eval_metrics = eval_fn(state)
                 last_metrics.update(eval_metrics)
                 if tb_writer is not None:
                     tb_writer.log(global_step, eval_metrics)
@@ -532,36 +586,20 @@ def run_training(state: TrainState,
                     # checkpointing. The storage serialize runs on the
                     # committer thread behind the write-ahead marker and
                     # lands as a ckpt_commit EVENT, never loop time.
-                    t_save0 = time.perf_counter()
-                    snap_dt = 0.0
-                    try:
-                        with paused(meter), allow_transfers():
-                            ckpt_manager.save(global_step,
-                                              save_view(state),
-                                              metrics=m_host)
-                    finally:
-                        snap_dt = time.perf_counter() - t_save0
-                        ledger.note("ckpt_async_s", snap_dt)
-                        if obs is not None:
-                            obs.span_add("ckpt_snapshot", snap_dt,
-                                         step=global_step, forced=False)
+                    with paused(meter):
+                        snap_dt = _snapshot_save(global_step, state,
+                                                 m_host)
                     if obs is not None:
                         obs.emit("ckpt_snapshot", step=global_step,
                                  snapshot_s=snap_dt, forced=False)
                 else:
                     t_save0 = time.perf_counter()
-                    _ck0 = ledger.eval_ckpt_stall_s
-                    try:
-                        with paused(meter), paused(ledger), \
-                                allow_transfers():
-                            ckpt_manager.save(global_step,
-                                              save_view(state),
-                                              metrics=m_host)
-                    finally:
-                        if obs is not None:
-                            obs.span_add("ckpt_save",
-                                         ledger.eval_ckpt_stall_s - _ck0,
-                                         step=global_step, forced=False)
+                    with _ledgered("ckpt_save", "eval_ckpt_stall_s",
+                                   step=global_step, forced=False), \
+                            paused(meter), paused(ledger), \
+                            allow_transfers():
+                        ckpt_manager.save(global_step, save_view(state),
+                                          metrics=m_host)
                     if obs is not None:
                         obs.emit("ckpt_save", step=global_step,
                                  save_s=time.perf_counter() - t_save0,
@@ -571,9 +609,11 @@ def run_training(state: TrainState,
                 # kind=ckpt_truncate at step k tears the step-k save
                 fault_injector.on_step(global_step)
         finally:
-            # normal exhaustion already joined the workers; this reclaims
-            # them on the exception path (a failing step must not leak
-            # prefetch threads parked on backpressure)
+            # closes the iteration a failing step left open, then the
+            # source: normal exhaustion already joined the workers; this
+            # reclaims them on the exception path (a failing step must
+            # not leak prefetch threads parked on backpressure)
+            iterations.close()
             source.close()
         yielded = source.yielded
         to_skip -= source.skipped
@@ -600,15 +640,10 @@ def run_training(state: TrainState,
         if meter is not None:
             epoch_metrics.update(meter.snapshot())
         if eval_fn is not None and eval_at_epoch_end:
-            _ev0 = ledger.eval_ckpt_stall_s
-            try:
-                with paused(ledger), allow_transfers():
-                    epoch_metrics.update(eval_fn(state))
-            finally:
-                if obs is not None:
-                    obs.span_add("eval",
-                                 ledger.eval_ckpt_stall_s - _ev0,
-                                 step=global_step)
+            with _ledgered("eval", "eval_ckpt_stall_s",
+                           step=global_step), \
+                    paused(ledger), allow_transfers():
+                epoch_metrics.update(eval_fn(state))
         if tb_writer is not None:
             tb_writer.log(global_step, epoch_metrics)
             tb_writer.flush()
@@ -617,28 +652,13 @@ def run_training(state: TrainState,
         last_metrics = epoch_metrics
         if ckpt_manager is not None:
             if getattr(ckpt_manager, "async_commit", False):
-                t_save0 = time.perf_counter()
-                try:
-                    with allow_transfers():
-                        ckpt_manager.save(global_step, save_view(state),
-                                          metrics=m_host)
-                finally:
-                    snap_dt = time.perf_counter() - t_save0
-                    ledger.note("ckpt_async_s", snap_dt)
-                    if obs is not None:
-                        obs.span_add("ckpt_snapshot", snap_dt,
-                                     step=global_step, forced=False)
+                _snapshot_save(global_step, state, m_host)
             else:
-                _ck0 = ledger.eval_ckpt_stall_s
-                try:
-                    with paused(ledger), allow_transfers():
-                        ckpt_manager.save(global_step, save_view(state),
-                                          metrics=m_host)
-                finally:
-                    if obs is not None:
-                        obs.span_add("ckpt_save",
-                                     ledger.eval_ckpt_stall_s - _ck0,
-                                     step=global_step, forced=False)
+                with _ledgered("ckpt_save", "eval_ckpt_stall_s",
+                               step=global_step, forced=False), \
+                        paused(ledger), allow_transfers():
+                    ckpt_manager.save(global_step, save_view(state),
+                                      metrics=m_host)
         if report_fn is not None:
             report_fn(epoch_metrics)
     finally:
@@ -668,6 +688,15 @@ def run_training(state: TrainState,
         # profile matters most in exactly that case
         if profiler is not None:
             profiler.close()
+            # after the last step and the window's end, never between
+            # two timed steps: the executable's {instruction: op_name}
+            # table, so that the window's device events can be joined
+            # to the program's scopes (obs/trace.py). An AOT-built step
+            # keeps its executable; a plain jitted one has none to read
+            note_table = getattr(train_step, "note_scope_table", None)
+            if note_table is not None:
+                note_table()
+            trace.RECORD.detach()
         if tb_writer is not None:
             tb_writer.close()
 

@@ -17,6 +17,8 @@ from typing import Any, Callable, Optional
 import jax
 import optax
 
+from gke_ray_train_tpu.obs.trace import scope
+
 
 def warmup_cosine_schedule(base_lr: float, total_steps: int, *,
                            warmup_frac: float = 0.05,
@@ -57,6 +59,18 @@ def default_weight_decay_mask(params: Any) -> Any:
     return jax.tree_util.tree_map_with_path(decay, params)
 
 
+def clip_by_global_norm(clip_norm: float) -> optax.GradientTransformation:
+    """``optax.clip_by_global_norm`` whose device ops carry the ``clip``
+    scope (obs/trace.py), so the profile tells the norm's reduction
+    from the AdamW update."""
+    tx = optax.clip_by_global_norm(clip_norm)
+
+    def update(updates, state, params=None):
+        with scope("clip"):
+            return tx.update(updates, state, params)
+    return optax.GradientTransformation(tx.init, update)
+
+
 def make_optimizer(schedule: optax.Schedule | float, *,
                    weight_decay: float = 0.01,
                    clip_norm: Optional[float] = 1.0,
@@ -65,7 +79,7 @@ def make_optimizer(schedule: optax.Schedule | float, *,
                    ) -> optax.GradientTransformation:
     txs = []
     if clip_norm is not None:
-        txs.append(optax.clip_by_global_norm(clip_norm))
+        txs.append(clip_by_global_norm(clip_norm))
     txs.append(optax.adamw(
         schedule, b1=b1, b2=b2, eps=eps, weight_decay=weight_decay,
         mask=weight_decay_mask or default_weight_decay_mask))

@@ -6,7 +6,11 @@ north star needs step-level traces, so this wires ``jax.profiler``
 (XProf/TensorBoard format) into the training loop as a first-class,
 config-gated subsystem: trace a window of steps mid-run (after compile +
 warmup noise) and write to the shared storage mount where TensorBoard
-reads it.
+reads it. The window holds the program's own names (obs/trace.py):
+``grt:<region>`` spans of the loop, the input pipeline and the step's
+build on the host plane, and on the device ops the ``jax.named_scope``
+path (``.../transpose(jvp())/.../attn/qkv/base/dot_general``) and the
+Pallas kernels' names (``flash_fwd``, ...).
 
 Config surface (fine_tune_config.json / pre-train config):
   "PROFILE": true | "gs-mounted/dir"   — enable (default dir under the
@@ -47,15 +51,6 @@ class TraceProfiler:
         self._done = False
 
     @property
-    def start_offset(self) -> int:
-        """Back-compat alias for start_step (read/write)."""
-        return self.start_step
-
-    @start_offset.setter
-    def start_offset(self, v: int) -> None:
-        self.start_step = v
-
-    @property
     def active(self) -> bool:
         """Whether a trace is in flight RIGHT NOW. jax.profiler is
         process-global, so the anomaly-capture scheduler
@@ -78,8 +73,8 @@ class TraceProfiler:
             return
         if self._first is None:
             self._first = global_step
-        # start_trace after `start_offset` steps have completed, so the
-        # first *traced* step is first + start_offset
+        # start_trace after `start_step` steps have completed, so the
+        # first *traced* step is first + start_step
         if not self._active and \
                 global_step >= self._first + self.start_step - 1:
             try:

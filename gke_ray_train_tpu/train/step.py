@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.transformer import (
     Params, forward, init_params, param_specs)
+from gke_ray_train_tpu.obs.trace import scope
 from gke_ray_train_tpu.parallel.mesh import BATCH_AXES
 from gke_ray_train_tpu.parallel.sharding import tree_shardings
 from gke_ray_train_tpu.train.lora import LoraConfig, init_lora, lora_specs
@@ -59,11 +60,13 @@ def token_nll(logits: jnp.ndarray, targets: jnp.ndarray,
     but the [B, S, V] log-probability array (1 GB at 8B's 128k vocab)
     is never materialized — backward recomputes the softmax from the
     logits it already holds."""
-    logits32 = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits32, axis=-1)
-    tgt = jnp.take_along_axis(logits32, targets[..., None], axis=-1)[..., 0]
-    w = weights.astype(jnp.float32)
-    return jnp.sum((lse - tgt) * w), jnp.sum(w)
+    with scope("loss"):
+        logits32 = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(logits32, axis=-1)
+        tgt = jnp.take_along_axis(logits32, targets[..., None],
+                                  axis=-1)[..., 0]
+        w = weights.astype(jnp.float32)
+        return jnp.sum((lse - tgt) * w), jnp.sum(w)
 
 
 def opt_state_specs(optimizer: optax.GradientTransformation,
@@ -282,9 +285,10 @@ def make_train_step(cfg: ModelConfig,
             # tied-embed gradient). LoRA keeps the frozen base head —
             # adapters never train the unembedding.
             head_params = frozen if lora_mode else trainable
-            nll, w = fused_cross_entropy(
-                hidden, unembed_head(head_params, cfg).astype(dtype),
-                micro["targets"], micro["weights"], mesh=mesh)
+            with scope("loss"):
+                nll, w = fused_cross_entropy(
+                    hidden, unembed_head(head_params, cfg).astype(dtype),
+                    micro["targets"], micro["weights"], mesh=mesh)
         else:
             nll, w = token_nll(hidden, micro["targets"], micro["weights"])
         if moe:
@@ -354,12 +358,14 @@ def make_train_step(cfg: ModelConfig,
         (g_sum, nll_sum, w_sum, *_), _ = jax.lax.scan(
             accum, carry0, scan_xs)
 
-        inv_w = jnp.where(w_sum > 0, 1.0 / w_sum, 0.0)
-        grads = jax.tree.map(lambda g: (g * inv_w).astype(g.dtype), g_sum)
-        loss = nll_sum * inv_w
-
-        updates, new_opt = optimizer.update(grads, state.opt_state, trainable)
-        new_trainable = optax.apply_updates(trainable, updates)
+        with scope("optimizer"):
+            inv_w = jnp.where(w_sum > 0, 1.0 / w_sum, 0.0)
+            grads = jax.tree.map(lambda g: (g * inv_w).astype(g.dtype),
+                                 g_sum)
+            loss = nll_sum * inv_w
+            updates, new_opt = optimizer.update(grads, state.opt_state,
+                                                trainable)
+            new_trainable = optax.apply_updates(trainable, updates)
 
         new_state = TrainState(
             params=state.params if lora_mode else new_trainable,
@@ -367,11 +373,11 @@ def make_train_step(cfg: ModelConfig,
             opt_state=new_opt,
             step=state.step + 1,
         )
-        metrics = {
-            "loss": loss,
-            "grad_norm": optax.global_norm(grads),
-            "tokens": w_sum,
-        }
+        with scope("clip"):
+            # the same reduction the optimizer's clip runs (XLA merges
+            # the two), so it carries the same name
+            grad_norm = optax.global_norm(grads)
+        metrics = {"loss": loss, "grad_norm": grad_norm, "tokens": w_sum}
         if schedule is not None:
             metrics["learning_rate"] = schedule(state.step)
         return new_state, metrics

@@ -104,6 +104,47 @@ def test_cache_hit_second_build_compiles_nothing(cache_sandbox, fsdp_mesh):
     assert np.isfinite(float(jax.device_get(m["loss"])))
 
 
+def test_an_executable_named_under_another_vocabulary_is_not_served(
+        cache_sandbox, monkeypatch):
+    """jax leaves metadata (the named_scope path) out of the cache key,
+    so the step an AOT build keeps is keyed with the names' digest
+    (perf/cache.py::salted_cache_key): same names hit, other names — or
+    a scope that moved — compile anew, and the table reads the names of
+    the code that runs."""
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    from gke_ray_train_tpu.perf.cache import build_or_load_step
+    enable_persistent_cache(str(cache_sandbox / "cache"))
+
+    def build():
+        jax.clear_caches()
+        before = cache_stats()
+
+        def scoped_step(x):
+            with obs_trace.scope("mlp/down"):
+                return jnp.tanh(x @ x.T).sum()
+        step = build_or_load_step(jax.jit(scoped_step),
+                                  jnp.ones((8, 8), jnp.float32),
+                                  label="salted step")
+        after = cache_stats()
+        return step, (after["hits"] - before["hits"],
+                      after["misses"] - before["misses"])
+
+    _, cold = build()                 # (hits, misses); the operand's
+    assert cold[1] >= 1               # own small programs count too
+    step, warm = build()
+    assert warm[0] >= 1 and warm[1] == 0
+    monkeypatch.setattr(obs_trace, "SCOPE_VERSION",
+                        obs_trace.SCOPE_VERSION + 1)
+    _, moved = build()
+    assert moved == (warm[0] - 1, 1)  # the step alone compiles anew
+    obs_trace.RECORD.clear()
+    step.note_scope_table()
+    table = obs_trace.RECORD.scope_tables.pop("salted step")
+    assert "mlp/down" in {obs_trace.scope_path(op)
+                          for op in table.values()}
+    obs_trace.RECORD.clear()
+
+
 def test_unusable_cache_dir_raises(cache_sandbox):
     """No fallback to a home directory and no silent disable: a cache
     directory that cannot be written is an error."""

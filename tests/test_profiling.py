@@ -65,3 +65,76 @@ def test_run_training_with_profiler(tmp_path):
                                   log_every=2, profiler=prof)
     assert prof._done
     assert os.path.isdir(logdir)
+
+
+def test_loss_stream_bitwise_with_a_profiler_attached(tmp_path,
+                                                      tiny_train_setup):
+    """The regions, the in-memory record and the scope table cost no
+    numerics: the loss stream with a profiler object attached (and a
+    real trace in flight) is bitwise the stream without."""
+    import numpy as np
+
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+
+    class Losses:
+        def __init__(self):
+            self.seen = []
+
+        def log(self, step, metrics):
+            if "loss" in metrics:
+                self.seen.append((step, metrics["loss"]))
+
+        def log_registry(self, *a, **k):
+            pass
+
+        def flush(self):
+            pass
+
+        def close(self):
+            pass
+
+    def run(profiler):
+        _, _, state, step = tiny_train_setup
+        log = Losses()
+        final, _ = run_training(state, step, _batches(6), epochs=1,
+                                log_every=1, prefetch=2, tb_writer=log,
+                                profiler=profiler)
+        return log.seen, jax.device_get(final.params)
+
+    obs_trace.RECORD.clear()
+    try:
+        plain, params_plain = run(None)
+        assert list(obs_trace.RECORD.spans) == []
+        traced, params_traced = run(TraceProfiler(
+            str(tmp_path / "prof"), start_step=2, num_steps=2))
+        assert any(s["name"] == "step_iter"
+                   for s in obs_trace.RECORD.spans)
+    finally:
+        obs_trace.RECORD.clear()
+    assert traced == plain and len(plain) >= 6      # bitwise, not approx
+    assert all(np.array_equal(a, b) for a, b in zip(
+        jax.tree_util.tree_leaves(params_traced),
+        jax.tree_util.tree_leaves(params_plain)))
+
+
+def test_profile_window_holds_the_programs_names(tmp_path,
+                                                 tiny_train_setup):
+    """A PROFILE window's host plane carries the loop's regions as
+    `grt:<name>` annotations."""
+    from jax.profiler import ProfileData
+
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    _, _, state, step = tiny_train_setup
+    logdir = str(tmp_path / "prof")
+    run_training(state, step, _batches(6), epochs=1, log_every=1,
+                 profiler=TraceProfiler(logdir, start_step=2, num_steps=3))
+    path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("grt:")}
+    assert {"grt:step_iter", "grt:data_wait", "grt:step_dispatch",
+            "grt:metrics_fetch", "grt:log_emit"} <= names

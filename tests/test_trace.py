@@ -454,3 +454,304 @@ def test_bench_emit_stamps_run_id(monkeypatch, capsys):
     bench._emit("m3", 3.0, "u", {}, compare_baseline=False)
     rec = json.loads(capsys.readouterr().out.strip())
     assert rec["run_id"] == "job-level-id"
+
+
+# ---------------------------------------------------------------------------
+# the program's own names in the profile (ISSUE 24): scopes on the
+# step's device ops, names on the kernels, loop phases as regions
+# ---------------------------------------------------------------------------
+
+class _NoProfiler:
+    """Stands where a profiler object would: the loop only asks for
+    ``step`` and ``close`` (and the capture manager for ``active``)."""
+    active = False
+
+    def __init__(self):
+        self.steps, self.closed = 0, False
+
+    def step(self, global_step):
+        self.steps += 1
+
+    def close(self):
+        self.closed = True
+
+
+@pytest.fixture
+def record():
+    obs_trace.RECORD.clear()
+    yield obs_trace.RECORD
+    obs_trace.RECORD.clear()
+
+
+@pytest.fixture(scope="module", params=[True, False],
+                ids=["remat", "no_remat"])
+def lora_step_table(request):
+    """(remat?, optimised HLO text, scope table) of a tiny scanned LoRA
+    train step with two micro-batches, compiled on the CPU."""
+    import jax
+    import jax.numpy as jnp
+
+    from gke_ray_train_tpu.models import tiny
+    from gke_ray_train_tpu.train import (
+        LoraConfig, make_optimizer, make_train_state, make_train_step)
+    cfg = tiny(vocab_size=64, d_model=32, n_layers=2, n_heads=2,
+               n_kv_heads=2, d_ff=64, dtype="float32",
+               param_dtype="float32", remat=request.param)
+    opt = make_optimizer(1e-3)
+    lora = LoraConfig(r=4, alpha=8, targets=(
+        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"))
+    state = make_train_state(cfg, opt, jax.random.key(0), lora_cfg=lora)
+    step = make_train_step(cfg, opt, lora_cfg=lora, grad_accum=2,
+                           donate=False)
+    batch = {"inputs": jnp.zeros((4, 16), jnp.int32),
+             "targets": jnp.zeros((4, 16), jnp.int32),
+             "weights": jnp.ones((4, 16), jnp.float32)}
+    text = step.lower(state, batch).compile().as_text()
+    return request.param, text, obs_trace.scope_table(text)
+
+
+def test_scope_table_names_every_matmul(lora_step_table):
+    import re
+    _, text, table = lora_step_table
+    matmuls = re.findall(
+        r"^\s+(?:ROOT )?%?([\w.\-]+) = \S+ (?:dot|convolution)\(", text,
+        re.M)
+    assert len(matmuls) > 20
+    # XLA:CPU leaves a dot unfused, so each is an entry of its own
+    unnamed = [m for m in matmuls if m in table
+               and obs_trace.scope_path(table[m]) is None]
+    assert unnamed == []
+    assert sum(m in table for m in matmuls) > 20
+
+
+def test_scope_table_shows_the_phase(lora_step_table):
+    remat, _, table = lora_step_table
+    base = [op for op in table.values()
+            if (obs_trace.scope_path(op) or "").endswith("/base")]
+    recomputed = [op for op in base if "rematted_computation" in op]
+    backward = [op for op in base if "transpose(" in op
+                and "rematted_computation" not in op]
+    forward = [op for op in base if "jvp(" in op and "transpose(" not in op]
+    assert forward and backward
+    assert bool(recomputed) == remat
+    if not remat:
+        assert not any("rematted_computation" in op
+                       for op in table.values())
+    else:
+        # full remat runs the frozen projections of the forward again,
+        # but for the block's last (w_down): nothing downstream in the
+        # block needs its output, so XLA drops the recomputation
+        assert len(recomputed) == len(forward) - 1
+        assert not any("mlp/down" in op for op in recomputed)
+
+
+def test_scope_table_separates_base_from_lora(lora_step_table):
+    _, _, table = lora_step_table
+    paths = {obs_trace.scope_path(op) for op in table.values()} - {None}
+    for module in ("attn/qkv", "attn/out", "mlp/gate_up", "mlp/down"):
+        assert {module + "/base", module + "/lora"} <= paths
+    assert not any(p.endswith("base/lora") or p.endswith("lora/base")
+                   for p in paths)
+    # the step's own scopes, and the ones jax wraps into a transform
+    # (`transpose(jvp(loss))`) because they were open where grad ran
+    assert {"embed", "attn_norm", "attn/rope", "attn/core", "mlp_norm",
+            "final_norm", "unembed", "loss", "optimizer",
+            "optimizer/clip"} <= paths
+
+
+def test_scope_table_prefers_a_fusion_s_matmul():
+    text = """HloModule m
+%fused_computation.1 (p: f32[4,4]) -> f32[4,4] {
+  %p = f32[4,4]{1,0} parameter(0)
+  %c = f32[4,4]{1,0} convolution(%p, %p), dim_labels=bf_io->bf, metadata={op_name="jit(f)/jvp()/attn/qkv/base/dot_general"}
+  ROOT %a = f32[4,4]{1,0} add(%c, %p), metadata={op_name="jit(f)/jvp()/attn/qkv/lora/add"}
+}
+ENTRY %main (x: f32[4,4]) -> f32[4,4] {
+  %x = f32[4,4]{1,0} parameter(0)
+  %w = f32[4,4]{1,0} multiply(%x, %x), metadata={op_name="jit(f)/jvp(embed)/mul"}
+  ROOT %fusion.7 = f32[4,4]{1,0} fusion(%w), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(f)/jvp()/attn/qkv/lora/add"}
+}
+"""
+    table = obs_trace.scope_table(text)
+    assert set(table) == {"x", "w", "fusion.7"}     # no fused member
+    assert obs_trace.scope_path(table["fusion.7"]) == "attn/qkv/base"
+    assert obs_trace.scope_path(table["w"]) == "embed"
+    assert obs_trace.scope_path("jit(f)/while/body/add") is None
+
+
+@pytest.mark.parametrize("key,code", [
+    ("scopes", obs_trace.SCOPE_NAMES), ("kernels", obs_trace.KERNEL_NAMES),
+    ("per_step", sorted(obs_trace.PER_STEP_SPANS))])
+def test_name_vocabularies_match_the_schema(key, code):
+    assert obs_trace.load_schema()[key] == list(code)
+    assert obs_trace.PER_STEP_SPANS | obs_trace.ALWAYS_RECORDED <= \
+        set(obs_trace.SPAN_NAMES)
+    with pytest.raises(obs_trace.SpanError):
+        obs_trace.scope("made_up_scope")
+    with pytest.raises(obs_trace.SpanError):
+        with obs_trace.region("made_up_span"):
+            pass
+    with pytest.raises(obs_trace.SpanError):
+        with obs_trace.region("step_build", stray=1):
+            pass
+
+
+def test_every_pallas_call_is_named():
+    import glob
+    import re
+    found = []
+    for path in glob.glob(os.path.join(REPO, "gke_ray_train_tpu", "ops",
+                                       "*.py")):
+        # each call, up to the `)(` that applies it to its operands
+        calls = re.findall(r"pl\.pallas_call\((.*?)\n\s*\)\(",
+                           open(path).read(), re.S)
+        names = [re.findall(r'^\s+name="(\w+)",$', c, re.M)
+                 for c in calls]
+        assert all(len(n) == 1 for n in names), path
+        found += [n[0] for n in names]
+    assert sorted(found) == sorted(obs_trace.KERNEL_NAMES)
+
+
+def test_names_salt_rides_the_aot_compile_s_cache_key(monkeypatch):
+    from jax._src import cache_key
+    from gke_ray_train_tpu.perf import cache as perf_cache
+    salt = perf_cache.names_salt()
+    assert salt.startswith("grt-names:") and salt == perf_cache.names_salt()
+    unsalted = cache_key.custom_hook()
+    with perf_cache.salted_cache_key():
+        assert cache_key.custom_hook() == unsalted + salt
+        # a scope that moves bumps the version, and with it the key
+        monkeypatch.setattr(obs_trace, "SCOPE_VERSION",
+                            obs_trace.SCOPE_VERSION + 1)
+        assert cache_key.custom_hook() != unsalted + salt
+    assert cache_key.custom_hook() == unsalted
+
+    seen = []
+
+    class Lowered:
+        def compile(self):
+            seen.append(cache_key.custom_hook())
+            return object()
+
+    class Jitted:
+        def lower(self, *args):
+            return Lowered()
+    perf_cache.build_or_load_step(Jitted(), label="fake step")
+    assert seen == [unsalted + perf_cache.names_salt()]
+    obs_trace.RECORD.clear()
+
+
+@pytest.mark.parametrize("prefetch", [0, 2], ids=["inline", "prefetch"])
+def test_step_iter_children_nest_and_pipeline_spans_land(
+        record, tiny_train_setup, prefetch):
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    _, _, state, step = tiny_train_setup
+    prof = _NoProfiler()
+    run_training(state, step, _batches(6), epochs=1, log_every=2,
+                 prefetch=prefetch, place_batch=lambda b: b,
+                 profiler=prof)
+    assert prof.closed and prof.steps == 6 and not record.attached
+    spans = list(record.spans)
+    by_id = {s["id"]: s for s in spans}
+    iters = [s for s in spans if s["name"] == "step_iter"]
+    assert [s["step"] for s in iters] == [1, 2, 3, 4, 5, 6]
+    for name, count in (("data_wait", 6), ("step_dispatch", 6),
+                        ("metrics_fetch", 3), ("log_emit", 3)):
+        mine = [s for s in spans if s["name"] == name
+                and s["parent"] in {i["id"] for i in iters}
+                or (name == "step_dispatch" and s["name"] == name)]
+        assert len(mine) == count, name
+    for s in spans:
+        parent = by_id.get(s["parent"])
+        if parent is not None:
+            assert parent["t0"] <= s["t0"] <= s["t1"] <= parent["t1"]
+    # the first dispatch sits under `compile`, which sits under step 1
+    first = next(s for s in spans if s["name"] == "compile")
+    assert by_id[first["parent"]]["name"] == "step_iter"
+    # the pipeline's two stages: on the prefetch thread they have no
+    # parent (a thread's regions nest among themselves); inline they
+    # are the hand-over the loop waits for
+    for name in ("batch_next", "batch_place"):
+        mine = [s for s in spans if s["name"] == name]
+        assert len(mine) >= 6
+        # (the wait that found the stream exhausted is dropped, so the
+        # last `next` under it names a parent that was never recorded)
+        parents = {by_id[s["parent"]]["name"] if s["parent"] else None
+                   for s in mine if s["parent"] is None
+                   or s["parent"] in by_id}
+        assert parents == ({None} if prefetch else {"data_wait"})
+    # a plain jitted step keeps no executable to read a table from
+    assert record.scope_tables == {}
+
+
+def test_no_profiler_no_session_records_nothing(record, tiny_train_setup):
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    _, _, state, step = tiny_train_setup
+    run_training(state, step, _batches(4), epochs=1, log_every=2,
+                 prefetch=2)
+    assert list(record.spans) == [] and record.scope_tables == {}
+
+
+def test_session_keeps_per_step_spans_out_of_the_stream(
+        record, tmp_path, tiny_train_setup):
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    _, _, state, step = tiny_train_setup
+    obs_runtime.start_attempt(obs_dir=str(tmp_path))
+    try:
+        run_training(state, step, _batches(4), epochs=1, log_every=2)
+    finally:
+        obs_runtime.end_attempt("ok")
+    written = {json.loads(line)["name"]
+               for line in open(tmp_path / "spans-r0.jsonl")}
+    assert {"compile", "step_window", "attempt"} <= written
+    assert not written & obs_trace.PER_STEP_SPANS
+    # while the in-memory record, on with a session, holds them
+    assert {"step_iter", "step_dispatch", "compile"} <= \
+        {s["name"] for s in record.spans}
+
+
+@pytest.mark.parametrize("attached", [True, False],
+                         ids=["profiler", "no_profiler"])
+def test_aot_step_build_spans_and_scope_table(record, attached):
+    import jax
+    import jax.numpy as jnp
+
+    from gke_ray_train_tpu.perf.cache import build_or_load_step
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    from gke_ray_train_tpu.models import tiny
+    from gke_ray_train_tpu.train import (
+        make_optimizer, make_train_state, make_train_step)
+    cfg = tiny(vocab_size=128, d_model=32, n_layers=1, n_heads=2,
+               n_kv_heads=2, d_ff=64, dtype="float32",
+               param_dtype="float32")
+    opt = make_optimizer(1e-3)
+    state = make_train_state(cfg, opt, jax.random.key(0))
+    batch = jax.tree.map(jnp.asarray, next(iter(_batches(1)(0))))
+    step = build_or_load_step(
+        make_train_step(cfg, opt, donate=False), state, batch,
+        label="tiny train_step")
+    # the build's spans are recorded whoever listens: it is over before
+    # a profiler could be attached
+    built = {s["name"]: s for s in record.spans}
+    assert set(built) == {"step_build", "step_lower", "step_compile"}
+    assert built["step_build"]["source"] == "compiled"
+    assert built["step_lower"]["parent"] == built["step_build"]["id"]
+    assert step.info["build_s"] == pytest.approx(
+        built["step_build"]["t1"] - built["step_build"]["t0"])
+    assert step.info["build_s"] >= (
+        built["step_compile"]["t1"] - built["step_lower"]["t0"])
+    run_training(state, step, _batches(3), epochs=1, log_every=1,
+                 profiler=_NoProfiler() if attached else None)
+    if attached:
+        table = record.scope_tables["tiny train_step"]
+        assert any(obs_trace.scope_path(op) == "mlp/gate_up/base"
+                   for op in table.values())
+        assert record.scope_table_s["tiny train_step"] > 0
+    else:
+        assert record.scope_tables == {}
+        assert not {s["name"] for s in record.spans} \
+            & obs_trace.PER_STEP_SPANS
