@@ -86,6 +86,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.models.remat import checkpoint_block
 from gke_ray_train_tpu.ops.attention import (
     dot_product_attention, make_attention_mask)
 from gke_ray_train_tpu.ops.norms import rms_norm
@@ -270,11 +271,9 @@ def _stage_repeats(x, pos, seg, w, blocks_r, lora_r, cfg: ModelConfig,
             x = _constrain(x, mesh, AXIS_PIPE, BATCH_AXES, seq_ax, None)
         return (x, aux), None
 
-    if cfg.remat:
-        policy = None
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        body = jax.checkpoint(body, prevent_cse=False, policy=policy)
+    # nothing kept beside the block inputs: a schedule's ticks in flight
+    # multiply what a block keeps, and no chooser sizes that yet
+    body = checkpoint_block(body, cfg)
     xs = [blocks_r]
     if lora_r is not None:
         xs.append(lora_r)

@@ -1,0 +1,153 @@
+"""What the per-block ``jax.checkpoint`` keeps.
+
+Every block of the layer scan is checkpointed (``cfg.remat``): its input
+is saved and its forward runs a second time in the backward pass. This
+module holds what a model's shapes say about saving more: activations
+tagged with ``jax.ad_checkpoint.checkpoint_name`` under the scope names
+the program already has (``obs/trace.py::SCOPE_NAMES``).
+
+- :func:`checkpoint_block` wraps a scan body; the three layer loops
+  (``models/transformer.py``, ``train/overlap.py``,
+  ``models/pipeline.py``) all call it. ``keep=()`` is ``policy=None``.
+- :func:`keep_candidates` / :func:`choose_keep`: per-device bytes of each
+  named tensor over all layers, first-fit into a byte budget.
+- :func:`working_set_bytes`: what a step with nothing kept holds beside
+  its arguments.
+
+The budget itself (the device's limit less the step's arguments) is the
+train step's to know: ``train/remat.py``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Callable, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from gke_ray_train_tpu.models.config import ModelConfig
+
+# the tensors a block can keep, first-fit in this order. `mlp/gate_up`
+# leads because it removes more recomputation than the other three
+# together (two d_model x d_ff matmuls a position against the q/k/v and
+# output projections and one attention forward); the rest follow by
+# device seconds saved per byte kept (the second flash forward, the
+# q/k/v projections with rope, the output projection: PERF.md, PR 25)
+KEEP_ORDER: Tuple[str, ...] = ("mlp/gate_up", "attn/core", "attn/qkv",
+                               "attn/out")
+
+# XLA's peak grows by less than a kept tensor's stacked bytes: the layer
+# in flight was among the block's temporaries already, and the backward
+# no longer holds a recomputed copy beside the cotangents. Over the same
+# compiles as `working_set_bytes`: 0.90-0.92 of the bytes for
+# `mlp/gate_up`, 0.94 for the three attention tensors (1.07 at one row
+# of 2048, which the margin of `working_set_bytes` covers half of)
+KEPT_PEAK_SHARE = 0.92
+
+Candidates = Tuple[Tuple[str, int], ...]
+
+
+def checkpoint_block(body: Callable, cfg: ModelConfig,
+                     keep: Sequence[str] = ()) -> Callable:
+    """``body`` under the block checkpoint ``cfg`` asks for.
+
+    ``remat_policy == "full"``: the block's input is saved, plus the
+    tensors named in ``keep``. ``"dots"`` saves every matmul output and
+    ignores ``keep``."""
+    if not cfg.remat:
+        return body
+    policy = None
+    if cfg.remat_policy == "dots":
+        # save matmul outputs, recompute only elementwise
+        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    elif keep:
+        policy = jax.checkpoint_policies.save_only_these_names(*keep)
+    return jax.checkpoint(body, prevent_cse=False, policy=policy)
+
+
+def keep_candidates(cfg: ModelConfig, rows: int, seq: int, *,
+                    model: int = 1, flash: bool = True) -> Candidates:
+    """``((name, bytes), ...)`` in :data:`KEEP_ORDER`: what keeping each
+    named tensor costs one device over all layers, for a micro-batch of
+    ``rows`` x ``seq`` positions on that device. ``model``: size of the
+    mesh's tensor-parallel axis (heads and d_ff are divided over it).
+    ``flash``: ``attn/core`` names the flash kernel's residuals (``o``,
+    ``lse``); the dense path has nothing under that name. MoE blocks
+    have no ``mlp/gate_up``."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    hd = cfg.resolved_head_dim
+    heads = math.ceil(cfg.n_heads / model)
+    kv_heads = math.ceil(cfg.n_kv_heads / model)
+    per_position = {
+        "attn/core": heads * (hd * item + 4),          # o + float32 lse
+        "mlp/gate_up": 2 * math.ceil(cfg.d_ff / model) * item,
+        "attn/qkv": (heads + 2 * kv_heads) * hd * item,
+        "attn/out": cfg.d_model * item,
+    }
+    if not flash:
+        del per_position["attn/core"]
+    if cfg.n_experts > 0:
+        del per_position["mlp/gate_up"]
+    positions = cfg.n_layers * rows * seq
+    return tuple((n, positions * per_position[n]) for n in KEEP_ORDER
+                 if n in per_position)
+
+
+def choose_keep(candidates: Candidates, budget_bytes: Optional[int], *,
+                peak_share: float = 1.0) -> Tuple[str, ...]:
+    """First-fit in the candidates' order, each charged ``peak_share``
+    of its bytes. ``None`` (the device reports no limit) keeps
+    nothing."""
+    if budget_bytes is None:
+        return ()
+    kept, left = [], budget_bytes
+    for name, nbytes in candidates:
+        charged = math.ceil(nbytes * peak_share)
+        if charged <= left:
+            kept.append(name)
+            left -= charged
+    return tuple(kept)
+
+
+def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
+                      model: int, trainable_bytes: int,
+                      trainable_full_bytes: int,
+                      cast_bytes: int = 0) -> int:
+    """What the step with nothing kept holds beside its arguments at
+    its fullest moment, on one device: inside the backward pass of one
+    micro-batch, or at its loss. ``trainable_bytes``: one device's share
+    of the trainable tree; ``trainable_full_bytes``: the whole tree, of
+    which one layer's gradient exists unsharded before it is reduced;
+    ``cast_bytes``: the compute-dtype copy of trainable leaves that XLA
+    makes once for all layers (it does so for adapters).
+
+    Meant to err high. Set against ``compiled.memory_analysis()`` of
+    the 7B QLoRA step on one described v5e chip over ranks, vocabularies,
+    rows, sequence lengths and depths, it reads 0.04-0.40 GB above
+    XLA's, and 0.04-0.72 GB above for a full fine-tune across four
+    (PERF.md, PR 25)."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    positions = rows * seq
+    d_ff = math.ceil(cfg.d_ff / model)
+    hd = cfg.resolved_head_dim
+    qkv = math.ceil((cfg.n_heads + 2 * cfg.n_kv_heads) / model) * hd
+    attn_io = qkv + cfg.d_model
+    block_weights = (cfg.d_model * (qkv + math.ceil(cfg.n_heads / model)
+                                    * hd)
+                     + max(cfg.n_experts, 1) * 3 * cfg.d_model * d_ff)
+    # float32 logits and their compute-dtype cotangent; this micro-batch
+    # has no gradients yet
+    at_loss = positions * math.ceil(cfg.vocab_size / model) * (4 + item)
+    # a block's backward, the larger of two moments. Many positions: its
+    # weights in the compute dtype (dequantised, cast or gathered) beside
+    # gate / up / act / h with three cotangents and q / k / v / o with
+    # theirs. Few positions: the weights twice (the dx matmuls read them
+    # in another layout) beside the attention's tensors.
+    in_block = (item * max(block_weights
+                           + positions * (7 * d_ff + 4 * attn_io),
+                           2 * block_weights + positions * 2 * attn_io)
+                + trainable_full_bytes // cfg.n_layers)
+    return (trainable_bytes + cast_bytes     # gradient accumulator, copy
+            + cfg.n_layers * positions * cfg.d_model * item  # block inputs
+            + max(at_loss, trainable_bytes + in_block))

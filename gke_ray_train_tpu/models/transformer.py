@@ -18,13 +18,15 @@ from __future__ import annotations
 
 import logging
 import math
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.models.remat import checkpoint_block
 from gke_ray_train_tpu.obs.trace import scope
 from gke_ray_train_tpu.ops.attention import (
     dot_product_attention, make_attention_mask)
@@ -265,6 +267,7 @@ def _mlp(x, lp, cfg: ModelConfig, dtype, lora_p=None, lora_scale=1.0,
                      _drop_key(drop_rng, 4), drop_rate)
         up = _proj(x, lp["w_up"], lr("w_up"), lora_scale, dtype,
                    _drop_key(drop_rng, 5), drop_rate)
+        gate, up = (checkpoint_name(t, "mlp/gate_up") for t in (gate, up))
         if cfg.activation == "silu":
             act = jax.nn.silu(gate)
         elif cfg.activation == "gelu_tanh":
@@ -302,6 +305,8 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
         with scope("attn/rope"):
             q, k = _apply_rope_qk(q, k, positions, rope,
                                   fused_ops=fused_ops, mesh=mesh)
+    # what the attention backward reads: q and k after rope, v
+    q, k, v = (checkpoint_name(t, "attn/qkv") for t in (q, k, v))
     with scope("attn/core"):
         if impl == "xla":
             out = dot_product_attention(
@@ -319,8 +324,9 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
                 logit_softcap=cfg.attn_softcap, mesh=mesh)
         out = out.reshape(B, S, H * hd)
     with scope("attn/out"):
-        return _proj(out, lp["wo"], lr("wo"), lora_scale, dtype,
-                     _drop_key(drop_rng, 3), drop_rate)
+        return checkpoint_name(
+            _proj(out, lp["wo"], lr("wo"), lora_scale, dtype,
+                  _drop_key(drop_rng, 3), drop_rate), "attn/out")
 
 
 def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
@@ -414,7 +420,8 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
             with_aux: bool = False,
             token_weights: Optional[jnp.ndarray] = None,
             fused_ops: bool = False,
-            return_pre_unembed: bool = False):
+            return_pre_unembed: bool = False,
+            remat_keep: Tuple[str, ...] = ()):
     """tokens [B, S] int32 → logits [B, S, vocab] float32.
 
     ``lora``: optional adapter pytree from train/lora.py (same block
@@ -444,6 +451,10 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
     [B, S, D] instead of logits — the fused cross-entropy path
     (ops/fused_ce.py) consumes it so the [B, S, V] logits are never
     materialized in HBM.
+
+    ``remat_keep``: the named activations every checkpointed block
+    saves beside its input (models/remat.py; ``()`` saves the input
+    alone, and so does a pipelined mesh whatever is named).
     """
     B, S = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
@@ -536,15 +547,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
             fused_ops=fused_ops)
         return (x, aux), None
 
-    body = repeat_body
-    if cfg.remat:
-        policy = None
-        if cfg.remat_policy == "dots":
-            # save matmul outputs, recompute only elementwise — trades
-            # HBM for the ~2N/token recompute the "full" policy pays
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        body = jax.checkpoint(repeat_body, prevent_cse=False,
-                              policy=policy)
+    body = checkpoint_block(repeat_body, cfg, remat_keep)
     xs = [params["blocks"]]
     if lora is not None:
         xs.append(lora["blocks"])

@@ -132,8 +132,10 @@ SPAN_NAMES: Dict[str, tuple] = {
     "batch_next": (),
     "batch_place": (),
     # perf/cache.py::build_or_load_step: a handful a process, recorded
-    # always; `source` is "deserialized" | "compiled"
-    "step_build": ("source",),
+    # always; `source` is "deserialized" | "compiled"; `remat_*`: what
+    # the block checkpoints keep beside their inputs (train/remat.py)
+    "step_build": ("source", "remat_keep", "remat_keep_bytes",
+                   "remat_budget_bytes", "remat_keep_fallback"),
     "step_lower": (),
     "step_compile": (),
 }

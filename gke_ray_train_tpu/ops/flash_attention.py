@@ -28,6 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -552,6 +553,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     def fa_fwd(qt, kt, vt, qp, kp, qs, ks):
         out, lse = _fwd(qt, kt, vt, qp, kp, qs, ks, **kw)
+        # the two residuals only this kernel can make, named here in the
+        # forward rule so that a block checkpoint can keep both
+        # (models/remat.py): with `out` alone kept, `lse` would still
+        # force the kernel to run again
+        out = checkpoint_name(out, "attn/core")
+        lse = checkpoint_name(lse, "attn/core")
         return out, (qt, kt, vt, out, lse, qp, kp, qs, ks)
 
     def fa_bwd(res, g):

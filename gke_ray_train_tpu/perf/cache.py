@@ -46,6 +46,7 @@ Gotchas this module owns so callers don't have to:
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import hashlib
 import json
 import logging
@@ -474,10 +475,41 @@ def _note_cost_report(compiled, plan) -> None:
         logger.warning("obs cost-report note skipped: %s", e)
 
 
+@dataclasses.dataclass(frozen=True)
+class StepFallback:
+    """The step to build where the one asked for does not fit the
+    device: its jitted function, the ``attrs`` that then replace the
+    first step's, and the peak (``compiled.memory_analysis()``) past
+    which the first step counts as not fitting although it compiled."""
+
+    fn: Callable
+    attrs: Dict[str, Any]
+    peak_limit_bytes: Optional[int] = None
+
+
+def _variant_key(key: str, variant: str) -> str:
+    return hashlib.sha256(f"{key}|{variant}".encode()).hexdigest()
+
+
+def _past_peak(compiled, limit: Optional[int]) -> Optional[str]:
+    """Says so where XLA's peak for ``compiled`` passes ``limit``."""
+    if limit is None:
+        return None
+    peak = getattr(compiled.memory_analysis(), "peak_memory_in_bytes", 0)
+    if peak <= limit:
+        return None
+    return (f"the compiled step's peak of {peak / 1e9:.2f} GB passes "
+            f"{limit / 1e9:.2f} GB")
+
+
 def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
                        sidecar: Optional[str] = None,
                        label: str = "train_step",
-                       plan=None, surface: str = "train") -> GuardedStep:
+                       plan=None, surface: str = "train",
+                       variant: str = "",
+                       attrs: Optional[Dict[str, Any]] = None,
+                       fallback: Optional[StepFallback] = None
+                       ) -> GuardedStep:
     """AOT-build a jitted step (or deserialize its sidecar) and return a
     :class:`GuardedStep`.
 
@@ -489,26 +521,64 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
       is set, serialize for the next restart. Only process 0 writes —
       every host of a slice lowers the same program and the sidecar
       lives on shared storage.
+    - ``variant``: what the arguments and the plan do not say about the
+      program ``jitted_fn`` traces to; part of the sidecar's key.
+      ``attrs``: further attributes of the ``step_build`` span, in
+      ``info`` too.
+    - ``fallback``: the compiler is the judge of what fits. A compile
+      that ends out of device memory, or in a peak past the fallback's
+      limit, builds the fallback's step instead and logs a warning. Its
+      sidecar remembers that, so that a restart does not compile the
+      first step again to learn the same.
     """
     args = tuple(abstractify(a) for a in abstract_args)
-    key = aot_signature(*args, plan=plan, surface=surface)
+    key = fb_key = aot_signature(*args, plan=plan, surface=surface)
+    if variant:
+        key = _variant_key(key, variant)
+    if fallback is not None:
+        fb_key = _variant_key(key, "fallback")
     info: Dict[str, Any] = {"label": label, "sidecar": sidecar}
     if plan is not None:
         info["plan_fingerprint"] = plan.fingerprint()
-    with trace.region("step_build") as build:
+
+    def lower_and_compile(fn):
+        # a step that cannot be lowered or compiled is an error, not a
+        # reason to leave AOT: the jitted path would hit the same wall
+        # at its first call, later and with less context
+        with trace.region("step_lower"):
+            lowered = fn.lower(*args)
+        with trace.region("step_compile"), salted_cache_key():
+            return lowered.compile()
+
+    fell_back = False
+    with trace.region("step_build", **(attrs or {})) as build:
         compiled = load_executable(sidecar, key) if sidecar else None
+        if compiled is None and sidecar and fallback is not None:
+            compiled = load_executable(sidecar, fb_key)
+            fell_back = compiled is not None
         if compiled is not None:
             build.attrs["source"] = "deserialized"
         else:
-            # a step that cannot be lowered or compiled is an error,
-            # not a reason to fall back: the jitted path would hit the
-            # same wall at its first call, later and with less context
-            with trace.region("step_lower"):
-                lowered = jitted_fn.lower(*args)
-            with trace.region("step_compile"), salted_cache_key():
-                compiled = lowered.compile()
             build.attrs["source"] = "compiled"
-    info.update(source=build.attrs["source"], build_s=build.t1 - build.t0)
+            why = None
+            try:
+                compiled = lower_and_compile(jitted_fn)
+                if fallback is not None:
+                    why = _past_peak(compiled, fallback.peak_limit_bytes)
+            except jax.errors.JaxRuntimeError as e:
+                if fallback is None or "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                why = str(e).splitlines()[0]
+            if why is not None:
+                logger.warning(
+                    "%s: the step asked for (%s) does not fit: %s; "
+                    "building the fallback", label, variant, why)
+                fell_back = True
+                compiled = lower_and_compile(fallback.fn)
+        if fell_back:
+            build.attrs.update(fallback.attrs)
+            jitted_fn, key = fallback.fn, fb_key
+    info.update(build.attrs, build_s=build.t1 - build.t0)
     logger.info("%s: %s AOT executable in %.2fs%s", label, info["source"],
                 info["build_s"],
                 f" ({sidecar})" if info["source"] == "deserialized" else "")

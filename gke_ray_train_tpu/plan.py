@@ -1065,5 +1065,12 @@ def compile_step_with_plan(plan: ExecutionPlan, mesh, fn: Callable,
         # traces+compiles, hitting the persistent cache when warm)
         return fn
     from gke_ray_train_tpu.perf.cache import build_or_load_step
-    return build_or_load_step(fn, *abstract_args, sidecar=sidecar,
-                              label=label, plan=plan, surface=surface)
+    build_kw = dict(sidecar=sidecar, label=label, plan=plan,
+                    surface=surface)
+    remat = getattr(fn, "remat", None)
+    if remat is not None:
+        # a train step of make_train_step: now that its arguments are
+        # known it sizes what its block checkpoints keep, and builds
+        # itself with that (train/remat.py)
+        return remat.build(fn, *abstract_args, **build_kw)
+    return build_or_load_step(fn, *abstract_args, **build_kw)

@@ -57,6 +57,7 @@ from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.models.remat import checkpoint_block
 from gke_ray_train_tpu.models.transformer import (
     Params, param_specs, pre_unembed, resolve_seq_impl, run_block_stack,
     unembed_head, _unembed, make_attention_mask)
@@ -197,7 +198,7 @@ def _gather_layer(blocks, block_specs, mesh: Mesh, i, shard_reduce=None):
 
 def _pipelined_hidden(full_nonblock: Params, blocks_local, cfg: ModelConfig,
                       mesh: Mesh, tokens, positions, segment_ids,
-                      fused_ops: bool, shard_reduce=None):
+                      fused_ops: bool, shard_reduce=None, remat_keep=()):
     """tokens -> final hidden state, with the per-layer double-buffered
     fsdp gather. Per-layer math is :func:`run_block_stack` — the same
     function ``forward``'s scan body calls, so the two paths cannot
@@ -254,14 +255,9 @@ def _pipelined_hidden(full_nonblock: Params, blocks_local, cfg: ModelConfig,
             masks, segment_ids, None, fused_ops=fused_ops)
         return (x, aux, nxt), None
 
-    bodyf = body
-    if cfg.remat:
-        policy = None
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        bodyf = jax.checkpoint(body, prevent_cse=False, policy=policy)
     (x, _, _), _ = jax.lax.scan(
-        bodyf, (x, jnp.zeros((), jnp.float32), cur0), jnp.arange(R))
+        checkpoint_block(body, cfg, remat_keep),
+        (x, jnp.zeros((), jnp.float32), cur0), jnp.arange(R))
     return x
 
 
@@ -272,7 +268,8 @@ def make_manual_grad_fn(cfg: ModelConfig, mesh: Mesh, *,
                         use_fused_ce: bool = False,
                         num_slices: int = 1,
                         dcn_sync: str = "flat",
-                        dcn_compress: str = "none"):
+                        dcn_compress: str = "none",
+                        remat_keep: Tuple[str, ...] = ()):
     """Build ``(params, micro) -> ((nll_sum, w_sum), grads)`` — the
     drop-in replacement for the GSPMD path's
     ``value_and_grad(micro_loss)`` that the accum scan consumes. The
@@ -288,7 +285,10 @@ def make_manual_grad_fn(cfg: ModelConfig, mesh: Mesh, *,
     ``(params, micro, residual) -> ((nll, w), grads, new_residual)`` —
     the residual tree is params-shaped (zeros at step start; the accum
     scan in ``train/step.py`` carries it across microbatches) and the
-    returned fn carries ``grad_fn.compressed = True``."""
+    returned fn carries ``grad_fn.compressed = True``.
+
+    ``remat_keep``: what each checkpointed layer keeps beside its input
+    (models/remat.py)."""
     from gke_ray_train_tpu.parallel.hierarchical import (
         compressed_cross_psum, flat_reduce_shard, hier_reduce_full,
         hier_reduce_shard, intra_reduce_shard, slice_topology,
@@ -337,7 +337,8 @@ def make_manual_grad_fn(cfg: ModelConfig, mesh: Mesh, *,
             full_nb = _gather_full(nonblock, nb_specs, mesh, shard_reduce)
             x = _pipelined_hidden(full_nb, p["blocks"], cfg, mesh,
                                   micro_local["inputs"], positions,
-                                  segment_ids, fused_ops, shard_reduce)
+                                  segment_ids, fused_ops, shard_reduce,
+                                  remat_keep)
             dtype = jnp.dtype(cfg.dtype)
             if use_fused_ce and cfg.logit_softcap is None:
                 from gke_ray_train_tpu.ops.fused_ce import \

@@ -1,0 +1,170 @@
+"""The train step keeps in its block checkpoints what the device has
+room for.
+
+``models/remat.py`` knows what each named activation costs and what a
+step with nothing kept needs; here is what only a built step knows: the
+bytes of its arguments and the limit its device reports. The step of
+``train/step.py::make_train_step`` carries a :class:`StepRemat`, and
+the one compile surface (``plan.py::compile_step_with_plan``) hands it
+the abstract arguments: :meth:`StepRemat.build` sizes the keep set,
+and has ``perf/cache.py::build_or_load_step`` build the step that keeps
+it, with the step that keeps nothing as the fallback. The estimate
+picks; the compiler judges.
+
+The choice reads shapes, bytes and the device's reported limit, nothing
+else. A device that reports no ``bytes_limit`` (XLA:CPU) keeps nothing.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import logging
+import math
+from typing import Any, Callable, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.models.remat import (
+    KEPT_PEAK_SHARE, choose_keep, keep_candidates, working_set_bytes)
+from gke_ray_train_tpu.models.transformer import resolve_seq_impl
+from gke_ray_train_tpu.perf.cache import StepFallback, build_or_load_step
+
+logger = logging.getLogger(__name__)
+
+# HBM left alone beside XLA's peak for the step: the batches the
+# prefetcher holds, the metrics, the allocator's fragmentation
+RESERVE_BYTES = 128 * 2**20
+
+
+def shard_bytes(tree: Any, *, whole: bool = False, dtype=None) -> int:
+    """Bytes one device holds of a tree of (abstract) arrays: each
+    leaf's shard shape under its sharding, its full shape without one
+    (or with ``whole``: the bytes of the whole tree). ``dtype``: as if
+    every leaf were of that type."""
+    total = 0
+    for leaf in jax.tree.leaves(tree):
+        shape = tuple(leaf.shape)
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None and not whole:
+            shape = sharding.shard_shape(shape)
+        total += math.prod(shape) * jnp.dtype(dtype or leaf.dtype).itemsize
+    return total
+
+
+def device_bytes_limit(mesh) -> Optional[int]:
+    """The HBM that this process's first device of ``mesh`` (of the
+    default backend without one) reports, None where it reports none
+    (XLA:CPU). A device of another host cannot be asked, and every host
+    of a slice has to reach the same keep set, so each asks its own: the
+    chips of a slice are alike.
+
+    A mesh over a described topology (``jax.experimental.topologies``:
+    compiled for, never run on) has no device to ask either: None."""
+    device = (jax.local_devices() if mesh is None
+              else mesh.local_devices)[0]
+    try:
+        stats = device.memory_stats()
+    except jax.errors.JaxRuntimeError as e:
+        if "addressable" not in str(e):
+            raise
+        return None                   # a compile-only client's device
+    limit = (stats or {}).get("bytes_limit")
+    return int(limit) if limit else None
+
+
+@dataclasses.dataclass(frozen=True)
+class RematChoice:
+    keep: Tuple[str, ...] = ()
+    keep_bytes: int = 0
+    budget_bytes: Optional[int] = None   # None: no limit reported
+    limit_bytes: Optional[int] = None
+
+    def attrs(self, *, fallback: bool = False) -> dict:
+        """The ``step_build`` span's ``remat_*`` attributes (and
+        ``GuardedStep.info``'s) for the step that keeps this choice, or
+        for the one built in its place with nothing kept."""
+        keep = () if fallback else self.keep
+        return {"remat_keep": list(keep),
+                "remat_keep_bytes": self.keep_bytes if keep else 0,
+                "remat_budget_bytes": self.budget_bytes,
+                "remat_keep_fallback": fallback}
+
+
+@dataclasses.dataclass(frozen=True)
+class StepRemat:
+    """Attached to a jitted train step as ``.remat``: how to size the
+    keep set for given abstract arguments, and how to make the same
+    step with one."""
+
+    cfg: ModelConfig
+    mesh: Any
+    grad_accum: int
+    lora: bool
+    with_keep: Callable[[Tuple[str, ...]], Callable]
+
+    def choose(self, state, batch) -> RematChoice:
+        limit = device_bytes_limit(self.mesh)
+        axes = {} if self.mesh is None else dict(self.mesh.shape)
+        if limit is None or axes.get("pipe", 1) > 1:
+            # models/pipeline.py keeps nothing, whatever is named
+            return RematChoice()
+        inputs = batch["inputs"]
+        flash = resolve_seq_impl(self.cfg, self.mesh,
+                                 inputs.shape[1]) == "flash"
+        rows, seq = inputs.sharding.shard_shape(tuple(inputs.shape))
+        rows //= self.grad_accum
+        model = axes.get("model", 1)
+        trainable = state.lora if self.lora else state.params
+        budget = (limit - shard_bytes((state, batch))
+                  - working_set_bytes(
+                      self.cfg, rows, seq, model=model,
+                      trainable_bytes=shard_bytes(trainable),
+                      trainable_full_bytes=shard_bytes(trainable,
+                                                       whole=True),
+                      # adapters are cast once, for all layers
+                      cast_bytes=shard_bytes(trainable,
+                                             dtype=self.cfg.dtype)
+                      if self.lora else 0)
+                  - RESERVE_BYTES)
+        candidates = keep_candidates(self.cfg, rows, seq, model=model,
+                                     flash=flash)
+        keep = choose_keep(candidates, budget,
+                           peak_share=KEPT_PEAK_SHARE)
+        sizes = dict(candidates)
+        return RematChoice(keep, sum(sizes[n] for n in keep), budget,
+                           limit)
+
+    def build(self, step: Callable, state, batch, *,
+              label: str = "train_step", **build_kw):
+        """``build_or_load_step`` for ``step`` (the one that keeps
+        nothing) or, where the device has room, for the same step with
+        the names that fit. One lower and one compile on the path that
+        fits; a compile that ends out of HBM, or within the reserve of
+        the limit, builds ``step`` instead."""
+        choice = self.choose(state, batch)
+        if choice.keep:
+            built = build_or_load_step(
+                self.with_keep(choice.keep), state, batch, label=label,
+                variant=f"remat_keep={choice.keep}",
+                attrs=choice.attrs(),
+                fallback=StepFallback(
+                    step, choice.attrs(fallback=True),
+                    peak_limit_bytes=choice.limit_bytes - RESERVE_BYTES),
+                **build_kw)
+        else:
+            built = build_or_load_step(step, state, batch, label=label,
+                                       attrs=choice.attrs(), **build_kw)
+        if built.info["remat_keep_fallback"]:
+            logger.warning(
+                "%s: keeping %s (%.2f GB a device) in the block "
+                "checkpoints does not fit; built with nothing kept",
+                label, list(choice.keep), choice.keep_bytes / 1e9)
+        logger.info(
+            "%s: block checkpoints keep %s (%.2f GB a device; room for "
+            "%s)", label, built.info["remat_keep"] or "their inputs only",
+            built.info["remat_keep_bytes"] / 1e9,
+            "no device limit reported" if choice.budget_bytes is None
+            else f"{choice.budget_bytes / 1e9:.2f} GB")
+        return built

@@ -18,7 +18,8 @@ TPU-first differences:
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, NamedTuple, Optional
+import functools
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,7 @@ from gke_ray_train_tpu.obs.trace import scope
 from gke_ray_train_tpu.parallel.mesh import BATCH_AXES
 from gke_ray_train_tpu.parallel.sharding import tree_shardings
 from gke_ray_train_tpu.train.lora import LoraConfig, init_lora, lora_specs
+from gke_ray_train_tpu.train.remat import StepRemat
 
 Batch = Dict[str, jnp.ndarray]
 
@@ -192,7 +194,8 @@ def make_train_step(cfg: ModelConfig,
                     donate: Any = _UNSET,
                     donate_batch: Any = _UNSET,
                     pipe_microbatches: Any = _UNSET,
-                    plan=None
+                    plan=None,
+                    remat_keep: Tuple[str, ...] = ()
                     ) -> Callable[[TrainState, Batch], tuple]:
     """Build the jitted ``(state, batch) -> (state, metrics)`` function.
 
@@ -216,7 +219,22 @@ def make_train_step(cfg: ModelConfig,
 
     ``pipe_microbatches``: pipeline microbatch count per forward when the
     mesh has a pipe axis > 1 (models/pipeline.py; default = stage count).
+
+    ``remat_keep``: the named activations each checkpointed block saves
+    beside its input (models/remat.py). A step that names none carries
+    ``.remat`` (train/remat.py): the AOT build
+    (``plan.py::compile_step_with_plan`` with abstract arguments) sizes
+    from it the set that fits the device, and builds this step with
+    that set in place of this one. The jitted step itself keeps what is
+    named here and nothing else.
     """
+    keep = tuple(remat_keep)
+    # as passed, before the plan fills them in
+    same_step = functools.partial(
+        make_train_step, cfg, optimizer, mesh=mesh, lora_cfg=lora_cfg,
+        grad_accum=grad_accum, schedule=schedule, donate=donate,
+        donate_batch=donate_batch, pipe_microbatches=pipe_microbatches,
+        plan=plan)
     if grad_accum is _UNSET:
         grad_accum = plan.grad_accum if plan is not None else 1
     if donate is _UNSET:
@@ -256,7 +274,8 @@ def make_train_step(cfg: ModelConfig,
             num_slices=plan.num_slices if plan is not None else 1,
             dcn_sync=plan.dcn_sync if plan is not None else "flat",
             dcn_compress=(plan.dcn_compress if plan is not None
-                          else "none"))
+                          else "none"),
+            remat_keep=keep)
 
     def micro_loss(trainable: Params, frozen: Params, micro: Batch,
                    drop_rng=None):
@@ -266,7 +285,7 @@ def make_train_step(cfg: ModelConfig,
                    with_aux=moe,
                    token_weights=micro["weights"] if moe else None,
                    fused_ops=fused_ops,
-                   return_pre_unembed=fused_ce)
+                   return_pre_unembed=fused_ce, remat_keep=keep)
         if lora_mode:
             out = forward(frozen, micro["inputs"], cfg, lora=trainable,
                           lora_scale=lora_cfg.scale,
@@ -388,15 +407,20 @@ def make_train_step(cfg: ModelConfig,
         # one compile surface: plan-routed steps jit through
         # compile_step_with_plan (which also tags donate_argnums)
         from gke_ray_train_tpu.plan import compile_step_with_plan
-        return compile_step_with_plan(plan, mesh, train_step,
-                                      donate_argnums=argnums)
-    fn = jax.jit(train_step, donate_argnums=argnums)
-    try:
-        # introspection hook for tests/tooling: jit wrappers do not
-        # expose their donate_argnums publicly
-        fn.donate_argnums = argnums
-    except (AttributeError, TypeError):  # pragma: no cover - frozen type
-        pass
+        fn = compile_step_with_plan(plan, mesh, train_step,
+                                    donate_argnums=argnums)
+    else:
+        fn = jax.jit(train_step, donate_argnums=argnums)
+        try:
+            # introspection hook for tests/tooling: jit wrappers do not
+            # expose their donate_argnums publicly
+            fn.donate_argnums = argnums
+        except (AttributeError, TypeError):  # pragma: no cover - frozen
+            pass
+    if not keep and cfg.remat and cfg.remat_policy == "full":
+        fn.remat = StepRemat(
+            cfg=cfg, mesh=mesh, grad_accum=grad_accum, lora=lora_mode,
+            with_keep=lambda names: same_step(remat_keep=names))
     return fn
 
 
