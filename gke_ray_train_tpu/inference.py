@@ -40,7 +40,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from gke_ray_train_tpu.data.sft import format_gretel_sql_example, render_chat
 from gke_ray_train_tpu.models.config import ModelConfig
-from gke_ray_train_tpu.models.kvcache import greedy_generate_cached
+from gke_ray_train_tpu.models.kvcache import (
+    greedy_generate_cached, require_decodable)
 from gke_ray_train_tpu.models.transformer import Params
 from gke_ray_train_tpu.serve.bucketing import (
     form_prompt_buffer, prompt_bucket, truncate_prompt)
@@ -108,6 +109,7 @@ def generate_answer(params: Params, cfg: ModelConfig, tokenizer,
                     lora: Optional[Params] = None,
                     lora_scale: float = 1.0,
                     mesh: Optional[Mesh] = None) -> str:
+    require_decodable(cfg)
     ids = np.asarray(
         tokenizer(prompt_text, add_special_tokens=False)["input_ids"],
         np.int32)

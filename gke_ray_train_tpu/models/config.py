@@ -20,6 +20,18 @@ from typing import Optional, Tuple
 # module, no deps) so ops/quant.py and train/lora.py can both import it
 # without a train↔ops cycle.
 PROJ_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# the shared expert of a routed layer (cfg.n_shared_experts): the MLP
+# every token passes beside the routed experts, adapted and quantized
+# like the dense MLP whose place it takes
+SHARED_TARGETS = ("shared_gate", "shared_up", "shared_down")
+
+
+# fields that came with the sigmoid-routed decoder (PR 26), after model
+# digests were first recorded (ModelConfig.to_dict)
+_LATER_FIELDS = frozenset({
+    "rope_kinds", "qk_norm", "router", "router_bias", "router_renorm",
+    "router_scale", "n_shared_experts", "expert_d_ff", "n_dense_layers",
+    "experts_held", "n_mtp_layers"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +58,12 @@ class ModelConfig:
     # "global" = full causal attention, "sliding" = windowed causal.
     block_pattern: Tuple[str, ...] = ("global",)
     sliding_window: Optional[int] = None
+    # the block kinds whose q and k are rotated (EXAONE-4 rotates in its
+    # windowed layers only; its full-attention layers carry no position)
+    rope_kinds: Tuple[str, ...] = ("global", "sliding")
+    # RMSNorm over each head's q and k before the rotation, one scale
+    # vector of head_dim a layer each (EXAONE-4, Qwen-3)
+    qk_norm: bool = False
 
     activation: str = "silu"                # "silu" | "gelu_tanh"
 
@@ -57,9 +75,34 @@ class ModelConfig:
     expert_top_k: int = 2
     # per-expert token capacity = capacity_factor * top_k * S / E
     # (GShard-style static capacity; overflow tokens drop to the
-    # residual path)
+    # residual path). Read by the "softmax" router only
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01           # Switch load-balance loss weight
+    # "softmax": Mixtral (probabilities, static capacity with drops, the
+    # Switch aux loss). "sigmoid": DeepSeek-V3 (independent scores, no
+    # capacity and no drop, no aux loss; grouped products over the pairs
+    # sorted by expert)
+    router: str = "softmax"
+    router_bias: bool = False       # frozen [E] added for selection only
+    router_renorm: bool = True      # weights / sum over the selected
+    router_scale: float = 1.0       # then times this
+    n_shared_experts: int = 0       # always-on SwiGLU beside the routed
+    expert_d_ff: Optional[int] = None       # default d_ff
+    # leading layers whose MLP is dense (width d_ff) before the routed
+    # ones (DeepSeek-V3's first_k_dense_replace)
+    n_dense_layers: int = 0
+    # [lo, hi) of the n_experts whose weights this program holds (one
+    # expert-parallel rank's share): the router still scores all
+    # n_experts and a token's weights are normalised over all it
+    # selected, but only pairs that land in the range are computed.
+    # None holds all
+    experts_held: Optional[Tuple[int, int]] = None
+    # multi-token-prediction layers the checkpoint carries after the
+    # decoder (DeepSeek-V3's num_nextn_predict_layers). Nothing here
+    # runs them: training leaves them out (an SFT loss has no term for
+    # them), and serving refuses a model that has them, since they are
+    # its draft head (models/kvcache.py::require_decodable)
+    n_mtp_layers: int = 0
 
     tie_embeddings: bool = False
     embed_scale: bool = False               # x *= sqrt(d_model) after embed
@@ -94,9 +137,9 @@ class ModelConfig:
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling",
                                tuple(sorted(self.rope_scaling.items())))
-        if isinstance(self.block_pattern, list):
-            object.__setattr__(self, "block_pattern",
-                               tuple(self.block_pattern))
+        for name in ("block_pattern", "rope_kinds", "experts_held"):
+            if isinstance(getattr(self, name), list):
+                object.__setattr__(self, name, tuple(getattr(self, name)))
         if self.n_layers % len(self.block_pattern) != 0:
             raise ValueError(
                 f"n_layers={self.n_layers} not divisible by block pattern "
@@ -117,10 +160,35 @@ class ModelConfig:
             raise ValueError(f"unknown remat_policy {self.remat_policy!r}")
         if self.pipe_virtual < 1:
             raise ValueError(f"pipe_virtual={self.pipe_virtual} must be >= 1")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router {self.router!r}")
+        if (self.n_shared_experts or self.n_dense_layers
+                or self.experts_held or self.router_bias) \
+                and not (self.n_experts and self.router == "sigmoid"):
+            raise ValueError(
+                "shared experts, leading dense layers, a held range and "
+                "a router bias belong to the 'sigmoid' router's layer "
+                "(n_experts > 0, router='sigmoid')")
+        if self.n_dense_layers > self.n_layers:
+            raise ValueError("n_dense_layers exceeds n_layers")
+        if (self.n_layers - self.prologue_layers) \
+                % len(self.block_pattern) != 0:   # pragma: no cover
+            raise ValueError("layers after the prologue do not fill "
+                             "whole periods of block_pattern")
+        lo, hi = self.held_range
+        if self.n_experts and not 0 <= lo < hi <= self.n_experts:
+            raise ValueError(f"experts_held={self.experts_held} is no "
+                             f"range of {self.n_experts} experts")
 
     def to_dict(self) -> dict:
-        """JSON-serializable form (offline converter sidecar files)."""
-        return dataclasses.asdict(self)
+        """JSON-serializable form (offline converter sidecar files, the
+        autotune registry's model digest). The fields of
+        :data:`_LATER_FIELDS` are left out at their defaults, so that a
+        model which does not use them keeps the digest it was recorded
+        under; ``from_dict`` fills them in again."""
+        defaults = {f.name: f.default for f in dataclasses.fields(self)}
+        return {k: v for k, v in dataclasses.asdict(self).items()
+                if k not in _LATER_FIELDS or v != defaults[k]}
 
     @staticmethod
     def from_dict(d: dict) -> "ModelConfig":
@@ -144,39 +212,82 @@ class ModelConfig:
         return "flash" if on_tpu() else "xla"
 
     @property
+    def prologue_layers(self) -> int:
+        """Leading layers that run before the scan, one by one: the
+        dense-MLP layers, rounded up to whole periods of the block
+        pattern so that the scanned periods stay aligned to it."""
+        period = len(self.block_pattern)
+        return -(-self.n_dense_layers // period) * period
+
+    @property
     def n_repeats(self) -> int:
-        return self.n_layers // len(self.block_pattern)
+        """Scanned periods (every layer of a model with no prologue)."""
+        return (self.n_layers - self.prologue_layers) \
+            // len(self.block_pattern)
+
+    def mlp_kind(self, layer: int) -> str:
+        """"dense" | "moe" for the MLP of layer ``layer``."""
+        return "moe" if self.n_experts and layer >= self.n_dense_layers \
+            else "dense"
+
+    @property
+    def scan_mlp_kind(self) -> str:
+        """The MLP of every scanned layer (the dense ones lead)."""
+        return self.mlp_kind(self.n_layers - 1)
+
+    @property
+    def resolved_expert_d_ff(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    @property
+    def n_experts_held(self) -> int:
+        lo, hi = self.held_range
+        return hi - lo if self.n_experts else 0
 
     def param_count(self) -> int:
         """Exact TOTAL param count (storage truth; for MoE this counts
-        every expert). MFU math uses active_param_count()."""
-        return self._count_params(self.n_experts)
+        every expert held). MFU math uses active_param_count()."""
+        return self._count_params(self.n_experts_held)
 
-    def active_param_count(self) -> int:
-        """Params touched per token: for MoE, the router plus the top-k
-        experts only — the FLOP-relevant count (train/metrics.py)."""
-        return self._count_params(min(self.expert_top_k, self.n_experts)
-                                  if self.n_experts else 0)
+    def active_param_count(self) -> float:
+        """Params touched per token: for MoE, the router, the shared
+        expert and the top-k experts only — the FLOP-relevant count
+        (train/metrics.py). Where only a range of the experts is held,
+        a token meets ``top_k * held / n_experts`` of them here on
+        average, and that is what is counted."""
+        if not self.n_experts:
+            return self._count_params(0)
+        k = min(self.expert_top_k, self.n_experts)
+        return self._count_params(
+            k * self.n_experts_held / self.n_experts
+            if self.experts_held else k)
 
-    def _count_params(self, experts_counted: int) -> int:
+    def _count_params(self, experts_counted) -> int:
         hd = self.resolved_head_dim
         attn = (self.d_model * self.n_heads * hd          # wq
                 + 2 * self.d_model * self.n_kv_heads * hd  # wk, wv
                 + self.n_heads * hd * self.d_model)        # wo
         if self.attn_qkv_bias:
             attn += self.n_heads * hd + 2 * self.n_kv_heads * hd
+        if self.qk_norm:
+            attn += 2 * hd
         ffn = 3 * self.d_model * self.d_ff
-        if self.n_experts:
-            mlp = (self.d_model * self.n_experts          # router
-                   + experts_counted * ffn)
-        else:
-            mlp = ffn
+        n_moe = self.n_layers - self.n_dense_layers if self.n_experts else 0
+        moe = (self.d_model * self.n_experts               # router
+               + (self.n_experts if self.router_bias else 0)
+               + (experts_counted + self.n_shared_experts)
+               * 3 * self.d_model * self.resolved_expert_d_ff)
         norms = 2 * self.d_model + (2 * self.d_model if self.post_block_norm
                                     else 0)
-        per_layer = attn + mlp + norms
         embed = self.vocab_size * self.d_model
         head = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        return self.n_layers * per_layer + embed + head + self.d_model
+        return (self.n_layers * (attn + norms) + n_moe * moe
+                + (self.n_layers - n_moe) * ffn
+                + embed + head + self.d_model)
 
 
 # ---------------------------------------------------------------------------
@@ -255,6 +366,29 @@ def mixtral_8x7b(**kw) -> ModelConfig:
         **kw)
 
 
+def k_exaone_236b(**kw) -> ModelConfig:
+    """K-EXAONE-236B-A23B (LGAI-EXAONE, ``model_type`` exaone_moe) at
+    its published sizes: 48 layers of which the first has a dense MLP
+    and the rest 128 sigmoid-routed experts (8 a token, weights
+    renormalised and scaled by 2.5, a frozen selection bias) beside one
+    shared expert; attention in periods of three window-128 layers with
+    rotary and one full layer without, RMSNorm on q and k per head.
+    The multi-token-prediction layer of the checkpoint is not part of
+    this decoder (ROADMAP R8). Keywords override: a deployment's share
+    of it states its own ``n_layers``, ``experts_held``, ``vocab_size``."""
+    published = dict(
+        name="k-exaone-236b", vocab_size=153600, d_model=6144,
+        n_layers=48, n_heads=64, n_kv_heads=8, head_dim=128, d_ff=18432,
+        max_seq_len=262144, rope_theta=1e6, norm_eps=1e-5,
+        block_pattern=("sliding", "sliding", "sliding", "global"),
+        sliding_window=128, rope_kinds=("sliding",), qk_norm=True,
+        n_experts=128, expert_top_k=8, expert_d_ff=2048,
+        n_shared_experts=1, n_dense_layers=1, router="sigmoid",
+        router_bias=True, router_renorm=True, router_scale=2.5,
+        n_mtp_layers=1)
+    return ModelConfig(**{**published, **kw})
+
+
 def qwen2_7b(**kw) -> ModelConfig:
     """Qwen-2/2.5 7B: Llama-style GQA decoder whose one architectural
     delta is bias on the q/k/v projections (public architecture; the HF
@@ -310,6 +444,7 @@ PRESETS = {
     "llama3-70b": llama3_70b,
     "mistral-7b": mistral_7b,
     "mixtral-8x7b": mixtral_8x7b,
+    "k-exaone-236b": k_exaone_236b,
     "gemma2-9b": gemma2_9b,
     "qwen2-7b": qwen2_7b,
 }
@@ -333,6 +468,8 @@ def preset_for_model_id(model_id: str, **kw) -> ModelConfig:
         return fn(**kw)
     if "mixtral" in mid:
         return mixtral_8x7b(**kw)
+    if "exaone" in mid:
+        return k_exaone_236b(**kw)
     if "mistral" in mid:
         if any(t in mid for t in ("v0.1", "v0.2")):
             kw.setdefault("vocab_size", 32000)

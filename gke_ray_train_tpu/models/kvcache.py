@@ -44,10 +44,37 @@ from gke_ray_train_tpu.ops.rope import (
 Cache = Dict[str, Any]
 
 
+def require_decodable(cfg: ModelConfig) -> None:
+    """The cached forward is a second copy of the block
+    (:func:`forward_step`), and it has not caught up with every model
+    the trainer runs: refuse those by name instead of computing
+    something else."""
+    missing = []
+    if cfg.prologue_layers:
+        missing.append("a cache for the leading dense layers outside "
+                       "the scan")
+    if cfg.qk_norm:
+        missing.append("q/k norm in the cached block")
+    if set(cfg.rope_kinds) != {"global", "sliding"}:
+        missing.append("a cache per attention kind (rotated keys in a "
+                       "window-sized ring for sliding layers, unrotated "
+                       "keys at full length for the others)")
+    if cfg.n_experts and cfg.router != "softmax":
+        missing.append("the sigmoid router's layer at decode shapes")
+    if cfg.n_mtp_layers:
+        missing.append("the multi-token-prediction head (self-drafting)")
+    if missing:
+        raise NotImplementedError(
+            f"{cfg.name} trains here but cannot be served yet "
+            f"(inference.py, serve/, models/kvcache.py): missing "
+            + "; ".join(missing))
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype: Optional[str] = None) -> Cache:
     """Zeroed cache pytree: blocks[i] = {"k","v"} of
     [n_repeats, batch, max_len, n_kv_heads, head_dim]."""
+    require_decodable(cfg)
     dt = jnp.dtype(dtype or cfg.dtype)
     hd = cfg.resolved_head_dim
     shape = (cfg.n_repeats, batch, max_len, cfg.n_kv_heads, hd)
@@ -120,6 +147,7 @@ def forward_step(params: Params, tokens: jnp.ndarray, cfg: ModelConfig,
     # ``_proj`` then sees per-row [B, d_in, r] entries
     aslot = lora.get("aslot") if lora is not None else None
 
+    require_decodable(cfg)
     B, T = tokens.shape
     dtype = jnp.dtype(cfg.dtype)
     eps, sp1 = cfg.norm_eps, cfg.norm_scale_plus_one

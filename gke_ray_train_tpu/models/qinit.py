@@ -12,7 +12,8 @@ quantizing and OOMs an 8B model on one 16 GB v5e chip.
 Design: each projection leaf [R, D, F] is built inside one jit by
 ``lax.map`` over its R repeat-slices — XLA serializes the map body, so
 peak memory is a single bf16 slice plus the int8 codes / fp32 scales
-being accumulated (~4.5 GB total for 8B NF4 instead of 32 GB fp32).
+being accumulated (~4.5 GB total for 8B NF4 instead of 32 GB fp32). An
+expert bank [R, G, D, F] is mapped over its R x G experts the same way.
 """
 
 from __future__ import annotations
@@ -26,25 +27,34 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
 from gke_ray_train_tpu.models.config import ModelConfig
-from gke_ray_train_tpu.models.transformer import Params, param_specs
+from gke_ray_train_tpu.models.transformer import (
+    Params, block_leaves, param_specs)
 from gke_ray_train_tpu.ops.quant import (
     DEFAULT_GROUP, QTensor, QUANT_TARGETS, quant_specs, quantize_tensor)
 
 
 def _quantized_leaf(shape, std, kind, group, key,
                     out_shardings=None) -> QTensor:
-    R = shape[0]
+    """``shape`` [..., D, F]: one draw and one quantisation a leading
+    index (a layer, or an expert of a layer)."""
+    lead = shape[:-2]
 
     def one(k):
-        w = (jax.random.truncated_normal(k, -3, 3, shape[1:], jnp.float32)
+        w = (jax.random.truncated_normal(k, -3, 3, shape[-2:], jnp.float32)
              * std).astype(jnp.bfloat16)
         qt = quantize_tensor(w[None], kind, group)
         return qt.codes[0], qt.scales[0]
 
+    def make(ks):
+        codes, scales = jax.lax.map(one, ks)
+        return (codes.reshape(lead + codes.shape[1:]),
+                scales.reshape(lead + scales.shape[1:]))
+
     kw = {} if out_shardings is None else {"out_shardings": out_shardings}
-    codes, scales = jax.jit(
-        lambda ks: jax.lax.map(one, ks), **kw)(jax.random.split(key, R))
-    return QTensor(codes, scales, kind, group)
+    codes, scales = jax.jit(make, **kw)(
+        jax.random.split(key, math.prod(lead)))
+    # quantize_tensor narrows the group where the input dim is smaller
+    return QTensor(codes, scales, kind, shape[-2] // scales.shape[-2])
 
 
 def _dense_leaf(make, sharding=None):
@@ -75,36 +85,8 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
     Norms/embed/lm_head stay full precision, like the reference's bnb
     pass which only rewrites the proj modules.
 
-    MoE configs take the simple path (full init, then quantize the
-    expert bank): the expert leaves are 4-D and per-slice streaming
-    buys less there since each expert is 1/E the FFN size."""
-    if cfg.n_experts > 0:
-        from gke_ray_train_tpu.models.transformer import init_params
-        from gke_ray_train_tpu.ops.quant import quantize_params
-        from gke_ray_train_tpu.parallel.sharding import tree_shardings
-        if mesh is not None:
-            p_shard = tree_shardings(mesh, param_specs(cfg))
-            params = jax.jit(lambda k: init_params(cfg, k),
-                             out_shardings=p_shard)(key)
-        else:
-            params = init_params(cfg, key)
-        return quantize_params(params, kind=kind, group=group,
-                               targets=targets)
+    An expert bank streams expert by expert."""
     pdt = jnp.dtype(cfg.param_dtype)
-    hd = cfg.resolved_head_dim
-    D, F, H, K, R = (cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads,
-                     cfg.n_repeats)
-    depth_scale = 1.0 / math.sqrt(2 * cfg.n_layers)
-    std = 0.02
-    proj_shapes = {
-        "wq": ((R, D, H * hd), std),
-        "wk": ((R, D, K * hd), std),
-        "wv": ((R, D, K * hd), std),
-        "wo": ((R, H * hd, D), std * depth_scale),
-        "w_gate": ((R, D, F), std),
-        "w_up": ((R, D, F), std),
-        "w_down": ((R, F, D), std * depth_scale),
-    }
     specs = param_specs(cfg)
 
     def q_shardings(spec, shape):
@@ -113,11 +95,11 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
             return None
         probe = jax.eval_shape(
             partial(quantize_tensor, kind=kind, group=group),
-            jax.ShapeDtypeStruct((1,) + shape[1:], jnp.bfloat16))
+            jax.ShapeDtypeStruct((1,) + shape[-2:], jnp.bfloat16))
         probe = QTensor(
-            jax.ShapeDtypeStruct((shape[0],) + probe.codes.shape[1:],
+            jax.ShapeDtypeStruct(shape[:-2] + probe.codes.shape[1:],
                                  probe.codes.dtype),
-            jax.ShapeDtypeStruct((shape[0],) + probe.scales.shape[1:],
+            jax.ShapeDtypeStruct(shape[:-2] + probe.scales.shape[1:],
                                  probe.scales.dtype),
             kind, group)
         qs = quant_specs(spec, probe, mesh)
@@ -135,42 +117,40 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
                         else jnp.ones(shape, pdt))
 
     keys = iter(jax.random.split(key, 16 * len(cfg.block_pattern) + 4))
+    pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
+                                  16 * max(cfg.prologue_layers, 1)))
 
-    def block(p):
-        bspec = specs["blocks"][p]
+    def block(bspec, R, mlp_kind, keys):
         out = {}
-        for name in ("attn_norm", "mlp_norm"):
-            out[name] = _dense_leaf(norm_maker((R, D)),
-                                    sharding_for(bspec[name]))
-        if cfg.post_block_norm:
-            for name in ("attn_post_norm", "mlp_post_norm"):
-                out[name] = _dense_leaf(norm_maker((R, D)),
+        for name, (shape, std) in block_leaves(cfg, R, mlp_kind).items():
+            if std is None:
+                # norm scales, full precision like the biases below
+                out[name] = _dense_leaf(norm_maker(shape),
                                         sharding_for(bspec[name]))
-        if cfg.attn_qkv_bias:
-            # Qwen-2 q/k/v biases: zero-init, full precision (never a
-            # quant target), same leaves init_params creates
-            for name, dim in (("bq", H * hd), ("bk", K * hd),
-                              ("bv", K * hd)):
+            elif std == 0.0:
+                # zero-init leaves (Qwen-2 q/k/v biases, a router's
+                # selection bias): never a quant target
                 out[name] = _dense_leaf(
-                    lambda dim=dim: jnp.zeros((R, dim), pdt),
+                    lambda shape=shape: jnp.zeros(shape, pdt),
                     sharding_for(bspec[name]))
-        for name, (shape, s) in proj_shapes.items():
-            k = next(keys)
-            if name in targets:
+            elif name in targets:
                 out[name] = _quantized_leaf(
-                    shape, s, kind, group, k,
+                    shape, std, kind, group, next(keys),
                     out_shardings=q_shardings(bspec[name], shape))
             else:
                 out[name] = _dense_leaf(
-                    normal_maker(shape, s, k),
+                    normal_maker(shape, std, next(keys)),
                     sharding_for(bspec[name]))
         return out
 
+    D = cfg.d_model
     params: Params = {
         "embed": _dense_leaf(
             normal_maker((cfg.vocab_size, D), 0.02, next(keys)),
             sharding_for(specs["embed"])),
-        "blocks": [block(p) for p in range(len(cfg.block_pattern))],
+        "blocks": [block(specs["blocks"][p], cfg.n_repeats,
+                         cfg.scan_mlp_kind, keys)
+                   for p in range(len(cfg.block_pattern))],
         "final_norm": _dense_leaf(norm_maker((D,)),
                                   sharding_for(specs["final_norm"])),
     }
@@ -178,4 +158,8 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
         params["lm_head"] = _dense_leaf(
             normal_maker((D, cfg.vocab_size), 0.02, next(keys)),
             sharding_for(specs["lm_head"]))
+    if cfg.prologue_layers:
+        params["prologue"] = [
+            block(specs["prologue"][i], 1, cfg.mlp_kind(i), pkeys)
+            for i in range(cfg.prologue_layers)]
     return params

@@ -35,7 +35,12 @@ from gke_ray_train_tpu.models.config import ModelConfig
 # device seconds saved per byte kept (the second flash forward, the
 # q/k/v projections with rope, the output projection: PERF.md, PR 25)
 KEEP_ORDER: Tuple[str, ...] = ("mlp/gate_up", "attn/core", "attn/qkv",
-                               "attn/out")
+                               "attn/out", "moe/shared", "moe/experts")
+# the routed layer's two names come last: `moe/shared` (the shared
+# expert's gate and up) saves what `mlp/gate_up` saves at a ninth of the
+# width, and `moe/experts` (gate and up of every row of the pair buffer)
+# pays for the buffer's worst case, eight times the rows that are real
+# at one rank of eight (PERF.md, PR 26)
 
 # XLA's peak grows by less than a kept tensor's stacked bytes: the layer
 # in flight was among the block's temporaries already, and the backward
@@ -49,12 +54,18 @@ Candidates = Tuple[Tuple[str, int], ...]
 
 
 def checkpoint_block(body: Callable, cfg: ModelConfig,
-                     keep: Sequence[str] = ()) -> Callable:
+                     keep: Sequence[str] = (), *,
+                     in_scan: bool = True) -> Callable:
     """``body`` under the block checkpoint ``cfg`` asks for.
 
     ``remat_policy == "full"``: the block's input is saved, plus the
     tensors named in ``keep``. ``"dots"`` saves every matmul output and
-    ignores ``keep``."""
+    ignores ``keep``. ``in_scan``: ``body`` is a whole scan body, whose
+    loop already keeps the recomputation where it belongs; a layer that
+    is one of several in a body (or in no loop at all) is tied to its
+    cotangent by jax's own barriers instead (``prevent_cse``), or XLA
+    recomputes every such layer ahead of the backward pass and holds all
+    their residuals at once."""
     if not cfg.remat:
         return body
     policy = None
@@ -63,7 +74,7 @@ def checkpoint_block(body: Callable, cfg: ModelConfig,
         policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
     elif keep:
         policy = jax.checkpoint_policies.save_only_these_names(*keep)
-    return jax.checkpoint(body, prevent_cse=False, policy=policy)
+    return jax.checkpoint(body, prevent_cse=not in_scan, policy=policy)
 
 
 def keep_candidates(cfg: ModelConfig, rows: int, seq: int, *,
@@ -73,25 +84,34 @@ def keep_candidates(cfg: ModelConfig, rows: int, seq: int, *,
     ``rows`` x ``seq`` positions on that device. ``model``: size of the
     mesh's tensor-parallel axis (heads and d_ff are divided over it).
     ``flash``: ``attn/core`` names the flash kernel's residuals (``o``,
-    ``lse``); the dense path has nothing under that name. MoE blocks
-    have no ``mlp/gate_up``."""
+    ``lse``); the dense path has nothing under that name. A name costs
+    the layers that have it: ``mlp/gate_up`` the dense-MLP layers, the
+    ``moe/`` names the sigmoid router's layers (the softmax layer names
+    nothing)."""
     item = jnp.dtype(cfg.dtype).itemsize
     hd = cfg.resolved_head_dim
     heads = math.ceil(cfg.n_heads / model)
     kv_heads = math.ceil(cfg.n_kv_heads / model)
+    n_moe = sum(cfg.mlp_kind(i) == "moe" for i in range(cfg.n_layers))
+    routed = n_moe if cfg.router == "sigmoid" else 0
+    d_fe = math.ceil(cfg.resolved_expert_d_ff / model)
+    # (bytes a position, layers that have the name)
     per_position = {
-        "attn/core": heads * (hd * item + 4),          # o + float32 lse
-        "mlp/gate_up": 2 * math.ceil(cfg.d_ff / model) * item,
-        "attn/qkv": (heads + 2 * kv_heads) * hd * item,
-        "attn/out": cfg.d_model * item,
+        "attn/core": (heads * (hd * item + 4),          # o + float32 lse
+                      cfg.n_layers if flash else 0),
+        "mlp/gate_up": (2 * math.ceil(cfg.d_ff / model) * item,
+                        cfg.n_layers - n_moe),
+        "attn/qkv": ((heads + 2 * kv_heads) * hd * item, cfg.n_layers),
+        "attn/out": (cfg.d_model * item, cfg.n_layers),
+        "moe/shared": (2 * cfg.n_shared_experts * d_fe * item,
+                       routed if cfg.n_shared_experts else 0),
+        "moe/experts": (2 * d_fe * item
+                        * min(cfg.expert_top_k, max(cfg.n_experts_held, 1)),
+                        routed),
     }
-    if not flash:
-        del per_position["attn/core"]
-    if cfg.n_experts > 0:
-        del per_position["mlp/gate_up"]
-    positions = cfg.n_layers * rows * seq
-    return tuple((n, positions * per_position[n]) for n in KEEP_ORDER
-                 if n in per_position)
+    return tuple((n, rows * seq * layers * nbytes)
+                 for n, (nbytes, layers) in
+                 ((n, per_position[n]) for n in KEEP_ORDER) if layers)
 
 
 def choose_keep(candidates: Candidates, budget_bytes: Optional[int], *,
@@ -126,16 +146,18 @@ def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
     the 7B QLoRA step on one described v5e chip over ranks, vocabularies,
     rows, sequence lengths and depths, it reads 0.04-0.40 GB above
     XLA's, and 0.04-0.72 GB above for a full fine-tune across four
-    (PERF.md, PR 25)."""
+    (PERF.md, PR 25). The sigmoid router's block is sized by
+    :func:`_routed_block_bytes` (PERF.md, PR 26)."""
     item = jnp.dtype(cfg.dtype).itemsize
     positions = rows * seq
     d_ff = math.ceil(cfg.d_ff / model)
     hd = cfg.resolved_head_dim
     qkv = math.ceil((cfg.n_heads + 2 * cfg.n_kv_heads) / model) * hd
     attn_io = qkv + cfg.d_model
-    block_weights = (cfg.d_model * (qkv + math.ceil(cfg.n_heads / model)
-                                    * hd)
-                     + max(cfg.n_experts, 1) * 3 * cfg.d_model * d_ff)
+    attn_weights = cfg.d_model * (qkv + math.ceil(cfg.n_heads / model) * hd)
+    routed = cfg.n_experts > 0 and cfg.router == "sigmoid"
+    block_weights = attn_weights + (
+        1 if routed else max(cfg.n_experts, 1)) * 3 * cfg.d_model * d_ff
     # float32 logits and their compute-dtype cotangent; this micro-batch
     # has no gradients yet
     at_loss = positions * math.ceil(cfg.vocab_size / model) * (4 + item)
@@ -148,6 +170,32 @@ def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
                            + positions * (7 * d_ff + 4 * attn_io),
                            2 * block_weights + positions * 2 * attn_io)
                 + trainable_full_bytes // cfg.n_layers)
+    if routed:
+        in_block = max(in_block if cfg.n_dense_layers else 0,
+                       _routed_block_bytes(cfg, positions, model, item,
+                                           attn_weights, attn_io)
+                       + trainable_full_bytes // cfg.n_layers)
     return (trainable_bytes + cast_bytes     # gradient accumulator, copy
             + cfg.n_layers * positions * cfg.d_model * item  # block inputs
             + max(at_loss, trainable_bytes + in_block))
+
+
+def _routed_block_bytes(cfg: ModelConfig, positions: int, model: int,
+                        item: int, attn_weights: int, attn_io: int) -> int:
+    """The backward of one sigmoid-routed block at its fullest, fitted
+    to XLA's peak for the K-EXAONE share on one described v5e chip (8
+    layers; 8192 and 4096 positions; 16 and 8 experts held: the step's
+    estimate reads 0.15-0.29 GB above XLA's; PERF.md, PR 26). The held
+    bank, the attention's and the shared expert's weights in the
+    compute dtype; the pair buffer at its worst-case rows, whatever the
+    routing: four tensors of the model's width (the gathered rows, the
+    experts' output and the cotangents of both) and three of the
+    expert's; the attention's tensors once."""
+    d_fe = math.ceil(cfg.resolved_expert_d_ff / model)
+    bank = math.ceil(cfg.n_experts_held / model) * 3 * cfg.d_model \
+        * cfg.resolved_expert_d_ff
+    shared = cfg.n_shared_experts * 3 * cfg.d_model * d_fe
+    pairs = positions * min(cfg.expert_top_k, cfg.n_experts_held)
+    return item * (bank + attn_weights + shared
+                   + pairs * (4 * cfg.d_model + 3 * d_fe)
+                   + positions * attn_io)

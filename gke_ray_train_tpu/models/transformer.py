@@ -16,6 +16,8 @@ Sharding (SURVEY.md §2c, TPU build disposition):
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import logging
 import math
 from typing import Any, Dict, Optional, Tuple
@@ -25,7 +27,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from gke_ray_train_tpu.models.config import ModelConfig
+from gke_ray_train_tpu.models.config import ModelConfig, SHARED_TARGETS
 from gke_ray_train_tpu.models.remat import checkpoint_block
 from gke_ray_train_tpu.obs.trace import scope
 from gke_ray_train_tpu.ops.attention import (
@@ -54,68 +56,108 @@ def _warn_flash_fallback(seq_len: int) -> None:
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
     """Initialize the stacked param pytree.
 
-    Truncated-normal fan-in style init; the two residual-writing matrices
-    (wo, w_down) are scaled down by 1/sqrt(2*n_layers) to keep the
+    Truncated-normal fan-in style init; the residual-writing matrices
+    (wo, every w_down) are scaled down by 1/sqrt(2*n_layers) to keep the
     residual-stream variance flat at depth.
+
+    ``blocks[p]`` stacks the scanned periods' layers of pattern position
+    ``p``. A model with leading dense-MLP layers (``cfg.prologue_layers``)
+    also has ``prologue``: one dict a leading layer, leaves with a
+    leading dim of 1, in the same layout.
     """
     pdt = jnp.dtype(cfg.param_dtype)
-    hd = cfg.resolved_head_dim
-    D, F, H, K, R = (cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads,
-                     cfg.n_repeats)
-    depth_scale = 1.0 / math.sqrt(2 * cfg.n_layers)
-
     keys = iter(jax.random.split(key, 16 * len(cfg.block_pattern) + 4))
 
-    def normal(shape, std):
+    def normal(shape, std, keys=keys):
         return (jax.random.truncated_normal(next(keys), -3, 3, shape,
                                             jnp.float32) * std).astype(pdt)
 
-    E = cfg.n_experts
+    def norm(shape):
+        return (jnp.zeros(shape, pdt) if cfg.norm_scale_plus_one
+                else jnp.ones(shape, pdt))
 
-    def block_params():
-        std = 0.02
-        p = {
-            "attn_norm": jnp.zeros((R, D), pdt) if cfg.norm_scale_plus_one
-            else jnp.ones((R, D), pdt),
-            "wq": normal((R, D, H * hd), std),
-            "wk": normal((R, D, K * hd), std),
-            "wv": normal((R, D, K * hd), std),
-            "wo": normal((R, H * hd, D), std * depth_scale),
-            "mlp_norm": jnp.zeros((R, D), pdt) if cfg.norm_scale_plus_one
-            else jnp.ones((R, D), pdt),
-        }
-        if cfg.attn_qkv_bias:
-            # Qwen-2: bias on q/k/v only (o_proj stays bias-free);
-            # zero-init — real values come from the HF checkpoint
-            p["bq"] = jnp.zeros((R, H * hd), pdt)
-            p["bk"] = jnp.zeros((R, K * hd), pdt)
-            p["bv"] = jnp.zeros((R, K * hd), pdt)
-        if E:
-            # MoE MLP (ops/moe.py): router + expert bank, expert dim
-            # sharded over `model` (expert parallelism, SURVEY.md EP row)
-            p["router"] = normal((R, D, E), std)
-            p["w_gate"] = normal((R, E, D, F), std)
-            p["w_up"] = normal((R, E, D, F), std)
-            p["w_down"] = normal((R, E, F, D), std * depth_scale)
-        else:
-            p["w_gate"] = normal((R, D, F), std)
-            p["w_up"] = normal((R, D, F), std)
-            p["w_down"] = normal((R, F, D), std * depth_scale)
-        if cfg.post_block_norm:
-            zero_or_one = (jnp.zeros if cfg.norm_scale_plus_one else jnp.ones)
-            p["attn_post_norm"] = zero_or_one((R, D), pdt)
-            p["mlp_post_norm"] = zero_or_one((R, D), pdt)
-        return p
+    def block_params(R, mlp_kind, normal=normal):
+        return {name: norm(shape) if std is None
+                else jnp.zeros(shape, pdt) if std == 0.0
+                else normal(shape, std)
+                for name, (shape, std) in block_leaves(cfg, R, mlp_kind)
+                .items()}
 
     params: Params = {
-        "embed": normal((cfg.vocab_size, D), 0.02),
-        "blocks": [block_params() for _ in cfg.block_pattern],
-        "final_norm": (jnp.zeros((D,), pdt) if cfg.norm_scale_plus_one
-                       else jnp.ones((D,), pdt)),
+        "embed": normal((cfg.vocab_size, cfg.d_model), 0.02),
+        "blocks": [block_params(cfg.n_repeats, cfg.scan_mlp_kind)
+                   for _ in cfg.block_pattern],
+        "final_norm": norm((cfg.d_model,)),
     }
     if not cfg.tie_embeddings:
-        params["lm_head"] = normal((D, cfg.vocab_size), 0.02)
+        params["lm_head"] = normal((cfg.d_model, cfg.vocab_size), 0.02)
+    if cfg.prologue_layers:
+        pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
+                                      16 * cfg.prologue_layers))
+        params["prologue"] = [
+            block_params(1, cfg.mlp_kind(i),
+                         lambda shape, std: normal(shape, std, pkeys))
+            for i in range(cfg.prologue_layers)]
     return params
+
+
+def block_layout(cfg: ModelConfig):
+    """``[(tree key, index in its list, first layer, layers stacked,
+    layer stride, mlp kind)]`` of every block dict of the param tree
+    (and of an adapter tree): the scanned pattern positions, then the
+    prologue's layers one by one."""
+    period = len(cfg.block_pattern)
+    return ([("blocks", p, cfg.prologue_layers + p, cfg.n_repeats, period,
+              cfg.scan_mlp_kind) for p in range(period)]
+            + [("prologue", i, i, 1, 1, cfg.mlp_kind(i))
+               for i in range(cfg.prologue_layers)])
+
+
+def block_leaves(cfg: ModelConfig, R: int, mlp_kind: str) -> Dict[str, Any]:
+    """``{leaf: (shape, std)}`` of one pattern position stacked over
+    ``R`` layers, in creation order (the order the init keys are drawn
+    in). ``std`` None marks a norm scale, 0.0 a leaf that starts at
+    zero; ``mlp_kind``: "dense" | "moe" (``cfg.mlp_kind``)."""
+    hd = cfg.resolved_head_dim
+    D, F, H, K = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads
+    std = 0.02
+    down = std * (1.0 / math.sqrt(2 * cfg.n_layers))
+    out = {
+        "attn_norm": ((R, D), None),
+        "wq": ((R, D, H * hd), std),
+        "wk": ((R, D, K * hd), std),
+        "wv": ((R, D, K * hd), std),
+        "wo": ((R, H * hd, D), down),
+        "mlp_norm": ((R, D), None),
+    }
+    if cfg.attn_qkv_bias:
+        # Qwen-2: bias on q/k/v only (o_proj stays bias-free);
+        # zero-init — real values come from the HF checkpoint
+        out.update(bq=((R, H * hd), 0.0), bk=((R, K * hd), 0.0),
+                   bv=((R, K * hd), 0.0))
+    if mlp_kind == "moe":
+        # MoE MLP (ops/moe.py): router + the held experts' bank, expert
+        # dim sharded over `model` (expert parallelism, SURVEY.md EP row)
+        E, G, Fe = (cfg.n_experts, cfg.n_experts_held,
+                    cfg.resolved_expert_d_ff)
+        out.update(router=((R, D, E), std), w_gate=((R, G, D, Fe), std),
+                   w_up=((R, G, D, Fe), std), w_down=((R, G, Fe, D), down))
+        if cfg.router_bias:
+            out["router_bias"] = ((R, E), 0.0)
+        if cfg.n_shared_experts:
+            Fs = cfg.n_shared_experts * Fe
+            out.update(shared_gate=((R, D, Fs), std),
+                       shared_up=((R, D, Fs), std),
+                       shared_down=((R, Fs, D), down))
+    else:
+        out.update(w_gate=((R, D, F), std), w_up=((R, D, F), std),
+                   w_down=((R, F, D), down))
+    if cfg.qk_norm:
+        out.update(q_norm=((R, hd), None), k_norm=((R, hd), None))
+    if cfg.post_block_norm:
+        out.update(attn_post_norm=((R, D), None),
+                   mlp_post_norm=((R, D), None))
+    return out
 
 
 def param_specs(cfg: ModelConfig) -> Params:
@@ -124,46 +166,55 @@ def param_specs(cfg: ModelConfig) -> Params:
     The ZeRO/FSDP sharding the reference gets from bitsandbytes+DDP
     (SURVEY.md rows D4/D5) is this table; nothing else.
     """
-    def block_specs():
-        # Leading dim = stacked repeats: sharded over `pipe` (pipeline
-        # stages own contiguous layer slices, models/pipeline.py); a
-        # size-1 pipe axis makes this a no-op on non-PP meshes.
-        s = {
-            "attn_norm": P("pipe", None),
-            "wq": P("pipe", "fsdp", "model"),
-            "wk": P("pipe", "fsdp", "model"),
-            "wv": P("pipe", "fsdp", "model"),
-            "wo": P("pipe", "model", "fsdp"),
-            "mlp_norm": P("pipe", None),
-        }
-        if cfg.attn_qkv_bias:
-            # bias vectors follow their projection's OUTPUT dim sharding
-            s["bq"] = P("pipe", "model")
-            s["bk"] = P("pipe", "model")
-            s["bv"] = P("pipe", "model")
-        if cfg.n_experts:
-            # expert dim over `model` = EP; GSPMD derives the token
-            # all-to-alls from the dispatch einsums (ops/moe.py)
-            s["router"] = P("pipe", "fsdp", None)
-            s["w_gate"] = P("pipe", "model", "fsdp", None)
-            s["w_up"] = P("pipe", "model", "fsdp", None)
-            s["w_down"] = P("pipe", "model", None, "fsdp")
-        else:
-            s["w_gate"] = P("pipe", "fsdp", "model")
-            s["w_up"] = P("pipe", "fsdp", "model")
-            s["w_down"] = P("pipe", "model", "fsdp")
-        if cfg.post_block_norm:
-            s["attn_post_norm"] = P("pipe", None)
-            s["mlp_post_norm"] = P("pipe", None)
-        return s
+    # Leading dim = stacked repeats: sharded over `pipe` (pipeline
+    # stages own contiguous layer slices, models/pipeline.py); a
+    # size-1 pipe axis makes this a no-op on non-PP meshes.
+    table = {
+        "attn_norm": P("pipe", None),
+        "wq": P("pipe", "fsdp", "model"),
+        "wk": P("pipe", "fsdp", "model"),
+        "wv": P("pipe", "fsdp", "model"),
+        "wo": P("pipe", "model", "fsdp"),
+        "mlp_norm": P("pipe", None),
+        # bias vectors follow their projection's OUTPUT dim sharding
+        "bq": P("pipe", "model"),
+        "bk": P("pipe", "model"),
+        "bv": P("pipe", "model"),
+        "w_gate": P("pipe", "fsdp", "model"),
+        "w_up": P("pipe", "fsdp", "model"),
+        "w_down": P("pipe", "model", "fsdp"),
+        "shared_gate": P("pipe", "fsdp", "model"),
+        "shared_up": P("pipe", "fsdp", "model"),
+        "shared_down": P("pipe", "model", "fsdp"),
+        "router": P("pipe", "fsdp", None),
+        "router_bias": P("pipe", None),
+        "q_norm": P("pipe", None),
+        "k_norm": P("pipe", None),
+        "attn_post_norm": P("pipe", None),
+        "mlp_post_norm": P("pipe", None),
+    }
+    # expert dim over `model` = EP; GSPMD derives the token
+    # all-to-alls from the dispatch einsums (ops/moe.py)
+    bank = {"w_gate": P("pipe", "model", "fsdp", None),
+            "w_up": P("pipe", "model", "fsdp", None),
+            "w_down": P("pipe", "model", None, "fsdp")}
+
+    def block_specs(mlp_kind):
+        return {name: bank[name] if mlp_kind == "moe" and name in bank
+                else table[name]
+                for name in block_leaves(cfg, 1, mlp_kind)}
 
     specs: Params = {
         "embed": P("model", "fsdp"),
-        "blocks": [block_specs() for _ in cfg.block_pattern],
+        "blocks": [block_specs(cfg.scan_mlp_kind)
+                   for _ in cfg.block_pattern],
         "final_norm": P(None),
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = P("fsdp", "model")
+    if cfg.prologue_layers:
+        specs["prologue"] = [block_specs(cfg.mlp_kind(i))
+                             for i in range(cfg.prologue_layers)]
     return specs
 
 
@@ -258,16 +309,24 @@ def _apply_rope_qk(q, k, positions, rope, fused_ops=False, mesh=None):
     return apply_rope(q, positions, rope), apply_rope(k, positions, rope)
 
 
+DENSE_MLP = (("w_gate", "w_up", "w_down"), "mlp/gate_up", "mlp/down")
+# the shared expert of a routed layer: the same SwiGLU under the
+# layer's own names, one scope for both halves
+SHARED_MLP = (SHARED_TARGETS, "moe/shared", "moe/shared")
+
+
 def _mlp(x, lp, cfg: ModelConfig, dtype, lora_p=None, lora_scale=1.0,
-         drop_rng=None, drop_rate=0.0):
+         drop_rng=None, drop_rate=0.0, which=DENSE_MLP):
+    (w_gate, w_up, w_down), gate_up_scope, down_scope = which
+
     def lr(name):
         return _lora_entry(lora_p, name)
-    with scope("mlp/gate_up"):
-        gate = _proj(x, lp["w_gate"], lr("w_gate"), lora_scale, dtype,
+    with scope(gate_up_scope):
+        gate = _proj(x, lp[w_gate], lr(w_gate), lora_scale, dtype,
                      _drop_key(drop_rng, 4), drop_rate)
-        up = _proj(x, lp["w_up"], lr("w_up"), lora_scale, dtype,
+        up = _proj(x, lp[w_up], lr(w_up), lora_scale, dtype,
                    _drop_key(drop_rng, 5), drop_rate)
-        gate, up = (checkpoint_name(t, "mlp/gate_up") for t in (gate, up))
+        gate, up = (checkpoint_name(t, gate_up_scope) for t in (gate, up))
         if cfg.activation == "silu":
             act = jax.nn.silu(gate)
         elif cfg.activation == "gelu_tanh":
@@ -275,14 +334,40 @@ def _mlp(x, lp, cfg: ModelConfig, dtype, lora_p=None, lora_scale=1.0,
         else:
             raise ValueError(f"unknown activation {cfg.activation}")
         h = act * up
-    with scope("mlp/down"):
-        return _proj(h, lp["w_down"], lr("w_down"), lora_scale, dtype,
+    with scope(down_scope):
+        return _proj(h, lp[w_down], lr(w_down), lora_scale, dtype,
                      _drop_key(drop_rng, 6), drop_rate)
+
+
+def _moe(x, lp, cfg: ModelConfig, dtype, segment_ids, token_weights,
+         lora_p=None, lora_scale=1.0, drop_rng=None, drop_rate=0.0):
+    """The routed MLP of one layer -> (y, stats of ops/moe.py).
+
+    "softmax" (Mixtral): LoRA adapts attention only, there being no
+    single delta-W an adapter pair could target across routed experts.
+    "sigmoid": the routed experts held here plus the shared expert,
+    which is a plain SwiGLU through `_proj` and takes adapters."""
+    from gke_ray_train_tpu.ops import moe
+    if cfg.router == "softmax":
+        y, aux = moe.moe_mlp(x, lp["router"], lp["w_gate"], lp["w_up"],
+                             lp["w_down"], cfg, dtype,
+                             weights=token_weights)
+        return y, {"router_aux": aux}
+    y, counters = moe.routed_experts(
+        x, lp, cfg, dtype,
+        valid=None if segment_ids is None else segment_ids != 0)
+    if cfg.n_shared_experts:
+        y = y + _mlp(x, lp, cfg, dtype, lora_p=lora_p,
+                     lora_scale=lora_scale, drop_rng=drop_rng,
+                     drop_rate=drop_rate, which=SHARED_MLP)
+    return y, counters
 
 
 def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
           window, segment_ids, mesh, lora_p=None, lora_scale=1.0,
-          drop_rng=None, drop_rate=0.0, fused_ops=False):
+          drop_rng=None, drop_rate=0.0, fused_ops=False, kind=None):
+    """``kind``: the layer's block kind when the model has more than
+    one (the attention then runs under a leaf scope of its own)."""
     B, S, D = x.shape
     hd = cfg.resolved_head_dim
     H, K = cfg.n_heads, cfg.n_kv_heads
@@ -301,13 +386,21 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
         v = v.reshape(B, S, K, hd)
         q = _constrain(q, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
         k = _constrain(k, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
+    if cfg.qk_norm:
+        with scope("attn/qk_norm"):
+            q = rms_norm(q, lp["q_norm"], eps=cfg.norm_eps,
+                         scale_plus_one=cfg.norm_scale_plus_one)
+            k = rms_norm(k, lp["k_norm"], eps=cfg.norm_eps,
+                         scale_plus_one=cfg.norm_scale_plus_one)
     if rope is not None:
         with scope("attn/rope"):
             q, k = _apply_rope_qk(q, k, positions, rope,
                                   fused_ops=fused_ops, mesh=mesh)
     # what the attention backward reads: q and k after rope, v
     q, k, v = (checkpoint_name(t, "attn/qkv") for t in (q, k, v))
-    with scope("attn/core"):
+    kind_scope = contextlib.nullcontext() if kind is None else scope(
+        "window" if kind == "sliding" else "full")
+    with scope("attn/core"), kind_scope:
         if impl == "xla":
             out = dot_product_attention(
                 q, k, v, mask, scale=cfg.attn_scale,
@@ -333,28 +426,42 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
                     rope, positions, masks, segment_ids, mesh, *,
                     lora_slice=None, lora_scale: float = 1.0,
                     lora_dropout: float = 0.0, rep_rng=None,
-                    token_weights=None, fused_ops: bool = False):
+                    token_weights=None, fused_ops: bool = False,
+                    first_layer: Optional[int] = None,
+                    checkpoint_layer=None):
     """One repeat of the stacked block pattern — the body every layer
     loop shares. ``forward``'s scan and the manual-overlap pipeline
     (train/overlap.py) both call exactly this function, so the per-layer
     math cannot fork between the GSPMD and shard_map paths (the bitwise
-    off/manual equivalence the overlap tests assert rides on that)."""
+    off/manual equivalence the overlap tests assert rides on that).
+
+    ``first_layer``: the index of this period's first layer when it is
+    one of the prologue's (its MLPs then follow ``cfg.mlp_kind`` layer by
+    layer); None for a scanned period, whose MLPs are all of the last
+    layer's kind. ``aux``: the carry beside the activations: ops/moe.py's
+    stats where the model routes, else passed through.
+    ``checkpoint_layer``: wraps each layer of the period by itself (a
+    period of several layers under one checkpoint would hold the
+    recomputed residuals of all of them at once)."""
     eps, sp1 = cfg.norm_eps, cfg.norm_scale_plus_one
-    moe = cfg.n_experts > 0
-    for p, kind in enumerate(cfg.block_pattern):
-        lp = layer_slice[p]
-        lo = lora_slice[p] if lora_slice is not None else None
-        drng = (jax.random.fold_in(rep_rng, p)
-                if rep_rng is not None else None)
+    kinds = len(set(cfg.block_pattern)) > 1
+
+    def layer(kind, moe, x, aux, lp, lo, drng):
+        if checkpoint_layer is not None:
+            # this layer's weights are not touched (dequantised, cast)
+            # before its input exists: the layers of a period are
+            # independent until then, and XLA would prepare them all
+            x, lp, lo = jax.lax.optimization_barrier((x, lp, lo))
         with scope("attn_norm"):
             h = _rms_norm(x, lp["attn_norm"], eps=eps, scale_plus_one=sp1,
                           fused_ops=fused_ops, mesh=mesh)
-        h = _attn(h, lp, cfg, impl, dtype, rope, positions,
+        h = _attn(h, lp, cfg, impl, dtype,
+                  rope if kind in cfg.rope_kinds else None, positions,
                   masks[kind],
                   cfg.sliding_window if kind == "sliding" else None,
                   segment_ids, mesh, lora_p=lo, lora_scale=lora_scale,
                   drop_rng=_drop_key(drng, 0), drop_rate=lora_dropout,
-                  fused_ops=fused_ops)
+                  fused_ops=fused_ops, kind=kind if kinds else None)
         with scope("attn/out"):
             # the post-norm and the residual add belong to the output
             # projection they finish (XLA fuses them into it)
@@ -368,26 +475,36 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
             h = _rms_norm(x, lp["mlp_norm"], eps=eps, scale_plus_one=sp1,
                           fused_ops=fused_ops, mesh=mesh)
         if moe:
-            # MoE MLP (ops/moe.py). LoRA adapts attention only on
-            # MoE models — there is no single delta-W an adapter
-            # pair could target across routed experts.
-            from gke_ray_train_tpu.ops.moe import moe_mlp
-            h, a = moe_mlp(h, lp["router"], lp["w_gate"], lp["w_up"],
-                           lp["w_down"], cfg, dtype,
-                           weights=token_weights)
-            aux = aux + a
+            from gke_ray_train_tpu.ops.moe import stats_merge
+            h, a = _moe(h, lp, cfg, dtype, segment_ids, token_weights,
+                        lora_p=lo, lora_scale=lora_scale,
+                        drop_rng=_drop_key(drng, 1),
+                        drop_rate=lora_dropout)
+            aux = stats_merge(aux, a)
         else:
             h = _mlp(h, lp, cfg, dtype, lora_p=lo,
                      lora_scale=lora_scale,
                      drop_rng=_drop_key(drng, 1),
                      drop_rate=lora_dropout)
-        with scope("moe/experts" if moe else "mlp/down"):
+        with scope("moe/combine" if moe else "mlp/down"):
             if cfg.post_block_norm:
                 h = _rms_norm(h, lp["mlp_post_norm"], eps=eps,
                               scale_plus_one=sp1, fused_ops=fused_ops,
                               mesh=mesh)
             x = x + h
             x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
+        return x, aux
+
+    for p, kind in enumerate(cfg.block_pattern):
+        mlp = (cfg.scan_mlp_kind if first_layer is None
+               else cfg.mlp_kind(first_layer + p))
+        one_layer = functools.partial(layer, kind, mlp == "moe")
+        if checkpoint_layer is not None:
+            one_layer = checkpoint_layer(one_layer)
+        x, aux = one_layer(x, aux, layer_slice[p],
+                     lora_slice[p] if lora_slice is not None else None,
+                     jax.random.fold_in(rep_rng, p)
+                     if rep_rng is not None else None)
     return x, aux
 
 
@@ -501,6 +618,13 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
             raise NotImplementedError(
                 "LoRA dropout is not supported on a pipelined mesh; set "
                 "LORA_DROPOUT=0 or pipe=1")
+        if cfg.prologue_layers or cfg.qk_norm or cfg.router != "softmax" \
+                or set(cfg.rope_kinds) != {"global", "sliding"}:
+            raise NotImplementedError(
+                f"{cfg.name}: a pipelined mesh runs its own copy of the "
+                "block (models/pipeline.py), which has no prologue of "
+                "leading layers, no q/k norm, no per-kind rotary and no "
+                "sigmoid router yet; use pipe=1")
         from gke_ray_train_tpu.models.pipeline import pipeline_blocks
         x, pipe_aux = pipeline_blocks(
             x, params["blocks"], cfg, mesh, impl=impl, dtype=dtype,
@@ -533,35 +657,62 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
         drop_keys = jax.random.split(lora_rng, cfg.n_repeats)
 
     moe = cfg.n_experts > 0
+    aux0 = jnp.zeros((), jnp.float32)
+    if moe:
+        from gke_ray_train_tpu.ops.moe import stats_init
+        aux0 = stats_init(cfg)
 
-    def repeat_body(carry, xs_slice):
-        x, aux = carry
-        layer_slice = xs_slice[0]
-        lora_slice = xs_slice[1] if lora is not None else None
-        rep_rng = xs_slice[-1] if drop_keys is not None else None
-        x, aux = run_block_stack(
-            x, aux, layer_slice, cfg, impl, dtype, rope, positions,
-            masks, segment_ids, mesh, lora_slice=lora_slice,
-            lora_scale=lora_scale, lora_dropout=lora_dropout,
-            rep_rng=rep_rng, token_weights=token_weights,
-            fused_ops=fused_ops)
-        return (x, aux), None
+    # a period of one layer is checkpointed whole; the layers of a longer
+    # one each by themselves
+    per_layer = len(cfg.block_pattern) > 1
+    checkpoint = functools.partial(checkpoint_block, cfg=cfg,
+                                   keep=remat_keep, in_scan=not per_layer)
 
-    body = checkpoint_block(repeat_body, cfg, remat_keep)
+    def period(first_layer):
+        def repeat_body(carry, xs_slice):
+            x, aux = carry
+            layer_slice = xs_slice[0]
+            lora_slice = xs_slice[1] if lora is not None else None
+            rep_rng = xs_slice[-1] if drop_keys is not None else None
+            x, aux = run_block_stack(
+                x, aux, layer_slice, cfg, impl, dtype, rope, positions,
+                masks, segment_ids, mesh, lora_slice=lora_slice,
+                lora_scale=lora_scale, lora_dropout=lora_dropout,
+                rep_rng=rep_rng, token_weights=token_weights,
+                fused_ops=fused_ops, first_layer=first_layer,
+                checkpoint_layer=checkpoint if per_layer else None)
+            return (x, aux), None
+        return repeat_body if per_layer else checkpoint(repeat_body)
+
+    carry = (x, aux0)
+    period_len = len(cfg.block_pattern)
+    for first in range(0, cfg.prologue_layers, period_len):
+        # the leading periods hold the dense-MLP layers: same body, one
+        # call each, their leaves' leading dim of 1 taken off
+        xs = [params["prologue"][first:first + period_len]]
+        if lora is not None:
+            xs.append(lora["prologue"][first:first + period_len])
+        xs = jax.tree.map(lambda leaf: leaf[0], xs)
+        if drop_keys is not None:
+            xs.append(jax.random.fold_in(lora_rng, first + 1))
+        carry, _ = period(first)(carry, tuple(xs))
     xs = [params["blocks"]]
     if lora is not None:
         xs.append(lora["blocks"])
     if drop_keys is not None:
         xs.append(drop_keys)
-    (x, aux_sum), _ = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), tuple(xs))
+    (x, aux_sum), _ = jax.lax.scan(period(None), carry, tuple(xs))
     if return_pre_unembed:
         out = pre_unembed(x, params, cfg, mesh)
     else:
         out = _unembed(x, params, cfg, dtype, mesh)
     if with_aux:
-        return out, {"router_aux": aux_sum / cfg.n_layers if moe
-                     else aux_sum}
+        if not moe:
+            return out, {"router_aux": aux_sum}
+        if "router_aux" in aux_sum:
+            aux_sum = dict(aux_sum,
+                           router_aux=aux_sum["router_aux"] / cfg.n_layers)
+        return out, aux_sum
     return out
 
 

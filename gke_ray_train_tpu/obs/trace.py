@@ -155,26 +155,37 @@ ALWAYS_RECORDED = frozenset({"step_build", "step_lower", "step_compile"})
 # the train step (models/transformer.py, ops/moe.py, train/step.py,
 # train/optim.py). A "/" is two nested scopes. `base` / `lora` are the
 # leaf scopes inside `_proj`: the frozen (maybe dequantized) projection
-# against the adapter bypass, whatever module calls it. The phase costs
-# no name: jax writes `jvp(` (forward), `transpose(` (backward) and
-# `rematted_computation` (recomputed forward) into every op_name.
+# against the adapter bypass, whatever module calls it. `window` /
+# `full` are leaf scopes inside `attn/core`, opened only by a model
+# that has both kinds of layer, so that each kind's attention can be
+# billed its own pairs. The `moe/` names are the routed layer's stages
+# (ops/moe.py): scores and selection, the sort and gather into the pair
+# buffer, the grouped products, the weighted way back, and the shared
+# expert beside them. The phase costs no name: jax writes `jvp(`
+# (forward), `transpose(` (backward) and `rematted_computation`
+# (recomputed forward) into every op_name.
 SCOPE_NAMES = (
-    "embed", "attn_norm", "attn/qkv", "attn/rope", "attn/core",
-    "attn/out", "mlp_norm", "mlp/gate_up", "mlp/down", "moe/route",
-    "moe/experts", "final_norm", "unembed", "loss", "clip", "optimizer",
-    "base", "lora")
+    "embed", "attn_norm", "attn/qkv", "attn/qk_norm", "attn/rope",
+    "attn/core", "attn/out", "mlp_norm", "mlp/gate_up", "mlp/down",
+    "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
+    "moe/shared", "final_norm", "unembed", "loss", "clip", "optimizer",
+    "base", "lora", "window", "full")
 
 # bumped when a scope MOVES without the vocabulary changing: it rides
 # the compile cache's key beside the names (perf/cache.py::names_salt),
-# so that an executable compiled under the old placement is not served
-SCOPE_VERSION = 1
+# so that an executable compiled under the old placement is not served.
+# 2: `moe/experts` no longer holds the routed layer's residual add
+SCOPE_VERSION = 2
 
 # pl.pallas_call(name=...) of every kernel (ops/flash_attention.py,
-# ops/fused_ce.py, ops/fused_norm_rope.py)
+# ops/fused_ce.py, ops/fused_norm_rope.py), then the library's kernels
+# the program calls under the names the library gave them: jax's
+# megablox grouped matmul and its transpose (ops/moe.py)
+LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
 KERNEL_NAMES = (
     "flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd", "fused_ce_dx",
     "fused_ce_dhead", "fused_rmsnorm", "fused_rope_qk",
-    "fused_rmsnorm_rope")
+    "fused_rmsnorm_rope") + LIBRARY_KERNEL_NAMES
 
 # the profiler's host plane shows a region under this prefix
 ANNOTATION_PREFIX = "grt:"

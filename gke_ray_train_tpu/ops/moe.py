@@ -20,6 +20,21 @@ passthrough), matching Switch/GShard drop behavior.
 Router numerics are fp32 end-to-end (softmax over experts is
 precision-critical at E=8..64); expert matmuls run in the model compute
 dtype.
+
+That is the ``router="softmax"`` layer (Mixtral), and it stays as it is:
+its capacity einsums are what GSPMD partitions into the expert exchange.
+``router="sigmoid"`` (DeepSeek-V3's layer, K-EXAONE's) is
+:func:`routed_experts` below: no capacity and no drop. The (token, held
+expert) pairs are sorted by expert into a buffer sized for the worst
+case the held share allows (every one of a token's picks held), and the
+three products run over the real group sizes (:func:`grouped_dot`: a
+kernel that visits only the tiles the groups cover), so imbalance costs
+time and never a token. The
+layer is told which experts it holds (``cfg.experts_held``); it scores
+all ``n_experts``, normalises a token's weights over all it selected,
+and computes the pairs that land in its range, which is what one
+expert-parallel rank computes between the exchanges. On one chip there
+is no exchange, and nothing here stands in for one.
 """
 
 from __future__ import annotations
@@ -27,8 +42,29 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from jax.ad_checkpoint import checkpoint_name
+
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.obs.trace import scope
+
+# per-step counters of the sigmoid layer, in the train step's metrics:
+# pairs dispatched to held experts (summed over layers and
+# micro-batches), the fullest held expert's pairs over the mean (the
+# largest of any layer), and picks of held experts that found no room
+# in the buffer (0 by construction: the buffer is the worst case)
+COUNTERS = ("moe_pairs", "moe_max_load", "moe_pairs_dropped")
+
+
+def stats_init(cfg: ModelConfig) -> dict:
+    """What a layer loop carries beside the activations: the softmax
+    router's aux loss, or the sigmoid layer's counters."""
+    names = ("router_aux",) if cfg.router == "softmax" else COUNTERS
+    return {n: jnp.zeros((), jnp.float32) for n in names}
+
+
+def stats_merge(a: dict, b: dict) -> dict:
+    return {n: jnp.maximum(a[n], b[n]) if n == "moe_max_load"
+            else a[n] + b[n] for n in a}
 
 
 def expert_capacity(cfg: ModelConfig, seq_len: int) -> int:
@@ -142,3 +178,181 @@ def _experts(x, combine, w_gate, w_up, w_down, cfg: ModelConfig, dtype):
     y = jnp.einsum("bsec,ebcd->bsd", combine, h,
                    preferred_element_type=f32)
     return y.astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# the sigmoid router's layer: no drops, grouped products over held experts
+# ---------------------------------------------------------------------------
+
+# (rows, contraction, columns) of a tile of the grouped product on the
+# chip. At the K-EXAONE share's shapes (65536 buffer rows of which 8192
+# live, 16 groups, 6144 x 2048) this tiling reads 2.0 ms a product on a
+# v5e against 2.3-2.9 for four others and 2.8 for XLA's own lowering of
+# `jax.lax.ragged_dot` (my chip run, PR 26)
+GMM_TILING = (256, 1024, 2048)
+
+
+def grouped_dot(x, w, sizes):
+    """x [P, K] rows sorted by group, w [G, K, N], sizes [G] int32 ->
+    [P, N]: row p times the matrix of its group. Rows past
+    ``sum(sizes)`` are NOT defined (the kernel never visits them).
+
+    On the chip: the megablox grouped-matmul kernel (its own
+    ``custom_vjp``: dx is the same kernel over the transposed bank, and
+    the bank's gradient, where nothing asks for it, is dead code). It
+    keeps the scope it runs under in the profile, which XLA's lowering
+    of ``jax.lax.ragged_dot`` to its own kernel does not (the op_name
+    becomes ``ragged-dot-none``). Elsewhere ``ragged_dot`` itself."""
+    from gke_ray_train_tpu.parallel.mesh import on_tpu
+    if not on_tpu():
+        return jax.lax.ragged_dot(x, w, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    tiling = tuple(min(t, n) for t, n in
+                   zip(GMM_TILING, (x.shape[0], x.shape[1], w.shape[2])))
+    return gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiling)
+
+
+def pair_buffer_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of the sorted pair buffer: every pick of every token held."""
+    return tokens * min(cfg.expert_top_k, cfg.n_experts_held)
+
+
+def select_experts(x, router_w, router_bias, cfg: ModelConfig):
+    """x [T, D] -> (idx [T, K] int32, weights [T, K] float32).
+
+    Scores ``sigmoid(x R)`` in float32; the K experts with the largest
+    ``score + bias`` are selected; the weights come from the scores
+    alone, renormalised over the K selected and scaled."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    biased = s if router_bias is None \
+        else s + router_bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(biased, cfg.expert_top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.router_renorm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx, w * cfg.router_scale
+
+
+@jax.custom_vjp
+def _dispatch(x, tok, row, held):
+    """x [T, D] -> x[tok] [P, D]. ``row`` [T, K]: the buffer row of each
+    pair, read only where ``held``. The transpose of a gather is a
+    scatter-add, which the chip serialises (21 ms for 65536 rows of
+    6144 against 7 for the gather below: PERF.md, PR 26); the sort has
+    both directions of the permutation, so dx is a gather too."""
+    return x[tok]
+
+
+def _dispatch_fwd(x, tok, row, held):
+    return x[tok], (row, held)
+
+
+def _dispatch_bwd(res, g):
+    row, held = res
+    dx = jnp.sum(jnp.where(held[..., None], g[row], 0), axis=1,
+                 dtype=jnp.float32)
+    return dx.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, w, tok, row, pair):
+    """y[t] = sum_k w[t, k] out[row[t, k]] -> [T, D]; ``w`` is 0 where
+    the pair is not held. ``tok`` [P] / ``pair`` [P]: the token and the
+    flat (t, k) of each buffer row, for the way back."""
+    return _weighted_rows(out, w, row)
+
+
+def _weighted_rows(out, w, row):
+    return jnp.einsum("tkd,tk->td", out[row], w.astype(out.dtype),
+                      preferred_element_type=jnp.float32).astype(out.dtype)
+
+
+def _combine_fwd(out, w, tok, row, pair):
+    return _weighted_rows(out, w, row), (out, w, tok, row, pair)
+
+
+def _combine_bwd(res, dy):
+    out, w, tok, row, pair = res
+    dy_rows = dy[tok]                                   # [P, D]
+    w_rows = w.reshape(-1)[pair]                        # [P]
+    d_out = dy_rows * w_rows[:, None].astype(dy.dtype)
+    d_w_rows = jnp.sum(out.astype(jnp.float32)
+                       * dy_rows.astype(jnp.float32), axis=-1)
+    # a row past the live ones belongs to a pair that is not held: its
+    # weight is 0 and nothing reads its d_w
+    d_w = jnp.where(w != 0, d_w_rows[row], 0.0).astype(w.dtype)
+    return d_out, d_w, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def routed_experts(x: jnp.ndarray, lp: dict, cfg: ModelConfig, dtype,
+                   valid: jnp.ndarray = None,
+                   buffer_rows: int = None) -> tuple:
+    """The routed part of the sigmoid layer for the experts held here:
+    x [B, S, D] -> (y [B, S, D], counters).
+
+    ``lp``: ``router`` [D, E], optional ``router_bias`` [E], the held
+    bank ``w_gate`` / ``w_up`` [G, D, F] and ``w_down`` [G, F, D]
+    (QTensors are dequantised here). ``valid`` [B, S] bool: positions
+    that are tokens (padding is not routed). ``buffer_rows``: rows of
+    the pair buffer when not the worst case (tests plant a capacity to
+    see ``moe_pairs_dropped`` count)."""
+    from gke_ray_train_tpu.ops.quant import maybe_dequantize
+    B, S, D = x.shape
+    T, K = B * S, cfg.expert_top_k
+    lo, hi = cfg.held_range
+    G = hi - lo
+    P = pair_buffer_rows(cfg, T) if buffer_rows is None else buffer_rows
+    xf = x.reshape(T, D)
+    with scope("moe/route"):
+        idx, w = select_experts(xf, lp["router"], lp.get("router_bias"),
+                                cfg)
+    with scope("moe/dispatch"):
+        held = (idx >= lo) & (idx < hi)
+        if valid is not None:
+            held &= valid.reshape(T, 1)
+        # sorted by expert, the pairs not held (sentinel G) last
+        e = jnp.where(held, idx - lo, G).reshape(T * K)
+        order = jnp.argsort(e, stable=True)[:P].astype(jnp.int32)
+        sizes = jnp.bincount(e, length=G + 1)[:G].astype(jnp.int32)
+        wanted = jnp.sum(sizes)
+        # a planted capacity cuts the last groups short
+        sizes = jnp.diff(jnp.minimum(jnp.cumsum(sizes), P), prepend=0)
+        pairs = jnp.sum(sizes)
+        tok = order // K
+        row = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(P, dtype=jnp.int32), unique_indices=True
+        ).reshape(T, K)
+        in_buffer = jnp.zeros((T * K,), bool).at[order].set(
+            jnp.arange(P) < pairs, unique_indices=True).reshape(T, K)
+        held &= in_buffer
+        xs = _dispatch(xf.astype(dtype), tok, row, held)
+    with scope("moe/experts"):
+        dot = grouped_dot
+        gate = dot(xs, maybe_dequantize(lp["w_gate"], dtype), sizes)
+        up = dot(xs, maybe_dequantize(lp["w_up"], dtype), sizes)
+        gate, up = (checkpoint_name(t, "moe/experts") for t in (gate, up))
+        if cfg.activation == "silu":
+            act = jax.nn.silu(gate)
+        elif cfg.activation == "gelu_tanh":
+            act = jax.nn.gelu(gate, approximate=True)
+        else:
+            raise ValueError(f"unknown activation {cfg.activation}")
+        out = dot(act * up, maybe_dequantize(lp["w_down"], dtype), sizes)
+        # rows past the live ones hold whatever the kernel left there
+        out = jnp.where((jnp.arange(P) < pairs)[:, None], out, 0)
+    with scope("moe/combine"):
+        y = _combine(out, jnp.where(held, w, 0.0), tok, row, order)
+    mean = jnp.maximum(pairs, 1).astype(jnp.float32) / G
+    counters = {
+        "moe_pairs": pairs.astype(jnp.float32),
+        "moe_max_load": jnp.max(sizes).astype(jnp.float32) / mean,
+        "moe_pairs_dropped": (wanted - pairs).astype(jnp.float32)}
+    return y.reshape(B, S, D), counters

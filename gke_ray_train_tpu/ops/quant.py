@@ -43,7 +43,9 @@ DEFAULT_GROUP = 64
 # bnb pass covers LLAMA_TARGET_MODULES, fine_tune_config.json:30-33);
 # the shared canonical tuple lives in models.config (leaf module) so
 # quantize→merge→export stay structurally in sync without a train↔ops cycle
-from gke_ray_train_tpu.models.config import PROJ_TARGETS as QUANT_TARGETS
+from gke_ray_train_tpu.models.config import PROJ_TARGETS, SHARED_TARGETS
+
+QUANT_TARGETS = PROJ_TARGETS + SHARED_TARGETS
 
 _U4_PROBED = None
 

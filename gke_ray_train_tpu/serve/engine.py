@@ -416,6 +416,8 @@ class BatchEngine:
                  draft: Optional[Tuple[Any, ModelConfig]] = None,
                  sidecar_dir: Optional[str] = None,
                  heartbeat_fn: Optional[Callable[[int], None]] = None):
+        from gke_ray_train_tpu.models.kvcache import require_decodable
+        require_decodable(cfg)
         self.plan = plan if plan is not None else serve_plan()
         self.cfg = cfg
         self.params = quantize_for_serving(params, self.plan.serve_quant)

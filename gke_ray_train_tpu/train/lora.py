@@ -17,8 +17,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from gke_ray_train_tpu.models.config import ModelConfig, PROJ_TARGETS
-from gke_ray_train_tpu.models.transformer import Params
+from gke_ray_train_tpu.models.config import (
+    ModelConfig, PROJ_TARGETS, SHARED_TARGETS)
+from gke_ray_train_tpu.models.transformer import (
+    Params, block_layout, block_leaves)
 
 # Default targets = every projection matrix, matching the reference config
 # LORA_TARGET_MODULES (fine_tune_config.json:33: all q/k/v/o/gate/up/down).
@@ -49,29 +51,26 @@ class LoraConfig:
         )
 
 
-def _target_shapes(cfg: ModelConfig) -> dict:
-    hd = cfg.resolved_head_dim
-    return {
-        "wq": (cfg.d_model, cfg.n_heads * hd),
-        "wk": (cfg.d_model, cfg.n_kv_heads * hd),
-        "wv": (cfg.d_model, cfg.n_kv_heads * hd),
-        "wo": (cfg.n_heads * hd, cfg.d_model),
-        "w_gate": (cfg.d_model, cfg.d_ff),
-        "w_up": (cfg.d_model, cfg.d_ff),
-        "w_down": (cfg.d_ff, cfg.d_model),
-    }
-
-
 ATTN_TARGETS = ("wq", "wk", "wv", "wo")
+# a routed layer's shared expert takes the adapters its dense MLP would
+_SHARED_OF = dict(zip(("w_gate", "w_up", "w_down"), SHARED_TARGETS))
 
 
-def _effective_targets(cfg: ModelConfig, lora_cfg: LoraConfig):
-    """MoE models adapt attention only: the routed expert bank has no
-    single delta-W an (A, B) pair could target (peft does the same for
-    Mixtral by default)."""
-    if cfg.n_experts > 0:
-        return tuple(t for t in lora_cfg.targets if t in ATTN_TARGETS)
-    return lora_cfg.targets
+def _effective_targets(cfg: ModelConfig, lora_cfg: LoraConfig,
+                       mlp_kind: str = None):
+    """The leaves of one block that get an (A, B) pair. A routed expert
+    bank has no single delta-W a pair could target (peft does the same
+    for Mixtral by default), so a routed layer adapts its attention and,
+    where it has one, its shared expert (under the shared leaves' own
+    names); the router stays frozen."""
+    if mlp_kind is None:
+        mlp_kind = cfg.scan_mlp_kind
+    if mlp_kind != "moe":
+        return lora_cfg.targets
+    return tuple(
+        t if t in ATTN_TARGETS else _SHARED_OF[t]
+        for t in lora_cfg.targets
+        if t in ATTN_TARGETS or (cfg.n_shared_experts and t in _SHARED_OF))
 
 
 def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
@@ -83,16 +82,17 @@ def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
     keeps trainables fp32 for the same reason). The forward casts them
     to the compute dtype at use (_proj)."""
     pdt = jnp.dtype(jnp.float32)
-    shapes = _target_shapes(cfg)
-    R = cfg.n_repeats
-    targets = _effective_targets(cfg, lora_cfg)
-    keys = iter(jax.random.split(key, len(cfg.block_pattern)
-                                 * len(targets) + 1))
+    n_scan = len(cfg.block_pattern)
+    scan_targets = _effective_targets(cfg, lora_cfg)
+    keys = iter(jax.random.split(key, n_scan * len(scan_targets) + 1))
+    pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
+                                  7 * max(cfg.prologue_layers, 1)))
 
-    def block():
+    def block(R, mlp_kind, keys):
+        shapes = block_leaves(cfg, R, mlp_kind)
         out = {}
-        for t in targets:
-            d_in, d_out = shapes[t]
+        for t in _effective_targets(cfg, lora_cfg, mlp_kind):
+            _, d_in, d_out = shapes[t][0]
             out[t] = {
                 "a": (jax.random.normal(next(keys), (R, d_in, lora_cfg.r),
                                         jnp.float32)
@@ -101,7 +101,11 @@ def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
             }
         return out
 
-    return {"blocks": [block() for _ in cfg.block_pattern]}
+    tree: Params = {}
+    for where, _, _, R, _, kind in block_layout(cfg):
+        tree.setdefault(where, []).append(
+            block(R, kind, keys if where == "blocks" else pkeys))
+    return tree
 
 
 def lora_specs(cfg: ModelConfig, lora_cfg: LoraConfig) -> Params:
@@ -109,18 +113,23 @@ def lora_specs(cfg: ModelConfig, lora_cfg: LoraConfig) -> Params:
     the same way the base matrix shards (fsdp on d_model-ish inputs,
     model on head/ffn outputs)."""
     in_spec = {"wq": "fsdp", "wk": "fsdp", "wv": "fsdp", "wo": "model",
-               "w_gate": "fsdp", "w_up": "fsdp", "w_down": "model"}
+               "w_gate": "fsdp", "w_up": "fsdp", "w_down": "model",
+               "shared_gate": "fsdp", "shared_up": "fsdp",
+               "shared_down": "model"}
     out_spec = {"wq": "model", "wk": "model", "wv": "model", "wo": "fsdp",
-                "w_gate": "model", "w_up": "model", "w_down": "fsdp"}
+                "w_gate": "model", "w_up": "model", "w_down": "fsdp",
+                "shared_gate": "model", "shared_up": "model",
+                "shared_down": "fsdp"}
 
-    def block():
+    tree: Params = {}
+    for where, _, _, _, _, kind in block_layout(cfg):
         # leading repeat dim follows the base weights onto `pipe`
         # (no-op while the pipe axis is size 1)
-        return {t: {"a": P("pipe", in_spec[t], None),
-                    "b": P("pipe", None, out_spec[t])}
-                for t in _effective_targets(cfg, lora_cfg)}
-
-    return {"blocks": [block() for _ in cfg.block_pattern]}
+        tree.setdefault(where, []).append(
+            {t: {"a": P("pipe", in_spec[t], None),
+                 "b": P("pipe", None, out_spec[t])}
+             for t in _effective_targets(cfg, lora_cfg, kind)})
+    return tree
 
 
 def merge_lora(params: Params, lora: Params, lora_cfg: LoraConfig, *,
@@ -159,7 +168,9 @@ def merge_lora(params: Params, lora: Params, lora_cfg: LoraConfig, *,
 
     merged = jax.tree.map(lambda x: x, params)  # shallow-ish copy
     with dev_ctx:
-        for p_blk, l_blk in zip(merged["blocks"], lora["blocks"]):
+        for p_blk, l_blk in zip(
+                merged["blocks"] + merged.get("prologue", []),
+                lora["blocks"] + lora.get("prologue", [])):
             for t, ab in l_blk.items():
                 delta = jnp.einsum("lir,lro->lio",
                                    pull(ab["a"]).astype(jnp.float32),

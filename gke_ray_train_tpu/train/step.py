@@ -30,6 +30,7 @@ from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.transformer import (
     Params, forward, init_params, param_specs)
 from gke_ray_train_tpu.obs.trace import scope
+from gke_ray_train_tpu.ops.moe import COUNTERS, stats_merge
 from gke_ray_train_tpu.parallel.mesh import BATCH_AXES
 from gke_ray_train_tpu.parallel.sharding import tree_shardings
 from gke_ray_train_tpu.train.lora import LoraConfig, init_lora, lora_specs
@@ -247,6 +248,9 @@ def make_train_step(cfg: ModelConfig,
     lora_mode = lora_cfg is not None
     lora_dropout = lora_cfg.dropout if lora_mode else 0.0
     moe = cfg.n_experts > 0
+    # the sigmoid router's layer counts its pairs (ops/moe.py); the
+    # counts ride the micro-batch scan into the step's metrics
+    counter_names = COUNTERS if moe and cfg.router == "sigmoid" else ()
     overlap = plan.overlap if plan is not None else "off"
     fused_ops = plan.fused_ops if plan is not None else False
     # fused cross-entropy (ops/fused_ce.py) replaces materialized
@@ -293,7 +297,7 @@ def make_train_step(cfg: ModelConfig,
                           lora_rng=drop_rng, **fkw)
         else:
             out = forward(trainable, micro["inputs"], cfg, **fkw)
-        hidden, aux = out if moe else (out, None)
+        hidden, aux = out if moe else (out, {})
         if fused_ce:
             from gke_ray_train_tpu.models.transformer import unembed_head
             from gke_ray_train_tpu.ops.fused_ce import fused_cross_entropy
@@ -310,11 +314,12 @@ def make_train_step(cfg: ModelConfig,
                     micro["targets"], micro["weights"], mesh=mesh)
         else:
             nll, w = token_nll(hidden, micro["targets"], micro["weights"])
-        if moe:
+        if "router_aux" in aux:
             # Switch load-balance term, billed per token so the final
             # divide-by-total-weight recovers ce_mean + coef * aux_mean
-            nll = nll + cfg.router_aux_coef * aux["router_aux"] * w
-        return nll, w
+            nll = nll + cfg.router_aux_coef * aux.pop("router_aux") * w
+        # what is left of aux: the routed layer's counters (ops/moe.py)
+        return nll, (w, aux)
 
     def train_step(state: TrainState, batch: Batch):
         trainable = state.lora if lora_mode else state.params
@@ -343,7 +348,7 @@ def make_train_step(cfg: ModelConfig,
             micro = xs[0]
             drop_rng = xs[1] if drop_rngs is not None else None
             if dcn_residual:
-                g_acc, nll_acc, w_acc, resid = carry
+                g_acc, nll_acc, w_acc, _, resid = carry
                 # compressed DCN hop with error feedback: microbatch
                 # k's bf16 quantization residual feeds microbatch
                 # k+1's pre-quantization value (train/overlap.py);
@@ -351,8 +356,8 @@ def make_train_step(cfg: ModelConfig,
                 (nll, w), g, resid = manual_grad(trainable, micro,
                                                  resid)
                 return (jax.tree.map(jnp.add, g_acc, g),
-                        nll_acc + nll, w_acc + w, resid), None
-            g_acc, nll_acc, w_acc = carry
+                        nll_acc + nll, w_acc + w, {}, resid), None
+            g_acc, nll_acc, w_acc, counters = carry
             if manual_grad is not None:
                 # the shard_map microbatch pipeline (train/overlap.py):
                 # per-layer fsdp all-gathers double-buffered behind
@@ -361,20 +366,23 @@ def make_train_step(cfg: ModelConfig,
                 # grad_fn branch, asserted by tests/test_overlap.py
                 (nll, w), g = manual_grad(trainable, micro)
             else:
-                (nll, w), g = grad_fn(trainable, frozen, micro, drop_rng)
+                (nll, (w, seen)), g = grad_fn(trainable, frozen, micro,
+                                              drop_rng)
+                counters = stats_merge(counters, seen)
             return (jax.tree.map(jnp.add, g_acc, g),
-                    nll_acc + nll, w_acc + w), None
+                    nll_acc + nll, w_acc + w, counters), None
 
         zeros = jax.tree.map(jnp.zeros_like, trainable)
         scan_xs = (micros,) if drop_rngs is None else (micros, drop_rngs)
         carry0 = (zeros, jnp.zeros((), jnp.float32),
-                  jnp.zeros((), jnp.float32))
+                  jnp.zeros((), jnp.float32),
+                  {n: jnp.zeros((), jnp.float32) for n in counter_names})
         if dcn_residual:
             # the residual is params-shaped (sharded leaves carry the
             # DCN-hop error at local-shard granularity) and zeroed per
             # step — no TrainState change, no checkpoint-layout change
             carry0 = carry0 + (jax.tree.map(jnp.zeros_like, trainable),)
-        (g_sum, nll_sum, w_sum, *_), _ = jax.lax.scan(
+        (g_sum, nll_sum, w_sum, counters, *_), _ = jax.lax.scan(
             accum, carry0, scan_xs)
 
         with scope("optimizer"):
@@ -396,7 +404,8 @@ def make_train_step(cfg: ModelConfig,
             # the same reduction the optimizer's clip runs (XLA merges
             # the two), so it carries the same name
             grad_norm = optax.global_norm(grads)
-        metrics = {"loss": loss, "grad_norm": grad_norm, "tokens": w_sum}
+        metrics = {"loss": loss, "grad_norm": grad_norm, "tokens": w_sum,
+                   **counters}
         if schedule is not None:
             metrics["learning_rate"] = schedule(state.step)
         return new_state, metrics

@@ -33,7 +33,7 @@ CANDS = (("mlp/gate_up", 3758), ("attn/core", 545), ("attn/qkv", 805),
     (2000, ("attn/core", "attn/qkv", "attn/out")),
     (3800, ("mlp/gate_up",)),
     (4400, ("mlp/gate_up", "attn/core")),
-    (6000, KEEP_ORDER),
+    (6000, KEEP_ORDER[:4]),
     (None, ()),
 ], ids=["nothing_fits", "negative_budget", "only_attn_core",
         "skips_gate_up_takes_the_smaller", "gate_up_alone",
