@@ -407,14 +407,18 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
                 logit_softcap=cfg.attn_softcap)
         else:
             # flash (pallas) / ring (context-parallel) kernels take the
-            # mask *inputs*, never a materialized [S, S] mask
+            # mask *inputs*, never a materialized [S, S] mask. Self-
+            # attention over the batch's own rows: positions are the
+            # default arange or pack_examples' (from 0 in each
+            # document), so they rise inside a segment (rows_ordered)
             from gke_ray_train_tpu.ops.dispatch import attention_dispatch
             out = attention_dispatch(
                 impl, q, k, v,
                 q_positions=positions, kv_positions=positions,
                 q_segment_ids=segment_ids, kv_segment_ids=segment_ids,
                 causal=True, sliding_window=window, scale=cfg.attn_scale,
-                logit_softcap=cfg.attn_softcap, mesh=mesh)
+                logit_softcap=cfg.attn_softcap, mesh=mesh,
+                rows_ordered=True)
         out = out.reshape(B, S, H * hd)
     with scope("attn/out"):
         return checkpoint_name(
@@ -525,6 +529,36 @@ def resolve_seq_impl(cfg: ModelConfig, mesh, S: int) -> str:
     return impl
 
 
+def flash_grids(cfg: ModelConfig, mesh, rows: int, seq: int) -> dict:
+    """What the flash kernels of a step over ``rows`` x ``seq`` local
+    positions do with their grids, by attention kind (the leaf scopes'
+    names: ``window`` / ``full``): the blocks, and for each kernel the
+    grid steps a call visits against those of the rectangular grid at
+    these blocks (1.0: the band is the whole grid). ``{}`` where
+    ``_attn`` does not reach ``flash_attention`` with its own rows (the
+    dense-mask path, ring attention, all-to-all over a sharded context,
+    a pipelined mesh). The ``step_build`` span's ``flash_grid``
+    (train/remat.py)."""
+    from gke_ray_train_tpu.ops.flash_attention import call_plan
+    axes = {} if mesh is None else dict(mesh.shape)
+    impl = resolve_seq_impl(cfg, mesh, seq)
+    if axes.get("pipe", 1) > 1 or not (
+            impl == "flash"
+            or (impl == "a2a" and axes.get(AXIS_CONTEXT, 1) == 1)):
+        return {}
+    calls = rows * max(cfg.n_heads // axes.get("model", 1), 1)
+    grids = {}
+    for kind in dict.fromkeys(cfg.block_pattern):
+        block_q, block_kv, bands = call_plan(
+            seq, seq, causal=True, rows_ordered=True,
+            window=cfg.sliding_window if kind == "sliding" else None)
+        grids["window" if kind == "sliding" else "full"] = {
+            "block_q": block_q, "block_kv": block_kv,
+            **{name: [calls * band.visited, calls * band.rectangular]
+               for name, band in bands.items()}}
+    return grids
+
+
 def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
             positions: Optional[jnp.ndarray] = None,
             segment_ids: Optional[jnp.ndarray] = None,
@@ -540,6 +574,12 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
             return_pre_unembed: bool = False,
             remat_keep: Tuple[str, ...] = ()):
     """tokens [B, S] int32 → logits [B, S, vocab] float32.
+
+    ``positions`` (optional [B, S], default arange): rise with the
+    index inside each segment of ``segment_ids``, as
+    ``data/packing.py::pack_examples`` numbers a packed row (from 0 in
+    each document): the flash kernels walk the causal / window band of
+    blocks on that promise (``_attn``).
 
     ``lora``: optional adapter pytree from train/lora.py (same block
     structure as params, leaves {"a","b"}); base weights stay frozen —

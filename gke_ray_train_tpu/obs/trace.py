@@ -133,9 +133,13 @@ SPAN_NAMES: Dict[str, tuple] = {
     "batch_place": (),
     # perf/cache.py::build_or_load_step: a handful a process, recorded
     # always; `source` is "deserialized" | "compiled"; `remat_*`: what
-    # the block checkpoints keep beside their inputs (train/remat.py)
+    # the block checkpoints keep beside their inputs (train/remat.py);
+    # `flash_grid`: grid steps a flash call visits against its
+    # rectangular grid's, by attention kind and kernel
+    # (models/transformer.py::flash_grids)
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
-                   "remat_budget_bytes", "remat_keep_fallback"),
+                   "remat_budget_bytes", "remat_keep_fallback",
+                   "flash_grid"),
     "step_lower": (),
     "step_compile": (),
 }

@@ -32,7 +32,8 @@ from gke_ray_train_tpu.parallel.mesh import (
 
 def _flash_sharded(q, k, v, q_positions, kv_positions, q_segment_ids,
                    kv_segment_ids, *, mesh, causal, sliding_window, scale,
-                   logit_softcap, interpret, batch_axes=BATCH_AXES):
+                   logit_softcap, interpret, rows_ordered,
+                   batch_axes=BATCH_AXES):
     from gke_ray_train_tpu.ops.flash_attention import flash_attention
 
     def local(q, k, v, qp, kp, qs, ks):
@@ -40,7 +41,8 @@ def _flash_sharded(q, k, v, q_positions, kv_positions, q_segment_ids,
             q, k, v, q_positions=qp, kv_positions=kp, q_segment_ids=qs,
             kv_segment_ids=ks, causal=causal,
             sliding_window=sliding_window, scale=scale,
-            logit_softcap=logit_softcap, interpret=interpret)
+            logit_softcap=logit_softcap, rows_ordered=rows_ordered,
+            interpret=interpret)
 
     if mesh is None:
         return local(q, k, v, q_positions, kv_positions, q_segment_ids,
@@ -68,10 +70,17 @@ def attention_dispatch(impl: str, q, k, v, *,
                        sliding_window: Optional[int] = None,
                        scale=None, logit_softcap=None, mesh=None,
                        interpret: Optional[bool] = None,
+                       rows_ordered: bool = False,
                        batch_axes=BATCH_AXES) -> jnp.ndarray:
     """``batch_axes``: mesh axes sharding dim 0 of q/k/v — the default is
     the (data, fsdp) batch; the pipeline path passes (pipe, data, fsdp)
-    for its stage-folded batch (models/pipeline.py)."""
+    for its stage-folded batch (models/pipeline.py).
+
+    ``rows_ordered``: the caller's statement that q and kv are the same
+    rows with positions rising inside each segment; the flash kernels
+    then walk only the causal / window band of blocks
+    (ops/flash_attention.py::kernel_bands). Ring and all-to-all
+    attention see shifted or regrouped kv and keep the full grid."""
     B, S = q.shape[:2]
     T = k.shape[1]
     if q_positions is None:
@@ -91,7 +100,7 @@ def attention_dispatch(impl: str, q, k, v, *,
             kv_segment_ids, mesh=mesh, causal=causal,
             sliding_window=sliding_window, scale=scale,
             logit_softcap=logit_softcap, interpret=interpret,
-            batch_axes=batch_axes)
+            rows_ordered=rows_ordered, batch_axes=batch_axes)
     if impl == "ring":
         try:
             from gke_ray_train_tpu.ops.ring_attention import ring_attention
@@ -117,7 +126,7 @@ def attention_dispatch(impl: str, q, k, v, *,
                 kv_segment_ids, mesh=mesh, causal=causal,
                 sliding_window=sliding_window, scale=scale,
                 logit_softcap=logit_softcap, interpret=interpret,
-                batch_axes=batch_axes)
+                rows_ordered=rows_ordered, batch_axes=batch_axes)
         if not a2a_supported(mesh, q.shape[2], k.shape[2]):
             # context axis does not divide the local head counts — ring
             # computes the identical function without that constraint

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.remat import (
     KEPT_PEAK_SHARE, choose_keep, keep_candidates, working_set_bytes)
-from gke_ray_train_tpu.models.transformer import resolve_seq_impl
+from gke_ray_train_tpu.models.transformer import (
+    flash_grids, resolve_seq_impl)
 from gke_ray_train_tpu.perf.cache import StepFallback, build_or_load_step
 
 logger = logging.getLogger(__name__)
@@ -104,17 +105,20 @@ class StepRemat:
     lora: bool
     with_keep: Callable[[Tuple[str, ...]], Callable]
 
+    def micro_shape(self, batch) -> Tuple[int, int]:
+        """(rows, positions a row) of one micro-batch on one device."""
+        inputs = batch["inputs"]
+        rows, seq = inputs.sharding.shard_shape(tuple(inputs.shape))
+        return rows // self.grad_accum, seq
+
     def choose(self, state, batch) -> RematChoice:
         limit = device_bytes_limit(self.mesh)
         axes = {} if self.mesh is None else dict(self.mesh.shape)
         if limit is None or axes.get("pipe", 1) > 1:
             # models/pipeline.py keeps nothing, whatever is named
             return RematChoice()
-        inputs = batch["inputs"]
-        flash = resolve_seq_impl(self.cfg, self.mesh,
-                                 inputs.shape[1]) == "flash"
-        rows, seq = inputs.sharding.shard_shape(tuple(inputs.shape))
-        rows //= self.grad_accum
+        rows, seq = self.micro_shape(batch)
+        flash = resolve_seq_impl(self.cfg, self.mesh, seq) == "flash"
         model = axes.get("model", 1)
         trainable = state.lora if self.lora else state.params
         budget = (limit - shard_bytes((state, batch))
@@ -144,18 +148,22 @@ class StepRemat:
         fits; a compile that ends out of HBM, or within the reserve of
         the limit, builds ``step`` instead."""
         choice = self.choose(state, batch)
+        # the same for either step: the checkpoints move no kernel
+        grid = {"flash_grid": flash_grids(self.cfg, self.mesh,
+                                          *self.micro_shape(batch))}
         if choice.keep:
             built = build_or_load_step(
                 self.with_keep(choice.keep), state, batch, label=label,
                 variant=f"remat_keep={choice.keep}",
-                attrs=choice.attrs(),
+                attrs={**choice.attrs(), **grid},
                 fallback=StepFallback(
-                    step, choice.attrs(fallback=True),
+                    step, {**choice.attrs(fallback=True), **grid},
                     peak_limit_bytes=choice.limit_bytes - RESERVE_BYTES),
                 **build_kw)
         else:
-            built = build_or_load_step(step, state, batch, label=label,
-                                       attrs=choice.attrs(), **build_kw)
+            built = build_or_load_step(
+                step, state, batch, label=label,
+                attrs={**choice.attrs(), **grid}, **build_kw)
         if built.info["remat_keep_fallback"]:
             logger.warning(
                 "%s: keeping %s (%.2f GB a device) in the block "
@@ -167,4 +175,11 @@ class StepRemat:
             built.info["remat_keep_bytes"] / 1e9,
             "no device limit reported" if choice.budget_bytes is None
             else f"{choice.budget_bytes / 1e9:.2f} GB")
+        logger.info(
+            "%s: flash grid steps a call, visited / rectangular: %s",
+            label, "; ".join(
+                f"{kind} ({g['block_q']} x {g['block_kv']}) " + ", ".join(
+                    f"{k} {g[k][0]}/{g[k][1]}" for k in ("fwd", "dq", "dkv"))
+                for kind, g in grid["flash_grid"].items())
+            or "no flash kernel walks its own rows")
         return built

@@ -324,6 +324,8 @@ def test_room_keeps_every_name_with_one_lower(monkeypatch, devices,
     assert span["remat_keep_bytes"] == built.info["remat_keep_bytes"] > 0
     assert span["remat_budget_bytes"] >= span["remat_keep_bytes"]
     assert span["remat_keep_fallback"] is False
+    # the dense-mask attention of the CPU walks no flash grid
+    assert span["flash_grid"] == built.info["flash_grid"] == {}
     assert len(_spans(record, "step_lower")) == 1
     assert len(_spans(record, "step_compile")) == 1
     assert "mlp/gate_up/base" not in _recomputed_matmuls(built._compiled)
@@ -448,3 +450,35 @@ def test_the_compile_surface_sizes_a_train_step(monkeypatch, devices,
     plain = cache.build_or_load_step(fn, state, batch)
     assert "remat_keep" not in plain.info
     assert "mlp/gate_up/base" in _recomputed_matmuls(plain._compiled)
+
+
+@pytest.mark.parametrize("preset, seq, expect", [
+    # the routed cell: window 128 at 8192 walks an eighth of its grid at
+    # blocks of 512, the full layers the causal triangle at the defaults
+    ("k-exaone-236b", 8192, {
+        "window": {"block_q": 512, "block_kv": 512, "fwd": [31, 256],
+                   "dq": [31, 256], "dkv": [31, 256]},
+        "full": {"block_q": 256, "block_kv": 1024, "fwd": [144, 256],
+                 "dq": [144, 256], "dkv": [144, 256]}}),
+    # the dense cell: window 4096 over 1024 is the whole grid
+    ("mistral-7b", 1024, {
+        "window": {"block_q": 256, "block_kv": 1024, "fwd": [4, 4],
+                   "dq": [4, 4], "dkv": [4, 4]}}),
+    ("gemma2-9b", 8192, {
+        "window": {"block_q": 512, "block_kv": 1024, "fwd": [60, 128],
+                   "dq": [60, 128], "dkv": [60, 128]},
+        "full": {"block_q": 256, "block_kv": 1024, "fwd": [144, 256],
+                 "dq": [144, 256], "dkv": [144, 256]}}),
+], ids=["routed_8k", "dense_1k", "gemma2_8k"])
+def test_flash_grid_attribute_of_the_presets(preset, seq, expect):
+    """The step_build span's flash_grid: grid steps a call visits over
+    the rectangular grid's, a head-row here (one row, one head)."""
+    from gke_ray_train_tpu.models.config import PRESETS
+    from gke_ray_train_tpu.models.transformer import flash_grids
+    cfg = dataclasses.replace(PRESETS[preset](), attn_impl="flash",
+                              n_heads=1, n_kv_heads=1)
+    assert flash_grids(cfg, None, 1, seq) == expect
+    assert flash_grids(dataclasses.replace(cfg, attn_impl="xla"),
+                       None, 1, seq) == {}
+    assert flash_grids(dataclasses.replace(cfg, attn_impl="ring"),
+                       None, 1, seq) == {}
