@@ -37,7 +37,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 # the repo's runtime surface; tests/ are deliberately excluded (their
 # fixtures CONTAIN the bad snippets the rules must keep catching)
-DEFAULT_LINT_PATHS = ("gke_ray_train_tpu", "ray-jobs", "bench.py",
+DEFAULT_LINT_PATHS = ("gke_ray_train_tpu", "ray-jobs",
                       "__graft_entry__.py")
 
 

@@ -276,8 +276,7 @@ def _base_chips(args) -> int:
     """The base plan's chip count, derived WITHOUT touching a jax
     backend (plan arithmetic only) — the parent process must never
     probe a possibly-dead accelerator before the re-exec (the same
-    discipline as perf.budget's unconditional re-exec; bench.py
-    documents a backend whose ``jax.devices()`` hangs outright)."""
+    discipline as perf.budget's unconditional re-exec)."""
     if args.config:
         from gke_ray_train_tpu.plan import ExecutionPlan
         with open(args.config) as f:

@@ -71,8 +71,8 @@ TRAIN_DIMS: Tuple[str, ...] = ("mesh", "batch", "sync", "fused",
 SERVE_DIMS: Tuple[str, ...] = ("max_batch", "buckets", "adapters",
                                "spec_k")
 
-# the flash-block sweep grid (the same cells scripts/record_baselines.sh
-# has swept by hand since r4)
+# the flash-block sweep grid (on the chip the kernels alone are swept by
+# scripts/flash_block_sweep.py)
 FLASH_BLOCK_GRID: Tuple[Tuple[int, int], ...] = tuple(
     (q, kv) for q in (128, 256, 512) for kv in (512, 1024, 2048))
 

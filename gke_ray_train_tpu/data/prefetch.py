@@ -25,9 +25,8 @@ Two things must overlap to fix the input-bound regime:
    serial one. ``place_fn`` is therefore where expensive per-batch work
    must live to parallelize: the sharded form-up of
    ``parallel.placement.make_place_batch`` (batches land distributed
-   over the mesh, never staged replicated) and — as
-   ``bench.py::bench_input_bound`` shows — any read/tokenize/pack stage
-   routed into it (the iterator then yields cheap work descriptors,
+   over the mesh, never staged replicated) and any read/tokenize/pack
+   stage routed into it (the iterator then yields cheap work descriptors,
    tf.data ``map``-style). GIL-releasing work (FUSE/network reads,
    ``device_put``, HF fast tokenizers) genuinely parallelizes; work
    left inside the iterator gains only overlap #1.

@@ -5,7 +5,7 @@ full-precision weights never sit in accelerator memory
 (/root/reference/ray-jobs/fine_tune_llama_ray.py:216-227,240). The
 stream-load path here does the same (ckpt/hf_io.py: one layer-slice on
 device at a time); this module covers the third acquisition path —
-RANDOM init at full model dims (offline smoke / bench runs with no
+RANDOM init at full model dims (offline smoke runs with no
 checkpoint) — which otherwise materializes the full fp32 tree before
 quantizing and OOMs an 8B model on one 16 GB v5e chip.
 

@@ -4,7 +4,7 @@ Verbs:
 
 - ``report <run_dir>`` — merge the run's events/spans/metrics/ledger/
   bench records into ``<obs_dir>/report.json``, print ONE JSON summary
-  line on stdout (the record_baselines.sh / driver contract; ``--text``
+  line on stdout (the contract of a script that collects it; ``--text``
   additionally renders the per-attempt timeline + critical-path flame
   summary on stderr).
 - ``diff <A> <B>`` — the cross-run regression gate (obs/diff.py):

@@ -123,7 +123,7 @@ def critical_path(spans: List[Dict[str, Any]],
     if not any(s.get("name") in SPAN_TERM or s.get("name") ==
                "step_window" for s in leaves):
         # no ledger-mapped spans at all: the session never ran the
-        # instrumented loop (a serve-only drain, a bench emitting bare
+        # instrumented loop (a serve-only drain, a script emitting bare
         # events, an attempt killed before restore) — there is no path
         # to attribute and nothing to reconcile
         return None

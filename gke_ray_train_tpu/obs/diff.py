@@ -13,8 +13,8 @@ two-sided relative tolerances, per-field overrides recorded in the
 checked-in JSON, the offending-term delta printed on a trip).
 
 The checked-in side lives in ``tests/regressions/*.json`` — one file
-per recorded drill (the ``BENCH_MODE=elastic`` 8→4→8 run is the
-flagship). Re-record after an INTENTIONAL change with
+per recorded drill (``elastic_cpu8.json``, an 8→4→8 shrink and grow, is
+the flagship). Re-record after an INTENTIONAL change with
 ``REGRESSION_UPDATE=1`` (or ``obs diff <run> <ledger> --update``) and
 review the JSON diff like code — that diff IS the goodput review.
 

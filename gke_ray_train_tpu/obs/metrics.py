@@ -76,7 +76,7 @@ METRIC_NAMES: Dict[str, str] = {
     # anomaly-triggered profiling (obs/capture.py)
     "anomalies_total": "counter",
     "captures_total": "counter",
-    # serving (serve/engine.py stats — same numbers BENCH_MODE=serve pins)
+    # serving (serve/engine.py stats(); tests/test_obs.py pins the two equal)
     "serve_iterations_total": "counter",
     "serve_refills_total": "counter",
     "serve_completed_total": "counter",

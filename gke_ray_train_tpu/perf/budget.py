@@ -62,13 +62,6 @@ DEFAULT_TOLERANCES = {
     # dtypes x num_slices, via jax.eval_shape), so any drift means the
     # replicated tree itself changed and the pin must be re-reviewed
     "peer_dcn_bytes": 0.0,
-    # serve-preset modeled latency/throughput (serve_modeled_fields):
-    # deterministic functions of the compile analyses + the declared
-    # ChipSpec, so the same relative band as flops applies — a decode
-    # step that got 10% more expensive moves p50 by the same 10%
-    "serve_tenant_p50_s": 0.05,
-    "serve_tenant_p99_s": 0.05,
-    "serve_tokens_per_s_per_chip": 0.05,
 }
 
 BUDGET_DIR = os.path.join(
@@ -306,72 +299,16 @@ def build_serve_preset_step(preset: Union[str, ServePreset], *,
     return compiled, params, state
 
 
-def serve_modeled_fields(preset: Union[str, ServePreset],
-                         decode_report: StepCostReport
-                         ) -> Dict[str, float]:
-    """Modeled per-tenant latency/throughput for a serve preset —
-    deterministic functions of the compile analyses, so they gate in CI
-    with no wall clock (the ``autotune/score.py`` roofline model at the
-    plan's declared ChipSpec):
-
-    - ``serve_tenant_p50_s``: one decode iteration — the steady-state
-      per-token latency every resident tenant sees (continuous batching
-      emits one token per slot per iteration);
-    - ``serve_tenant_p99_s``: decode iteration + one full-bucket
-      prefill — the tail where a token waits behind a refill admission
-      stalling the shared batch;
-    - ``serve_tokens_per_s_per_chip``: max_batch tokens per modeled
-      iteration, over the plan's chip count.
-    """
-    from gke_ray_train_tpu.autotune.score import (
-        chip_for_plan, modeled_step_time)
-
-    p = SERVE_PRESETS[preset] if isinstance(preset, str) else preset
-    plan = plan_for_serve_preset(p)
-    chip = chip_for_plan(plan)
-    t_decode = modeled_step_time(decode_report, chip)["modeled_step_s"]
-    t_prefill = modeled_step_time(_serve_prefill_report(p),
-                                  chip)["modeled_step_s"]
-    return {
-        "serve_tenant_p50_s": t_decode,
-        "serve_tenant_p99_s": t_decode + t_prefill,
-        "serve_tokens_per_s_per_chip":
-            p.max_batch / t_decode / max(plan.chips, 1),
-    }
-
-
-def _serve_prefill_report(p: ServePreset) -> StepCostReport:
-    """Cost report of the preset's [1, bucket] prefill — the refill
-    executable whose modeled time is the p99 stall term."""
-    import jax
-    import jax.numpy as jnp
-
-    from gke_ray_train_tpu.models import init_params
-    from gke_ray_train_tpu.ops.quant import quantize_for_serving
-    from gke_ray_train_tpu.serve.engine import make_prefill_fn
-
-    cfg = _serve_model_cfg(p)
-    params = quantize_for_serving(init_params(cfg, jax.random.key(0)),
-                                  p.quant)
-    prompt = jnp.zeros((1, p.bucket), jnp.int32)
-    plen = jnp.ones((1,), jnp.int32)
-    compiled = jax.jit(make_prefill_fn(cfg)).lower(
-        params, prompt, plen, None).compile()
-    return step_cost_report(compiled, tokens_per_step=p.bucket)
-
-
 def build_budget_doc(preset: Union[str, Preset, ServePreset],
                      *, remat=None) -> Dict[str, Any]:
     """The full dict a budget records/checks: the StepCostReport plus,
-    on serve presets, the modeled per-tenant fields — the one builder
-    the CLI and the tier-1 budget tests share, so the recorded and the
-    checked documents can never diverge in shape."""
-    report = build_preset_report(preset, remat=remat)
-    doc = report.to_dict()
+    on hybrid presets, ``peer_dcn_bytes`` — the one builder the CLI and
+    the tier-1 budget tests share, so the recorded and the checked
+    documents can never diverge in shape. Counts only (flops, bytes,
+    collectives): a compile on XLA:CPU gives no time and no rate."""
+    doc = build_preset_report(preset, remat=remat).to_dict()
     name = preset if isinstance(preset, str) else preset.name
-    if isinstance(preset, ServePreset) or name in SERVE_PRESETS:
-        doc.update(serve_modeled_fields(preset, report))
-    else:
+    if not isinstance(preset, ServePreset) and name not in SERVE_PRESETS:
         p = PRESETS[name] if isinstance(preset, str) else preset
         if p.num_slices > 1:
             doc["peer_dcn_bytes"] = peer_replication_bytes(p)
@@ -545,10 +482,9 @@ def main(argv: Optional[List[str]] = None) -> int:
     parser.add_argument("--all", action="store_true", dest="sweep_all",
                         help="sweep EVERY checked-in preset (train + "
                              "hybrid + serve) in one invocation — the "
-                             "explicit spelling record_baselines.sh and "
-                             "the CI budget step use, so the gate can "
-                             "never silently narrow to a hand-kept "
-                             "preset list")
+                             "explicit spelling the CI budget step "
+                             "uses, so the gate can never silently "
+                             "narrow to a hand-kept preset list")
     parser.add_argument("--dir", default=BUDGET_DIR,
                         help="budget directory (default tests/budgets)")
     args = parser.parse_args(argv)

@@ -122,8 +122,8 @@ def cpu_mesh_env(n_devices: int = 8, **extra: str) -> Dict[str, str]:
     """os.environ copy that forces an ``n_devices`` virtual CPU platform
     in a CHILD process (XLA_FLAGS must land before backend init, hence
     re-exec rather than in-process switching). The one canonical recipe
-    shared by the bench's CPU-mesh arms and the budget/analysis CLIs —
-    keep it here so they cannot drift."""
+    shared by the budget, analysis and autotune CLIs — keep it here so
+    they cannot drift."""
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "xla_force_host_platform_device_count" not in f]

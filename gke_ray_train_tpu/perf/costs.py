@@ -737,7 +737,8 @@ class StepCostReport:
         return StepCostReport(**{k: v for k, v in d.items() if k in known})
 
     def summary(self) -> Dict[str, Any]:
-        """Compact form for one-line JSON records (bench output)."""
+        """Compact form for one-line JSON records (the autotune and
+        analysis CLIs print it)."""
         out = {
             "flops_per_step": self.flops,
             "bytes_accessed": self.bytes_accessed,

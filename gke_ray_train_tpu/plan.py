@@ -19,7 +19,7 @@ with a constructor per legacy dialect (:meth:`from_config`,
 
 ``fingerprint()`` is the plan's stable identity: a digest of the
 canonical field dict, independent of process, host, and backend. It is
-recorded in budget JSONs (``_plan_fingerprint``), BENCH records, and
+recorded in budget JSONs (``_plan_fingerprint``), attempt logs, and
 AOT sidecar keys (``perf/cache.py`` composes it with the runtime
 topology fingerprint, which it thereby subsumes: two runs share a
 compiled artifact only when both the physical topology AND the declared
@@ -204,7 +204,7 @@ class ExecutionPlan:
     # shares a sidecar record ambiguously (ISSUE 17 contract).
     prefix_cache: bool = False
     # speculative decoding: "none" (off) | "self" (the target model
-    # drafts for itself — the accept-all drill/bench arm) | "distilled"
+    # drafts for itself — the accept-all drill arm) | "distilled"
     # (a separate small draft model handed to the engine). spec_k =
     # draft tokens proposed per round; the fused draft+verify
     # executable compiles its verify forward at [max_batch, spec_k+1].
@@ -258,7 +258,7 @@ class ExecutionPlan:
     #   manual — the shard_map microbatch pipeline (train/overlap.py):
     #            layer k+1's FSDP all-gather is double-buffered behind
     #            layer k's compute; bitwise-identical losses to "off",
-    #            asserted by test + the BENCH_MODE=overlap A/B
+    #            to the last ulp (tests/test_overlap.py)
     overlap: str = "off"
     # route the memory-bound epilogue ops through the fused Pallas
     # kernels (ops/fused_norm_rope.py, ops/fused_ce.py) instead of the
@@ -464,10 +464,10 @@ class ExecutionPlan:
     def canonical(self) -> Dict[str, Any]:
         """JSON-safe canonical field dict — the fingerprint payload.
         ``obs_dir`` is excluded: it is a RUN-scoped scratch/output path
-        (record_baselines points it at a mktemp dir), and two runs of
-        the byte-identical plan must fingerprint identically or the
-        stable identity budget JSONs / BENCH records / attempt logs
-        correlate on dissolves into per-run noise."""
+        (a drill points it at a mktemp dir), and two runs of the
+        byte-identical plan must fingerprint identically or the stable
+        identity budget JSONs / attempt logs correlate on dissolves
+        into per-run noise."""
         return {f.name: getattr(self, f.name)
                 for f in dataclasses.fields(self)
                 if f.name != "obs_dir"}
@@ -475,8 +475,8 @@ class ExecutionPlan:
     def fingerprint(self, surface: Optional[str] = None) -> str:
         """Stable 16-hex-char identity of the declared plan — every
         field except the run-scoped ``obs_dir`` path (see
-        :meth:`canonical`). Recorded in budget JSONs, BENCH records,
-        attempt logs.
+        :meth:`canonical`). Recorded in budget JSONs and attempt
+        logs.
 
         ``surface="train"|"serve"`` narrows the identity to that
         surface's compile-relevant fields (delegates to
@@ -1003,7 +1003,7 @@ def compile_step_with_plan(plan: ExecutionPlan, mesh, fn: Callable,
                            label: str = "train_step",
                            surface: str = "train") -> Callable:
     """Compile a step function under one plan — the single surface
-    training, bench, and analysis all route through.
+    training, serving, the budgets and analysis all route through.
 
     ``fn`` may be a plain python step body (jitted here with the plan's
     donation policy and any explicit in/out shardings — PartitionSpec

@@ -397,8 +397,7 @@ class _BucketRuntime:
 
 class BatchEngine:
     """The in-process continuous-batching engine (the CPU-mesh tests
-    and ``BENCH_MODE=serve`` drive this directly; rayint/serving.py
-    wraps it in a Ray actor).
+    drive this directly; rayint/serving.py wraps it in a Ray actor).
 
     ``params`` may be a plain or quantized tree, optionally mesh-placed;
     ``plan.serve_quant`` quantizes at construction when asked. All

@@ -146,9 +146,9 @@ def run_training(state: TrainState,
     durable, and raises Preempted — the trainer retries WITHOUT
     consuming the max_failures budget.
     """
-    # time-to-first-step accounting (BENCH_MODE=compile / recovery):
-    # the clock starts BEFORE the checkpoint restore below — at 8B scale
-    # restore is the other dominant term besides compile, and
+    # time-to-first-step accounting: the clock starts BEFORE the
+    # checkpoint restore below — at 8B scale restore is the other
+    # dominant term besides compile, and
     # restart_to_first_step_s must cover restore + fast-forward +
     # compile (compile_s isolates the first step call, ≈0 when the step
     # is a deserialized AOT executable; perf/cache.py)
@@ -159,7 +159,7 @@ def run_training(state: TrainState,
     # the context (or the Preempted exception) into Result.goodput
     ledger = GoodputLedger()
     # unified telemetry (obs/): the attempt-scoped session the trainer
-    # (or a test/bench) configured; None = one is-None check per step.
+    # (or a test) configured; None = one is-None check per step.
     # Per-step feed = host floats the loop already measures (iteration
     # wall minus data wait minus eval/ckpt pauses) — no device sync,
     # no event emission off the log cadence, so the A/B stream with

@@ -37,8 +37,8 @@ from gke_ray_train_tpu.models.config import ModelConfig
 
 # the terms of the per-attempt goodput ledger (ISSUE 8). These existed
 # piecemeal — compile_s / restart_to_first_step_s in the loop timings,
-# data_stall_frac in the meter, recompile/restore splits in
-# BENCH_MODE=recovery, ckpt_save_s on Preempted — and are unified here:
+# data_stall_frac in the meter, recompile/restore splits in the
+# recovery drill, ckpt_save_s on Preempted — and are unified here:
 # every attempt's wall-clock decomposes into exactly these buckets, and
 # tests assert they reconcile (sum == attempt wall within tolerance).
 # ckpt_async_s is the RESIDUAL blocking time of an async-commit save

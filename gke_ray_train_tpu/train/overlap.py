@@ -20,8 +20,9 @@ pipeline, the way Megatron-LM-style stacks hide their collectives:
   all-reduce over the consecutive {data x fsdp} group, then the local
   fsdp slice), which is what makes the manual path's losses AND grads
   **bitwise-identical** to the GSPMD scan on the CPU mesh — the
-  equivalence `tests/test_overlap.py` drills and ``BENCH_MODE=overlap``
-  re-asserts per run.
+  equivalence `tests/test_overlap.py` drills (the gradients exactly;
+  the loss, whose last reduction XLA:CPU fuses per program, to its
+  last ulp).
 
 On a **multi-slice hybrid mesh** (``num_slices > 1`` — the data axis
 spans slices, PR 5's contract) the reduction is additionally

@@ -203,8 +203,8 @@ def make_train_step(cfg: ModelConfig,
     ``plan``: an :class:`~gke_ray_train_tpu.plan.ExecutionPlan` — the
     declarative source for grad_accum / donation / pipeline
     microbatching (explicit kwargs still win), and the route through
-    ``plan.compile_step_with_plan`` so training, bench and analysis
-    share ONE compile surface.
+    ``plan.compile_step_with_plan`` so training, the budgets and
+    analysis share ONE compile surface.
 
     batch: dict with "inputs"/"targets" [B, S] int32, "weights" [B, S]
     float, optional "segment_ids"/"positions" [B, S]. B must be divisible
@@ -215,8 +215,9 @@ def make_train_step(cfg: ModelConfig,
     instead of surviving until the Python reference dies. The input
     pipeline owns its own host copies and never re-feeds a placed batch
     (data/prefetch.py), so this is pure peak-memory headroom. Pass
-    False when the SAME placed batch is fed repeatedly (bench timing
-    loops) — a donated buffer must not be reused.
+    False when the SAME placed batch is fed repeatedly (the budget
+    presets of perf/budget.py, analysis/jaxprcheck.py and the tests do)
+    — a donated buffer must not be reused.
 
     ``pipe_microbatches``: pipeline microbatch count per forward when the
     mesh has a pipe axis > 1 (models/pipeline.py; default = stage count).

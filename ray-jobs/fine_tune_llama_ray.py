@@ -84,7 +84,7 @@ def train_eval_save(config: dict) -> types.SimpleNamespace:
     # knob — mesh, batch shape, donation, prefetch, compile-once
     # policy, runtime guards — from the config (env fallback), and its
     # fingerprint identifies the run in cache dirs, AOT sidecar keys
-    # and BENCH/budget records
+    # and budget records
     from gke_ray_train_tpu.plan import ExecutionPlan, compile_step_with_plan
     plan = ExecutionPlan.resolve(config)
     apply_debug_flags(config)
@@ -708,8 +708,7 @@ if __name__ == "__main__":
         logger.info("run telemetry: python -m gke_ray_train_tpu.obs "
                     "report %s --text", _obs_dir)
     # one machine-readable line on stdout (logging goes to stderr) so
-    # drivers/scripts (scripts/record_baselines.sh) can collect the
-    # job's meter numbers the same way they collect bench.py records
+    # a driver or script can collect the job's meter numbers
     print(json.dumps({"metric": "flagship_final",
                       "attempts": result.attempts,
                       "preemptions": result.preemptions, **{

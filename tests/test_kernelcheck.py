@@ -535,7 +535,7 @@ def test_cli_rc_contract(tmp_path, capsys):
 def test_cli_full_repo_clean():
     """The acceptance gate: the full CLI (static + every differential
     sweep vs the shipped ledger) exits 0 on the repo at HEAD. Slow —
-    CI's lint job and record_baselines.sh run the identical command."""
+    CI's lint job runs the identical command."""
     r = subprocess.run(
         [sys.executable, "-m", "gke_ray_train_tpu.analysis",
          "kernelcheck"],

@@ -1,7 +1,6 @@
 """obs/ — unified run telemetry (ISSUE 11).
 
-Contract tests in the style of test_bench_contract: the event/metric
-schema is PINNED (shipped schema files == code vocabularies), the
+Contract tests: the event/metric schema is PINNED (shipped schema files == code vocabularies), the
 anomaly-capture drill proves fire-once semantics on the CPU mesh with
 injected faults, `obs report` over the elastic 8->4->8 drill shows both
 reshards with every attempt's ledger reconciling to its wall-clock
@@ -385,7 +384,7 @@ def test_obs_plan_knobs_three_dialects():
     for surface in ("train", "serve", "all"):
         assert base.compile_fingerprint(surface) == \
             toggled.compile_fingerprint(surface)
-    # obs_dir is RUN-scoped (record_baselines points it at mktemp):
+    # obs_dir is RUN-scoped (a drill points it at a mktemp dir):
     # two runs of the byte-identical plan must share a fingerprint
     assert ExecutionPlan.from_kwargs(obs_dir="/tmp/a").fingerprint() \
         == ExecutionPlan.from_kwargs(obs_dir="/tmp/b").fingerprint() \
@@ -410,7 +409,7 @@ def test_resolve_obs_dir_precedence(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def _elastic_drill(work):
-    """The BENCH_MODE=elastic shape (8->4->8 injected pool change
+    """The elastic drill (8->4->8 injected pool change
     through the real trainer) with obs enabled — shared by the report
     tests below."""
     from gke_ray_train_tpu.ckpt import CheckpointManager
@@ -583,7 +582,7 @@ def test_terminal_pool_failure_attempt_still_reported(tmp_path,
         reset_pool()
         # the run ENDS preempted-with-flag-up (no further attempt
         # resets it) — clear it or later tests in this process
-        # preempt-exit at step 0 (the bench_recovery convention)
+        # preempt-exit at step 0
         preempt.reset()
         preempt.uninstall()
     assert res.status == "failed" and "MIN_DEVICES" in res.error
@@ -841,7 +840,7 @@ def test_trace_critical_path_and_diff_on_elastic_drill(elastic_drill):
 def test_serve_engine_exports_obs(tmp_path):
     """run_until_drained lands serve_start/serve_drained on the event
     stream and the p50/p99/occupancy numbers in the metric export —
-    the same stats() dict BENCH_MODE=serve pins."""
+    the engine's own stats() dict."""
     import dataclasses
 
     from gke_ray_train_tpu.models import init_params, llama3_8b

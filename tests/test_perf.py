@@ -13,10 +13,10 @@ The contract under test (ISSUE 4):
 - the checked-in budgets under tests/budgets/ pass on main.
 """
 
+import glob
 import json
 import os
-import subprocess
-import sys
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,8 @@ import pytest
 
 import gke_ray_train_tpu.perf.cache as perf_cache
 from gke_ray_train_tpu.perf.budget import (
-    PRESETS, BudgetViolation, assert_within_budget, budget_path,
+    BUDGET_DIR, DEFAULT_TOLERANCES, PRESETS, BudgetViolation,
+    all_preset_names, assert_within_budget, budget_path,
     build_preset_report, build_preset_step, compare_to_budget, load_budget,
     write_budget)
 from gke_ray_train_tpu.perf.cache import (
@@ -372,6 +373,25 @@ def test_checked_in_budgets_pass_on_main(fsdp_mesh):
         assert_within_budget(rep, path)
 
 
+def test_budget_documents_hold_counts_only():
+    """A compile on XLA:CPU gives flops, bytes and collectives: counts.
+    No budget document and no tolerance carries a time (``*_s``) or a
+    rate (``*_per_s``), and the documents were recorded together, under
+    the jax that is installed."""
+    timed = re.compile(r"_s$|_per_s(_|$)")
+    paths = sorted(glob.glob(os.path.join(BUDGET_DIR, "*.json")))
+    assert [os.path.basename(p)[:-5] for p in paths] == \
+        sorted(all_preset_names())
+    docs = {p: load_budget(p) for p in paths}
+    named = {k for doc in docs.values()
+             for k in list(doc) + list(doc.get("tolerances", {}))}
+    assert not [k for k in named | set(DEFAULT_TOLERANCES)
+                if timed.search(k)]
+    assert "tokens_per_step" in named          # a count: let be
+    assert {doc["_recorded_with"]["jax"] for doc in docs.values()} == \
+        {jax.__version__}
+
+
 def test_budget_catches_remat_silently_off(fsdp_mesh):
     """Flipping remat=False drops flops (no recompute) and roughly
     doubles peak temp memory — the budget harness must scream."""
@@ -453,7 +473,7 @@ def test_eval_step_pinned_shardings_trace_once(fsdp_mesh, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# loop metrics + bench record (subprocess; slow)
+# loop metrics
 # ---------------------------------------------------------------------------
 
 def test_run_training_reports_compile_metrics():
@@ -475,32 +495,3 @@ def test_run_training_reports_compile_metrics():
     _, metrics = run_training(state, step, batches, epochs=1)
     assert metrics["compile_s"] > 0
     assert metrics["restart_to_first_step_s"] >= metrics["compile_s"]
-
-
-@pytest.mark.slow
-def test_bench_compile_mode_stamps_the_platform_it_ran_on():
-    """BENCH_MODE=compile on the CPU mesh: one valid JSON record whose
-    backend is the platform jax attached (no fallback tag, no fallback
-    reason), warm-cache (or AOT) build under 30% of cold, and a
-    bitwise-equal AOT step."""
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("BENCH_")}
-    # conftest's suite-wide COMPILE_CACHE=0 must not leak into the
-    # cache-measuring child
-    env.pop("COMPILE_CACHE", None)
-    env.update(BENCH_MODE="compile", PYTHONPATH=REPO)
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                       capture_output=True, text=True, cwd=REPO,
-                       timeout=600, env=env)
-    assert r.returncode == 0, r.stderr[-1500:]
-    lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 1, lines
-    rec = json.loads(lines[0])
-    assert rec["unit"] != "error" and rec["value"] > 0
-    assert rec["backend"] == "cpu"
-    assert "fallback_reason" not in rec
-    assert min(rec["warm_frac_of_cold"],
-               rec.get("aot_frac_of_cold", 1.0)) < 0.3
-    assert rec["aot_loss_bitwise_equal"] is True
-    assert rec["cost_report"]["flops_per_step"] > 0
-    assert rec["cache_hits"] >= 1

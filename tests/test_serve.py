@@ -472,12 +472,6 @@ def test_serve_decode_budget_checked_in():
             "gke_ray_train_tpu.perf.budget record")
         assert_within_budget(doc, path, plan=plan_for_preset(name))
         assert sum(doc["collective_counts"].values()) == 0
-        # the modeled per-tenant fields ride (and are therefore pinned
-        # in) every serve budget — serve_multilora8's is the recorded
-        # multi-tenant throughput/latency claim
-        for f in ("serve_tenant_p50_s", "serve_tenant_p99_s",
-                  "serve_tokens_per_s_per_chip"):
-            assert doc[f] > 0
 
 
 def test_serve_preset_plan_is_pinned_consistently():

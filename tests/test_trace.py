@@ -394,7 +394,7 @@ def test_diff_noise_floor_and_named_terms():
 
 
 # ---------------------------------------------------------------------------
-# satellites: histogram reservoir + bench run_id
+# satellite: histogram reservoir
 # ---------------------------------------------------------------------------
 
 def test_histogram_reservoir_spans_whole_run():
@@ -431,29 +431,6 @@ def test_histogram_reservoir_spans_whole_run():
     for _ in range(5000):
         h2.observe(1.0)
     assert h2._samples == h._samples
-
-
-def test_bench_emit_stamps_run_id(monkeypatch, capsys):
-    """The satellite: bench records carry a run identity even with no
-    active obs session (process-stable), and an exported OBS_RUN_ID
-    always wins — `obs diff`/report merges key A/B arms by it."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    monkeypatch.delenv("OBS_RUN_ID", raising=False)
-    monkeypatch.delenv("OBS_DIR", raising=False)
-    bench._BENCH_RUN_ID = None
-    bench._emit("m", 1.0, "u", {}, compare_baseline=False)
-    bench._emit("m2", 2.0, "u", {}, compare_baseline=False)
-    recs = [json.loads(ln) for ln in
-            capsys.readouterr().out.strip().splitlines()]
-    assert recs[0]["run_id"] and recs[0]["run_id"] == recs[1]["run_id"]
-    monkeypatch.setenv("OBS_RUN_ID", "job-level-id")
-    bench._emit("m3", 3.0, "u", {}, compare_baseline=False)
-    rec = json.loads(capsys.readouterr().out.strip())
-    assert rec["run_id"] == "job-level-id"
 
 
 # ---------------------------------------------------------------------------
