@@ -386,7 +386,13 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
         v = v.reshape(B, S, K, hd)
         q = _constrain(q, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
         k = _constrain(k, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
+    # "attn/qkv" names what spares the three projections their second
+    # run. Without q/k norm that is what the attention's backward reads:
+    # q and k after rope (rope's own backward reads neither). The norm's
+    # backward reads its input, so with it the name sits on the
+    # projections' outputs, and norm and rope (elementwise) run again
     if cfg.qk_norm:
+        q, k = (checkpoint_name(t, "attn/qkv") for t in (q, k))
         with scope("attn/qk_norm"):
             q = rms_norm(q, lp["q_norm"], eps=cfg.norm_eps,
                          scale_plus_one=cfg.norm_scale_plus_one)
@@ -396,8 +402,9 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
         with scope("attn/rope"):
             q, k = _apply_rope_qk(q, k, positions, rope,
                                   fused_ops=fused_ops, mesh=mesh)
-    # what the attention backward reads: q and k after rope, v
-    q, k, v = (checkpoint_name(t, "attn/qkv") for t in (q, k, v))
+    if not cfg.qk_norm:
+        q, k = (checkpoint_name(t, "attn/qkv") for t in (q, k))
+    v = checkpoint_name(v, "attn/qkv")
     kind_scope = contextlib.nullcontext() if kind is None else scope(
         "window" if kind == "sliding" else "full")
     with scope("attn/core"), kind_scope:

@@ -138,8 +138,8 @@ SPAN_NAMES: Dict[str, tuple] = {
     # rectangular grid's, by attention kind and kernel
     # (models/transformer.py::flash_grids)
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
-                   "remat_budget_bytes", "remat_keep_fallback",
-                   "flash_grid"),
+                   "remat_budget_bytes", "remat_args_bytes",
+                   "remat_keep_fallback", "flash_grid"),
     "step_lower": (),
     "step_compile": (),
 }

@@ -9,8 +9,14 @@ inside the jitted forward — XLA fuses the dequant into the consuming
 matmul's prologue, and the frozen base stays 4-bit/8-bit in HBM, which
 is what makes 8B QLoRA fit a single 16 GB v5e chip.
 
-- "nf4": 4-bit NormalFloat codebook (the QLoRA data type) stored as
-  uint4 (2 codes/byte in HBM), absmax-scaled per group.
+- "nf4": 4-bit NormalFloat codebook (the QLoRA data type), absmax-scaled
+  per group. The codes are ``jnp.uint4`` of the weight's own shape, on
+  every backend: the device packs two a byte (XLA bills an executable
+  half a byte a code, and the v5e takes them as arguments of the AOT
+  step, stacked by ``lax.map`` and sliced inside the fusion that holds
+  the dequant: PERF.md, PR 29), while ``itemsize`` / ``nbytes`` and a
+  host copy still say a byte a code (``train/remat.py::shard_bytes``
+  bills bits). There is no other storage and no key.
 - "int8": symmetric per-group int8 (the load_in_8bit analogue).
 
 Scales keep the rank of the weight (input dim / group), so one
@@ -21,7 +27,6 @@ shard with the same spec tree as fp32 ones.
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import partial
 from typing import Any
 
@@ -47,37 +52,11 @@ from gke_ray_train_tpu.models.config import PROJ_TARGETS, SHARED_TARGETS
 
 QUANT_TARGETS = PROJ_TARGETS + SHARED_TARGETS
 
-_U4_PROBED = None
-
-
-def _nf4_store_dtype():
-    """Storage dtype for NF4 codes: int8 by default, uint4 by opt-in.
-
-    uint4 halves the codes' HBM footprint (2 codes/byte) but sub-byte
-    arrays were fragile as *executable arguments* on an earlier runtime:
-    when a consuming jit wanted a different tiled layout than the
-    producing jit emitted, the dispatch-time relayout ``device_put``
-    recursively re-entered jit and died with a RecursionError, depending
-    on layout assignment. So the default is the dtype that always
-    works. Whether ``QUANT_STORE=uint4`` works on the installed runtime
-    (jax 0.9.0 / libtpu 0.0.34) has not been checked on the chip."""
-    global _U4_PROBED
-    if _U4_PROBED is None:
-        want = os.environ.get("QUANT_STORE", "int8").lower()
-        if want not in ("int8", "uint4"):
-            raise ValueError(f"QUANT_STORE={want!r}; use int8|uint4")
-        if want == "uint4" and not hasattr(jnp, "uint4"):
-            raise ValueError(
-                "QUANT_STORE=uint4 requested but this JAX build has no "
-                "jnp.uint4")
-        _U4_PROBED = jnp.uint4 if want == "uint4" else jnp.int8
-    return _U4_PROBED
-
-
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class QTensor:
-    """codes [..., D, F] (uint4/int8) + scales [..., D/group, F] fp32."""
+    """codes [..., D, F] (``uint4`` for "nf4": half a byte a weight on
+    the device; ``int8`` for "int8") + scales [..., D/group, F] fp32."""
     codes: jnp.ndarray
     scales: jnp.ndarray
     kind: str = "nf4"
@@ -103,16 +82,19 @@ def is_qtensor(x: Any) -> bool:
     return isinstance(x, QTensor)
 
 
+def stored_bits(dtype) -> int:
+    """Bits an element of ``dtype`` takes on the device: ``itemsize``
+    says a byte for the sub-byte integers that XLA packs."""
+    dtype = jnp.dtype(dtype)
+    if jnp.issubdtype(dtype, jnp.integer):
+        return jnp.iinfo(dtype).bits
+    return dtype.itemsize * 8
+
+
+@partial(jax.jit, static_argnames=("kind", "group"))
 def quantize_tensor(w: jnp.ndarray, kind: str = "nf4",
                     group: int = DEFAULT_GROUP) -> QTensor:
     """Quantize along the input dim (axis -2) in groups of ``group``."""
-    store = jnp.dtype(_nf4_store_dtype()).name if kind == "nf4" else "int8"
-    return _quantize_jit(w, kind, group, store)
-
-
-@partial(jax.jit, static_argnames=("kind", "group", "store"))
-def _quantize_jit(w: jnp.ndarray, kind: str, group: int,
-                  store: str) -> QTensor:
     *lead, D, F = w.shape
     if D % group:
         # largest divisor of D <= group (tiny/smoke models have odd dims)
@@ -125,7 +107,7 @@ def _quantize_jit(w: jnp.ndarray, kind: str, group: int,
         book = jnp.asarray(NF4_CODEBOOK)
         codes = jnp.argmin(
             jnp.abs(normed[..., None] - book),
-            axis=-1).astype(jnp.dtype(store))
+            axis=-1).astype(jnp.uint4)
     elif kind == "int8":
         scales = absmax / 127.0
         codes = jnp.round(

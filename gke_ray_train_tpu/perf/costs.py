@@ -827,8 +827,10 @@ def assert_state_donation(compiled, state: Any,
     ma = compiled.memory_analysis()
     if ma is None:  # pragma: no cover - backend without the analysis
         return -1
+    from gke_ray_train_tpu.ops.quant import stored_bits
+    # bits, not itemsize: NF4 codes are two a byte on the device
     state_bytes = sum(
-        x.size * x.dtype.itemsize for x in jax.tree.leaves(state)
+        x.size * stored_bits(x.dtype) // 8 for x in jax.tree.leaves(state)
         if hasattr(x, "dtype")) // max(len(jax.devices()), 1)
     alias = int(ma.alias_size_in_bytes)
     if alias < min_frac * state_bytes:

@@ -118,8 +118,8 @@ def run_training(state: TrainState,
     ckpt_view: optional (save_view, load_view) pair mapping the state to
     the subset the checkpoint persists — LoRA mode saves only adapters +
     optimizer state (the frozen/quantized base is rebuilt from the
-    pretrained weights on resume, and quantized uint4 codes are not
-    serializable anyway).
+    pretrained weights on resume, and its NF4 codes are ``uint4``, which
+    orbax's tensorstore refuses: "Unsupported data type", orbax 0.11.32).
     heartbeat_fn(step, done=False) → per-step liveness report
     (rayint/supervisor.py; entry scripts wire ctx.heartbeat). Called
     after every completed step — supervision arms at the first beat,

@@ -23,13 +23,13 @@ import math
 from typing import Any, Callable, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.remat import (
     KEPT_PEAK_SHARE, choose_keep, keep_candidates, working_set_bytes)
 from gke_ray_train_tpu.models.transformer import (
     flash_grids, resolve_seq_impl)
+from gke_ray_train_tpu.ops.quant import stored_bits
 from gke_ray_train_tpu.perf.cache import StepFallback, build_or_load_step
 
 logger = logging.getLogger(__name__)
@@ -43,14 +43,17 @@ def shard_bytes(tree: Any, *, whole: bool = False, dtype=None) -> int:
     """Bytes one device holds of a tree of (abstract) arrays: each
     leaf's shard shape under its sharding, its full shape without one
     (or with ``whole``: the bytes of the whole tree). ``dtype``: as if
-    every leaf were of that type."""
+    every leaf were of that type. A sub-byte leaf (NF4 codes, two a
+    byte) is billed by its bits, rounded up a leaf: ``itemsize`` says 1
+    for a type the device packs."""
     total = 0
     for leaf in jax.tree.leaves(tree):
         shape = tuple(leaf.shape)
         sharding = getattr(leaf, "sharding", None)
         if sharding is not None and not whole:
             shape = sharding.shard_shape(shape)
-        total += math.prod(shape) * jnp.dtype(dtype or leaf.dtype).itemsize
+        total += math.ceil(
+            math.prod(shape) * stored_bits(dtype or leaf.dtype) / 8)
     return total
 
 
@@ -81,6 +84,7 @@ class RematChoice:
     keep_bytes: int = 0
     budget_bytes: Optional[int] = None   # None: no limit reported
     limit_bytes: Optional[int] = None
+    args_bytes: Optional[int] = None     # what the budget subtracted
 
     def attrs(self, *, fallback: bool = False) -> dict:
         """The ``step_build`` span's ``remat_*`` attributes (and
@@ -90,6 +94,7 @@ class RematChoice:
         return {"remat_keep": list(keep),
                 "remat_keep_bytes": self.keep_bytes if keep else 0,
                 "remat_budget_bytes": self.budget_bytes,
+                "remat_args_bytes": self.args_bytes,
                 "remat_keep_fallback": fallback}
 
 
@@ -121,7 +126,8 @@ class StepRemat:
         flash = resolve_seq_impl(self.cfg, self.mesh, seq) == "flash"
         model = axes.get("model", 1)
         trainable = state.lora if self.lora else state.params
-        budget = (limit - shard_bytes((state, batch))
+        args = shard_bytes((state, batch))
+        budget = (limit - args
                   - working_set_bytes(
                       self.cfg, rows, seq, model=model,
                       trainable_bytes=shard_bytes(trainable),
@@ -138,7 +144,7 @@ class StepRemat:
                            peak_share=KEPT_PEAK_SHARE)
         sizes = dict(candidates)
         return RematChoice(keep, sum(sizes[n] for n in keep), budget,
-                           limit)
+                           limit, args)
 
     def build(self, step: Callable, state, batch, *,
               label: str = "train_step", **build_kw):
@@ -174,7 +180,8 @@ class StepRemat:
             "%s)", label, built.info["remat_keep"] or "their inputs only",
             built.info["remat_keep_bytes"] / 1e9,
             "no device limit reported" if choice.budget_bytes is None
-            else f"{choice.budget_bytes / 1e9:.2f} GB")
+            else f"{choice.budget_bytes / 1e9:.2f} GB beside "
+                 f"{choice.args_bytes / 1e9:.2f} GB of arguments")
         logger.info(
             "%s: flash grid steps a call, visited / rectangular: %s",
             label, "; ".join(

@@ -110,17 +110,25 @@ def test_one_backend_test_decides_every_device_choice(monkeypatch):
     from gke_ray_train_tpu.models import tiny
     from gke_ray_train_tpu.ops.flash_attention import interpret_default
     from gke_ray_train_tpu.plan import (
-        XLA_OVERLAP_OPTIONS, ExecutionPlan, overlap_compiler_options)
+        XLA_OVERLAP_OPTIONS, XLA_TPU_OPTIONS, ExecutionPlan,
+        overlap_compiler_options, tpu_compiler_options)
     plan = ExecutionPlan(overlap="xla")
     assert not mesh_mod.on_tpu()
     assert tiny().resolved_attn_impl == "xla"
     assert interpret_default(None) is True
     assert overlap_compiler_options(plan) is None
+    assert tpu_compiler_options(plan) is None
     monkeypatch.setattr(mesh_mod, "on_tpu", lambda: True)
     assert tiny().resolved_attn_impl == "flash"
     assert interpret_default(None) is False
     assert overlap_compiler_options(plan) == XLA_OVERLAP_OPTIONS
     assert overlap_compiler_options(ExecutionPlan(overlap="off")) is None
+    # what the compile surface passes: the TPU's own options always,
+    # the scheduler's under overlap="xla"
+    assert tpu_compiler_options(plan) == {**XLA_TPU_OPTIONS,
+                                          **XLA_OVERLAP_OPTIONS}
+    assert tpu_compiler_options(ExecutionPlan(overlap="off")) == (
+        XLA_TPU_OPTIONS)
 
 
 def test_chip_smoke_refuses_a_cpu_and_a_bare_directory(tmp_path):

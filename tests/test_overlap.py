@@ -517,8 +517,9 @@ def test_xla_overlap_options_parse_as_bools():
     strings for bool compiler options — the dict must hold values the
     option parser accepts (verified against a real bool option here,
     since the TPU-only flag names don't exist on the CPU backend)."""
-    from gke_ray_train_tpu.plan import XLA_OVERLAP_OPTIONS
+    from gke_ray_train_tpu.plan import XLA_OVERLAP_OPTIONS, XLA_TPU_OPTIONS
     assert all(isinstance(v, bool) for v in XLA_OVERLAP_OPTIONS.values())
+    assert all(isinstance(v, bool) for v in XLA_TPU_OPTIONS.values())
     import jax
     f = jax.jit(lambda x: x + 1,
                 compiler_options={"xla_cpu_enable_fast_math": False})

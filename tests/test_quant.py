@@ -26,9 +26,63 @@ def test_round_trip_error_bounds(kind, tol):
 def test_nf4_storage_is_4bit_codes():
     w = jax.random.normal(jax.random.key(1), (64, 32))
     qt = quantize_tensor(w, "nf4")
-    assert qt.codes.dtype in (jnp.uint4, jnp.int8)
+    assert qt.codes.dtype == jnp.uint4      # the one storage
+    assert qt.shape == w.shape
     codes = np.asarray(qt.codes.astype(jnp.int32))
     assert codes.min() >= 0 and codes.max() <= 15
+
+
+def test_nf4_codes_cost_half_a_byte_as_arguments():
+    """What the device holds of the frozen base: XLA bills an executable
+    two codes a byte (``itemsize`` and ``nbytes`` say 1 a code)."""
+    w = jax.random.normal(jax.random.key(3), (2, 128, 64))
+    qt = quantize_tensor(w, "nf4")
+
+    def arguments(x):
+        return jax.jit(lambda c: c.astype(jnp.int32).sum()).lower(
+            x).compile().memory_analysis().argument_size_in_bytes
+
+    assert arguments(qt.codes) == w.size // 2
+    assert arguments(qt.codes.astype(jnp.int8)) == w.size
+
+
+def _stacked_as_the_benchmark_draws_it(w, kind, group):
+    """``benchmark/drivers/common.py::params_maker``: a layer at a time
+    under ``lax.map``, the leaf made from its four parts."""
+    def one(r):
+        qt = quantize_tensor(w[r][None], kind, group)
+        return qt.codes[0], qt.scales[0]
+    codes, scales = jax.jit(lambda: jax.lax.map(
+        one, jnp.arange(w.shape[0], dtype=jnp.int32)))()
+    return QTensor(codes, scales, kind,
+                   codes.shape[-2] // scales.shape[-2])
+
+
+@pytest.mark.parametrize("shape,group,make", [
+    ((2, 128, 64), 64, quantize_tensor),
+    ((3, 96, 8), 64, quantize_tensor),              # falls back to 48
+    ((2, 4, 128, 32), 64, quantize_tensor),         # [layers, experts, D, F]
+    ((3, 128, 64), 64, _stacked_as_the_benchmark_draws_it),
+], ids=["group_64", "odd_group", "bank_axis", "stacked_lax_map"])
+def test_nf4_dequantize_gives_the_bits_of_a_byte_a_code(shape, group, make):
+    """Two codes a byte are the codes a byte each held before: the same
+    values out of ``dequantize``, alone and through a jitted matmul."""
+    w = jax.random.normal(jax.random.key(4), shape) * 0.02
+    qt = make(w, "nf4", group)
+    assert qt.codes.dtype == jnp.uint4 and qt.shape == shape
+    direct = quantize_tensor(w, "nf4", group)
+    assert qt.group == direct.group
+    byte_a_code = QTensor(direct.codes.astype(jnp.int8), direct.scales,
+                          "nf4", direct.group)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        np.testing.assert_array_equal(
+            np.asarray(dequantize(qt, dtype).astype(jnp.float32)),
+            np.asarray(dequantize(byte_a_code, dtype).astype(jnp.float32)))
+    x = jax.random.normal(jax.random.key(5), (5, shape[-2]))
+    prod = jax.jit(lambda q: jnp.einsum(
+        "td,...df->...tf", x, dequantize(q, jnp.float32)))
+    np.testing.assert_array_equal(np.asarray(prod(qt)),
+                                  np.asarray(prod(byte_a_code)))
 
 
 def test_exact_for_codebook_values():

@@ -76,23 +76,52 @@ def test_keep_candidates_bytes(cfg_kw, kw, expected):
     assert [n for n, _ in got] == [n for n in KEEP_ORDER if n in expected]
 
 
-def test_working_set_and_budget_of_the_cell():
+def _kexaone_share(**kw):
+    from gke_ray_train_tpu.models.config import k_exaone_236b
+    return k_exaone_236b(**{**dict(
+        n_layers=8, vocab_size=19200, experts_held=(0, 16), n_mtp_layers=0,
+        max_seq_len=8192, dtype="bfloat16", param_dtype="bfloat16"), **kw})
+
+
+# arguments: ``compiled.memory_analysis().argument_size_in_bytes`` of the
+# cell's step on the chip (PERF.md, PR 25 / PR 26: NF4 codes a byte each;
+# PR 29: two a byte)
+@pytest.mark.parametrize("make_cfg,rows,seq,lora_bytes,ws_gb,cases", [
+    (_mistral7b, 2, 1024, 671_088_640, 3.251, [
+        (9_953_715_712, ("mlp/gate_up",)),
+        (6_464_054_784, ("mlp/gate_up", "attn/core", "attn/qkv",
+                         "attn/out"))]),
+    (_kexaone_share, 1, 8192, 150_994_944, 7.007, [
+        (7_033_021_440, ("mlp/gate_up", "attn/core", "attn/out",
+                         "moe/shared")),
+        (4_164_117_504, ("mlp/gate_up", "attn/core", "attn/qkv",
+                         "attn/out", "moe/shared"))]),
+], ids=["dense_cell", "routed_cell"])
+def test_working_set_and_budget_of_the_cell(make_cfg, rows, seq,
+                                            lora_bytes, ws_gb, cases):
     """The arithmetic PERF.md (PR 25) sets beside XLA's memory analysis
-    of the 7B QLoRA step: 3.25 GB against 3.08 GB, and with it the
-    benchmark cell's choice on a v5e chip."""
-    lora_bytes, limit, arguments = 671_088_640, 16_909_336_064, 9_953_715_712
-    cfg = _mistral7b()
-    ws = remat.working_set_bytes(cfg, 2, 1024, model=1,
+    of the 7B QLoRA step: 3.25 GB against 3.08 GB, and with it both
+    benchmark cells' choices on a v5e chip, with the frozen base's codes
+    a byte each and at two a byte: the room that the codes give back
+    goes to the attention's names."""
+    limit = 16_909_336_064
+    cfg = make_cfg()
+    ws = remat.working_set_bytes(cfg, rows, seq, model=1,
                                  trainable_bytes=lora_bytes,
                                  trainable_full_bytes=lora_bytes,
                                  cast_bytes=lora_bytes // 2)
-    assert ws == pytest.approx(3.251e9, rel=1e-3)
-    budget = limit - arguments - ws - step_remat.RESERVE_BYTES
-    assert choose_keep(keep_candidates(cfg, 2, 1024), budget,
-                       peak_share=remat.KEPT_PEAK_SHARE) == ("mlp/gate_up",)
-    # charged in full, the same budget would keep the three smaller ones
-    assert choose_keep(keep_candidates(cfg, 2, 1024), budget) == (
-        "attn/core", "attn/qkv", "attn/out")
+    assert ws == pytest.approx(ws_gb * 1e9, rel=1e-3)
+    candidates = keep_candidates(cfg, rows, seq)
+    for arguments, expected in cases:
+        budget = limit - arguments - ws - step_remat.RESERVE_BYTES
+        assert choose_keep(candidates, budget,
+                           peak_share=remat.KEPT_PEAK_SHARE) == expected
+    # charged in full, the dense cell's old budget would have kept the
+    # three smaller names
+    if make_cfg is _mistral7b:
+        budget = limit - cases[0][0] - ws - step_remat.RESERVE_BYTES
+        assert choose_keep(candidates, budget) == (
+            "attn/core", "attn/qkv", "attn/out")
 
 
 @pytest.mark.parametrize("kw,grows", [
@@ -122,10 +151,36 @@ def test_shard_bytes_counts_one_device(devices):
                                           mesh, P("fsdp", None))),
             "b": jax.ShapeDtypeStruct((16,), jnp.bfloat16,
                                       sharding=NamedSharding(mesh, P())),
-            "c": jax.ShapeDtypeStruct((3, 5), jnp.int8)}
-    assert step_remat.shard_bytes(tree) == 2 * 64 * 4 + 16 * 2 + 15
+            "c": jax.ShapeDtypeStruct((3, 5), jnp.int8),
+            # NF4 codes: two a byte on the device, rounded up a leaf
+            "d": jax.ShapeDtypeStruct((8, 32), jnp.uint4,
+                                      sharding=NamedSharding(
+                                          mesh, P("fsdp", None))),
+            "e": jax.ShapeDtypeStruct((3, 5), jnp.uint4)}
+    assert step_remat.shard_bytes(tree) == (
+        2 * 64 * 4 + 16 * 2 + 15 + 2 * 32 // 2 + 8)
     assert step_remat.shard_bytes(tree, whole=True) == (
-        8 * 64 * 4 + 16 * 2 + 15)
+        8 * 64 * 4 + 16 * 2 + 15 + 8 * 32 // 2 + 8)
+    # as if every leaf were of one type: a byte type bills bytes again
+    assert step_remat.shard_bytes(tree["e"], dtype=jnp.bfloat16) == 30
+
+
+def test_donation_of_a_quantised_state_is_counted_in_bits(devices):
+    """``assert_state_donation`` sets XLA's aliased bytes against the
+    state's: the NF4 base is most of a QLoRA state, and XLA counts its
+    codes two a byte."""
+    from gke_ray_train_tpu.perf.costs import assert_state_donation
+    from gke_ray_train_tpu.train import make_train_step
+    cfg, opt, state, batch, step_kw = _setup("qlora_xla", devices)
+    step = make_train_step(cfg, opt, **{**step_kw, "donate": True})
+    compiled = step.lower(state, batch).compile()
+    held = step_remat.shard_bytes(state)
+    assert held < sum(x.nbytes for x in jax.tree.leaves(state))
+    # the assertion divides the state over every device of the process;
+    # this state lives whole on one
+    aliased = assert_state_donation(compiled, state,
+                                    min_frac=0.95 * len(jax.devices()))
+    assert 0.95 * held <= aliased <= 1.05 * held
 
 
 # ---------------------------------------------------------------------------
@@ -153,7 +208,9 @@ def _setup(kind, devices):
     opt = make_optimizer(1e-2)
     step_kw, mesh, lora_cfg, params = {"grad_accum": 2}, None, None, None
     if kind.startswith("qlora"):
-        cfg = tiny(**kw, attn_impl=kind.split("_")[1])
+        cfg = (tiny(**kw, attn_impl="xla", qk_norm=True)
+               if kind == "qlora_qk_norm"
+               else tiny(**kw, attn_impl=kind.split("_")[1]))
         lora_cfg = LoraConfig(r=4, alpha=8)
         params = quantize_params(init_params(cfg, jax.random.key(0)),
                                  "nf4")
@@ -176,7 +233,7 @@ def _setup(kind, devices):
 
 
 @pytest.mark.parametrize("kind", ["qlora_xla", "qlora_flash", "full_ft",
-                                  "manual_overlap"])
+                                  "manual_overlap", "qlora_qk_norm"])
 def test_kept_activations_are_bitwise_the_recomputed_ones(kind, devices):
     """One optimizer step: the loss, the gradient norm and every updated
     leaf (so every gradient) are equal bit for bit with nothing kept and
@@ -223,6 +280,23 @@ def test_scope_table_loses_the_recomputed_gate_up_matmul(devices):
     assert "mlp/gate_up/base" not in gate_up_kept
     assert {"attn/qkv/base", "attn/out/base"} <= gate_up_kept
     assert not {p for p in recomputed(KEEP_ORDER) if p.endswith("/base")}
+
+
+@pytest.mark.parametrize("kind", ["qlora_xla", "qlora_qk_norm"])
+def test_attn_qkv_kept_spares_the_three_projections(kind, devices):
+    """The name sits where keeping it is worth its bytes: on q and k
+    after rope, or, where a norm follows the projections, on their
+    outputs (the norm's backward reads them)."""
+    from gke_ray_train_tpu.train import make_train_step
+    cfg, opt, state, batch, step_kw = _setup(kind, devices)
+
+    def recomputed(keep):
+        step = make_train_step(cfg, opt, remat_keep=keep, **step_kw)
+        return _recomputed_matmuls(step.lower(state, batch).compile())
+
+    assert "attn/qkv/base" in recomputed(())
+    kept = recomputed(("attn/qkv",))
+    assert "attn/qkv/base" not in kept and "attn/out/base" in kept
 
 
 # ---------------------------------------------------------------------------
@@ -310,6 +384,7 @@ def test_no_bytes_limit_keeps_nothing(monkeypatch, devices, record):
     built = _aot_build(monkeypatch, devices, limit=None)
     assert built.info["remat_keep"] == []
     assert built.info["remat_budget_bytes"] is None
+    assert built.info["remat_args_bytes"] is None
     assert built.info["remat_keep_fallback"] is False
     assert len(_spans(record, "step_lower")) == 1
 
@@ -323,6 +398,14 @@ def test_room_keeps_every_name_with_one_lower(monkeypatch, devices,
         "mlp/gate_up", "attn/qkv", "attn/out"]
     assert span["remat_keep_bytes"] == built.info["remat_keep_bytes"] > 0
     assert span["remat_budget_bytes"] >= span["remat_keep_bytes"]
+    # what the budget subtracted from the limit: codes at half a byte
+    _, _, state, batch, _ = _setup("qlora_xla", devices)
+    assert span["remat_args_bytes"] == built.info["remat_args_bytes"] == (
+        step_remat.shard_bytes((state, batch)))
+    codes = sum(x.size for x in jax.tree.leaves(state.params)
+                if x.dtype == jnp.uint4)
+    assert codes > 0 and span["remat_args_bytes"] == sum(
+        x.nbytes for x in jax.tree.leaves((state, batch))) - codes // 2
     assert span["remat_keep_fallback"] is False
     # the dense-mask attention of the CPU walks no flash grid
     assert span["flash_grid"] == built.info["flash_grid"] == {}
@@ -424,10 +507,10 @@ def test_which_steps_carry_a_sizer(devices):
         make_train_step(cfg, opt, remat_keep=("attn/out",)), "remat")
     for other in (tiny(remat=False), tiny(remat=True, remat_policy="dots")):
         assert not hasattr(make_train_step(other, opt), "remat")
-    assert RematChoice() == RematChoice((), 0, None, None)
-    assert RematChoice(("attn/out",), 7, 9, 20).attrs(fallback=True) == {
+    assert RematChoice() == RematChoice((), 0, None, None, None)
+    assert RematChoice(("attn/out",), 7, 9, 20, 4).attrs(fallback=True) == {
         "remat_keep": [], "remat_keep_bytes": 0, "remat_budget_bytes": 9,
-        "remat_keep_fallback": True}
+        "remat_args_bytes": 4, "remat_keep_fallback": True}
 
 
 def test_the_compile_surface_sizes_a_train_step(monkeypatch, devices,
