@@ -1,0 +1,334 @@
+"""The latent-attention routed decoder's cell
+(``glm47flash_ep4.qlora_sft_packed_8k_mla``) at tiny widths with the look
+for a chip skipped: a sound run is correct; the rotary slice left off the
+keys, the key/value latent's norm left out, the fp8 control and half a
+batch are not, each through the harness's own ``judge`` under the
+committed limits; the number that tells them apart (``grad_dir_gap``:
+the first gradient tensor against tensor) is exact on a made-up table;
+its configuration file keeps the published widths; its readers and its
+arithmetic are exact on synthetic facts.
+"""
+
+import time
+
+import numpy as np
+import pytest
+
+from benchmark import check, flops_mla, run
+from benchmark import harness as hs
+from benchmark.drivers import train_mla
+from benchmark.readers import (
+    attn_scope_share, flash_kind_roofline, nf4_roofline_mla,
+    step_mfu_mla_train)
+from benchmark.rehearse.glm_tiny import CELL, TINY_CONFIG, shrink
+
+
+def drive(seconds=1.0):
+    out = run.run_cell(CELL, seed=2 ** 31 + 7, seconds=seconds, trace=False,
+                       require_chip=False, t_start=time.perf_counter(),
+                       override=shrink)
+    return out["result"], out["checks"]
+
+
+def test_a_sound_run_is_correct():
+    result, checks = drive()
+    assert result["correct"], checks
+    assert set(checks) == {"grad_gap", "change_gap", "grad_dir_gap",
+                           "attn_dir_gap", "pairs_gap"}
+    assert checks["pairs_gap"][0] == 0.0
+    assert result["failed"] == 0
+    assert {"train_tok_s_chip", "setup_s"} <= set(result["metrics"])
+
+
+def test_rotary_slice_left_off_the_keys(monkeypatch):
+    """The one-head rotated part of the key goes into k unturned: q is
+    still turned, so a pair's score no longer depends on the distance
+    alone. With weights drawn at std 0.02 the logits are small, and what
+    turns them moves the norms (``grad_gap``) by no more than bfloat16
+    does at 47 layers; the gradient's direction tells it."""
+    from gke_ray_train_tpu.models import transformer
+    real = transformer.apply_rope
+
+    def apply_rope(x, positions, inv_freqs):
+        return x if x.shape[-2] == 1 else real(x, positions, inv_freqs)
+    monkeypatch.setattr(transformer, "apply_rope", apply_rope)
+    result, checks = drive()
+    assert not result["correct"], checks
+    # the query's two matrices alone feel it (0.40 against 0.01)
+    value, limit = checks["attn_dir_gap"]
+    assert value > 1.15 * limit
+
+
+def test_kv_latent_norm_left_out(monkeypatch):
+    from gke_ray_train_tpu.models import transformer
+    real = transformer.rms_norm
+    kv_rank = TINY_CONFIG["kv_lora_rank"]
+    assert kv_rank not in (TINY_CONFIG["q_lora_rank"],
+                           TINY_CONFIG["hidden_size"])
+
+    def rms_norm(x, scale, **kw):
+        return x if scale.shape[-1] == kv_rank else real(x, scale, **kw)
+    monkeypatch.setattr(transformer, "rms_norm", rms_norm)
+    result, checks = drive()
+    assert not result["correct"], checks
+
+
+def test_control_and_half_a_batch_are_not_correct():
+    """The reference in fp8, and the reference fed half of each batch,
+    put in the program's place: the first by the gradient's direction,
+    the second by every number."""
+    from benchmark.tools import gradient_readings, readings
+    ctx = hs.make_ctx(CELL, 2 ** 31 + 7, 0.0, False, require_chip=False,
+                      override=shrink)
+    facts = hs.driver_of(ctx).run(ctx)
+    limits = ctx["limits"]
+    assert readings.judged(facts["readings"], limits)["correct"]
+    got = {k: readings.judged(r, limits) for k, (r, _) in
+           gradient_readings.control_readings(
+               facts["raw"], [limits["control"], "half_batch"]).items()}
+    # float32 at four layers here: the control reads 0.09, an eighth of
+    # what it reads on the chip, where the limits are set (PERF.md);
+    # test_control.py has it not correct at bfloat16
+    assert got["fp8"]["readings"]["grad_dir_gap"] \
+        > 1e4 * facts["readings"]["grad_dir_gap"]
+    assert {"grad_dir_gap", "attn_dir_gap", "grad_gap", "pairs_gap"} \
+        <= set(got["half_batch"]["failed"])
+
+
+def test_direction_gap_does_not_cancel():
+    """An error of a tenth of a leaf's gradient at right angles to it:
+    the norms agree to half a percent, the tensors differ by a tenth.
+    One leaf over all its layers is one vector."""
+    rng = np.random.default_rng(0)
+    want, got = [], []
+    for layer in range(3):
+        w = rng.normal(size=(4, 6))
+        e = rng.normal(size=(4, 6))
+        e -= w * (e.ravel() @ w.ravel()) / (w.ravel() @ w.ravel())
+        e *= 0.1 * np.linalg.norm(w) / np.linalg.norm(e)
+        want.append({"wo": {"a": np.zeros((6, 2)), "b": w},
+                     "wq_a": {"a": np.zeros((6, 2)), "b": 2 * w}})
+        got.append({"wo": {"a": np.zeros((6, 2)), "b": w + e},
+                    "wq_a": {"a": np.zeros((6, 2)), "b": 2 * w}})
+    table = train_mla.gradient_table(got, want)
+    assert set(table) == {"wo.a", "wo.b", "wq_a.a", "wq_a.b"}
+    assert table["wo.b"].shape == (3, 3)
+    assert train_mla.direction_gap(table) == pytest.approx(0.1)
+    assert train_mla.gradient_readings(table) == {
+        "grad_dir_gap": pytest.approx(0.1),
+        "attn_dir_gap": pytest.approx(0.1)}
+    assert train_mla.direction_gap(table, ("wq_a",)) == 0.0
+    norms = [{k: float(np.sqrt(t[:, i].sum())) for k, t in table.items()}
+             for i in (0, 1)]
+    assert check.worst_leaf_gap(*norms) == pytest.approx(
+        np.sqrt(1.01) - 1)
+    # the program's stacked tree, layer by layer: the prologue's layer
+    # first, then the scanned block's
+    from gke_ray_train_tpu.models.config import glm_4_7_flash
+    cfg = glm_4_7_flash(n_layers=4)
+    tree = {"prologue": [{"wo": {"b": np.full((1, 2), 7.0)}}],
+            "blocks": [{"wo": {"b": np.arange(6.0).reshape(3, 2)}}]}
+    layers = train_mla.gradient_by_layer(cfg, tree, 2.0)
+    assert [x["wo"]["b"].tolist() for x in layers] == [
+        [14.0, 14.0], [0.0, 2.0], [4.0, 6.0], [8.0, 10.0]]
+
+
+# ---------------------------------------------------------------------------
+# the configuration file
+# ---------------------------------------------------------------------------
+
+def test_configuration_keeps_the_published_widths():
+    bench = hs.load_json(hs.ROOT, "BENCHMARK.json")
+    files = hs.cell_files(bench, hs.find_cell(bench, CELL))
+    c = files["config"]
+    catalog = {
+        "attention_bias": False, "hidden_act": "silu", "hidden_size": 2048,
+        "intermediate_size": 10240, "max_position_embeddings": 202752,
+        "model_type": "glm4_moe_lite", "moe_intermediate_size": 1536,
+        "topk_method": "noaux_tc", "norm_topk_prob": True,
+        "num_attention_heads": 20, "n_group": 1, "topk_group": 1,
+        "n_routed_experts": 64, "n_shared_experts": 1,
+        "routed_scaling_factor": 1.8, "num_experts_per_tok": 4,
+        "first_k_dense_replace": 1, "num_hidden_layers": 47,
+        "num_key_value_heads": 20, "num_nextn_predict_layers": 1,
+        "partial_rotary_factor": 1, "rms_norm_eps": 1e-05,
+        "rope_scaling": None, "rope_theta": 1000000,
+        "tie_word_embeddings": False, "q_lora_rank": 768,
+        "kv_lora_rank": 512, "qk_nope_head_dim": 192,
+        "qk_rope_head_dim": 64, "v_head_dim": 256, "vocab_size": 154880}
+    assert sorted(c["reduced"]) == ["n_routed_experts",
+                                    "num_nextn_predict_layers",
+                                    "vocab_size"]
+    # every key of the source, as published, but for the three reduced
+    for key, value in catalog.items():
+        if key not in c["reduced"]:
+            assert c[key] == value, key
+    assert c["published"] == {k: catalog[k] for k in c["reduced"]}
+    assert (c["n_routed_experts"], c["experts_held"], c["router_outputs"],
+            c["vocab_size"], c["num_nextn_predict_layers"]) \
+        == (16, [0, 16], 64, 38720, 0)
+    assert set(c["assumed"]) >= {"rotary_pairs", "norm_placement",
+                                 "selection_bias", "weights"}
+    assert "4 chips share every layer" in c["deployment"]
+    assert set(c["cut"]) >= set(c["reduced"])
+    # floors of a cut: the whole depth, 8 experts, an eighth of the ids
+    assert c["n_routed_experts"] >= 8 and c["vocab_size"] * 8 >= 154880
+    mix = files["mix"]
+    assert mix["kind"] == "train_mla" and mix["job"]["PACKING"]
+    routed = hs.load_json(hs.BENCH_DIR, "traffic",
+                          "qlora_sft_packed_8k.json")
+    assert mix["rows"] == routed["rows"]
+    assert {**routed["job"], "MODEL_ID": "zai-org/GLM-4.7-Flash"} \
+        == mix["job"]
+
+
+# ---------------------------------------------------------------------------
+# arithmetic and readers on synthetic facts
+# ---------------------------------------------------------------------------
+
+DIMS = {"vocab": 100, "hidden": 8, "layers": 2, "heads": 2, "kv_heads": 2,
+        "head_dim": 4, "nope": 3, "rope": 1, "q_rank": 6, "kv_rank": 5,
+        "ff": 16, "expert_ff": 4, "experts": 8, "held": 2, "held_lo": 0,
+        "top_k": 2, "shared": 1, "dense_layers": 1, "layers_published": 2}
+KINDS = [("latent", "dense"), ("latent", "sparse")]
+# q down and up, kv down (latent + rotary slice) and up (keys without
+# position + values, a head), out
+ATTN = 8 * 6 + 6 * 8 + 8 * (5 + 1) + 5 * 2 * (3 + 4) + 8 * 8
+TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def test_arithmetic_bills_the_five_latent_matrices():
+    frozen = 8 * 100 + 2 * ATTN + 3 * 8 * 16 + 8 * 8 + 3 * 8 * 4
+    assert flops_mla.frozen_params(DIMS, KINDS) == frozen
+    assert flops_mla.expert_params(DIMS) == 3 * 8 * 4
+    # rank 1: (in + out) of the five attention matrices a layer; the
+    # dense layer's MLP 3 x 24; the sparse layer's shared expert 3 x 12
+    attn = (8 + 6) + (6 + 8) + (8 + 6) + (5 + 14) + (8 + 8)
+    assert flops_mla.lora_params(DIMS, KINDS, 1, TARGETS) \
+        == 2 * attn + 72 + 36
+    # a job that adapts q alone adapts the query's two matrices
+    assert flops_mla.lora_params(DIMS, KINDS, 1, ("wq",)) \
+        == 2 * ((8 + 6) + (6 + 8))
+    need = flops_mla.train_flops(DIMS, KINDS, [3, 5], held_pairs=7,
+                                 lora_rank=1, lora_targets=TARGETS)
+    assert need == (4 * frozen + 6 * (2 * attn + 108)) * 8 + 4 * 96 * 7 \
+        + 12 * 8 * 2 * (6 + 15)
+
+
+def facts(ops, **work):
+    return {"dims": DIMS, "peaks": {"flops_bf16": 1e6,
+                                    "hbm_bytes_per_s": 1e9},
+            "chips": 1, "t0": 0.0, "window_s": 10.0,
+            "trace": {"devices": 1, "op_time": {}, "op_count": {}},
+            "scoped_ops": ops, "notes": [],
+            "work": dict({"steps": 2, "step_docs": [[3, 5], [4, 4]],
+                          "step_pairs": [7.0, 9.0], "micro_steps": 2,
+                          "rows_per_call": 1, "seq": 8,
+                          "lora_rank": 1, "lora_targets": list(TARGETS),
+                          "layer_kinds": KINDS,
+                          "step_times": [4.0, 8.0]}, **work)}
+
+
+def test_step_mfu_mla_counts_pairs_and_the_traced_slice():
+    f = facts([])
+    need = sum(flops_mla.train_flops(
+        DIMS, KINDS, docs, held_pairs=p, lora_rank=1, lora_targets=TARGETS)
+        for docs, p in zip([[3, 5], [4, 4]], [7.0, 9.0]))
+    assert step_mfu_mla_train.read(f) == pytest.approx(
+        100 * need / (10.0 * 1e6))
+    # a traced run: the steps that ended before the profiler started
+    f["trace_window"] = (5.0, 9.0)
+    first = flops_mla.train_flops(DIMS, KINDS, [3, 5], held_pairs=7.0,
+                                  lora_rank=1, lora_targets=TARGETS)
+    assert step_mfu_mla_train.read(f) == pytest.approx(
+        100 * first / (4.0 * 1e6))
+    assert step_mfu_mla_train.read(facts([], step_pairs=[])) is None
+    # another family's facts give it nothing to read
+    other = facts([])
+    other["dims"] = {k: v for k, v in DIMS.items() if k != "q_rank"}
+    assert step_mfu_mla_train.read(other) is None
+
+
+OPS = [
+    (2.0, 4, "jit(step)/jvp(attn/q_latent/base)/dot", "attn/q_latent/base"),
+    (1.0, 4, "jit(step)/jvp(attn/q_latent)/mul", "attn/q_latent"),
+    (3.0, 4, "jit(step)/rematted_computation/attn/kv_latent/lora/dot",
+     "attn/kv_latent/lora"),
+    (0.5, 4, "jit(step)/jvp(attn/rope)/concatenate", "attn/rope"),
+    (4.0, 4, "jit(step)/jvp(attn/core/latent)/pallas_call",
+     "attn/core/latent"),
+    (1.5, 4, "jit(step)/transpose(jvp(attn/core/latent))/pallas_call",
+     "attn/core/latent"),
+    (2.0, 4, "jit(step)/jvp(attn/out/base)/dot", "attn/out/base"),
+    (6.0, 4, "jit(step)/jvp(moe/experts)/pallas_call", "moe/experts"),
+]
+
+
+def test_attention_scope_shares():
+    f = facts(OPS)
+    assert attn_scope_share.read(f, stages=["q_latent", "kv_latent"]) \
+        == pytest.approx(100 * 6.0 / 20.0)
+    assert attn_scope_share.read(f, stages=["core"]) \
+        == pytest.approx(100 * 5.5 / 20.0)
+    assert attn_scope_share.read(facts(None), stages=["core"]) is None
+    # a program that opens no such scope (the parent's) leaves it out
+    assert attn_scope_share.read(facts(OPS[-2:]),
+                                 stages=["q_latent", "kv_latent"]) is None
+
+
+def test_nf4_roofline_bills_the_base_leaves_of_a_counted_pass():
+    # the five attention matrices a layer, the dense layer's MLP, the
+    # sparse layer's shared expert: not the router, head or experts
+    base = 2 * ATTN + 3 * 8 * 16 + 3 * 8 * 4
+    assert flops_mla.base_params(DIMS, KINDS) == base
+    ops = OPS + [
+        (0.3, 4, "jit(step)/jvp(unembed)/dot", "unembed"),
+        (5.0, 4, "jit(step)/transpose(jvp(attn/out/base))/dot",
+         "attn/out/base"),
+        (1.0, 4, "jit(step)/rematted_computation/moe/shared/base/dot",
+         "moe/shared/base")]
+    # 4 passes of 1 row x 8 positions; forward `base` time alone: 2 + 2
+    assert nf4_roofline_mla.read(facts(ops)) == pytest.approx(
+        100 * (4 * 2.0 * 8 * base / 1e6) / 4.0)
+    # no pass to count, another family's facts, no trace: nothing
+    assert nf4_roofline_mla.read(facts(OPS)) is None
+    other = facts(ops)
+    other["dims"] = {k: v for k, v in DIMS.items() if k != "q_rank"}
+    assert nf4_roofline_mla.read(other) is None
+    assert nf4_roofline_mla.read(facts(None)) is None
+
+
+def test_flash_latent_roofline_bills_full_causal_pairs(monkeypatch):
+    from benchmark.readers import program_trace as pt
+
+    class Record:
+        scope_tables = {"step": {
+            "flash_fwd.1": "jit(step)/jvp(attn/core/latent)/pallas_call",
+            "flash_dkv.1":
+                "jit(s)/transpose(jvp(attn/core/latent))/pallas_call",
+            "flash_fwd.7": "jit(step)/jvp(attn/core/full)/pallas_call",
+        }}
+
+    class Program:
+        RECORD = Record()
+
+        @staticmethod
+        def scope_path(op):
+            from gke_ray_train_tpu.obs.trace import scope_path
+            return scope_path(op)
+    monkeypatch.setattr(pt, "program", lambda: Program)
+    f = facts(OPS)
+    texts = {"%flash_fwd.1 = (bf16[1,2,8,4]) custom-call()": (1.0, 4),
+             "%flash_dkv.1 = (bf16[1,2,8,4]) custom-call()": (3.0, 4),
+             "%flash_fwd.7 = (bf16[1,2,8,4]) custom-call()": (9.0, 4)}
+    f["trace"].update(op_time={k: v[0] for k, v in texts.items()},
+                      op_count={k: v[1] for k, v in texts.items()})
+    rows = 4                                  # 2 steps x 2 micro x 1 row
+    need = flops_mla.flash_call(
+        DIMS, 1, 8, flops_mla.attention_pairs([3, 5, 4, 4]) / rows)
+
+    def least(kernel):
+        return 4 * max(need[kernel]["flops"] / 1e6,
+                       need[kernel]["bytes"] / 1e9)
+    assert flash_kind_roofline.read(f, kind="latent") == pytest.approx(
+        100 * (least("flash_fwd") + least("flash_dkv")) / 4.0)

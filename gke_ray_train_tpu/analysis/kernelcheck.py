@@ -133,7 +133,7 @@ def kernel_constraint_findings(plan, model_cfg, label: str = "",
                                ) -> List[KernelFinding]:
     """KER001 + KER002 + KER003 for one plan/model pair."""
     from gke_ray_train_tpu.ops.flash_attention import (
-        DEFAULT_BLOCK_KV, DEFAULT_BLOCK_Q, estimate_vmem_bytes, pick_block)
+        estimate_vmem_bytes, pick_block, window_blocks)
     from gke_ray_train_tpu.perf.costs import CHIP_SPECS
 
     out: List[KernelFinding] = []
@@ -175,9 +175,14 @@ def kernel_constraint_findings(plan, model_cfg, label: str = "",
     # sequence — the Pallas grid covers s_local // block blocks, and a
     # non-divisor block silently leaves tail rows unwritten, which is
     # why pick_block hard-fails; lint moves that failure to CI
+    # (the blocks a full causal row asks for at this head size: the
+    # largest kv block any kind of layer takes)
     blocks: Dict[str, int] = {}
-    for name, requested in (("block_q", DEFAULT_BLOCK_Q),
-                            ("block_kv", DEFAULT_BLOCK_KV)):
+    for name, requested in zip(("block_q", "block_kv"),
+                               window_blocks(
+                                   s_local, None, head_dim,
+                                   model_cfg.n_heads
+                                   // model_cfg.n_kv_heads)):
         try:
             blocks[name] = pick_block(requested, s_local)
         except ValueError as e:

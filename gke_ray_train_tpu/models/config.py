@@ -24,6 +24,10 @@ PROJ_TARGETS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 # every token passes beside the routed experts, adapted and quantized
 # like the dense MLP whose place it takes
 SHARED_TARGETS = ("shared_gate", "shared_up", "shared_down")
+# the four matrices a latent-attention layer has in the place of
+# wq / wk / wv (cfg.latent_attention): the down- and up-projection of
+# the query's latent and of the latent that keys and values share
+LATENT_TARGETS = ("wq_a", "wq_b", "wkv_a", "wkv_b")
 
 
 # fields that came with the sigmoid-routed decoder (PR 26), after model
@@ -31,7 +35,10 @@ SHARED_TARGETS = ("shared_gate", "shared_up", "shared_down")
 _LATER_FIELDS = frozenset({
     "rope_kinds", "qk_norm", "router", "router_bias", "router_renorm",
     "router_scale", "n_shared_experts", "expert_d_ff", "n_dense_layers",
-    "experts_held", "n_mtp_layers"})
+    "experts_held", "n_mtp_layers",
+    # latent attention (PR 30)
+    "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +71,18 @@ class ModelConfig:
     # RMSNorm over each head's q and k before the rotation, one scale
     # vector of head_dim a layer each (EXAONE-4, Qwen-3)
     qk_norm: bool = False
+    # latent attention (DeepSeek-V2/V3's MLA; GLM-4.7-Flash): q comes
+    # from a latent of q_lora_rank, keys and values from one of
+    # kv_lora_rank (each RMSNormed, then projected up to the heads); a
+    # head's q and k are qk_nope_head_dim values without position plus
+    # qk_rope_head_dim rotated ones, and the rotated part of the key is
+    # one vector a position, shared by all heads. Stated together or
+    # not at all; n_kv_heads == n_heads (nothing is grouped)
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
 
     activation: str = "silu"                # "silu" | "gelu_tanh"
 
@@ -160,6 +179,27 @@ class ModelConfig:
             raise ValueError(f"unknown remat_policy {self.remat_policy!r}")
         if self.pipe_virtual < 1:
             raise ValueError(f"pipe_virtual={self.pipe_virtual} must be >= 1")
+        latent = (self.q_lora_rank, self.kv_lora_rank,
+                  self.qk_nope_head_dim, self.qk_rope_head_dim,
+                  self.v_head_dim)
+        if any(latent) and not all(latent):
+            raise ValueError(
+                "latent attention states q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim "
+                f"together; got {latent}")
+        if self.latent_attention:
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            if self.v_head_dim != qk or (self.head_dim or qk) != qk:
+                raise ValueError(
+                    f"latent attention with heads of {qk} for q and k and "
+                    f"{self.v_head_dim} for v (head_dim={self.head_dim}): "
+                    "the attention kernels take one head size")
+            if self.n_kv_heads != self.n_heads or self.qk_norm \
+                    or self.attn_qkv_bias:
+                raise ValueError(
+                    "a latent-attention layer has a key and a value head "
+                    "for every query head, no per-head q/k norm and no "
+                    "bias")
         if self.router not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
         if (self.n_shared_experts or self.n_dense_layers
@@ -201,8 +241,37 @@ class ModelConfig:
         return ModelConfig(**d)
 
     @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank is not None
+
+    @property
     def resolved_head_dim(self) -> int:
+        """The one head size of q, k and v as the attention sees them
+        (a latent layer's: the assembled no-rotary + rotary parts)."""
+        if self.latent_attention:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def rope_dim(self) -> int:
+        """Values of a head that the rotary embedding turns."""
+        return self.qk_rope_head_dim if self.latent_attention \
+            else self.resolved_head_dim
+
+    def attn_leaf_shapes(self) -> dict:
+        """``{leaf: (d_in, d_out)}`` of one layer's attention matrices,
+        in creation order."""
+        hd, D, H = self.resolved_head_dim, self.d_model, self.n_heads
+        if not self.latent_attention:
+            kv = self.n_kv_heads * hd
+            return {"wq": (D, H * hd), "wk": (D, kv), "wv": (D, kv),
+                    "wo": (H * hd, D)}
+        return {"wq_a": (D, self.q_lora_rank),
+                "wq_b": (self.q_lora_rank, H * hd),
+                "wkv_a": (D, self.kv_lora_rank + self.qk_rope_head_dim),
+                "wkv_b": (self.kv_lora_rank,
+                          H * (self.qk_nope_head_dim + self.v_head_dim)),
+                "wo": (H * self.v_head_dim, D)}
 
     @property
     def resolved_attn_impl(self) -> str:
@@ -268,9 +337,9 @@ class ModelConfig:
 
     def _count_params(self, experts_counted) -> int:
         hd = self.resolved_head_dim
-        attn = (self.d_model * self.n_heads * hd          # wq
-                + 2 * self.d_model * self.n_kv_heads * hd  # wk, wv
-                + self.n_heads * hd * self.d_model)        # wo
+        attn = sum(a * b for a, b in self.attn_leaf_shapes().values())
+        if self.latent_attention:
+            attn += self.q_lora_rank + self.kv_lora_rank   # latent norms
         if self.attn_qkv_bias:
             attn += self.n_heads * hd + 2 * self.n_kv_heads * hd
         if self.qk_norm:
@@ -389,6 +458,31 @@ def k_exaone_236b(**kw) -> ModelConfig:
     return ModelConfig(**{**published, **kw})
 
 
+def glm_4_7_flash(**kw) -> ModelConfig:
+    """GLM-4.7-Flash (zai-org, ``model_type`` glm4_moe_lite; 30B-A3B) at
+    its published sizes: 47 layers of latent attention (a query latent
+    of 768 and a key/value latent of 512, 20 heads of 192 values
+    without position + 64 rotated, values of 256; the rotated part of
+    the key is one vector a position for all heads), the first layer
+    with a dense MLP of 10240 and the rest with 64 sigmoid-routed
+    experts of 1536 (4 a token, weights renormalised and scaled by 1.8,
+    a frozen selection bias, one group) beside one shared expert. The
+    multi-token-prediction layer of the checkpoint is not part of this
+    decoder (ROADMAP R8). Keywords override: a deployment's share of it
+    states its own ``experts_held`` and ``vocab_size``."""
+    published = dict(
+        name="glm-4.7-flash", vocab_size=154880, d_model=2048,
+        n_layers=47, n_heads=20, n_kv_heads=20, d_ff=10240,
+        max_seq_len=202752, rope_theta=1e6, norm_eps=1e-5,
+        q_lora_rank=768, kv_lora_rank=512, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256,
+        n_experts=64, expert_top_k=4, expert_d_ff=1536,
+        n_shared_experts=1, n_dense_layers=1, router="sigmoid",
+        router_bias=True, router_renorm=True, router_scale=1.8,
+        n_mtp_layers=1)
+    return ModelConfig(**{**published, **kw})
+
+
 def qwen2_7b(**kw) -> ModelConfig:
     """Qwen-2/2.5 7B: Llama-style GQA decoder whose one architectural
     delta is bias on the q/k/v projections (public architecture; the HF
@@ -445,6 +539,7 @@ PRESETS = {
     "mistral-7b": mistral_7b,
     "mixtral-8x7b": mixtral_8x7b,
     "k-exaone-236b": k_exaone_236b,
+    "glm-4.7-flash": glm_4_7_flash,
     "gemma2-9b": gemma2_9b,
     "qwen2-7b": qwen2_7b,
 }
@@ -470,6 +565,8 @@ def preset_for_model_id(model_id: str, **kw) -> ModelConfig:
         return mixtral_8x7b(**kw)
     if "exaone" in mid:
         return k_exaone_236b(**kw)
+    if "glm-4.7-flash" in mid:
+        return glm_4_7_flash(**kw)
     if "mistral" in mid:
         if any(t in mid for t in ("v0.1", "v0.2")):
             kw.setdefault("vocab_size", 32000)

@@ -59,6 +59,10 @@ def require_decodable(cfg: ModelConfig) -> None:
         missing.append("a cache per attention kind (rotated keys in a "
                        "window-sized ring for sliding layers, unrotated "
                        "keys at full length for the others)")
+    if cfg.latent_attention:
+        missing.append("a latent cache (the normed key/value latent and "
+                       "the shared rotated key a position, with the "
+                       "up-projections absorbed at decode)")
     if cfg.n_experts and cfg.router != "softmax":
         missing.append("the sigmoid router's layer at decode shapes")
     if cfg.n_mtp_layers:

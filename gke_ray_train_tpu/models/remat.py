@@ -35,7 +35,13 @@ from gke_ray_train_tpu.models.config import ModelConfig
 # device seconds saved per byte kept (the second flash forward, the
 # q/k/v projections with rope, the output projection: PERF.md, PR 25)
 KEEP_ORDER: Tuple[str, ...] = ("mlp/gate_up", "attn/core", "attn/qkv",
-                               "attn/out", "moe/shared", "moe/experts")
+                               "attn/out", "attn/latent", "moe/shared",
+                               "moe/experts")
+# `attn/latent` (a latent-attention layer's two down-projections'
+# outputs) follows `attn/out`: a position's 1,344 values spare 2.75 M
+# weights of products where the output projection's 2,048 spare 10.5 M
+# (GLM-4.7-Flash's sizes); where `attn/qkv` fits as well, the latents
+# spare nothing more and cost a twelfth of it
 # the routed layer's two names come last: `moe/shared` (the shared
 # expert's gate and up) saves what `mlp/gate_up` saves at a ninth of the
 # width, and `moe/experts` (gate and up of every row of the pair buffer)
@@ -103,6 +109,9 @@ def keep_candidates(cfg: ModelConfig, rows: int, seq: int, *,
                         cfg.n_layers - n_moe),
         "attn/qkv": ((heads + 2 * kv_heads) * hd * item, cfg.n_layers),
         "attn/out": (cfg.d_model * item, cfg.n_layers),
+        # every device has the latents whole (models/transformer.py)
+        "attn/latent": (_latent_width(cfg) * item,
+                        cfg.n_layers if cfg.latent_attention else 0),
         "moe/shared": (2 * cfg.n_shared_experts * d_fe * item,
                        routed if cfg.n_shared_experts else 0),
         "moe/experts": (2 * d_fe * item
@@ -112,6 +121,25 @@ def keep_candidates(cfg: ModelConfig, rows: int, seq: int, *,
     return tuple((n, rows * seq * layers * nbytes)
                  for n, (nbytes, layers) in
                  ((n, per_position[n]) for n in KEEP_ORDER) if layers)
+
+
+def _latent_width(cfg: ModelConfig) -> int:
+    """Values a position under ``attn/latent``: what the two
+    down-projections of a latent-attention layer give (0 without)."""
+    if not cfg.latent_attention:
+        return 0
+    return cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def _latent_up_width(cfg: ModelConfig, model: int) -> int:
+    """Values a position that the two up-projections of a
+    latent-attention layer give one device (0 without): a head's q
+    before its rotary slice is turned, and its keys without position
+    beside its values."""
+    if not cfg.latent_attention:
+        return 0
+    return math.ceil(cfg.n_heads / model) * (
+        2 * cfg.qk_nope_head_dim + cfg.qk_rope_head_dim + cfg.v_head_dim)
 
 
 def choose_keep(candidates: Candidates, budget_bytes: Optional[int], *,
@@ -147,14 +175,25 @@ def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
     rows, sequence lengths and depths, it reads 0.04-0.40 GB above
     XLA's, and 0.04-0.72 GB above for a full fine-tune across four
     (PERF.md, PR 25). The sigmoid router's block is sized by
-    :func:`_routed_block_bytes` (PERF.md, PR 26)."""
+    :func:`_routed_block_bytes` (PERF.md, PR 26). With latent attention
+    (the GLM-4.7-Flash share on one described v5e chip at 47, 24 and 12
+    layers, 8192 and 4096 positions) it reads 0.00-0.32 GB above XLA's
+    ``peak_memory_in_bytes`` (PERF.md, PR 30)."""
     item = jnp.dtype(cfg.dtype).itemsize
     positions = rows * seq
     d_ff = math.ceil(cfg.d_ff / model)
     hd = cfg.resolved_head_dim
     qkv = math.ceil((cfg.n_heads + 2 * cfg.n_kv_heads) / model) * hd
-    attn_io = qkv + cfg.d_model
+    # a latent layer's q, k and v are assembled from the up-projections'
+    # outputs, which live beside them (with the latents they come from)
+    attn_io = qkv + cfg.d_model + _latent_width(cfg) + _latent_up_width(
+        cfg, model)
     attn_weights = cfg.d_model * (qkv + math.ceil(cfg.n_heads / model) * hd)
+    if cfg.latent_attention:
+        # the five matrices, the up- and output projections' heads divided
+        attn_weights = sum(
+            a * b // (model if name in ("wq_b", "wkv_b", "wo") else 1)
+            for name, (a, b) in cfg.attn_leaf_shapes().items())
     routed = cfg.n_experts > 0 and cfg.router == "sigmoid"
     block_weights = attn_weights + (
         1 if routed else max(cfg.n_experts, 1)) * 3 * cfg.d_model * d_ff

@@ -122,14 +122,15 @@ def block_leaves(cfg: ModelConfig, R: int, mlp_kind: str) -> Dict[str, Any]:
     D, F, H, K = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads
     std = 0.02
     down = std * (1.0 / math.sqrt(2 * cfg.n_layers))
-    out = {
-        "attn_norm": ((R, D), None),
-        "wq": ((R, D, H * hd), std),
-        "wk": ((R, D, K * hd), std),
-        "wv": ((R, D, K * hd), std),
-        "wo": ((R, H * hd, D), down),
-        "mlp_norm": ((R, D), None),
-    }
+    out = {"attn_norm": ((R, D), None)}
+    for name, shape in cfg.attn_leaf_shapes().items():
+        out[name] = ((R,) + shape, down if name == "wo" else std)
+        # a latent layer norms each latent before its up-projection
+        if name == "wq_a":
+            out["q_latent_norm"] = ((R, cfg.q_lora_rank), None)
+        elif name == "wkv_a":
+            out["kv_latent_norm"] = ((R, cfg.kv_lora_rank), None)
+    out["mlp_norm"] = ((R, D), None)
     if cfg.attn_qkv_bias:
         # Qwen-2: bias on q/k/v only (o_proj stays bias-free);
         # zero-init — real values come from the HF checkpoint
@@ -175,6 +176,14 @@ def param_specs(cfg: ModelConfig) -> Params:
         "wk": P("pipe", "fsdp", "model"),
         "wv": P("pipe", "fsdp", "model"),
         "wo": P("pipe", "model", "fsdp"),
+        # latent attention: the latents are small and every device has
+        # them whole; the up-projections divide their heads like wq
+        "wq_a": P("pipe", "fsdp", None),
+        "wq_b": P("pipe", "fsdp", "model"),
+        "wkv_a": P("pipe", "fsdp", None),
+        "wkv_b": P("pipe", "fsdp", "model"),
+        "q_latent_norm": P("pipe", None),
+        "kv_latent_norm": P("pipe", None),
         "mlp_norm": P("pipe", None),
         # bias vectors follow their projection's OUTPUT dim sharding
         "bq": P("pipe", "model"),
@@ -363,27 +372,16 @@ def _moe(x, lp, cfg: ModelConfig, dtype, segment_ids, token_weights,
     return y, counters
 
 
-def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
-          window, segment_ids, mesh, lora_p=None, lora_scale=1.0,
-          drop_rng=None, drop_rate=0.0, fused_ops=False, kind=None):
-    """``kind``: the layer's block kind when the model has more than
-    one (the attention then runs under a leaf scope of its own)."""
-    B, S, D = x.shape
+def _qkv(x, lp, cfg: ModelConfig, rope, positions, mesh, proj, fused_ops):
+    """q [B, S, H, hd], k, v [B, S, K, hd] of a layer with one
+    projection each, rotated where the layer's kind is (``rope``)."""
+    B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     H, K = cfg.n_heads, cfg.n_kv_heads
-
-    def lr(name):
-        return _lora_entry(lora_p, name)
     with scope("attn/qkv"):
-        q = _proj(x, lp["wq"], lr("wq"), lora_scale, dtype,
-                  _drop_key(drop_rng, 0), drop_rate, bias=lp.get("bq"))
-        k = _proj(x, lp["wk"], lr("wk"), lora_scale, dtype,
-                  _drop_key(drop_rng, 1), drop_rate, bias=lp.get("bk"))
-        v = _proj(x, lp["wv"], lr("wv"), lora_scale, dtype,
-                  _drop_key(drop_rng, 2), drop_rate, bias=lp.get("bv"))
-        q = q.reshape(B, S, H, hd)
-        k = k.reshape(B, S, K, hd)
-        v = v.reshape(B, S, K, hd)
+        q = proj(x, "wq", 0, bias=lp.get("bq")).reshape(B, S, H, hd)
+        k = proj(x, "wk", 1, bias=lp.get("bk")).reshape(B, S, K, hd)
+        v = proj(x, "wv", 2, bias=lp.get("bv")).reshape(B, S, K, hd)
         q = _constrain(q, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
         k = _constrain(k, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
     # "attn/qkv" names what spares the three projections their second
@@ -404,9 +402,68 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
                                   fused_ops=fused_ops, mesh=mesh)
     if not cfg.qk_norm:
         q, k = (checkpoint_name(t, "attn/qkv") for t in (q, k))
-    v = checkpoint_name(v, "attn/qkv")
-    kind_scope = contextlib.nullcontext() if kind is None else scope(
-        "window" if kind == "sliding" else "full")
+    return q, k, checkpoint_name(v, "attn/qkv")
+
+
+def _latent_qkv(x, lp, cfg: ModelConfig, rope, positions, mesh, proj):
+    """q, k, v [B, S, H, hd] of a latent-attention layer
+    (``cfg.latent_attention``): the query through its latent, keys and
+    values through the one they share; a head's q and k are the part
+    without position followed by the rotated part, and the key's rotated
+    part is one vector a position, repeated over the heads.
+
+    Two names for the block checkpoints. ``attn/latent``: what the two
+    down-projections give (1,344 values a position at GLM-4.7-Flash's
+    sizes), before the norms: a norm's backward reads its input, so the
+    name after it would leave the down-projection to run again (as
+    ``cfg.qk_norm`` does to ``attn/qkv``). ``attn/qkv``: the assembled
+    q, k, v (15,360 values), which spare the up-projections too."""
+    B, S, _ = x.shape
+    H = cfg.n_heads
+    nope, rot, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim)
+    eps, sp1 = cfg.norm_eps, cfg.norm_scale_plus_one
+    with scope("attn/q_latent"):
+        c_q = checkpoint_name(proj(x, "wq_a", 0), "attn/latent")
+        c_q = rms_norm(c_q, lp["q_latent_norm"], eps=eps,
+                       scale_plus_one=sp1)
+        q = proj(c_q, "wq_b", 1).reshape(B, S, H, nope + rot)
+        q = _constrain(q, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
+    with scope("attn/kv_latent"):
+        a = checkpoint_name(proj(x, "wkv_a", 2), "attn/latent")
+        c_kv, k_rot = a[..., :cfg.kv_lora_rank], a[..., cfg.kv_lora_rank:]
+        c_kv = rms_norm(c_kv, lp["kv_latent_norm"], eps=eps,
+                        scale_plus_one=sp1)
+        kv = proj(c_kv, "wkv_b", 4).reshape(B, S, H, nope + vd)
+        kv = _constrain(kv, mesh, BATCH_AXES, AXIS_CONTEXT, "model", None)
+    with scope("attn/rope"):
+        q_rot, k_rot = q[..., nope:], k_rot[:, :, None, :]
+        if rope is not None:
+            q_rot = apply_rope(q_rot, positions, rope)
+            k_rot = apply_rope(k_rot, positions, rope)
+        q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rot, (B, S, H, rot))],
+            axis=-1)
+    return tuple(checkpoint_name(t, "attn/qkv")
+                 for t in (q, k, kv[..., nope:]))
+
+
+def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
+          window, segment_ids, mesh, lora_p=None, lora_scale=1.0,
+          drop_rng=None, drop_rate=0.0, fused_ops=False, kind=None):
+    """``kind``: the leaf scope the attention itself runs under
+    (``attn_kind_scope``), None for none."""
+    B, S, D = x.shape
+
+    def proj(h, name, tag, bias=None):
+        return _proj(h, lp[name], _lora_entry(lora_p, name), lora_scale,
+                     dtype, _drop_key(drop_rng, tag), drop_rate, bias=bias)
+    if cfg.latent_attention:
+        q, k, v = _latent_qkv(x, lp, cfg, rope, positions, mesh, proj)
+    else:
+        q, k, v = _qkv(x, lp, cfg, rope, positions, mesh, proj, fused_ops)
+    kind_scope = contextlib.nullcontext() if kind is None else scope(kind)
     with scope("attn/core"), kind_scope:
         if impl == "xla":
             out = dot_product_attention(
@@ -426,11 +483,21 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
                 causal=True, sliding_window=window, scale=cfg.attn_scale,
                 logit_softcap=cfg.attn_softcap, mesh=mesh,
                 rows_ordered=True)
-        out = out.reshape(B, S, H * hd)
+        out = out.reshape(B, S, -1)
     with scope("attn/out"):
-        return checkpoint_name(
-            _proj(out, lp["wo"], lr("wo"), lora_scale, dtype,
-                  _drop_key(drop_rng, 3), drop_rate), "attn/out")
+        return checkpoint_name(proj(out, "wo", 3), "attn/out")
+
+
+def attn_kind_scope(cfg: ModelConfig, kind: str) -> Optional[str]:
+    """The leaf scope inside ``attn/core`` that a layer of block kind
+    ``kind`` runs its attention under, so that a profile tells the
+    kinds apart: ``latent`` for a latent-attention layer, ``window`` /
+    ``full`` where a model mixes both kinds, else None."""
+    if cfg.latent_attention:
+        return "latent"
+    if len(set(cfg.block_pattern)) > 1:
+        return "window" if kind == "sliding" else "full"
+    return None
 
 
 def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
@@ -455,7 +522,6 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
     period of several layers under one checkpoint would hold the
     recomputed residuals of all of them at once)."""
     eps, sp1 = cfg.norm_eps, cfg.norm_scale_plus_one
-    kinds = len(set(cfg.block_pattern)) > 1
 
     def layer(kind, moe, x, aux, lp, lo, drng):
         if checkpoint_layer is not None:
@@ -472,7 +538,7 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
                   cfg.sliding_window if kind == "sliding" else None,
                   segment_ids, mesh, lora_p=lo, lora_scale=lora_scale,
                   drop_rng=_drop_key(drng, 0), drop_rate=lora_dropout,
-                  fused_ops=fused_ops, kind=kind if kinds else None)
+                  fused_ops=fused_ops, kind=attn_kind_scope(cfg, kind))
         with scope("attn/out"):
             # the post-norm and the residual add belong to the output
             # projection they finish (XLA fuses them into it)
@@ -554,12 +620,14 @@ def flash_grids(cfg: ModelConfig, mesh, rows: int, seq: int) -> dict:
             or (impl == "a2a" and axes.get(AXIS_CONTEXT, 1) == 1)):
         return {}
     calls = rows * max(cfg.n_heads // axes.get("model", 1), 1)
-    grids = {}
+    grids, group = {}, cfg.n_heads // cfg.n_kv_heads
     for kind in dict.fromkeys(cfg.block_pattern):
         block_q, block_kv, bands = call_plan(
-            seq, seq, causal=True, rows_ordered=True,
+            seq, seq, causal=True, rows_ordered=True, q_per_kv=group,
+            head_dim=cfg.resolved_head_dim,
             window=cfg.sliding_window if kind == "sliding" else None)
-        grids["window" if kind == "sliding" else "full"] = {
+        grids[attn_kind_scope(cfg, kind)
+              or ("window" if kind == "sliding" else "full")] = {
             "block_q": block_q, "block_kv": block_kv,
             **{name: [calls * band.visited, calls * band.rectangular]
                for name, band in bands.items()}}
@@ -645,7 +713,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
             x = x + table.astype(dtype)[positions]
         else:
             rope = jnp.asarray(rope_frequencies(
-                cfg.resolved_head_dim, theta=cfg.rope_theta,
+                cfg.rope_dim, theta=cfg.rope_theta,
                 llama3_scaling=cfg.rope_scaling))
         x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
 
@@ -666,12 +734,13 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
                 "LoRA dropout is not supported on a pipelined mesh; set "
                 "LORA_DROPOUT=0 or pipe=1")
         if cfg.prologue_layers or cfg.qk_norm or cfg.router != "softmax" \
-                or set(cfg.rope_kinds) != {"global", "sliding"}:
+                or set(cfg.rope_kinds) != {"global", "sliding"} \
+                or cfg.latent_attention:
             raise NotImplementedError(
                 f"{cfg.name}: a pipelined mesh runs its own copy of the "
                 "block (models/pipeline.py), which has no prologue of "
-                "leading layers, no q/k norm, no per-kind rotary and no "
-                "sigmoid router yet; use pipe=1")
+                "leading layers, no q/k norm, no per-kind rotary, no "
+                "sigmoid router and no latent attention yet; use pipe=1")
         from gke_ray_train_tpu.models.pipeline import pipeline_blocks
         x, pipe_aux = pipeline_blocks(
             x, params["blocks"], cfg, mesh, impl=impl, dtype=dtype,

@@ -173,13 +173,18 @@ SCOPE_NAMES = (
     "attn/core", "attn/out", "mlp_norm", "mlp/gate_up", "mlp/down",
     "moe/route", "moe/dispatch", "moe/experts", "moe/combine",
     "moe/shared", "final_norm", "unembed", "loss", "clip", "optimizer",
-    "base", "lora", "window", "full")
+    "base", "lora", "window", "full",
+    # a latent-attention layer: each latent's down-projection, norm and
+    # up-projection, and the leaf scope its attention runs under
+    "attn/q_latent", "attn/kv_latent", "latent")
 
 # bumped when a scope MOVES without the vocabulary changing: it rides
 # the compile cache's key beside the names (perf/cache.py::names_salt),
 # so that an executable compiled under the old placement is not served.
 # 2: `moe/experts` no longer holds the routed layer's residual add
-SCOPE_VERSION = 2
+# 3: a latent-attention layer projects under `attn/q_latent` and
+#    `attn/kv_latent`, and its `attn/rope` holds the assembly of q and k
+SCOPE_VERSION = 3
 
 # pl.pallas_call(name=...) of every kernel (ops/flash_attention.py,
 # ops/fused_ce.py, ops/fused_norm_rope.py), then the library's kernels

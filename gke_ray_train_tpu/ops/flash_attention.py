@@ -610,28 +610,41 @@ def _bwd(res, g, *, scale, causal, window, softcap, block_q, block_kv,
 # public entry
 # ---------------------------------------------------------------------------
 
-def window_blocks(T: int, window: Optional[int]) -> "tuple[int, int]":
+def window_blocks(T: int, window: Optional[int], head_dim: int = 128,
+                  q_per_kv: int = 1) -> "tuple[int, int]":
     """The (block_q, block_kv) to ask ``pick_block`` for where the grid
-    walks a window's band over a row of ``T``: twice the default query
-    block, and half the default kv block where the window fits in it.
+    walks a band over a row of ``T`` (causal self-attention over
+    ordered rows): under a window twice the default query block, and
+    half the default kv block where the window fits in it; without one
+    the defaults, and twice the default query block at ungrouped heads
+    of 256 and wider (grouped ones were not swept: they keep the defaults).
 
-    Read off a sweep on the v5e (scripts/flash_block_sweep.py; PERF.md
-    section 6): a band step costs 1.5-2 us before its first product, so
-    at window 128 blocks of 256 x 128 or 256 x 256 (little dead
-    arithmetic, many steps) lose to 512 x 512 (two steps a query block)
-    in all three kernels; a wider query block adds no step to a band;
-    and past half the default a kv block of the default's size wins
-    again. Without a window, or with one that reaches across the row,
-    the defaults (tuned for full rows) stand."""
+    Read off two sweeps on the v5e (scripts/flash_block_sweep.py;
+    PERF.md section 6). Heads of 128 (PR 27): a band step costs 1.5-2 us
+    before its first product, so at window 128 blocks of 256 x 128 or
+    256 x 256 (little dead arithmetic, many steps) lose to 512 x 512
+    (two steps a query block) in all three kernels; a wider query block
+    adds no step to a band; and past half the default a kv block of the
+    default's size wins again. Without a window, or with one that
+    reaches across the row, the defaults (tuned for full rows) stand
+    there. Heads of 256 (PR 30: 20 ungrouped heads, full causal): a kv
+    block is twice the bytes to fetch for the same products a query
+    row, and a query block twice as tall halves how often it is
+    fetched: 512 x 1024 reads 16.0 ms for the three kernels against
+    19.5 at 256 x 1024, 16.3-16.5 at 1024-tall or 512-wide blocks and
+    21.9 at 256 x 2048 (512 x 2048 does not fit ``flash_dkv``'s
+    VMEM)."""
     if window is None or window >= T:
-        return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV
+        tall = 2 if head_dim >= 256 and q_per_kv == 1 else 1
+        return tall * DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV
     half = DEFAULT_BLOCK_KV // 2
     return (2 * DEFAULT_BLOCK_Q,
             half if window <= half else DEFAULT_BLOCK_KV)
 
 
 def call_plan(S: int, T: int, *, causal: bool, window: Optional[int],
-              rows_ordered: bool, block_q: Optional[int] = None,
+              rows_ordered: bool, head_dim: int = 128, q_per_kv: int = 1,
+              block_q: Optional[int] = None,
               block_kv: Optional[int] = None):
     """``(block_q, block_kv, kernel_bands)`` of one ``flash_attention``
     call: what the call does with its grid, from static facts alone, so
@@ -640,7 +653,8 @@ def call_plan(S: int, T: int, *, causal: bool, window: Optional[int],
     # the band needs causal self-attention (kernel_bands); anything
     # else keeps the full grid and the blocks tuned for it
     rows_ordered = rows_ordered and causal and S == T
-    want_q, want_kv = window_blocks(T, window if rows_ordered else None)
+    want_q, want_kv = window_blocks(T, window, head_dim, q_per_kv) \
+        if rows_ordered else (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_KV)
     block_q = pick_block(want_q if block_q is None else block_q, S)
     block_kv = pick_block(want_kv if block_kv is None else block_kv, T)
     return block_q, block_kv, kernel_bands(
@@ -685,7 +699,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     scale = dh ** -0.5 if scale is None else scale
     block_q, block_kv, _ = call_plan(
         S, T, causal=causal, window=sliding_window,
-        rows_ordered=rows_ordered, block_q=block_q, block_kv=block_kv)
+        rows_ordered=rows_ordered, head_dim=dh, q_per_kv=H // k.shape[2],
+        block_q=block_q, block_kv=block_kv)
 
     if q_positions is None:
         q_positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32),

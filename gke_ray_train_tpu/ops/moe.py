@@ -185,11 +185,31 @@ def _experts(x, combine, w_gate, w_up, w_down, cfg: ModelConfig, dtype):
 # ---------------------------------------------------------------------------
 
 # (rows, contraction, columns) of a tile of the grouped product on the
-# chip. At the K-EXAONE share's shapes (65536 buffer rows of which 8192
-# live, 16 groups, 6144 x 2048) this tiling reads 2.0 ms a product on a
-# v5e against 2.3-2.9 for four others and 2.8 for XLA's own lowering of
-# `jax.lax.ragged_dot` (my chip run, PR 26)
+# chip, at most. At the K-EXAONE share's shapes (65536 buffer rows of
+# which 8192 live, 16 groups, 6144 x 2048) this tiling reads 2.0 ms a
+# product on a v5e against 2.3-2.9 for four others and 2.8 for XLA's own
+# lowering of `jax.lax.ragged_dot` (my chip run, PR 26)
 GMM_TILING = (256, 1024, 2048)
+
+
+def gmm_tiling(m: int, k: int, n: int) -> tuple:
+    """The tile of one grouped product ``[m, k] x [groups, k, n]`` from
+    its own shapes: in the contraction and in the columns the largest
+    multiple of 128 that divides the dimension, up to
+    :data:`GMM_TILING`'s (the dimension itself where none does). The
+    kernel's backward hands this very function the shapes of the dx
+    product (the same kernel over the transposed bank, contraction and
+    columns exchanged), so each direction gets a tile that divides its
+    own: an expert of 2048 x 1536 takes (256, 1024, 1536) forward and
+    (256, 768, 2048) backward, where one clamped tuple left a third of
+    a contraction tile and a quarter of a column tile empty. At 6144 x
+    2048 both directions read (256, 1024, 2048), as before the rule."""
+    def tile(size, most):
+        fits = [t for t in range(128, min(most, size) + 1, 128)
+                if size % t == 0]
+        return fits[-1] if fits else size
+    return (min(GMM_TILING[0], m), tile(k, GMM_TILING[1]),
+            tile(n, GMM_TILING[2]))
 
 
 def grouped_dot(x, w, sizes):
@@ -199,7 +219,8 @@ def grouped_dot(x, w, sizes):
 
     On the chip: the megablox grouped-matmul kernel (its own
     ``custom_vjp``: dx is the same kernel over the transposed bank, and
-    the bank's gradient, where nothing asks for it, is dead code). It
+    the bank's gradient, where nothing asks for it, is dead code), at
+    the tile :func:`gmm_tiling` reads off each product's shapes. It
     keeps the scope it runs under in the profile, which XLA's lowering
     of ``jax.lax.ragged_dot`` to its own kernel does not (the op_name
     becomes ``ragged-dot-none``). Elsewhere ``ragged_dot`` itself."""
@@ -207,9 +228,8 @@ def grouped_dot(x, w, sizes):
     if not on_tpu():
         return jax.lax.ragged_dot(x, w, sizes)
     from jax.experimental.pallas.ops.tpu.megablox import gmm
-    tiling = tuple(min(t, n) for t, n in
-                   zip(GMM_TILING, (x.shape[0], x.shape[1], w.shape[2])))
-    return gmm(x, w, sizes, preferred_element_type=x.dtype, tiling=tiling)
+    return gmm(x, w, sizes, preferred_element_type=x.dtype,
+               tiling=gmm_tiling)
 
 
 def pair_buffer_rows(cfg: ModelConfig, tokens: int) -> int:

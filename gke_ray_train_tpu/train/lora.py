@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import (
-    ModelConfig, PROJ_TARGETS, SHARED_TARGETS)
+    LATENT_TARGETS, ModelConfig, PROJ_TARGETS, SHARED_TARGETS)
 from gke_ray_train_tpu.models.transformer import (
     Params, block_layout, block_leaves)
 
@@ -54,23 +54,36 @@ class LoraConfig:
 ATTN_TARGETS = ("wq", "wk", "wv", "wo")
 # a routed layer's shared expert takes the adapters its dense MLP would
 _SHARED_OF = dict(zip(("w_gate", "w_up", "w_down"), SHARED_TARGETS))
+# a latent-attention layer has no wq / wk / wv: whoever asks for one
+# gets the two matrices (down and up) that make it there
+_LATENT_OF = {"wq": ("wq_a", "wq_b"), "wk": ("wkv_a", "wkv_b"),
+              "wv": ("wkv_a", "wkv_b")}
 
 
 def _effective_targets(cfg: ModelConfig, lora_cfg: LoraConfig,
                        mlp_kind: str = None):
-    """The leaves of one block that get an (A, B) pair. A routed expert
-    bank has no single delta-W a pair could target (peft does the same
-    for Mixtral by default), so a routed layer adapts its attention and,
+    """The leaves of one block that get an (A, B) pair, from the job's
+    target list. Attention: the names as given, or, in a
+    latent-attention layer (``cfg.latent_attention``), the layer's own
+    matrices in their place (so the default list adapts all five). MLP:
+    a routed expert bank has no single delta-W a pair could target (peft
+    does the same for Mixtral by default), so a routed layer adapts,
     where it has one, its shared expert (under the shared leaves' own
     names); the router stays frozen."""
     if mlp_kind is None:
         mlp_kind = cfg.scan_mlp_kind
-    if mlp_kind != "moe":
-        return lora_cfg.targets
-    return tuple(
-        t if t in ATTN_TARGETS else _SHARED_OF[t]
-        for t in lora_cfg.targets
-        if t in ATTN_TARGETS or (cfg.n_shared_experts and t in _SHARED_OF))
+    out = []
+    for t in lora_cfg.targets:
+        if t in ATTN_TARGETS or t in LATENT_TARGETS:
+            names = _LATENT_OF.get(t, (t,)) if cfg.latent_attention \
+                else (t,)
+        elif mlp_kind != "moe":
+            names = (t,)
+        else:
+            names = (_SHARED_OF[t],) if cfg.n_shared_experts \
+                and t in _SHARED_OF else ()
+        out += [n for n in names if n not in out]
+    return tuple(out)
 
 
 def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
@@ -85,8 +98,13 @@ def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
     n_scan = len(cfg.block_pattern)
     scan_targets = _effective_targets(cfg, lora_cfg)
     keys = iter(jax.random.split(key, n_scan * len(scan_targets) + 1))
+    # seven a leading layer, as when seven projections were all a layer
+    # had (a latent-attention layer with a dense MLP has eight)
+    a_layer = max([7] + [len(_effective_targets(cfg, lora_cfg,
+                                                cfg.mlp_kind(i)))
+                         for i in range(cfg.prologue_layers)])
     pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
-                                  7 * max(cfg.prologue_layers, 1)))
+                                  a_layer * max(cfg.prologue_layers, 1)))
 
     def block(R, mlp_kind, keys):
         shapes = block_leaves(cfg, R, mlp_kind)
@@ -115,11 +133,15 @@ def lora_specs(cfg: ModelConfig, lora_cfg: LoraConfig) -> Params:
     in_spec = {"wq": "fsdp", "wk": "fsdp", "wv": "fsdp", "wo": "model",
                "w_gate": "fsdp", "w_up": "fsdp", "w_down": "model",
                "shared_gate": "fsdp", "shared_up": "fsdp",
-               "shared_down": "model"}
+               "shared_down": "model",
+               "wq_a": "fsdp", "wq_b": "fsdp", "wkv_a": "fsdp",
+               "wkv_b": "fsdp"}
     out_spec = {"wq": "model", "wk": "model", "wv": "model", "wo": "fsdp",
                 "w_gate": "model", "w_up": "model", "w_down": "fsdp",
                 "shared_gate": "model", "shared_up": "model",
-                "shared_down": "fsdp"}
+                "shared_down": "fsdp",
+                "wq_a": None, "wq_b": "model", "wkv_a": None,
+                "wkv_b": "model"}
 
     tree: Params = {}
     for where, _, _, _, _, kind in block_layout(cfg):

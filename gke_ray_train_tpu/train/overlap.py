@@ -224,7 +224,7 @@ def _pipelined_hidden(full_nonblock: Params, blocks_local, cfg: ModelConfig,
         rope = None
     else:
         rope = jnp.asarray(rope_frequencies(
-            cfg.resolved_head_dim, theta=cfg.rope_theta,
+            cfg.rope_dim, theta=cfg.rope_theta,
             llama3_scaling=cfg.rope_scaling))
 
     impl = resolve_seq_impl(cfg, None, S)

@@ -2,8 +2,10 @@
 
 Times the three kernels one by one (``flash_fwd``, ``flash_dq``,
 ``flash_dkv``: each jitted alone, so that XLA removes the other calls)
-at one packed row of 8192 with 64 query heads over 8 kv heads of 128,
-bf16: the window-128 layers and the full layers of the routed cell.
+at one packed row of 8192, bf16; by default 64 query heads over 8 kv
+heads of 128: the window-128 layers and the full layers of the routed
+cell (``--heads 20 --kv-heads 20 --head-dim 256``: the latent layers of
+the GLM cell, whose assembled q, k and v have 20 ungrouped heads of 256).
 Arms: the full grid at the default blocks (what the kernels did before
 the band), and the band at ``block_q`` in {256, 512} x ``block_kv`` in
 {128, 256, 512, 1024}. ``ops/flash_attention.py::window_blocks`` holds
@@ -74,13 +76,19 @@ def kernels(window, ordered, bq, bkv):
 
 
 def main() -> None:
+    global H, K, DH
     ap = argparse.ArgumentParser()
     ap.add_argument("--compile", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--heads", type=int, default=H)
+    ap.add_argument("--kv-heads", type=int, default=K)
+    ap.add_argument("--head-dim", type=int, default=DH)
+    ap.add_argument("--out", default="chiprun_out/flash_block_sweep.json")
     ap.add_argument("--arms", type=json.loads, default=ARMS,
                     help="JSON [[window, band, block_q, block_kv], ...]; "
                     "a window's full-grid arm first")
     args = ap.parse_args()
+    H, K, DH = args.heads, args.kv_heads, args.head_dim
 
     shapes = dict(q=(B, H, S, DH), k=(B, K, S, DH), v=(B, K, S, DH),
                   out=(B, H, S, DH), g=(B, H, S, DH))
@@ -148,8 +156,8 @@ def main() -> None:
         print(json.dumps(row), flush=True)
         rows.append(row)
 
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/flash_block_sweep.json", "w") as f:
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "w") as f:
         json.dump({"device": str(jax.devices()[0].device_kind),
                    "compile_only": args.compile, "shape": [B, H, S, DH],
                    "kv_heads": K, "rows": rows}, f, indent=1)

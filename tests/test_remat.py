@@ -83,6 +83,13 @@ def _kexaone_share(**kw):
         max_seq_len=8192, dtype="bfloat16", param_dtype="bfloat16"), **kw})
 
 
+def _glm_share(**kw):
+    from gke_ray_train_tpu.models.config import glm_4_7_flash
+    return glm_4_7_flash(**{**dict(
+        vocab_size=38720, experts_held=(0, 16), n_mtp_layers=0,
+        max_seq_len=8192, dtype="bfloat16", param_dtype="bfloat16"), **kw})
+
+
 # arguments: ``compiled.memory_analysis().argument_size_in_bytes`` of the
 # cell's step on the chip (PERF.md, PR 25 / PR 26: NF4 codes a byte each;
 # PR 29: two a byte)
@@ -96,7 +103,12 @@ def _kexaone_share(**kw):
                          "moe/shared")),
         (4_164_117_504, ("mlp/gate_up", "attn/core", "attn/qkv",
                          "attn/out", "moe/shared"))]),
-], ids=["dense_cell", "routed_cell"])
+    # PR 30: XLA's peak with nothing kept is 12.53 GB there (6.02 beside
+    # the arguments); with these two names 16.01 of 16.91, and with
+    # `attn/latent` as well the compiler refuses the step by 128 MiB
+    (_glm_share, 1, 8192, 472_563_712, 6.264, [
+        (6_509_583_628, ("mlp/gate_up", "attn/core"))]),
+], ids=["dense_cell", "routed_cell", "latent_cell"])
 def test_working_set_and_budget_of_the_cell(make_cfg, rows, seq,
                                             lora_bytes, ws_gb, cases):
     """The arithmetic PERF.md (PR 25) sets beside XLA's memory analysis
@@ -547,19 +559,33 @@ def test_the_compile_surface_sizes_a_train_step(monkeypatch, devices,
     ("mistral-7b", 1024, {
         "window": {"block_q": 256, "block_kv": 1024, "fwd": [4, 4],
                    "dq": [4, 4], "dkv": [4, 4]}}),
+    # grouped heads of 256 (with a softcap): not what PR 30 swept, so
+    # its full causal rows keep the defaults
     ("gemma2-9b", 8192, {
         "window": {"block_q": 512, "block_kv": 1024, "fwd": [60, 128],
                    "dq": [60, 128], "dkv": [60, 128]},
         "full": {"block_q": 256, "block_kv": 1024, "fwd": [144, 256],
                  "dq": [144, 256], "dkv": [144, 256]}}),
-], ids=["routed_8k", "dense_1k", "gemma2_8k"])
+    # ungrouped heads of 256: full causal rows take a query block of 512
+    # (window_blocks, PR 30's sweep), 72 of 128 steps
+    ("glm-4.7-flash", 8192, {
+        "latent": {"block_q": 512, "block_kv": 1024, "fwd": [72, 128],
+                   "dq": [72, 128], "dkv": [72, 128]}}),
+], ids=["routed_8k", "dense_1k", "gemma2_8k", "latent_8k"])
 def test_flash_grid_attribute_of_the_presets(preset, seq, expect):
     """The step_build span's flash_grid: grid steps a call visits over
     the rectangular grid's, a head-row here (one row, one head)."""
     from gke_ray_train_tpu.models.config import PRESETS
     from gke_ray_train_tpu.models.transformer import flash_grids
-    cfg = dataclasses.replace(PRESETS[preset](), attn_impl="flash",
-                              n_heads=1, n_kv_heads=1)
+    cfg = PRESETS[preset]()
+    # one kv head of the preset's own size with the query heads that
+    # share it: the blocks follow the head's size and the grouping
+    group = cfg.n_heads // cfg.n_kv_heads
+    cfg = dataclasses.replace(cfg, attn_impl="flash", n_heads=group,
+                              n_kv_heads=1, head_dim=cfg.resolved_head_dim)
+    expect = {kind: {k: v if isinstance(v, int) else [group * c for c in v]
+                     for k, v in grid.items()}
+              for kind, grid in expect.items()}
     assert flash_grids(cfg, None, 1, seq) == expect
     assert flash_grids(dataclasses.replace(cfg, attn_impl="xla"),
                        None, 1, seq) == {}
