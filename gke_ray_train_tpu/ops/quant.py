@@ -5,9 +5,12 @@ The reference gets 4-bit NF4 base weights + LoRA from CUDA kernels
 ray-jobs/fine_tune_llama_ray.py:216-227). Here quantization is a pytree
 transform: each targeted weight leaf becomes a ``QTensor`` (codes +
 per-group scales, group along the input dim), dequantized on the fly
-inside the jitted forward — XLA fuses the dequant into the consuming
-matmul's prologue, and the frozen base stays 4-bit/8-bit in HBM, which
-is what makes 8B QLoRA fit a single 16 GB v5e chip.
+inside the jitted forward, and the frozen base stays 4-bit/8-bit in
+HBM, which is what makes 8B QLoRA fit a single 16 GB v5e chip. On the
+v5e a decode is one fusion of its own (half a byte a weight in, the
+compute dtype out, 4.5-4.9 ps a weight) and the product after it reads
+the decoded weight; ``dequantize`` says why it is not the product's
+prologue, and what that cost until PR 31 (PERF.md section 6).
 
 - "nf4": 4-bit NormalFloat codebook (the QLoRA data type), absmax-scaled
   per group. The codes are ``jnp.uint4`` of the weight's own shape, on
@@ -122,27 +125,67 @@ def quantize_tensor(w: jnp.ndarray, kind: str = "nf4",
 
 
 def _nf4_lookup(codes: jnp.ndarray) -> jnp.ndarray:
-    """Codebook lookup. On TPU: a flat select chain — a per-element
-    gather from a 16-entry table lowers to a catastrophically slow TPU
-    gather (measured 23x step slowdown); 15 VPU selects are ~free. On
-    CPU (the host-merge export path): the select chain is the slow one
-    (15 full passes over an 8B-element tensor), a table take is one."""
-    c = codes.astype(jnp.int32)
+    """``NF4_CODEBOOK[code]`` as float32, bit for bit. Traced (and on
+    any device but the CPU): a select tree on the code's four bits, 8
+    selects between pairs of constants by bit 0, then 4, 2, 1: 15
+    selects and 7 mask operations a weight, where
+    the chain of ``where(c == i, book[i], out)`` that stood here until
+    PR 31 took 30. A per-element gather from the 16-entry table lowers
+    to a catastrophically slow TPU gather (measured 23x step slowdown).
+    What the v5e measured (PERF.md section 6, PR 31): in a decode that
+    runs as one fusion the tree costs 0 to 0.6 ps a weight over no
+    lookup at all and the chain 1.1 to 1.8; ``select_n`` reads as the
+    chain, a 4 x 4 tree as this one. Eager on the CPU
+    (the host-merge export path) selects are the slow way (a full pass
+    over an 8B-element tensor each) and a table take is one pass."""
     on_cpu_eager = (not isinstance(codes, jax.core.Tracer)
                     and all(d.platform == "cpu"
                             for d in codes.devices()))
+    c = codes.astype(jnp.int32)
     if on_cpu_eager:
         return jnp.asarray(NF4_CODEBOOK, jnp.float32)[c]
-    out = jnp.full(c.shape, NF4_CODEBOOK[0], jnp.float32)
-    for i in range(1, 16):
-        out = jnp.where(c == i, NF4_CODEBOOK[i], out)
-    return out
+    level = [jnp.float32(v) for v in NF4_CODEBOOK]
+    for mask in [(c & (1 << b)) != 0 for b in range(3)] + [c >= 8]:
+        level = [jnp.where(mask, hi, lo)
+                 for lo, hi in zip(level[::2], level[1::2])]
+    return level[0]
 
 
 def dequantize(qt: QTensor, dtype=jnp.bfloat16) -> jnp.ndarray:
+    """``value(code) * scale`` in float32, cast to ``dtype``.
+
+    The barrier is what PR 31's sweep found the decode's time to be.
+    Without it XLA's TPU pipeline sinks the group reshape through the
+    elementwise chain onto the scales' broadcast, cannot fuse a
+    broadcast through the bitcast that leaves, and writes the broadcast
+    to HBM: float32 ``[D, F]``, 4 bytes a weight written and read back
+    on every decode, sixteen times the codes' own traffic. That was 12
+    of the 16.5 ps a weight of a bank decoded alone and the 31% over the
+    roofline that ``nf4_matmul_roofline.train`` read from PR 25 to PR
+    30; the lookup was 0.4 ps of it. Behind the barrier the codes arrive
+    in the grouped shape as an operand, there is no reshape to sink, and
+    the decode is one fusion over half a byte in and two out (4.5-4.9 ps
+    a weight alone); the product that consumes it reads the decoded
+    weight from HBM as it would a bf16 one. The values are the same
+    float32 products, bit for bit. Eager calls have no fusion to keep.
+
+    What the barrier costs: it takes a whole operand, so inside a scan
+    over stacked layers the slice of one layer's codes out of the stack
+    is a copy of its own before each decode (``dynamic-slice`` fusion,
+    half a byte a weight in and out, 1.1 ps a weight on the v5e, 2.7%
+    of the dense cell's step) and carries the scan's name, not the
+    caller's scope: a profile reads it as unscoped time, and a roofline
+    of the caller's scope has to add it back (PERF.md section 6, PR
+    31). Storing the codes grouped does not spare it: compiled for a
+    described v5e, the slice's own reshape sinks onto the broadcast
+    just the same without the barrier, and with it the copy stays
+    (ROADMAP S3). ``tests/test_quant.py`` compiles this function for a
+    described v5e and fails if the broadcast comes back."""
     *lead, D, F = qt.codes.shape
     g = qt.group
     codes = qt.codes.reshape(*lead, D // g, g, F)
+    if isinstance(codes, jax.core.Tracer):
+        codes = jax.lax.optimization_barrier(codes)
     scales = qt.scales[..., :, None, :]
     if qt.kind == "nf4":
         vals = _nf4_lookup(codes)

@@ -1,5 +1,7 @@
 """Weight quantization (ops/quant.py) — NF4/int8 QLoRA parity (D5)."""
 
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -7,8 +9,8 @@ import numpy as np
 import pytest
 
 from gke_ray_train_tpu.ops.quant import (
-    QTensor, dequantize, is_qtensor, quant_specs, quantize_params,
-    quantize_tensor)
+    NF4_CODEBOOK, QTensor, dequantize, is_qtensor, quant_specs,
+    quantize_params, quantize_tensor)
 
 
 @pytest.mark.parametrize("kind,tol", [("nf4", 0.15), ("int8", 0.012)])
@@ -83,6 +85,129 @@ def test_nf4_dequantize_gives_the_bits_of_a_byte_a_code(shape, group, make):
         "td,...df->...tf", x, dequantize(q, jnp.float32)))
     np.testing.assert_array_equal(np.asarray(prod(qt)),
                                   np.asarray(prod(byte_a_code)))
+
+
+def _chain_lookup(codes):
+    """The decode up to PR 30, kept as the oracle."""
+    c = codes.astype(jnp.int32)
+    out = jnp.full(c.shape, NF4_CODEBOOK[0], jnp.float32)
+    for i in range(1, 16):
+        out = jnp.where(c == i, NF4_CODEBOOK[i], out)
+    return out
+
+
+@pytest.mark.parametrize("scale", [0.0625, 0.0437219],
+                         ids=["scale_pow2", "scale_odd"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("code", range(16))
+def test_nf4_decode_is_the_codebook_entry_times_the_scale(code, dtype,
+                                                          scale):
+    """``NF4_CODEBOOK[code] * scale`` as a float32 product, cast: bit
+    for bit, from the jitted select tree and from the eager CPU table."""
+    qt = QTensor(jnp.full((8, 2), code, jnp.uint4),
+                 jnp.full((2, 2), scale, jnp.float32), "nf4", 4)
+    want = (np.float32(NF4_CODEBOOK[code]) * np.float32(scale)).astype(
+        jnp.dtype(dtype))
+    for got in (jax.jit(lambda q: dequantize(q, dtype))(qt),
+                dequantize(qt, dtype)):
+        assert got.dtype == dtype and got.shape == (8, 2)
+        np.testing.assert_array_equal(
+            np.asarray(got).view(np.uint8),
+            np.broadcast_to(want, (8, 2)).copy().view(np.uint8))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_nf4_dequantize_equals_the_chain_it_replaced(dtype, monkeypatch):
+    """A whole ``dequantize``, jitted alone and as the operand of a
+    product, against the fifteen-step chain of PR 30 and before."""
+    from gke_ray_train_tpu.ops import quant
+    w = jax.random.normal(jax.random.key(31), (3, 128, 256)) * 0.02
+    qt = quantize_tensor(w, "nf4")
+    x = jax.random.normal(jax.random.key(32), (3, 16, 128), dtype)
+    def run():  # a new function a call, so that each traces its lookup
+        return jax.jit(lambda q: (
+            dequantize(q, dtype),
+            jnp.einsum("emd,edf->emf", x, dequantize(q, dtype))))(qt)
+
+    got = run()
+    monkeypatch.setattr(quant, "_nf4_lookup", _chain_lookup)
+    want = run()
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
+
+
+def test_nf4_lookup_is_the_select_tree():
+    """15 selects on four masks: no compare with a code's value (the
+    chain of PR 30 and before took fifteen) and no gather."""
+    from gke_ray_train_tpu.ops import quant
+
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    names = list(primitives(jax.make_jaxpr(quant._nf4_lookup)(
+        jnp.zeros((64,), jnp.uint4)).jaxpr))
+    assert names.count("select_n") == 15
+    assert sum(names.count(n) for n in ("and", "ne", "ge")) == 7
+    assert "eq" not in names and "gather" not in names
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e: libtpu compiles for it with no
+    chip attached (and looks nothing up: both variables are set), or
+    the tests that ask for it are skipped."""
+    with pytest.MonkeyPatch.context() as mp:
+        for name, value in (("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                            ("TPU_WORKER_HOSTNAMES", "localhost")):
+            if name not in os.environ:
+                mp.setenv(name, value)
+        try:
+            from jax.experimental import topologies
+            yield topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:1x1",
+                chips_per_host_bounds=(1, 1, 1)).devices[0]
+        except Exception as e:  # noqa: BLE001 - no TPU compiler here
+            pytest.skip(f"no TPU compiler: {type(e).__name__}: {e}")
+
+
+@pytest.mark.parametrize("mode", ["alone", "product"])
+@pytest.mark.parametrize("kind,code_dtype", [("nf4", jnp.uint4),
+                                             ("int8", jnp.int8)])
+def test_v5e_decode_is_one_fusion_and_no_broadcast_in_hbm(
+        kind, code_dtype, mode, v5e):
+    """What PR 31's gain rests on, at the dense gate/up shape: compiled
+    for the v5e, a decode is ONE fusion, and the scales' broadcast is
+    not an operation of its own that goes through HBM at 4 bytes a
+    weight (the program's only temporary is the decoded weight a
+    product reads: 2 bytes a weight). Without ``dequantize``'s barrier
+    XLA sinks the group reshape onto the broadcast and both fail."""
+    from jax.sharding import SingleDeviceSharding
+    from gke_ray_train_tpu.ops.quant import DEFAULT_GROUP
+    from gke_ray_train_tpu.plan import XLA_TPU_OPTIONS
+    rows, D, F = 2048, 4096, 14336
+    sharding = SingleDeviceSharding(v5e)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    qt = QTensor(spec((D, F), code_dtype),
+                 spec((D // DEFAULT_GROUP, F), jnp.float32), kind,
+                 DEFAULT_GROUP)
+    fn = {"alone": lambda x, q: dequantize(q, jnp.bfloat16),
+          "product": lambda x, q: x @ dequantize(q, jnp.bfloat16)}[mode]
+    built = jax.jit(fn, compiler_options=XLA_TPU_OPTIONS).lower(
+        spec((rows, D), jnp.bfloat16), qt).compile()
+    hlo = built.as_text()
+    entry = re.findall(r"= \S+ ([a-z][\w-]*)\(", hlo[hlo.index("ENTRY"):])
+    assert "broadcast" not in entry, entry
+    assert entry.count("fusion") == {"alone": 1, "product": 2}[mode], entry
+    assert built.memory_analysis().temp_size_in_bytes <= 2.5 * D * F
 
 
 def test_exact_for_codebook_values():
