@@ -68,7 +68,8 @@ def convert(orbax_dir: str, out_dir: str, *, step: int = None,
 
     from gke_ray_train_tpu.ckpt.hf_io import (
         ShardedSafetensorsWriter, _EXPERT_KEYS, _hf_expert_names,
-        _hf_layer_names, _maybe_t, hf_dtype_np, write_hf_config)
+        _hf_layer_names, _maybe_t, hf_dtype_np, require_name_map,
+        write_hf_config)
     from gke_ray_train_tpu.ckpt.manager import CheckpointManager
     from gke_ray_train_tpu.models.config import ModelConfig
 
@@ -80,6 +81,7 @@ def convert(orbax_dir: str, out_dir: str, *, step: int = None,
             "checkpoints, craft one from ModelConfig.to_dict()")
     with open(cfg_path) as f:
         cfg = ModelConfig.from_dict(json.load(f))
+    require_name_map(cfg)
     P_ = len(cfg.block_pattern)
 
     mgr = CheckpointManager(orbax_dir, score_attribute=None,

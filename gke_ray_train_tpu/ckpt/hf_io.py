@@ -32,6 +32,22 @@ from gke_ray_train_tpu.models.transformer import (
 from gke_ray_train_tpu.parallel.sharding import tree_shardings
 
 
+def require_name_map(cfg: ModelConfig) -> None:
+    """The tensor names below are those of the families whose
+    checkpoints' index files they were read from. A model with
+    state-space layers (``granitemoehybrid``) has none here yet: its
+    mixer's tensors (``mamba.in_proj``, ``conv1d``, ``A_log`` ...) and
+    its experts' fused layout want a checkpoint's index file to be
+    written from, not a guess; refuse it by name instead."""
+    if "ssm" in cfg.block_pattern:
+        raise NotImplementedError(
+            f"{cfg.name}: no name map for a model with state-space "
+            "layers (ckpt/hf_io.py, ckpt/convert.py): its checkpoint's "
+            "tensor names are not in this repository; train it from "
+            "seeded weights, or add the map from the checkpoint's "
+            "model.safetensors.index.json")
+
+
 def _hf_layer_names(cfg: ModelConfig, i: int) -> Dict[str, str]:
     """our-key → HF tensor name for decoder layer i (per-layer tensors;
     MoE expert banks are per-(layer, expert), see _hf_expert_names)."""
@@ -115,6 +131,7 @@ def load_hf_checkpoint(model_dir: str, cfg: ModelConfig, *,
     """
     from safetensors import safe_open
 
+    require_name_map(cfg)
     specs = param_specs(cfg)
     shardings = (tree_shardings(mesh, specs) if mesh is not None else None)
     pdt = jnp.dtype(cfg.param_dtype)
@@ -370,6 +387,7 @@ def save_hf_checkpoint(params: Params, cfg: ModelConfig, out_dir: str,
     Tensors are pulled off device one LAYER at a time and flushed
     incrementally — host RAM stays O(max_shard_bytes), not O(model)
     (VERDICT r3 weak #4: the 70B export must not buffer every tensor)."""
+    require_name_map(cfg)
     P_ = len(cfg.block_pattern)
     w = ShardedSafetensorsWriter(out_dir, max_shard_bytes=max_shard_bytes)
 

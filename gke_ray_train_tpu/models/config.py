@@ -12,6 +12,7 @@ checkpointing for free.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 # The seven projection matrices of every decoder block — the canonical
@@ -28,6 +29,10 @@ SHARED_TARGETS = ("shared_gate", "shared_up", "shared_down")
 # wq / wk / wv (cfg.latent_attention): the down- and up-projection of
 # the query's latent and of the latent that keys and values share
 LATENT_TARGETS = ("wq_a", "wq_b", "wkv_a", "wkv_b")
+# the two projections of a state-space layer's mixer ("ssm" in
+# cfg.block_pattern), which stand where a layer's attention matrices
+# would: nothing else of the mixer is quantized or adapted
+SSM_TARGETS = ("in_proj", "out_proj")
 
 
 # fields that came with the sigmoid-routed decoder (PR 26), after model
@@ -38,7 +43,11 @@ _LATER_FIELDS = frozenset({
     "experts_held", "n_mtp_layers",
     # latent attention (PR 30)
     "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
-    "v_head_dim"})
+    "v_head_dim",
+    # state-space layers and Granite's multipliers (PR 32)
+    "ssm_heads", "ssm_head_dim", "ssm_state", "ssm_groups", "ssm_conv",
+    "ssm_chunk", "ssm_conv_bias", "embed_multiplier",
+    "residual_multiplier", "logits_scaling", "shared_d_ff"})
 
 
 @dataclasses.dataclass(frozen=True)
@@ -62,7 +71,8 @@ class ModelConfig:
     rope_scaling: Optional[object] = None
 
     # block structure; n_layers must divide by len(block_pattern).
-    # "global" = full causal attention, "sliding" = windowed causal.
+    # "global" = full causal attention, "sliding" = windowed causal,
+    # "ssm" = a state-space mixer in the attention's place (below).
     block_pattern: Tuple[str, ...] = ("global",)
     sliding_window: Optional[int] = None
     # the block kinds whose q and k are rotated (EXAONE-4 rotates in its
@@ -83,6 +93,20 @@ class ModelConfig:
     qk_nope_head_dim: Optional[int] = None
     qk_rope_head_dim: Optional[int] = None
     v_head_dim: Optional[int] = None
+    # the mixer of an "ssm" layer (Mamba-2; ops/ssm.py): ssm_heads
+    # heads of ssm_head_dim values, each carrying a state of
+    # [ssm_head_dim, ssm_state] along the sequence; B and C are shared
+    # by the heads of a group; a causal depthwise conv of ssm_conv taps
+    # before the scan, which works in chunks of ssm_chunk positions; the
+    # two projections have no bias. Stated together where block_pattern
+    # has the kind
+    ssm_heads: Optional[int] = None
+    ssm_head_dim: Optional[int] = None
+    ssm_state: Optional[int] = None
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    ssm_conv_bias: bool = True
 
     activation: str = "silu"                # "silu" | "gelu_tanh"
 
@@ -100,13 +124,18 @@ class ModelConfig:
     # "softmax": Mixtral (probabilities, static capacity with drops, the
     # Switch aux loss). "sigmoid": DeepSeek-V3 (independent scores, no
     # capacity and no drop, no aux loss; grouped products over the pairs
-    # sorted by expert)
+    # sorted by expert). "topk_softmax": Granite's (the same layer
+    # without drops; the k largest logits are selected and a token's
+    # weights are the softmax over those k logits)
     router: str = "softmax"
     router_bias: bool = False       # frozen [E] added for selection only
     router_renorm: bool = True      # weights / sum over the selected
     router_scale: float = 1.0       # then times this
     n_shared_experts: int = 0       # always-on SwiGLU beside the routed
     expert_d_ff: Optional[int] = None       # default d_ff
+    # width of the shared expert where it is not a multiple of the
+    # routed experts' (default n_shared_experts * expert_d_ff)
+    shared_d_ff: Optional[int] = None
     # leading layers whose MLP is dense (width d_ff) before the routed
     # ones (DeepSeek-V3's first_k_dense_replace)
     n_dense_layers: int = 0
@@ -125,6 +154,12 @@ class ModelConfig:
 
     tie_embeddings: bool = False
     embed_scale: bool = False               # x *= sqrt(d_model) after embed
+    # Granite's multipliers: x *= embed_multiplier after the embedding,
+    # every sublayer's output times residual_multiplier before it is
+    # added, logits / logits_scaling (attn_scale is the fourth)
+    embed_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     attn_qkv_bias: bool = False             # Qwen-2: bias on q/k/v proj only
     norm_scale_plus_one: bool = False       # Gemma (1 + scale) RMSNorm
     post_block_norm: bool = False           # Gemma-2 post-attn/post-mlp norms
@@ -165,10 +200,17 @@ class ModelConfig:
                 f"length {len(self.block_pattern)}")
         if self.n_heads % self.n_kv_heads != 0:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
-        unknown = set(self.block_pattern) - {"global", "sliding"}
+        unknown = set(self.block_pattern) - {"global", "sliding", "ssm"}
         if unknown:
             raise ValueError(f"unknown block kinds {unknown}; "
-                             "valid: global, sliding")
+                             "valid: global, sliding, ssm")
+        if "ssm" in self.block_pattern and not (
+                self.ssm_heads and self.ssm_head_dim and self.ssm_state):
+            raise ValueError(
+                "block_pattern contains 'ssm': state ssm_heads, "
+                "ssm_head_dim and ssm_state")
+        if self.ssm_heads and self.ssm_heads % self.ssm_groups:
+            raise ValueError("ssm_heads must be a multiple of ssm_groups")
         if "sliding" in self.block_pattern and self.sliding_window is None:
             raise ValueError("block_pattern contains 'sliding' but "
                              "sliding_window is None — that would silently "
@@ -200,15 +242,15 @@ class ModelConfig:
                     "a latent-attention layer has a key and a value head "
                     "for every query head, no per-head q/k norm and no "
                     "bias")
-        if self.router not in ("softmax", "sigmoid"):
+        if self.router not in ("softmax", "sigmoid", "topk_softmax"):
             raise ValueError(f"unknown router {self.router!r}")
         if (self.n_shared_experts or self.n_dense_layers
                 or self.experts_held or self.router_bias) \
-                and not (self.n_experts and self.router == "sigmoid"):
+                and not self.dropless_router:
             raise ValueError(
                 "shared experts, leading dense layers, a held range and "
-                "a router bias belong to the 'sigmoid' router's layer "
-                "(n_experts > 0, router='sigmoid')")
+                "a router bias belong to the layer that drops nothing "
+                "(n_experts > 0, router='sigmoid' or 'topk_softmax')")
         if self.n_dense_layers > self.n_layers:
             raise ValueError("n_dense_layers exceeds n_layers")
         if (self.n_layers - self.prologue_layers) \
@@ -243,6 +285,46 @@ class ModelConfig:
     @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank is not None
+
+    @property
+    def dropless_router(self) -> bool:
+        """The routed layer of ``ops/moe.py::routed_experts``: no
+        capacity, no drop, no aux loss, a held range of experts."""
+        return self.n_experts > 0 and self.router != "softmax"
+
+    def block_kind(self, layer: int) -> str:
+        """The kind of layer ``layer`` ("global" | "sliding" | "ssm"):
+        the prologue is whole periods, so the pattern is aligned to 0."""
+        return self.block_pattern[layer % len(self.block_pattern)]
+
+    @property
+    def n_ssm_layers(self) -> int:
+        return sum(self.block_kind(i) == "ssm"
+                   for i in range(self.n_layers))
+
+    @property
+    def ssm_inner(self) -> int:
+        """Values a position the mixer's heads carry (d_inner)."""
+        return (self.ssm_heads or 0) * (self.ssm_head_dim or 0)
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Columns the conv runs over: x, then B and C of every group."""
+        return self.ssm_inner + 2 * self.ssm_groups * (self.ssm_state or 0)
+
+    def ssm_leaf_shapes(self) -> dict:
+        """``{leaf: shape}`` of one state-space layer's mixer, in
+        creation order. ``in_proj``'s columns are ``[z | x | B | C |
+        dt]``."""
+        H, C = self.ssm_heads, self.ssm_conv_dim
+        out = {"in_proj": (self.d_model, self.ssm_inner + C + H),
+               "conv_w": (C, self.ssm_conv)}
+        if self.ssm_conv_bias:
+            out["conv_b"] = (C,)
+        out.update(dt_bias=(H,), a_log=(H,), d_skip=(H,),
+                   ssm_norm=(self.ssm_inner,),
+                   out_proj=(self.ssm_inner, self.d_model))
+        return out
 
     @property
     def resolved_head_dim(self) -> int:
@@ -309,6 +391,11 @@ class ModelConfig:
         return self.expert_d_ff or self.d_ff
 
     @property
+    def resolved_shared_d_ff(self) -> int:
+        return self.shared_d_ff \
+            or self.n_shared_experts * self.resolved_expert_d_ff
+
+    @property
     def held_range(self) -> Tuple[int, int]:
         return self.experts_held or (0, self.n_experts)
 
@@ -348,13 +435,19 @@ class ModelConfig:
         n_moe = self.n_layers - self.n_dense_layers if self.n_experts else 0
         moe = (self.d_model * self.n_experts               # router
                + (self.n_experts if self.router_bias else 0)
-               + (experts_counted + self.n_shared_experts)
-               * 3 * self.d_model * self.resolved_expert_d_ff)
+               + experts_counted * 3 * self.d_model
+               * self.resolved_expert_d_ff
+               + 3 * self.d_model * self.resolved_shared_d_ff)
         norms = 2 * self.d_model + (2 * self.d_model if self.post_block_norm
                                     else 0)
         embed = self.vocab_size * self.d_model
         head = 0 if self.tie_embeddings else self.vocab_size * self.d_model
-        return (self.n_layers * (attn + norms) + n_moe * moe
+        n_ssm = self.n_ssm_layers
+        ssm = sum(math.prod(shape)
+                  for shape in self.ssm_leaf_shapes().values()) \
+            if n_ssm else 0
+        return ((self.n_layers - n_ssm) * attn + n_ssm * ssm
+                + self.n_layers * norms + n_moe * moe
                 + (self.n_layers - n_moe) * ffn
                 + embed + head + self.d_model)
 
@@ -483,6 +576,33 @@ def glm_4_7_flash(**kw) -> ModelConfig:
     return ModelConfig(**{**published, **kw})
 
 
+def granite_4_0_h_small(**kw) -> ModelConfig:
+    """Granite-4.0-H-Small (ibm-granite, ``model_type`` granitemoehybrid;
+    32B-A9B) at its published sizes: 40 layers in periods of ten, nine
+    Mamba-2 mixers (128 heads of 64, a state of 128, one group, conv of
+    4 taps with bias, chunks of 256) around one layer of grouped-query
+    attention without positions (32 / 8 heads of 128, softmax scale
+    1/128); every layer's MLP is 72 routed experts of 768 (the 10
+    largest logits a token, weights their softmax, nothing dropped)
+    beside a shared expert of 1536; the embedding times 12, every
+    sublayer's output times 0.22, the tied head's logits over 16.
+    Keywords override: a deployment's share of it states its own
+    ``n_layers``, ``experts_held`` and ``vocab_size``."""
+    published = dict(
+        name="granite-4.0-h-small", vocab_size=100352, d_model=4096,
+        n_layers=40, n_heads=32, n_kv_heads=8, d_ff=768,
+        max_seq_len=131072, norm_eps=1e-5,
+        block_pattern=("ssm",) * 5 + ("global",) + ("ssm",) * 4,
+        rope_kinds=(), attn_scale=0.0078125,
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_conv=4, ssm_chunk=256, ssm_conv_bias=True,
+        n_experts=72, expert_top_k=10, expert_d_ff=768,
+        n_shared_experts=1, shared_d_ff=1536, router="topk_softmax",
+        tie_embeddings=True, embed_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=16.0)
+    return ModelConfig(**{**published, **kw})
+
+
 def qwen2_7b(**kw) -> ModelConfig:
     """Qwen-2/2.5 7B: Llama-style GQA decoder whose one architectural
     delta is bias on the q/k/v projections (public architecture; the HF
@@ -540,6 +660,7 @@ PRESETS = {
     "mixtral-8x7b": mixtral_8x7b,
     "k-exaone-236b": k_exaone_236b,
     "glm-4.7-flash": glm_4_7_flash,
+    "granite-4.0-h-small": granite_4_0_h_small,
     "gemma2-9b": gemma2_9b,
     "qwen2-7b": qwen2_7b,
 }
@@ -567,6 +688,8 @@ def preset_for_model_id(model_id: str, **kw) -> ModelConfig:
         return k_exaone_236b(**kw)
     if "glm-4.7-flash" in mid:
         return glm_4_7_flash(**kw)
+    if "granite-4.0-h-small" in mid:
+        return granite_4_0_h_small(**kw)
     if "mistral" in mid:
         if any(t in mid for t in ("v0.1", "v0.2")):
             kw.setdefault("vocab_size", 32000)

@@ -63,6 +63,11 @@ def require_decodable(cfg: ModelConfig) -> None:
         missing.append("a latent cache (the normed key/value latent and "
                        "the shared rotated key a position, with the "
                        "up-projections absorbed at decode)")
+    if "ssm" in cfg.block_pattern:
+        missing.append("a recurrent state beside keys and values (a "
+                       "state-space layer's conv taps and scan state a "
+                       "slot, in the cache manager that pages the "
+                       "attention layers' keys and values)")
     if cfg.n_experts and cfg.router != "softmax":
         missing.append("the sigmoid router's layer at decode shapes")
     if cfg.n_mtp_layers:

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding
 
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.transformer import (
-    Params, block_leaves, param_specs)
+    Params, block_leaves, drawn_leaf, param_specs)
 from gke_ray_train_tpu.ops.quant import (
     DEFAULT_GROUP, QTensor, QUANT_TARGETS, quant_specs, quantize_tensor)
 
@@ -120,9 +120,10 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
     pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
                                   16 * max(cfg.prologue_layers, 1)))
 
-    def block(bspec, R, mlp_kind, keys):
+    def block(bspec, R, mlp_kind, block_kind, keys):
         out = {}
-        for name, (shape, std) in block_leaves(cfg, R, mlp_kind).items():
+        for name, (shape, std) in block_leaves(cfg, R, mlp_kind,
+                                               block_kind).items():
             if std is None:
                 # norm scales, full precision like the biases below
                 out[name] = _dense_leaf(norm_maker(shape),
@@ -132,6 +133,13 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
                 # selection bias): never a quant target
                 out[name] = _dense_leaf(
                     lambda shape=shape: jnp.zeros(shape, pdt),
+                    sharding_for(bspec[name]))
+            elif isinstance(std, str):
+                # a state-space mixer's own draws (decays, step bias,
+                # skip, conv taps): small, never a quant target
+                out[name] = _dense_leaf(
+                    lambda how=std, shape=shape, k=next(keys):
+                    drawn_leaf(how, shape, k).astype(pdt),
                     sharding_for(bspec[name]))
             elif name in targets:
                 out[name] = _quantized_leaf(
@@ -149,8 +157,8 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
             normal_maker((cfg.vocab_size, D), 0.02, next(keys)),
             sharding_for(specs["embed"])),
         "blocks": [block(specs["blocks"][p], cfg.n_repeats,
-                         cfg.scan_mlp_kind, keys)
-                   for p in range(len(cfg.block_pattern))],
+                         cfg.scan_mlp_kind, block_kind, keys)
+                   for p, block_kind in enumerate(cfg.block_pattern)],
         "final_norm": _dense_leaf(norm_maker((D,)),
                                   sharding_for(specs["final_norm"])),
     }
@@ -160,6 +168,7 @@ def _init_quantized_params(cfg: ModelConfig, key: jax.Array, *,
             sharding_for(specs["lm_head"]))
     if cfg.prologue_layers:
         params["prologue"] = [
-            block(specs["prologue"][i], 1, cfg.mlp_kind(i), pkeys)
+            block(specs["prologue"][i], 1, cfg.mlp_kind(i),
+                  cfg.block_kind(i), pkeys)
             for i in range(cfg.prologue_layers)]
     return params

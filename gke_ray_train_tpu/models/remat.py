@@ -35,8 +35,9 @@ from gke_ray_train_tpu.models.config import ModelConfig
 # device seconds saved per byte kept (the second flash forward, the
 # q/k/v projections with rope, the output projection: PERF.md, PR 25)
 KEEP_ORDER: Tuple[str, ...] = ("mlp/gate_up", "attn/core", "attn/qkv",
-                               "attn/out", "attn/latent", "moe/shared",
-                               "moe/experts")
+                               "attn/out", "attn/latent", "ssm/scan",
+                               "ssm/out", "moe/shared", "moe/experts",
+                               "ssm/in_proj")
 # `attn/latent` (a latent-attention layer's two down-projections'
 # outputs) follows `attn/out`: a position's 1,344 values spare 2.75 M
 # weights of products where the output projection's 2,048 spare 10.5 M
@@ -47,6 +48,13 @@ KEEP_ORDER: Tuple[str, ...] = ("mlp/gate_up", "attn/core", "attn/qkv",
 # width, and `moe/experts` (gate and up of every row of the pair buffer)
 # pays for the buffer's worst case, eight times the rows that are real
 # at one rank of eight (PERF.md, PR 26)
+# a state-space layer's three names (models/transformer.py::_ssm):
+# `ssm/scan` (the scan's output, 2 bytes a value of the mixer's heads)
+# spares the conv and the scan their second run, the slowest work a
+# byte in such a layer as XLA writes the scan (PERF.md, PR 32), so it
+# leads the routed layer's names; `ssm/out` is `attn/out`'s like;
+# `ssm/in_proj` (the first projection's output, twice the heads' values
+# and more) is last: where it fits, everything does
 
 # XLA's peak grows by less than a kept tensor's stacked bytes: the layer
 # in flight was among the block's temporaries already, and the backward
@@ -99,24 +107,30 @@ def keep_candidates(cfg: ModelConfig, rows: int, seq: int, *,
     heads = math.ceil(cfg.n_heads / model)
     kv_heads = math.ceil(cfg.n_kv_heads / model)
     n_moe = sum(cfg.mlp_kind(i) == "moe" for i in range(cfg.n_layers))
-    routed = n_moe if cfg.router == "sigmoid" else 0
+    routed = n_moe if cfg.dropless_router else 0
     d_fe = math.ceil(cfg.resolved_expert_d_ff / model)
+    n_ssm = cfg.n_ssm_layers
+    n_attn = cfg.n_layers - n_ssm
     # (bytes a position, layers that have the name)
     per_position = {
         "attn/core": (heads * (hd * item + 4),          # o + float32 lse
-                      cfg.n_layers if flash else 0),
+                      n_attn if flash else 0),
         "mlp/gate_up": (2 * math.ceil(cfg.d_ff / model) * item,
                         cfg.n_layers - n_moe),
-        "attn/qkv": ((heads + 2 * kv_heads) * hd * item, cfg.n_layers),
-        "attn/out": (cfg.d_model * item, cfg.n_layers),
+        "attn/qkv": ((heads + 2 * kv_heads) * hd * item, n_attn),
+        "attn/out": (cfg.d_model * item, n_attn),
         # every device has the latents whole (models/transformer.py)
         "attn/latent": (_latent_width(cfg) * item,
-                        cfg.n_layers if cfg.latent_attention else 0),
-        "moe/shared": (2 * cfg.n_shared_experts * d_fe * item,
+                        n_attn if cfg.latent_attention else 0),
+        "moe/shared": (2 * _shared_width(cfg, model) * item,
                        routed if cfg.n_shared_experts else 0),
         "moe/experts": (2 * d_fe * item
                         * min(cfg.expert_top_k, max(cfg.n_experts_held, 1)),
                         routed),
+        # the mixer is not divided over `model`
+        "ssm/scan": (cfg.ssm_inner * item, n_ssm),
+        "ssm/out": (cfg.d_model * item, n_ssm),
+        "ssm/in_proj": (_ssm_in_width(cfg) * item, n_ssm),
     }
     return tuple((n, rows * seq * layers * nbytes)
                  for n, (nbytes, layers) in
@@ -129,6 +143,29 @@ def _latent_width(cfg: ModelConfig) -> int:
     if not cfg.latent_attention:
         return 0
     return cfg.q_lora_rank + cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def _shared_width(cfg: ModelConfig, model: int) -> int:
+    """Hidden values of the shared expert on one device."""
+    if cfg.shared_d_ff:
+        return math.ceil(cfg.shared_d_ff / model)
+    return cfg.n_shared_experts * math.ceil(
+        cfg.resolved_expert_d_ff / model)
+
+
+def _ssm_in_width(cfg: ModelConfig) -> int:
+    """Columns of a state-space mixer's first projection: the gate, the
+    conv's columns and a step a head (0 without such layers)."""
+    return cfg.ssm_inner + cfg.ssm_conv_dim + (cfg.ssm_heads or 0)
+
+
+def _ssm_io_width(cfg: ModelConfig) -> int:
+    """Values a position that the backward of a state-space mixer holds
+    where an attention layer holds q, k, v and o: the first
+    projection's output and twice the heads' values (the scan's output
+    and the gated, normed input of the second projection), fitted to
+    XLA's peak for the Granite-4.0-H-Small share (PERF.md, PR 32)."""
+    return _ssm_in_width(cfg) + 2 * cfg.ssm_inner
 
 
 def _latent_up_width(cfg: ModelConfig, model: int) -> int:
@@ -178,7 +215,10 @@ def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
     :func:`_routed_block_bytes` (PERF.md, PR 26). With latent attention
     (the GLM-4.7-Flash share on one described v5e chip at 47, 24 and 12
     layers, 8192 and 4096 positions) it reads 0.00-0.32 GB above XLA's
-    ``peak_memory_in_bytes`` (PERF.md, PR 30)."""
+    ``peak_memory_in_bytes`` (PERF.md, PR 30). A state-space layer is
+    sized as the block it stands in with the mixer's weights and
+    tensors in the attention's place (:func:`_ssm_io_width`; PERF.md,
+    PR 32)."""
     item = jnp.dtype(cfg.dtype).itemsize
     positions = rows * seq
     d_ff = math.ceil(cfg.d_ff / model)
@@ -194,7 +234,7 @@ def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
         attn_weights = sum(
             a * b // (model if name in ("wq_b", "wkv_b", "wo") else 1)
             for name, (a, b) in cfg.attn_leaf_shapes().items())
-    routed = cfg.n_experts > 0 and cfg.router == "sigmoid"
+    routed = cfg.dropless_router
     block_weights = attn_weights + (
         1 if routed else max(cfg.n_experts, 1)) * 3 * cfg.d_model * d_ff
     # float32 logits and their compute-dtype cotangent; this micro-batch
@@ -210,13 +250,45 @@ def working_set_bytes(cfg: ModelConfig, rows: int, seq: int, *,
                            2 * block_weights + positions * 2 * attn_io)
                 + trainable_full_bytes // cfg.n_layers)
     if routed:
+        # (weights, values a position) of each kind of mixer the model has
+        mixers = []
+        if cfg.n_ssm_layers < cfg.n_layers:
+            mixers.append((attn_weights, attn_io))
+        if cfg.n_ssm_layers:
+            mixers.append((cfg.d_model * _ssm_in_width(cfg)
+                           + cfg.ssm_inner * cfg.d_model,
+                           _ssm_io_width(cfg)))
         in_block = max(in_block if cfg.n_dense_layers else 0,
-                       _routed_block_bytes(cfg, positions, model, item,
-                                           attn_weights, attn_io)
+                       max(_routed_block_bytes(cfg, positions, model, item,
+                                               weights, io)
+                           for weights, io in mixers)
                        + trainable_full_bytes // cfg.n_layers)
+        if cfg.n_repeats > 1 and len(cfg.block_pattern) > 1:
+            in_block += _routed_loop_bytes(
+                cfg, positions, model, item,
+                max(weights for weights, _ in mixers))
     return (trainable_bytes + cast_bytes     # gradient accumulator, copy
             + cfg.n_layers * positions * cfg.d_model * item  # block inputs
             + max(at_loss, trainable_bytes + in_block))
+
+
+def _routed_loop_bytes(cfg: ModelConfig, positions: int, model: int,
+                       item: int, mixer_weights: int) -> int:
+    """What XLA's peak holds more where the layers of a routed period
+    run inside a real loop over the periods (several layers a period,
+    each under its own checkpoint, more than one period scanned):
+    fitted as one layer's weights in the compute dtype and one pair
+    buffer's gathered rows once more. The Granite-4.0-H-Small share on
+    one described v5e chip reads XLA's peak 1.17 GB (20 and 30 layers
+    at 8192 positions) and 0.72 GB (20 layers at 4096) above the
+    estimate without this term and 0.10 GB under it at one period (10
+    layers, where the loop is no loop); with it the estimate reads
+    0.08-0.20 GB above (PERF.md, PR 32)."""
+    bank = math.ceil(cfg.n_experts_held / model) * 3 * cfg.d_model \
+        * cfg.resolved_expert_d_ff
+    shared = 3 * cfg.d_model * _shared_width(cfg, model)
+    pairs = positions * min(cfg.expert_top_k, cfg.n_experts_held)
+    return item * (bank + mixer_weights + shared + pairs * cfg.d_model)
 
 
 def _routed_block_bytes(cfg: ModelConfig, positions: int, model: int,
@@ -233,7 +305,7 @@ def _routed_block_bytes(cfg: ModelConfig, positions: int, model: int,
     d_fe = math.ceil(cfg.resolved_expert_d_ff / model)
     bank = math.ceil(cfg.n_experts_held / model) * 3 * cfg.d_model \
         * cfg.resolved_expert_d_ff
-    shared = cfg.n_shared_experts * 3 * cfg.d_model * d_fe
+    shared = 3 * cfg.d_model * _shared_width(cfg, model)
     pairs = positions * min(cfg.expert_top_k, cfg.n_experts_held)
     return item * (bank + attn_weights + shared
                    + pairs * (4 * cfg.d_model + 3 * d_fe)

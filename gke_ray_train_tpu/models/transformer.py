@@ -76,17 +76,18 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         return (jnp.zeros(shape, pdt) if cfg.norm_scale_plus_one
                 else jnp.ones(shape, pdt))
 
-    def block_params(R, mlp_kind, normal=normal):
+    def block_params(R, mlp_kind, kind, normal=normal, keys=keys):
         return {name: norm(shape) if std is None
                 else jnp.zeros(shape, pdt) if std == 0.0
-                else normal(shape, std)
-                for name, (shape, std) in block_leaves(cfg, R, mlp_kind)
-                .items()}
+                else drawn_leaf(std, shape, next(keys)).astype(pdt)
+                if isinstance(std, str) else normal(shape, std)
+                for name, (shape, std) in block_leaves(cfg, R, mlp_kind,
+                                                       kind).items()}
 
     params: Params = {
         "embed": normal((cfg.vocab_size, cfg.d_model), 0.02),
-        "blocks": [block_params(cfg.n_repeats, cfg.scan_mlp_kind)
-                   for _ in cfg.block_pattern],
+        "blocks": [block_params(cfg.n_repeats, cfg.scan_mlp_kind, kind)
+                   for kind in cfg.block_pattern],
         "final_norm": norm((cfg.d_model,)),
     }
     if not cfg.tie_embeddings:
@@ -95,10 +96,32 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
         pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
                                       16 * cfg.prologue_layers))
         params["prologue"] = [
-            block_params(1, cfg.mlp_kind(i),
-                         lambda shape, std: normal(shape, std, pkeys))
+            block_params(1, cfg.mlp_kind(i), cfg.block_kind(i),
+                         lambda shape, std: normal(shape, std, pkeys),
+                         pkeys)
             for i in range(cfg.prologue_layers)]
     return params
+
+
+def drawn_leaf(how: str, shape, key: jax.Array) -> jnp.ndarray:
+    """A leaf of a state-space mixer that is neither a normal draw, a
+    norm scale nor zeros (``block_leaves`` names how), in float32, as
+    Mamba-2 initialises it so that the decays are real ones: ``a_log``
+    the log of uniform [1, 16]; ``dt_bias`` the inverse softplus of a
+    log-uniform step in [0.001, 0.1]; ``ones`` (the skip ``D``);
+    ``conv``: uniform +-1/sqrt(taps)."""
+    if how == "a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if how == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    if how == "ones":
+        return jnp.ones(shape, jnp.float32)
+    if how == "conv":
+        bound = 1.0 / math.sqrt(shape[-1])
+        return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+    raise ValueError(f"unknown draw {how!r}")
 
 
 def block_layout(cfg: ModelConfig):
@@ -113,17 +136,29 @@ def block_layout(cfg: ModelConfig):
                for i in range(cfg.prologue_layers)])
 
 
-def block_leaves(cfg: ModelConfig, R: int, mlp_kind: str) -> Dict[str, Any]:
+def block_leaves(cfg: ModelConfig, R: int, mlp_kind: str,
+                 kind: str = "global") -> Dict[str, Any]:
     """``{leaf: (shape, std)}`` of one pattern position stacked over
     ``R`` layers, in creation order (the order the init keys are drawn
     in). ``std`` None marks a norm scale, 0.0 a leaf that starts at
-    zero; ``mlp_kind``: "dense" | "moe" (``cfg.mlp_kind``)."""
+    zero, a string one of :func:`drawn_leaf`'s draws; ``mlp_kind``:
+    "dense" | "moe" (``cfg.mlp_kind``); ``kind``: the layer's kind in
+    ``cfg.block_pattern`` (``cfg.block_kind``): an "ssm" layer has the
+    mixer's leaves where the others have the attention's, under the
+    same first norm (``attn_norm``)."""
     hd = cfg.resolved_head_dim
     D, F, H, K = cfg.d_model, cfg.d_ff, cfg.n_heads, cfg.n_kv_heads
     std = 0.02
     down = std * (1.0 / math.sqrt(2 * cfg.n_layers))
     out = {"attn_norm": ((R, D), None)}
-    for name, shape in cfg.attn_leaf_shapes().items():
+    ssm_draws = {"in_proj": std, "out_proj": down, "conv_w": "conv",
+                 "conv_b": std, "dt_bias": "dt_bias", "a_log": "a_log",
+                 "d_skip": "ones", "ssm_norm": None}
+    for name, shape in (cfg.ssm_leaf_shapes() if kind == "ssm"
+                        else {}).items():
+        out[name] = ((R,) + shape, ssm_draws[name])
+    for name, shape in ({} if kind == "ssm"
+                        else cfg.attn_leaf_shapes()).items():
         out[name] = ((R,) + shape, down if name == "wo" else std)
         # a latent layer norms each latent before its up-projection
         if name == "wq_a":
@@ -131,7 +166,7 @@ def block_leaves(cfg: ModelConfig, R: int, mlp_kind: str) -> Dict[str, Any]:
         elif name == "wkv_a":
             out["kv_latent_norm"] = ((R, cfg.kv_lora_rank), None)
     out["mlp_norm"] = ((R, D), None)
-    if cfg.attn_qkv_bias:
+    if cfg.attn_qkv_bias and kind != "ssm":
         # Qwen-2: bias on q/k/v only (o_proj stays bias-free);
         # zero-init — real values come from the HF checkpoint
         out.update(bq=((R, H * hd), 0.0), bk=((R, K * hd), 0.0),
@@ -146,14 +181,14 @@ def block_leaves(cfg: ModelConfig, R: int, mlp_kind: str) -> Dict[str, Any]:
         if cfg.router_bias:
             out["router_bias"] = ((R, E), 0.0)
         if cfg.n_shared_experts:
-            Fs = cfg.n_shared_experts * Fe
+            Fs = cfg.resolved_shared_d_ff
             out.update(shared_gate=((R, D, Fs), std),
                        shared_up=((R, D, Fs), std),
                        shared_down=((R, Fs, D), down))
     else:
         out.update(w_gate=((R, D, F), std), w_up=((R, D, F), std),
                    w_down=((R, F, D), down))
-    if cfg.qk_norm:
+    if cfg.qk_norm and kind != "ssm":
         out.update(q_norm=((R, hd), None), k_norm=((R, hd), None))
     if cfg.post_block_norm:
         out.update(attn_post_norm=((R, D), None),
@@ -201,6 +236,17 @@ def param_specs(cfg: ModelConfig) -> Params:
         "k_norm": P("pipe", None),
         "attn_post_norm": P("pipe", None),
         "mlp_post_norm": P("pipe", None),
+        # a state-space mixer: the two projections as wq / wo (the
+        # scan's heads are not divided over `model` yet: a mesh with
+        # model > 1 would gather in_proj's columns), the rest whole
+        "in_proj": P("pipe", "fsdp", None),
+        "out_proj": P("pipe", None, "fsdp"),
+        "conv_w": P("pipe", None, None),
+        "conv_b": P("pipe", None),
+        "dt_bias": P("pipe", None),
+        "a_log": P("pipe", None),
+        "d_skip": P("pipe", None),
+        "ssm_norm": P("pipe", None),
     }
     # expert dim over `model` = EP; GSPMD derives the token
     # all-to-alls from the dispatch einsums (ops/moe.py)
@@ -208,21 +254,21 @@ def param_specs(cfg: ModelConfig) -> Params:
             "w_up": P("pipe", "model", "fsdp", None),
             "w_down": P("pipe", "model", None, "fsdp")}
 
-    def block_specs(mlp_kind):
+    def block_specs(mlp_kind, kind):
         return {name: bank[name] if mlp_kind == "moe" and name in bank
                 else table[name]
-                for name in block_leaves(cfg, 1, mlp_kind)}
+                for name in block_leaves(cfg, 1, mlp_kind, kind)}
 
     specs: Params = {
         "embed": P("model", "fsdp"),
-        "blocks": [block_specs(cfg.scan_mlp_kind)
-                   for _ in cfg.block_pattern],
+        "blocks": [block_specs(cfg.scan_mlp_kind, kind)
+                   for kind in cfg.block_pattern],
         "final_norm": P(None),
     }
     if not cfg.tie_embeddings:
         specs["lm_head"] = P("fsdp", "model")
     if cfg.prologue_layers:
-        specs["prologue"] = [block_specs(cfg.mlp_kind(i))
+        specs["prologue"] = [block_specs(cfg.mlp_kind(i), cfg.block_kind(i))
                              for i in range(cfg.prologue_layers)]
     return specs
 
@@ -354,8 +400,9 @@ def _moe(x, lp, cfg: ModelConfig, dtype, segment_ids, token_weights,
 
     "softmax" (Mixtral): LoRA adapts attention only, there being no
     single delta-W an adapter pair could target across routed experts.
-    "sigmoid": the routed experts held here plus the shared expert,
-    which is a plain SwiGLU through `_proj` and takes adapters."""
+    "sigmoid" / "topk_softmax": the routed experts held here plus the
+    shared expert, which is a plain SwiGLU through `_proj` and takes
+    adapters."""
     from gke_ray_train_tpu.ops import moe
     if cfg.router == "softmax":
         y, aux = moe.moe_mlp(x, lp["router"], lp["w_gate"], lp["w_up"],
@@ -488,6 +535,69 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
         return checkpoint_name(proj(out, "wo", 3), "attn/out")
 
 
+def _ssm(x, lp, cfg: ModelConfig, dtype, segment_ids, lora_p=None,
+         lora_scale=1.0, drop_rng=None, drop_rate=0.0):
+    """The mixer of a state-space layer (Mamba-2), x [B, S, D] ->
+    [B, S, D]: ``[z | xBC | dt] = x W_in``; a causal depthwise conv and
+    SiLU over ``xBC``; the selective scan over ``[x | B | C]`` with
+    ``dt = softplus(dt + dt_bias)`` and ``A = -exp(a_log)``
+    (ops/ssm.py: in chunks, the state and the conv's taps starting
+    again at every document of ``segment_ids``); the gate ``silu(z)``,
+    then ONE RMSNorm over all the heads' values; ``W_out``.
+
+    Three names for the block checkpoints: ``ssm/in_proj`` (what the
+    first projection gives), ``ssm/scan`` (the scan's output, which
+    spares conv and scan their second run) and ``ssm/out``."""
+    from gke_ray_train_tpu.ops import ssm
+    B, S, _ = x.shape
+    H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                  cfg.ssm_groups)
+    inner = cfg.ssm_inner
+
+    def proj(h, name, tag):
+        return _proj(h, lp[name], _lora_entry(lora_p, name), lora_scale,
+                     dtype, _drop_key(drop_rng, tag), drop_rate)
+    with scope("ssm/in_proj"):
+        zxbcdt = checkpoint_name(proj(x, "in_proj", 0), "ssm/in_proj")
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:inner + cfg.ssm_conv_dim]
+        dt = zxbcdt[..., inner + cfg.ssm_conv_dim:]
+    with scope("ssm/conv"):
+        xbc = jax.nn.silu(ssm.causal_conv(
+            xbc, lp["conv_w"], lp.get("conv_b"), segment_ids))
+    with scope("ssm/scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + lp["dt_bias"].astype(jnp.float32))
+        y = ssm.ssd_scan(
+            xbc[..., :inner].reshape(B, S, H, P), dt,
+            -jnp.exp(lp["a_log"].astype(jnp.float32)),
+            xbc[..., inner:inner + G * N].reshape(B, S, G, N),
+            xbc[..., inner + G * N:].reshape(B, S, G, N),
+            lp["d_skip"], segment_ids, chunk=cfg.ssm_chunk)
+        y = checkpoint_name(y.reshape(B, S, inner), "ssm/scan")
+    with scope("ssm/gate_norm"):
+        y = rms_norm((y.astype(jnp.float32)
+                      * jax.nn.silu(z.astype(jnp.float32))).astype(dtype),
+                     lp["ssm_norm"], eps=cfg.norm_eps,
+                     scale_plus_one=cfg.norm_scale_plus_one)
+    with scope("ssm/out_proj"):
+        return checkpoint_name(proj(y, "out_proj", 3), "ssm/out")
+
+
+def ssm_geometry(cfg: ModelConfig, seq: int) -> dict:
+    """The scan's geometry for rows of ``seq`` positions (the
+    ``step_build`` span's ``ssm_scan``): ``{}`` for a model without
+    state-space layers."""
+    if "ssm" not in cfg.block_pattern:
+        return {}
+    from gke_ray_train_tpu.ops.ssm import HEAD_BLOCK, scan_geometry
+    chunk, chunks = scan_geometry(seq, cfg.ssm_chunk)
+    return {"chunk": chunk, "chunks_a_row": chunks,
+            "heads": cfg.ssm_heads, "head_dim": cfg.ssm_head_dim,
+            "state": cfg.ssm_state, "groups": cfg.ssm_groups,
+            "head_block": HEAD_BLOCK, "layers": cfg.n_ssm_layers}
+
+
 def attn_kind_scope(cfg: ModelConfig, kind: str) -> Optional[str]:
     """The leaf scope inside ``attn/core`` that a layer of block kind
     ``kind`` runs its attention under, so that a profile tells the
@@ -498,6 +608,12 @@ def attn_kind_scope(cfg: ModelConfig, kind: str) -> Optional[str]:
     if len(set(cfg.block_pattern)) > 1:
         return "window" if kind == "sliding" else "full"
     return None
+
+
+def attention_kinds(cfg: ModelConfig) -> tuple:
+    """The kinds of ``cfg.block_pattern`` that are attention, in order
+    of first appearance."""
+    return tuple(k for k in dict.fromkeys(cfg.block_pattern) if k != "ssm")
 
 
 def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
@@ -532,21 +648,26 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
         with scope("attn_norm"):
             h = _rms_norm(x, lp["attn_norm"], eps=eps, scale_plus_one=sp1,
                           fused_ops=fused_ops, mesh=mesh)
-        h = _attn(h, lp, cfg, impl, dtype,
-                  rope if kind in cfg.rope_kinds else None, positions,
-                  masks[kind],
-                  cfg.sliding_window if kind == "sliding" else None,
-                  segment_ids, mesh, lora_p=lo, lora_scale=lora_scale,
-                  drop_rng=_drop_key(drng, 0), drop_rate=lora_dropout,
-                  fused_ops=fused_ops, kind=attn_kind_scope(cfg, kind))
-        with scope("attn/out"):
+        if kind == "ssm":
+            h = _ssm(h, lp, cfg, dtype, segment_ids, lora_p=lo,
+                     lora_scale=lora_scale, drop_rng=_drop_key(drng, 0),
+                     drop_rate=lora_dropout)
+        else:
+            h = _attn(h, lp, cfg, impl, dtype,
+                      rope if kind in cfg.rope_kinds else None, positions,
+                      masks[kind],
+                      cfg.sliding_window if kind == "sliding" else None,
+                      segment_ids, mesh, lora_p=lo, lora_scale=lora_scale,
+                      drop_rng=_drop_key(drng, 0), drop_rate=lora_dropout,
+                      fused_ops=fused_ops, kind=attn_kind_scope(cfg, kind))
+        with scope("ssm/out_proj" if kind == "ssm" else "attn/out"):
             # the post-norm and the residual add belong to the output
             # projection they finish (XLA fuses them into it)
             if cfg.post_block_norm:
                 h = _rms_norm(h, lp["attn_post_norm"], eps=eps,
                               scale_plus_one=sp1, fused_ops=fused_ops,
                               mesh=mesh)
-            x = x + h
+            x = x + _residual(h, cfg, dtype)
             x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
         with scope("mlp_norm"):
             h = _rms_norm(x, lp["mlp_norm"], eps=eps, scale_plus_one=sp1,
@@ -568,7 +689,7 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
                 h = _rms_norm(h, lp["mlp_post_norm"], eps=eps,
                               scale_plus_one=sp1, fused_ops=fused_ops,
                               mesh=mesh)
-            x = x + h
+            x = x + _residual(h, cfg, dtype)
             x = _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
         return x, aux
 
@@ -583,6 +704,13 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
                      jax.random.fold_in(rep_rng, p)
                      if rep_rng is not None else None)
     return x, aux
+
+
+def _residual(h, cfg: ModelConfig, dtype):
+    """A sublayer's output as the residual stream takes it."""
+    if cfg.residual_multiplier == 1.0:
+        return h
+    return h * jnp.asarray(cfg.residual_multiplier, dtype)
 
 
 def resolve_seq_impl(cfg: ModelConfig, mesh, S: int) -> str:
@@ -621,7 +749,7 @@ def flash_grids(cfg: ModelConfig, mesh, rows: int, seq: int) -> dict:
         return {}
     calls = rows * max(cfg.n_heads // axes.get("model", 1), 1)
     grids, group = {}, cfg.n_heads // cfg.n_kv_heads
-    for kind in dict.fromkeys(cfg.block_pattern):
+    for kind in attention_kinds(cfg):
         block_q, block_kv, bands = call_plan(
             seq, seq, causal=True, rows_ordered=True, q_per_kv=group,
             head_dim=cfg.resolved_head_dim,
@@ -707,6 +835,8 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
         x = params["embed"].astype(dtype)[tokens]
         if cfg.embed_scale:
             x = x * jnp.asarray(math.sqrt(cfg.d_model), dtype)
+        if cfg.embed_multiplier is not None:
+            x = x * jnp.asarray(cfg.embed_multiplier, dtype)
         if cfg.positional == "sinusoidal":
             table = jnp.asarray(
                 sinusoidal_positions(cfg.max_seq_len, cfg.d_model))
@@ -735,12 +865,14 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
                 "LORA_DROPOUT=0 or pipe=1")
         if cfg.prologue_layers or cfg.qk_norm or cfg.router != "softmax" \
                 or set(cfg.rope_kinds) != {"global", "sliding"} \
-                or cfg.latent_attention:
+                or cfg.latent_attention or "ssm" in cfg.block_pattern \
+                or cfg.residual_multiplier != 1.0:
             raise NotImplementedError(
                 f"{cfg.name}: a pipelined mesh runs its own copy of the "
                 "block (models/pipeline.py), which has no prologue of "
                 "leading layers, no q/k norm, no per-kind rotary, no "
-                "sigmoid router and no latent attention yet; use pipe=1")
+                "dropless router, no latent attention, no state-space "
+                "layer and no residual multiplier yet; use pipe=1")
         from gke_ray_train_tpu.models.pipeline import pipeline_blocks
         x, pipe_aux = pipeline_blocks(
             x, params["blocks"], cfg, mesh, impl=impl, dtype=dtype,
@@ -760,7 +892,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig, *,
     # Kernel impls (flash/ring) build masks blockwise in-kernel instead.
     masks = {kind: None for kind in set(cfg.block_pattern)}
     if impl == "xla":
-        for kind in masks:
+        for kind in attention_kinds(cfg):
             masks[kind] = make_attention_mask(
                 positions, positions, segment_ids, segment_ids, causal=True,
                 sliding_window=(cfg.sliding_window if kind == "sliding"
@@ -838,9 +970,19 @@ def pre_unembed(x, params: Params, cfg: ModelConfig, mesh):
     (ops/fused_ce.py) takes it together with :func:`unembed_head` so
     the [B, S, vocab] logits never materialize in HBM."""
     with scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
-                     scale_plus_one=cfg.norm_scale_plus_one)
+        x = _final_norm(x, params, cfg)
         return _constrain(x, mesh, BATCH_AXES, AXIS_CONTEXT, None)
+
+
+def _final_norm(x, params: Params, cfg: ModelConfig):
+    """The last norm; ``cfg.logits_scaling`` divides the logits, which
+    are linear in what this returns, so it divides here (one place for
+    the materialized head and the fused cross-entropy)."""
+    x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
+                 scale_plus_one=cfg.norm_scale_plus_one)
+    if cfg.logits_scaling != 1.0:
+        x = x * jnp.asarray(1.0 / cfg.logits_scaling, x.dtype)
+    return x
 
 
 def unembed_head(params: Params, cfg: ModelConfig):
@@ -851,8 +993,7 @@ def unembed_head(params: Params, cfg: ModelConfig):
 def _unembed(x, params: Params, cfg: ModelConfig, dtype, mesh):
     """Shared tail: final norm → (tied) unembedding → logit softcap."""
     with scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], eps=cfg.norm_eps,
-                     scale_plus_one=cfg.norm_scale_plus_one)
+        x = _final_norm(x, params, cfg)
     with scope("unembed"):
         logits = jnp.einsum("bsd,dv->bsv", x, unembed_head(params, cfg
                                                            ).astype(dtype),

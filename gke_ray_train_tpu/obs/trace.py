@@ -136,10 +136,13 @@ SPAN_NAMES: Dict[str, tuple] = {
     # the block checkpoints keep beside their inputs (train/remat.py);
     # `flash_grid`: grid steps a flash call visits against its
     # rectangular grid's, by attention kind and kernel
-    # (models/transformer.py::flash_grids)
+    # (models/transformer.py::flash_grids). `ssm_scan`: the geometry of
+    # a state-space layer's scan for the step's rows (chunk, chunks a
+    # row, heads, head size, state, groups, heads a block, layers;
+    # models/transformer.py::ssm_geometry), {} without such layers
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
                    "remat_budget_bytes", "remat_args_bytes",
-                   "remat_keep_fallback", "flash_grid"),
+                   "remat_keep_fallback", "flash_grid", "ssm_scan"),
     "step_lower": (),
     "step_compile": (),
 }
@@ -176,7 +179,13 @@ SCOPE_NAMES = (
     "base", "lora", "window", "full",
     # a latent-attention layer: each latent's down-projection, norm and
     # up-projection, and the leaf scope its attention runs under
-    "attn/q_latent", "attn/kv_latent", "latent")
+    "attn/q_latent", "attn/kv_latent", "latent",
+    # a state-space layer's mixer (models/transformer.py::_ssm): the
+    # first projection, the causal conv with its SiLU, the selective
+    # scan (ops/ssm.py), the gate with its norm, and the second
+    # projection with the residual add
+    "ssm/in_proj", "ssm/conv", "ssm/scan", "ssm/gate_norm",
+    "ssm/out_proj")
 
 # bumped when a scope MOVES without the vocabulary changing: it rides
 # the compile cache's key beside the names (perf/cache.py::names_salt),
@@ -184,7 +193,8 @@ SCOPE_NAMES = (
 # 2: `moe/experts` no longer holds the routed layer's residual add
 # 3: a latent-attention layer projects under `attn/q_latent` and
 #    `attn/kv_latent`, and its `attn/rope` holds the assembly of q and k
-SCOPE_VERSION = 3
+# 4: a state-space layer's mixer runs under the `ssm/` names
+SCOPE_VERSION = 4
 
 # pl.pallas_call(name=...) of every kernel (ops/flash_attention.py,
 # ops/fused_ce.py, ops/fused_norm_rope.py), then the library's kernels

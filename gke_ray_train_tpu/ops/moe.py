@@ -23,8 +23,10 @@ dtype.
 
 That is the ``router="softmax"`` layer (Mixtral), and it stays as it is:
 its capacity einsums are what GSPMD partitions into the expert exchange.
-``router="sigmoid"`` (DeepSeek-V3's layer, K-EXAONE's) is
-:func:`routed_experts` below: no capacity and no drop. The (token, held
+``router="sigmoid"`` (DeepSeek-V3's layer, K-EXAONE's) and
+``router="topk_softmax"`` (Granite's: the largest logits selected, their
+softmax the weights) are :func:`routed_experts` below: no capacity and
+no drop. The (token, held
 expert) pairs are sorted by expert into a buffer sized for the worst
 case the held share allows (every one of a token's picks held), and the
 three products run over the real group sizes (:func:`grouped_dot`: a
@@ -47,7 +49,7 @@ from jax.ad_checkpoint import checkpoint_name
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.obs.trace import scope
 
-# per-step counters of the sigmoid layer, in the train step's metrics:
+# per-step counters of the dropless layer, in the train step's metrics:
 # pairs dispatched to held experts (summed over layers and
 # micro-batches), the fullest held expert's pairs over the mean (the
 # largest of any layer), and picks of held experts that found no room
@@ -240,12 +242,18 @@ def pair_buffer_rows(cfg: ModelConfig, tokens: int) -> int:
 def select_experts(x, router_w, router_bias, cfg: ModelConfig):
     """x [T, D] -> (idx [T, K] int32, weights [T, K] float32).
 
-    Scores ``sigmoid(x R)`` in float32; the K experts with the largest
-    ``score + bias`` are selected; the weights come from the scores
-    alone, renormalised over the K selected and scaled."""
-    s = jax.nn.sigmoid(jnp.einsum(
+    "sigmoid": scores ``sigmoid(x R)`` in float32; the K experts with
+    the largest ``score + bias`` are selected; the weights come from the
+    scores alone, renormalised over the K selected and scaled.
+    "topk_softmax": the K largest logits ``x R`` are selected and the
+    weights are the softmax over those K logits."""
+    logits = jnp.einsum(
         "td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
+        precision=jax.lax.Precision.HIGHEST)
+    if cfg.router == "topk_softmax":
+        top, idx = jax.lax.top_k(logits, cfg.expert_top_k)
+        return idx, jax.nn.softmax(top, axis=-1) * cfg.router_scale
+    s = jax.nn.sigmoid(logits)
     biased = s if router_bias is None \
         else s + router_bias.astype(jnp.float32)
     _, idx = jax.lax.top_k(biased, cfg.expert_top_k)
@@ -315,7 +323,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 def routed_experts(x: jnp.ndarray, lp: dict, cfg: ModelConfig, dtype,
                    valid: jnp.ndarray = None,
                    buffer_rows: int = None) -> tuple:
-    """The routed part of the sigmoid layer for the experts held here:
+    """The routed part of the dropless layer for the experts held here:
     x [B, S, D] -> (y [B, S, D], counters).
 
     ``lp``: ``router`` [D, E], optional ``router_bias`` [E], the held

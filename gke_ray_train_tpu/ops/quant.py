@@ -52,9 +52,10 @@ DEFAULT_GROUP = 64
 # the shared canonical tuple lives in models.config (leaf module) so
 # quantize→merge→export stay structurally in sync without a train↔ops cycle
 from gke_ray_train_tpu.models.config import (
-    LATENT_TARGETS, PROJ_TARGETS, SHARED_TARGETS)
+    LATENT_TARGETS, PROJ_TARGETS, SHARED_TARGETS, SSM_TARGETS)
 
-QUANT_TARGETS = PROJ_TARGETS + SHARED_TARGETS + LATENT_TARGETS
+QUANT_TARGETS = PROJ_TARGETS + SHARED_TARGETS + LATENT_TARGETS \
+    + SSM_TARGETS
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from gke_ray_train_tpu.models.config import (
-    LATENT_TARGETS, ModelConfig, PROJ_TARGETS, SHARED_TARGETS)
+    LATENT_TARGETS, ModelConfig, PROJ_TARGETS, SHARED_TARGETS, SSM_TARGETS)
 from gke_ray_train_tpu.models.transformer import (
     Params, block_layout, block_leaves)
 
@@ -58,14 +58,20 @@ _SHARED_OF = dict(zip(("w_gate", "w_up", "w_down"), SHARED_TARGETS))
 # gets the two matrices (down and up) that make it there
 _LATENT_OF = {"wq": ("wq_a", "wq_b"), "wk": ("wkv_a", "wkv_b"),
               "wv": ("wkv_a", "wkv_b")}
+# nor has a state-space layer: its mixer's first projection stands
+# where q, k and v would, its second where the output projection would
+_SSM_OF = {"wq": ("in_proj",), "wk": ("in_proj",), "wv": ("in_proj",),
+           "wo": ("out_proj",)}
 
 
 def _effective_targets(cfg: ModelConfig, lora_cfg: LoraConfig,
-                       mlp_kind: str = None):
+                       mlp_kind: str = None, kind: str = "global"):
     """The leaves of one block that get an (A, B) pair, from the job's
     target list. Attention: the names as given, or, in a
     latent-attention layer (``cfg.latent_attention``), the layer's own
-    matrices in their place (so the default list adapts all five). MLP:
+    matrices in their place (so the default list adapts all five); in a
+    state-space layer (``kind == "ssm"``) the mixer's two projections
+    in the place of the attention's four. MLP:
     a routed expert bank has no single delta-W a pair could target (peft
     does the same for Mixtral by default), so a routed layer adapts,
     where it has one, its shared expert (under the shared leaves' own
@@ -74,7 +80,11 @@ def _effective_targets(cfg: ModelConfig, lora_cfg: LoraConfig,
         mlp_kind = cfg.scan_mlp_kind
     out = []
     for t in lora_cfg.targets:
-        if t in ATTN_TARGETS or t in LATENT_TARGETS:
+        if kind == "ssm" and (t in ATTN_TARGETS or t in SSM_TARGETS):
+            names = _SSM_OF.get(t, (t,))
+        elif t in SSM_TARGETS:
+            names = ()
+        elif t in ATTN_TARGETS or t in LATENT_TARGETS:
             names = _LATENT_OF.get(t, (t,)) if cfg.latent_attention \
                 else (t,)
         elif mlp_kind != "moe":
@@ -96,20 +106,24 @@ def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
     to the compute dtype at use (_proj)."""
     pdt = jnp.dtype(jnp.float32)
     n_scan = len(cfg.block_pattern)
-    scan_targets = _effective_targets(cfg, lora_cfg)
-    keys = iter(jax.random.split(key, n_scan * len(scan_targets) + 1))
+    # as many keys a scanned position as its kind with most targets has
+    # (models of one kind: that kind's)
+    scan_targets = max(len(_effective_targets(cfg, lora_cfg, kind=kind))
+                       for kind in cfg.block_pattern)
+    keys = iter(jax.random.split(key, n_scan * scan_targets + 1))
     # seven a leading layer, as when seven projections were all a layer
     # had (a latent-attention layer with a dense MLP has eight)
     a_layer = max([7] + [len(_effective_targets(cfg, lora_cfg,
-                                                cfg.mlp_kind(i)))
+                                                cfg.mlp_kind(i),
+                                                cfg.block_kind(i)))
                          for i in range(cfg.prologue_layers)])
     pkeys = iter(jax.random.split(jax.random.fold_in(key, 7),
                                   a_layer * max(cfg.prologue_layers, 1)))
 
-    def block(R, mlp_kind, keys):
-        shapes = block_leaves(cfg, R, mlp_kind)
+    def block(R, mlp_kind, kind, keys):
+        shapes = block_leaves(cfg, R, mlp_kind, kind)
         out = {}
-        for t in _effective_targets(cfg, lora_cfg, mlp_kind):
+        for t in _effective_targets(cfg, lora_cfg, mlp_kind, kind):
             _, d_in, d_out = shapes[t][0]
             out[t] = {
                 "a": (jax.random.normal(next(keys), (R, d_in, lora_cfg.r),
@@ -120,9 +134,10 @@ def init_lora(cfg: ModelConfig, lora_cfg: LoraConfig, key: jax.Array) -> Params:
         return out
 
     tree: Params = {}
-    for where, _, _, R, _, kind in block_layout(cfg):
+    for where, _, first, R, _, mlp_kind in block_layout(cfg):
         tree.setdefault(where, []).append(
-            block(R, kind, keys if where == "blocks" else pkeys))
+            block(R, mlp_kind, cfg.block_kind(first),
+                  keys if where == "blocks" else pkeys))
     return tree
 
 
@@ -135,22 +150,23 @@ def lora_specs(cfg: ModelConfig, lora_cfg: LoraConfig) -> Params:
                "shared_gate": "fsdp", "shared_up": "fsdp",
                "shared_down": "model",
                "wq_a": "fsdp", "wq_b": "fsdp", "wkv_a": "fsdp",
-               "wkv_b": "fsdp"}
+               "wkv_b": "fsdp", "in_proj": "fsdp", "out_proj": None}
     out_spec = {"wq": "model", "wk": "model", "wv": "model", "wo": "fsdp",
                 "w_gate": "model", "w_up": "model", "w_down": "fsdp",
                 "shared_gate": "model", "shared_up": "model",
                 "shared_down": "fsdp",
                 "wq_a": None, "wq_b": "model", "wkv_a": None,
-                "wkv_b": "model"}
+                "wkv_b": "model", "in_proj": None, "out_proj": "fsdp"}
 
     tree: Params = {}
-    for where, _, _, _, _, kind in block_layout(cfg):
+    for where, _, first, _, _, mlp_kind in block_layout(cfg):
         # leading repeat dim follows the base weights onto `pipe`
         # (no-op while the pipe axis is size 1)
         tree.setdefault(where, []).append(
             {t: {"a": P("pipe", in_spec[t], None),
                  "b": P("pipe", None, out_spec[t])}
-             for t in _effective_targets(cfg, lora_cfg, kind)})
+             for t in _effective_targets(cfg, lora_cfg, mlp_kind,
+                                         cfg.block_kind(first))})
     return tree
 
 

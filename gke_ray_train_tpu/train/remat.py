@@ -28,7 +28,7 @@ from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.remat import (
     KEPT_PEAK_SHARE, choose_keep, keep_candidates, working_set_bytes)
 from gke_ray_train_tpu.models.transformer import (
-    flash_grids, resolve_seq_impl)
+    flash_grids, resolve_seq_impl, ssm_geometry)
 from gke_ray_train_tpu.ops.quant import stored_bits
 from gke_ray_train_tpu.perf.cache import StepFallback, build_or_load_step
 
@@ -156,7 +156,9 @@ class StepRemat:
         choice = self.choose(state, batch)
         # the same for either step: the checkpoints move no kernel
         grid = {"flash_grid": flash_grids(self.cfg, self.mesh,
-                                          *self.micro_shape(batch))}
+                                          *self.micro_shape(batch)),
+                "ssm_scan": ssm_geometry(self.cfg,
+                                         self.micro_shape(batch)[1])}
         if choice.keep:
             built = build_or_load_step(
                 self.with_keep(choice.keep), state, batch, label=label,

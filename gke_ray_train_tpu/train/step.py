@@ -249,9 +249,9 @@ def make_train_step(cfg: ModelConfig,
     lora_mode = lora_cfg is not None
     lora_dropout = lora_cfg.dropout if lora_mode else 0.0
     moe = cfg.n_experts > 0
-    # the sigmoid router's layer counts its pairs (ops/moe.py); the
+    # the dropless routed layer counts its pairs (ops/moe.py); the
     # counts ride the micro-batch scan into the step's metrics
-    counter_names = COUNTERS if moe and cfg.router == "sigmoid" else ()
+    counter_names = COUNTERS if cfg.dropless_router else ()
     overlap = plan.overlap if plan is not None else "off"
     fused_ops = plan.fused_ops if plan is not None else False
     # fused cross-entropy (ops/fused_ce.py) replaces materialized

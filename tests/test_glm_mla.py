@@ -424,7 +424,9 @@ def test_chooser_names_and_bytes_of_the_latent_layer():
         "attn/latent": T * 47 * (768 + 512 + 64) * 2,
         "moe/shared": T * 46 * 2 * 1536 * 2,
         "moe/experts": T * 46 * 2 * 1536 * 2 * 4}
-    assert list(got) == list(remat.KEEP_ORDER)
+    # every name but a state-space layer's, in the chooser's order
+    assert list(got) == [n for n in remat.KEEP_ORDER
+                         if not n.startswith("ssm/")]
     # the latents divide over no tensor-parallel axis: every device has
     # them whole
     assert dict(remat.keep_candidates(glm_share(), 1, T, model=4))[
