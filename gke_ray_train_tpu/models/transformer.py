@@ -586,16 +586,22 @@ def _ssm(x, lp, cfg: ModelConfig, dtype, segment_ids, lora_p=None,
 
 def ssm_geometry(cfg: ModelConfig, seq: int) -> dict:
     """The scan's geometry for rows of ``seq`` positions (the
-    ``step_build`` span's ``ssm_scan``): ``{}`` for a model without
+    ``step_build`` span's ``ssm_scan``): which form the rows take
+    (``impl``: ``pallas`` / ``xla``, ops/ssm.py::scan_plan), the heads
+    of a grid step (of a block of the quadratic part under ``xla``) and
+    the grid steps of one kernel call a row; ``{}`` for a model without
     state-space layers."""
     if "ssm" not in cfg.block_pattern:
         return {}
-    from gke_ray_train_tpu.ops.ssm import HEAD_BLOCK, scan_geometry
-    chunk, chunks = scan_geometry(seq, cfg.ssm_chunk)
-    return {"chunk": chunk, "chunks_a_row": chunks,
-            "heads": cfg.ssm_heads, "head_dim": cfg.ssm_head_dim,
-            "state": cfg.ssm_state, "groups": cfg.ssm_groups,
-            "head_block": HEAD_BLOCK, "layers": cfg.n_ssm_layers}
+    from gke_ray_train_tpu.ops.ssm import scan_plan
+    plan = scan_plan(seq, cfg.ssm_chunk, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_state, cfg.ssm_groups)
+    return {"impl": plan.impl, "chunk": plan.chunk,
+            "chunks_a_row": plan.chunks, "heads": cfg.ssm_heads,
+            "head_dim": cfg.ssm_head_dim, "state": cfg.ssm_state,
+            "groups": cfg.ssm_groups, "head_block": plan.head_block,
+            "grid_steps_a_row": plan.grid_steps(cfg.ssm_heads),
+            "layers": cfg.n_ssm_layers}
 
 
 def attn_kind_scope(cfg: ModelConfig, kind: str) -> Optional[str]:

@@ -137,9 +137,11 @@ SPAN_NAMES: Dict[str, tuple] = {
     # `flash_grid`: grid steps a flash call visits against its
     # rectangular grid's, by attention kind and kernel
     # (models/transformer.py::flash_grids). `ssm_scan`: the geometry of
-    # a state-space layer's scan for the step's rows (chunk, chunks a
-    # row, heads, head size, state, groups, heads a block, layers;
-    # models/transformer.py::ssm_geometry), {} without such layers
+    # a state-space layer's scan for the step's rows (the form they
+    # take, `impl`: pallas / xla; chunk, chunks a row, heads, head size,
+    # state, groups, heads a grid step or block, grid steps of a kernel
+    # call a row, layers; models/transformer.py::ssm_geometry), {}
+    # without such layers
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
                    "remat_budget_bytes", "remat_args_bytes",
                    "remat_keep_fallback", "flash_grid", "ssm_scan"),
@@ -197,14 +199,15 @@ SCOPE_NAMES = (
 SCOPE_VERSION = 4
 
 # pl.pallas_call(name=...) of every kernel (ops/flash_attention.py,
-# ops/fused_ce.py, ops/fused_norm_rope.py), then the library's kernels
-# the program calls under the names the library gave them: jax's
-# megablox grouped matmul and its transpose (ops/moe.py)
+# ops/fused_ce.py, ops/fused_norm_rope.py, ops/ssm.py), then the
+# library's kernels the program calls under the names the library gave
+# them: jax's megablox grouped matmul and its transpose (ops/moe.py)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
 KERNEL_NAMES = (
     "flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd", "fused_ce_dx",
     "fused_ce_dhead", "fused_rmsnorm", "fused_rope_qk",
-    "fused_rmsnorm_rope") + LIBRARY_KERNEL_NAMES
+    "fused_rmsnorm_rope", "ssd_fwd", "ssd_states", "ssd_bwd",
+    ) + LIBRARY_KERNEL_NAMES
 
 # the profiler's host plane shows a region under this prefix
 ANNOTATION_PREFIX = "grt:"

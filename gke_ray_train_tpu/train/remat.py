@@ -191,4 +191,12 @@ class StepRemat:
                     f"{k} {g[k][0]}/{g[k][1]}" for k in ("fwd", "dq", "dkv"))
                 for kind, g in grid["flash_grid"].items())
             or "no flash kernel walks its own rows")
+        if grid["ssm_scan"]:
+            g = grid["ssm_scan"]
+            logger.info(
+                "%s: state-space scan as %s: %d chunks of %d a row, %d "
+                "heads a %s, %d grid steps a kernel call and row", label,
+                g["impl"], g["chunks_a_row"], g["chunk"], g["head_block"],
+                "grid step" if g["impl"] == "pallas" else "block",
+                g["grid_steps_a_row"])
         return built

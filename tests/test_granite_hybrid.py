@@ -10,6 +10,8 @@ checkpoints' names, the scopes, the refusals.
 """
 
 import dataclasses
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -286,6 +288,80 @@ def test_chunked_scan_is_the_recurrence(chunk):
                                        err_msg=name)
     assert ssm.scan_geometry(48, chunk) == {8: (8, 6), 16: (16, 3),
                                             48: (48, 1), 5: (4, 12)}[chunk]
+
+
+def kernel_inputs(bounds, H=16, G=1, S=512, P=64, N=128, seed=0):
+    """A row of 512 positions in chunks of 128 at sizes the kernels
+    take (heads of 64, a state of 128): documents cut at ``bounds``."""
+    k = jax.random.split(jax.random.key(seed), 7)
+    seg = np.ones((1, S), np.int32)
+    for at in bounds:
+        seg[0, at:] += 1
+    return (jax.random.normal(k[0], (1, S, H, P)),
+            0.3 * jax.nn.softplus(jax.random.normal(k[1], (1, S, H))),
+            -jnp.exp(jax.random.normal(k[2], (H,))),
+            0.3 * jax.random.normal(k[3], (1, S, G, N)),
+            0.3 * jax.random.normal(k[4], (1, S, G, N)),
+            jax.random.normal(k[5], (H,))), jnp.asarray(seg), \
+        jax.random.normal(k[6], (1, S, H, P))
+
+
+@pytest.mark.parametrize("bounds,groups,heads_a_step", [
+    ((), 1, 16),                    # one document
+    ((256,), 1, 16),                # a boundary at a chunk's first position
+    ((200,), 2, 8),                 # mid-chunk; a group a grid step
+    ((140, 230), 1, 8),             # two in one chunk; two steps a group
+    ((50, 450), 1, 16),             # a document longer than several chunks
+    ((128, 200, 210, 384), 2, 8),   # all of them
+])
+def test_the_kernel_pair_is_the_scan(bounds, groups, heads_a_step):
+    """``ssd_fwd`` and ``ssd_states`` + ``ssd_bwd`` (interpreted here)
+    against the ``jax.numpy`` form and against the recurrence position
+    by position: values and all six gradients, float32."""
+    args, seg, cot = kernel_inputs(bounds, G=groups)
+    plan = ssm.scan_plan(512, 128, 16, 64, 128, groups, heads_a_step)
+    assert plan == ssm.ScanPlan("pallas", 128, 4, heads_a_step)
+    assert plan.grid_steps(16) == 4 * 16 // heads_a_step
+    with jax.default_matmul_precision("highest"):
+        def kernels(*a):
+            return ssm.ssd_scan(*a, seg, chunk=128,
+                                head_block=heads_a_step)
+
+        def numpy_form(*a):
+            return ssm._scan_xla(*a, seg, ssm.ScanPlan("xla", 128, 4, 4))
+
+        def recurrence(*a):
+            return ref.selective_scan(*a, seg)
+        got, vjp = jax.vjp(kernels, *args)
+        grads = vjp(cot)
+        for other in (numpy_form, recurrence):
+            want, other_vjp = jax.vjp(other, *args)
+            np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4,
+                                       err_msg=other.__name__)
+            for g, w, name in zip(grads, other_vjp(cot),
+                                  ("x", "dt", "a", "B", "C", "D")):
+                np.testing.assert_allclose(
+                    g, w, rtol=2e-4, atol=2e-5 * float(jnp.abs(w).max()),
+                    err_msg=f"{other.__name__}: {name}")
+
+
+@pytest.mark.parametrize("seq,chunk,sizes,want", [
+    (48, 5, (4, 8, 6, 2), ("xla", 4, 12, 2)),
+    (48, 48, (4, 8, 6, 2), ("xla", 48, 1, 2)),
+    (512, 128, (16, 64, 128, 2), ("pallas", 128, 4, 8)),
+    (8192, 256, (128, 64, 128, 1), ("pallas", 256, 32, 16)),
+    # a row that is no multiple of the chunk, a state or a head that
+    # fills no lane tile, a group too small for a sublane tile
+    (8000, 256, (128, 64, 128, 1), ("xla", 250, 32, 16)),
+    (8192, 256, (128, 64, 16, 1), ("xla", 256, 32, 16)),
+    (8192, 256, (128, 48, 128, 1), ("xla", 256, 32, 16)),
+    (8192, 256, (128, 64, 128, 32), ("xla", 256, 32, 4)),
+])
+def test_the_shape_rule_picks_the_form(seq, chunk, sizes, want):
+    """One function reads the sizes (heads, head size, state, groups):
+    the kernels where they tile, else the ``jax.numpy`` form."""
+    assert ssm.scan_plan(seq, chunk, *sizes) == ssm.ScanPlan(*want)
+
 
 
 def test_state_and_taps_start_again_at_every_document():
@@ -606,17 +682,33 @@ def test_chooser_names_and_bytes_of_a_state_space_layer():
         assert not [n for n in names if n.startswith("ssm/")]
     assert dict(remat.keep_candidates(mistral_7b(), 1, 1024))[
         "attn/out"] == 1024 * 32 * 4096 * 2
+    # the cell's rows take the kernels, the tiny model's the jax.numpy form
     assert ssm_geometry(granite_share(), 8192) == {
-        "chunk": 256, "chunks_a_row": 32, "heads": 128, "head_dim": 64,
-        "state": 128, "groups": 1, "head_block": 16, "layers": 18}
+        "impl": "pallas", "chunk": 256, "chunks_a_row": 32, "heads": 128,
+        "head_dim": 64, "state": 128, "groups": 1, "head_block": 16,
+        "grid_steps_a_row": 256, "layers": 18}
+    assert ssm_geometry(hybrid_tiny(), 64) == {
+        "impl": "xla", "chunk": 8, "chunks_a_row": 8, "heads": 4,
+        "head_dim": 32, "state": 8, "groups": 1, "head_block": 4,
+        "grid_steps_a_row": 0, "layers": 2}
     assert ssm_geometry(tiny(), 128) == {}
 
 
-def test_scopes_and_kept_names_of_the_mixer(devices):
+@pytest.mark.parametrize("form,seq,lengths,sizes", [
+    ("xla", 64, (21, 5, 14, 17), {}),
+    ("pallas", 256, (90, 30, 60, 70), dict(
+        ssm_heads=8, ssm_head_dim=64, ssm_state=128, ssm_chunk=128)),
+])
+def test_scopes_and_kept_names_of_the_mixer(devices, form, seq, lengths,
+                                            sizes):
     """The mixer's stages are in the compiled step's scope table; the
     scan's output kept spares conv and scan their second run, and the
     first projection runs again (its adapters' gradients read its
-    input)."""
+    input). At 64 positions the scan is the ``jax.numpy`` form; at 256
+    in chunks of 128 the kernels (interpreted here: their operations
+    carry the kernel's name): all of them under ``ssm/scan``, and with
+    ``ssm/scan`` kept the rematerialised part holds no operation of the
+    forward kernel."""
     from gke_ray_train_tpu.obs import trace as obs_trace
     from gke_ray_train_tpu.ops.quant import quantize_params
     from gke_ray_train_tpu.train import (
@@ -627,13 +719,15 @@ def test_scopes_and_kept_names_of_the_mixer(devices):
     assert "ssm_scan" in obs_trace.SPAN_NAMES["step_build"]
     assert obs_trace.check_schema() == []
     cfg = hybrid_tiny(n_layers=2, remat=True, attn_impl="xla",
-                      max_seq_len=64)
+                      max_seq_len=seq, **sizes)
+    assert ssm_geometry(cfg, seq)["impl"] == form
     opt = make_optimizer(1e-2)
     lora_cfg = LoraConfig(r=4, alpha=8)
     params = quantize_params(init_params(cfg, jax.random.key(0)), "nf4")
     state = make_train_state(cfg, opt, jax.random.key(1),
                              lora_cfg=lora_cfg, params=params)
-    batch = {k: jnp.asarray(v) for k, v in packed_batch(vocab=128).items()}
+    batch = {k: jnp.asarray(v) for k, v in packed_batch(
+        seq=seq, vocab=128, lengths=lengths).items()}
 
     def paths(keep):
         step = make_train_step(cfg, opt, lora_cfg=lora_cfg, grad_accum=2,
@@ -643,17 +737,90 @@ def test_scopes_and_kept_names_of_the_mixer(devices):
         every = {obs_trace.scope_path(op) for op in table.values()}
         again = {obs_trace.scope_path(op) for op in table.values()
                  if "rematted_computation" in op}
-        return every, again
+        kernels = {(name, obs_trace.scope_path(op),
+                    "rematted_computation" in op)
+                   for op in table.values()
+                   for name in ("ssd_fwd", "ssd_states", "ssd_bwd")
+                   if f"/{name}/" in op}
+        return every, again, kernels
 
-    every, again = paths(())
+    every, again, kernels = paths(())
     assert {"ssm/in_proj/base", "ssm/in_proj/lora", "ssm/conv", "ssm/scan",
             "ssm/gate_norm", "ssm/out_proj/base", "attn/core/full",
             "moe/experts", "moe/shared/base"} <= every
     assert {"ssm/scan", "ssm/conv", "ssm/in_proj/base"} <= again
-    _, kept = paths(("ssm/scan",))
+    _, kept, kernels_kept = paths(("ssm/scan",))
     assert "ssm/in_proj/base" in kept
     assert len([p for p in kept if p == "ssm/scan"]) \
         <= len([p for p in again if p == "ssm/scan"])
+    if form == "pallas":
+        # the forward kernel runs again only where its output is not kept
+        assert kernels == {
+            ("ssd_fwd", "ssm/scan", False), ("ssd_fwd", "ssm/scan", True),
+            ("ssd_states", "ssm/scan", False), ("ssd_bwd", "ssm/scan", False)}
+        assert kernels_kept == kernels - {("ssd_fwd", "ssm/scan", True)}
+    else:
+        assert not kernels and not kernels_kept
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of a described v5e: libtpu compiles for it with no
+    chip attached (and looks nothing up: both variables are set), or
+    the tests that ask for it are skipped."""
+    with pytest.MonkeyPatch.context() as mp:
+        for name, value in (("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                            ("TPU_WORKER_HOSTNAMES", "localhost")):
+            if name not in os.environ:
+                mp.setenv(name, value)
+        try:
+            from jax.experimental import topologies
+            yield topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:1x1",
+                chips_per_host_bounds=(1, 1, 1)).devices[0]
+        except Exception as e:  # noqa: BLE001 - no TPU compiler here
+            pytest.skip(f"no TPU compiler: {type(e).__name__}: {e}")
+
+
+def test_v5e_scan_keeps_a_heads_decay_out_of_hbm(v5e):
+    """What PR 33's gain rests on, at the cell's geometry (a row of
+    8192, 128 heads of 64, state 128, chunks of 256, bf16): compiled
+    for the v5e, a mixer's scan forward + backward is the three kernels
+    and no float32 ``[.., Q, Q]`` tensor is among the program's own
+    operations, where the ``jax.numpy`` form wrote ``f32[1, 32, 1, 16,
+    256, 256]`` a block of heads, three times over."""
+    from jax.sharding import SingleDeviceSharding
+    from gke_ray_train_tpu.plan import XLA_TPU_OPTIONS
+    cfg = granite_share()
+    S, H, P, N, G, Q = (8192, cfg.ssm_heads, cfg.ssm_head_dim,
+                        cfg.ssm_state, cfg.ssm_groups, cfg.ssm_chunk)
+    sharding = SingleDeviceSharding(v5e)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def scan(x, dt, a, b, c, d, seg, g, interpret=False):
+        def fwd(x, dt, a, b, c, d):
+            return ssm.ssd_scan(x.reshape(1, S, H, P), dt, a, b, c, d, seg,
+                                chunk=Q, interpret=interpret
+                                ).reshape(1, S, H * P)
+        y, vjp = jax.vjp(jax.checkpoint(fwd), x, dt, a, b, c, d)
+        return y, vjp(g)
+    # the suite asks for float32 products (conftest.py), which Mosaic
+    # refuses for bf16 operands: the program's own default here
+    with jax.default_matmul_precision("default"):
+        built = jax.jit(scan, compiler_options=XLA_TPU_OPTIONS).lower(
+            spec((1, S, H * P)), spec((1, S, H), jnp.float32),
+            spec((H,), jnp.float32), spec((1, S, G, N)), spec((1, S, G, N)),
+            spec((H,), jnp.float32), spec((1, S), jnp.int32),
+            spec((1, S, H * P))).compile()
+    hlo = built.as_text()
+    calls = re.findall(r"%[\w.\-]*?(ssd_(?:fwd|states|bwd))[\w.\-]* = .*"
+                       r"custom_call_target=\"tpu_custom_call\"", hlo)
+    assert sorted(calls) == ["ssd_bwd", "ssd_fwd", "ssd_states"], calls
+    assert not re.findall(rf"f32\[[\d,]*{Q},{Q}\]", hlo)
+    # x, y and the two cotangents are 134 MB each; a block's decay was 134
+    assert built.memory_analysis().temp_size_in_bytes < 2 * S * H * P * 2
 
 
 def test_serving_a_pipelined_mesh_and_the_converters_refuse_by_name(devices):
