@@ -37,7 +37,9 @@ from typing import Any, Dict, List, Optional
 # must run without jax, and test_trace pins the mapping against the
 # ledger). step_window spans split between step_s (their duration
 # minus the stall attr) and data_stall_s; serve/reshard/attempt spans
-# map to no term (reshard time is inside restore; serve runs post-loop).
+# map to no term (reshard time is inside restore; serve runs post-loop),
+# and neither do the build's (step_build and its children, state_build)
+# nor train_loop, which holds the whole loop: its children book it.
 SPAN_TERM = {
     "restore": "restore_s",
     "peer_restore": "peer_restore_s",
@@ -114,11 +116,12 @@ def critical_path(spans: List[Dict[str, Any]],
     t_base = (att_spans[-1].get("t0") if att_spans
               else (mine[0].get("t0") if mine else 0.0)) or 0.0
     # the path: causally-ordered leaf spans (serve children excluded —
-    # their parent request span already covers them)
+    # their parent request span already covers them — and train_loop,
+    # which holds every span of the loop)
     child_parents = {s.get("span_id") for s in mine
                      if s.get("name") == "serve_request"}
     leaves = [s for s in mine
-              if s.get("name") != "attempt"
+              if s.get("name") not in ("attempt", "train_loop")
               and s.get("parent_id") not in child_parents]
     if not any(s.get("name") in SPAN_TERM or s.get("name") ==
                "step_window" for s in leaves):

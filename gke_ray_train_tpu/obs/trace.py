@@ -93,6 +93,8 @@ SPAN_NAMES: Dict[str, tuple] = {
     # the ledger-timed loop boundaries (train/loop.py); durations are
     # the EXACT floats the GoodputLedger booked for the same regions
     "restore": ("resumed_step",),
+    # the first step call of a run_training call and the wait for it:
+    # once a call, so it is among ALWAYS_RECORDED
     "compile": (),
     "fast_forward": (),
     "step_window": ("steps", "data_stall_s"),
@@ -141,12 +143,38 @@ SPAN_NAMES: Dict[str, tuple] = {
     # take, `impl`: pallas / xla; chunk, chunks a row, heads, head size,
     # state, groups, heads a grid step or block, grid steps of a kernel
     # call a row, layers; models/transformer.py::ssm_geometry), {}
-    # without such layers
+    # without such layers. `remat_estimate_bytes`: the peak the
+    # chooser's arithmetic expects for the step it asked for (None
+    # where no limit is reported). `xla_memory`: what XLA laid out for
+    # the executable that will run, from `compiled.memory_analysis()`,
+    # in bytes: peak, arguments, outputs, aliased, temporaries, code,
+    # and `limit`, the device's own (None where it reports none); {}
+    # where the backend gives no analysis
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
                    "remat_budget_bytes", "remat_args_bytes",
-                   "remat_keep_fallback", "flash_grid", "ssm_scan"),
-    "step_lower": (),
-    "step_compile": (),
+                   "remat_keep_fallback", "flash_grid", "ssm_scan",
+                   "remat_estimate_bytes", "xla_memory"),
+    # what jax's own events said while the region was open
+    # (perf/cache.py's listener): `trace_s` the step's trace to a
+    # jaxpr, `to_mlir_s` its lowering to a module, each the time of the
+    # outermost events only (a jitted function traced inside the step
+    # reports a duration of its own, which the step's holds already)
+    "step_lower": ("trace_s", "to_mlir_s"),
+    # `cache`: "hit" (the executable came out of the persistent compile
+    # cache) | "miss" (it was built and written there) | None (no
+    # cache in use); `retrieval_s`: the read; `backend_compile_s`: the
+    # build, 0 on a hit. `step_build`'s `source` says another thing:
+    # whether a sidecar was deserialized, with no compile at all
+    "step_compile": ("cache", "retrieval_s", "backend_compile_s"),
+    # set-up outside the build, once a call each, recorded always:
+    # train/step.py::make_train_state (`args_bytes`: one device's share
+    # of the state it made) and the whole of one run_training call,
+    # entry to return, the parent of what the loop opens (`steps`
+    # trained in the call; `to_first_step_s`: the loop's own
+    # restart_to_first_step_s). obs/critical.py books neither to a
+    # ledger term: what they hold is booked by their children
+    "state_build": ("args_bytes",),
+    "train_loop": ("steps", "to_first_step_s"),
 }
 
 # names that never reach the JSONL stream: they end once a step (or
@@ -156,9 +184,10 @@ PER_STEP_SPANS = frozenset({
     "step_iter", "data_wait", "step_dispatch", "metrics_fetch",
     "log_emit", "batch_next", "batch_place"})
 # names recorded in memory whether or not anything listens: outside
-# every hot path, and the build they time is over before a profiler
-# could be attached
-ALWAYS_RECORDED = frozenset({"step_build", "step_lower", "step_compile"})
+# every hot path (they end once a build or once a run_training call),
+# and the set-up they time is over before a profiler could be attached
+ALWAYS_RECORDED = frozenset({"step_build", "step_lower", "step_compile",
+                             "state_build", "train_loop", "compile"})
 
 # the closed vocabulary of jax.named_scope names on the device ops of
 # the train step (models/transformer.py, ops/moe.py, train/step.py,
@@ -396,14 +425,15 @@ class Region:
     and ``attrs`` may be filled in while the region is open; ``drop``
     discards it (an iteration that found the stream exhausted)."""
 
-    __slots__ = ("name", "id", "parent", "t0", "t1", "step", "attrs",
-                 "dur_s", "drop")
+    __slots__ = ("name", "id", "parent", "up", "t0", "t1", "step",
+                 "attrs", "dur_s", "drop")
 
     def __init__(self, name: str, step: Optional[int],
-                 attrs: Dict[str, Any], parent: Optional[int]):
+                 attrs: Dict[str, Any], up: Optional["Region"]):
         self.name, self.step, self.attrs = name, step, attrs
         self.id = next(_ids)
-        self.parent = parent
+        self.up = up                 # the region open around this one
+        self.parent = None if up is None else up.id
         self.t0 = self.t1 = 0.0
         self.dur_s: Optional[float] = None
         self.drop = False
@@ -417,7 +447,8 @@ class MemoryRecord:
     ``run_training`` call that was given a profiler object. A span is
     ``{"name", "id", "parent", "t0", "t1", "step", **attrs}`` with
     ``time.perf_counter()`` endpoints and the id of the region that was
-    open on the same thread when it opened."""
+    open on the same thread when it opened (while nothing listens, of
+    the nearest such region that is itself recorded)."""
 
     def __init__(self, maxlen: int = 65536):
         self.spans: Deque[Dict[str, Any]] = collections.deque(
@@ -467,7 +498,7 @@ def region(name: str, *, step: Optional[int] = None,
     stack = getattr(_open, "stack", None)
     if stack is None:
         stack = _open.stack = []
-    r = Region(name, step, attrs, stack[-1].id if stack else None)
+    r = Region(name, step, attrs, stack[-1] if stack else None)
     stack.append(r)
     r.t0 = time.perf_counter()
     try:
@@ -485,7 +516,16 @@ def region(name: str, *, step: Optional[int] = None,
 def _finish(r: Region, run) -> None:
     # again: the name may have changed and attributes been added since
     validate_span(r.name, r.attrs)
-    RECORD.spans.append({"name": r.name, "id": r.id, "parent": r.parent,
+    parent = r.parent
+    if run is None and not RECORD.attached:
+        # only the always-recorded names are kept now: the parent is
+        # the nearest of them, so that a reader finds it in the record
+        # (`compile` under `train_loop`, past the iteration's region)
+        up = r.up
+        while up is not None and up.name not in ALWAYS_RECORDED:
+            up = up.up
+        parent = None if up is None else up.id
+    RECORD.spans.append({"name": r.name, "id": r.id, "parent": parent,
                          "t0": r.t0, "t1": r.t1, "step": r.step,
                          **r.attrs})
     if run is not None and r.name not in PER_STEP_SPANS:

@@ -52,6 +52,7 @@ import json
 import logging
 import os
 import pickle
+import threading
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -68,9 +69,24 @@ DEFAULT_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 # hit/miss counters fed by jax.monitoring events — the same counters
-# the cache-hit tests assert on (ISSUE 4 satellite).
+# the cache-hit tests assert on (ISSUE 4 satellite) — and the seconds
+# of jax's own timed blocks: the process's totals; a region of
+# :func:`build_or_load_step` reads the difference across itself
 _STATS = {"hits": 0, "misses": 0, "compile_time_saved_s": 0.0,
-          "retrieval_s": 0.0}
+          "retrieval_s": 0.0, "trace_s": 0.0, "to_mlir_s": 0.0,
+          "backend_compile_s": 0.0}
+# jax's timed blocks (``dispatch.log_elapsed_time``) -> their counter.
+# They nest: a jitted function traced inside another reports its own
+# trace, a trace inside a lowering its own, and every backend compile
+# is asked for under one of them or alone. Each second is counted
+# once, under the outermost block of its thread, so the three never
+# sum past the wall clock (jax's `backend_compile_duration` holds the
+# cache's lookup: on a hit it is the read, not a build)
+_TIMED_BLOCKS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "to_mlir_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s"}
+_blocks = threading.local()      # .open: timed blocks open on a thread
 _LISTENER_INSTALLED = False
 _ENABLED_DIR: Optional[str] = None
 
@@ -82,11 +98,21 @@ def _on_event(event: str, **kw) -> None:
         _STATS["misses"] += 1
 
 
+def _on_scalar(event: str, value: float, **kw) -> None:
+    # a timed block reports its start as a scalar under its own name
+    if event in _TIMED_BLOCKS:
+        _blocks.open = getattr(_blocks, "open", 0) + 1
+
+
 def _on_duration(event: str, duration: float, **kw) -> None:
     if event.endswith("/compile_time_saved_sec"):
         _STATS["compile_time_saved_s"] += max(duration, 0.0)
     elif event.endswith("/cache_retrieval_time_sec"):
         _STATS["retrieval_s"] += max(duration, 0.0)
+    elif event in _TIMED_BLOCKS:
+        _blocks.open = still = max(getattr(_blocks, "open", 0) - 1, 0)
+        if not still:
+            _STATS[_TIMED_BLOCKS[event]] += max(duration, 0.0)
 
 
 def _install_listener() -> None:
@@ -95,6 +121,7 @@ def _install_listener() -> None:
         return
     from jax._src import monitoring
     monitoring.register_event_listener(_on_event)
+    monitoring.register_scalar_listener(_on_scalar)
     monitoring.register_event_duration_secs_listener(_on_duration)
     _LISTENER_INSTALLED = True
 
@@ -410,9 +437,11 @@ class GuardedStep:
     whose layout drifted from the recorded signature), it logs ONCE and
     permanently falls back to the jitted function — a stale sidecar
     costs one retrace, never a crash. ``info`` records the build source
-    ("deserialized" | "compiled") and seconds, for the loop's
-    compile-time metrics; ``fell_back`` says whether a call ever left
-    the executable for the jitted path.
+    ("deserialized" | "compiled"), beside it ``cache`` ("hit" | "miss"
+    | None: whether the compile found its executable in the persistent
+    cache) and seconds, for the loop's compile-time metrics;
+    ``fell_back`` says whether a call ever left the executable for the
+    jitted path.
     """
 
     def __init__(self, compiled, jitted_fn: Callable, info: Dict[str, Any]):
@@ -491,12 +520,50 @@ def _variant_key(key: str, variant: str) -> str:
     return hashlib.sha256(f"{key}|{variant}".encode()).hexdigest()
 
 
-def _past_peak(compiled, limit: Optional[int]) -> Optional[str]:
-    """Says so where XLA's peak for ``compiled`` passes ``limit``."""
-    if limit is None:
-        return None
-    peak = getattr(compiled.memory_analysis(), "peak_memory_in_bytes", 0)
-    if peak <= limit:
+# the ``step_build`` span's ``xla_memory`` <- compiled.memory_analysis()
+_XLA_MEMORY = {"peak": "peak_memory_in_bytes",
+               "arguments": "argument_size_in_bytes",
+               "outputs": "output_size_in_bytes",
+               "aliased": "alias_size_in_bytes",
+               "temporaries": "temp_size_in_bytes",
+               "code": "generated_code_size_in_bytes"}
+
+
+def _device_limit(args) -> Optional[int]:
+    """``train/remat.py::device_bytes_limit`` for the mesh the
+    arguments are laid out over (the default backend's first device
+    where none says)."""
+    from gke_ray_train_tpu.train.remat import device_bytes_limit
+    meshes = (getattr(getattr(x, "sharding", None), "mesh", None)
+              for x in jax.tree.leaves(args))
+    return device_bytes_limit(next(
+        (m for m in meshes if hasattr(m, "local_devices")), None))
+
+
+def xla_memory(compiled, limit: Optional[int]) -> Dict[str, Any]:
+    """The step's memory as XLA laid it out, in bytes, beside the
+    device's ``limit``: one ``memory_analysis()`` call. {} where the
+    executable gives none (a backend without the analysis, an
+    executable that cannot serve it again after deserializing)."""
+    t0 = time.perf_counter()
+    try:
+        stats = compiled.memory_analysis()
+    except Exception as e:  # noqa: BLE001 - the build stands without
+        logger.warning("memory analysis skipped: %s: %s",
+                       type(e).__name__, e)
+        return {}
+    logger.debug("memory analysis in %.4fs", time.perf_counter() - t0)
+    if stats is None:
+        return {}
+    return {**{k: int(getattr(stats, attr, 0) or 0)
+               for k, attr in _XLA_MEMORY.items()}, "limit": limit}
+
+
+def _past_peak(memory: Dict[str, Any],
+               limit: Optional[int]) -> Optional[str]:
+    """Says so where XLA's peak (``xla_memory``'s) passes ``limit``."""
+    peak = memory.get("peak", 0)
+    if limit is None or peak <= limit:
         return None
     return (f"the compiled step's peak of {peak / 1e9:.2f} GB passes "
             f"{limit / 1e9:.2f} GB")
@@ -530,6 +597,13 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
       limit, builds the fallback's step instead and logs a warning. Its
       sidecar remembers that, so that a restart does not compile the
       first step again to learn the same.
+
+    The build is on the record (``obs/trace.py``: ``step_build`` with
+    ``step_lower`` and ``step_compile`` under it, recorded always):
+    jax's own seconds for the trace and the lowering, whether the
+    compile was a ``cache`` hit (``info`` has it beside ``source``),
+    and ``xla_memory``, the memory of the executable that will run as
+    XLA laid it out, which is also what the fallback's limit judges.
     """
     args = tuple(abstractify(a) for a in abstract_args)
     key = fb_key = aot_signature(*args, plan=plan, surface=surface)
@@ -537,20 +611,46 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
         key = _variant_key(key, variant)
     if fallback is not None:
         fb_key = _variant_key(key, "fallback")
-    info: Dict[str, Any] = {"label": label, "sidecar": sidecar}
+    info: Dict[str, Any] = {"label": label, "sidecar": sidecar,
+                            "cache": None}
     if plan is not None:
         info["plan_fingerprint"] = plan.fingerprint()
+
+    # jax's own seconds and the cache's verdict reach the regions
+    # below through the listener, with or without a cache directory
+    _install_listener()
+
+    def since(before, *names):
+        return {n: _STATS[n] - before[n] for n in names}
 
     def lower_and_compile(fn):
         # a step that cannot be lowered or compiled is an error, not a
         # reason to leave AOT: the jitted path would hit the same wall
         # at its first call, later and with less context
-        with trace.region("step_lower"):
+        before = dict(_STATS)
+        with trace.region("step_lower") as low:
             lowered = fn.lower(*args)
-        with trace.region("step_compile"), salted_cache_key():
-            return lowered.compile()
+            spent = time.perf_counter() - low.t0
+            # never above the region's own seconds: jax times its
+            # blocks on another clock, and on every thread
+            low.attrs.update({k: min(v, spent) for k, v in since(
+                before, "trace_s", "to_mlir_s").items()})
+        before = dict(_STATS)
+        with trace.region("step_compile") as comp, salted_cache_key():
+            compiled = lowered.compile()
+            got = since(before, "hits", "misses", "retrieval_s",
+                        "backend_compile_s")
+            cache = ("hit" if got["hits"] and not got["misses"]
+                     else "miss" if got["misses"] else None)
+            comp.attrs.update(
+                cache=cache, retrieval_s=got["retrieval_s"],
+                backend_compile_s=0.0 if cache == "hit"
+                else got["backend_compile_s"])
+        info["cache"] = cache
+        return compiled, xla_memory(compiled, limit)
 
     fell_back = False
+    limit = _device_limit(args)
     with trace.region("step_build", **(attrs or {})) as build:
         compiled = load_executable(sidecar, key) if sidecar else None
         if compiled is None and sidecar and fallback is not None:
@@ -558,13 +658,14 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
             fell_back = compiled is not None
         if compiled is not None:
             build.attrs["source"] = "deserialized"
+            memory = xla_memory(compiled, limit)
         else:
             build.attrs["source"] = "compiled"
             why = None
             try:
-                compiled = lower_and_compile(jitted_fn)
+                compiled, memory = lower_and_compile(jitted_fn)
                 if fallback is not None:
-                    why = _past_peak(compiled, fallback.peak_limit_bytes)
+                    why = _past_peak(memory, fallback.peak_limit_bytes)
             except jax.errors.JaxRuntimeError as e:
                 if fallback is None or "RESOURCE_EXHAUSTED" not in str(e):
                     raise
@@ -574,14 +675,23 @@ def build_or_load_step(jitted_fn: Callable, *abstract_args: Any,
                     "%s: the step asked for (%s) does not fit: %s; "
                     "building the fallback", label, variant, why)
                 fell_back = True
-                compiled = lower_and_compile(fallback.fn)
+                compiled, memory = lower_and_compile(fallback.fn)
         if fell_back:
             build.attrs.update(fallback.attrs)
             jitted_fn, key = fallback.fn, fb_key
+        # of the executable that will run, the fallback's after one
+        build.attrs["xla_memory"] = memory
     info.update(build.attrs, build_s=build.t1 - build.t0)
-    logger.info("%s: %s AOT executable in %.2fs%s", label, info["source"],
-                info["build_s"],
-                f" ({sidecar})" if info["source"] == "deserialized" else "")
+    logger.info(
+        "%s: %s AOT executable in %.2fs%s; XLA's peak %s", label,
+        info["source"], info["build_s"],
+        f" ({sidecar})" if info["source"] == "deserialized"
+        else {"hit": " (out of the compile cache)",
+              "miss": " (built, and written to the compile cache)",
+              None: " (built: no compile cache in use)"}[info["cache"]],
+        "not reported" if not memory else
+        f"{memory['peak'] / 1e9:.2f} GB"
+        + ("" if limit is None else f" of {limit / 1e9:.2f}"))
     # a warm-restart attempt must feed the obs network gauges too — the
     # note guards internally against a deserialized executable that
     # cannot re-serve its analyses

@@ -18,6 +18,7 @@ context (:296-310). Differences by design:
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import time
@@ -72,6 +73,18 @@ def _iterations(source):
             yield batch, span
 
 
+def _in_train_loop_region(fn):
+    """``fn`` inside one ``train_loop`` region (obs/trace.py): the
+    whole call, entry to return, on every way out; the parent of what
+    the loop opens. The call gets the open region as ``loop_span``."""
+    @functools.wraps(fn)
+    def in_region(*args, **kw):
+        with trace.region("train_loop") as span:
+            return fn(*args, loop_span=span, **kw)
+    return in_region
+
+
+@_in_train_loop_region
 def run_training(state: TrainState,
                  train_step: Callable,
                  epoch_batches: Callable[[int], Iterable],
@@ -94,7 +107,8 @@ def run_training(state: TrainState,
                  heartbeat_fn: Optional[Callable] = None,
                  fault_injector=None,
                  guards: Optional[RuntimeGuards] = None,
-                 is_host0: bool = True) -> tuple:
+                 is_host0: bool = True,
+                 loop_span=None) -> tuple:
     """Returns (final_state, last_metrics).
 
     last_metrics carries two compile-level timings alongside the step
@@ -295,7 +309,7 @@ def run_training(state: TrainState,
         get_context().note_resume(resumed)
 
     last_metrics = {}
-    global_step = int(jax.device_get(state.step))
+    global_step = first_step = int(jax.device_get(state.step))
 
     n_procs = max(jax.process_count(), 1)
     # multi-host flag agreement runs only every K-th boundary: blocking
@@ -662,6 +676,10 @@ def run_training(state: TrainState,
         if report_fn is not None:
             report_fn(epoch_metrics)
     finally:
+        # what this call trained, on its `train_loop` region
+        loop_span.attrs.update(
+            steps=global_step - first_step,
+            to_first_step_s=loop_timing.get("restart_to_first_step_s"))
         # seal the attempt's goodput ledger on EVERY exit path (normal,
         # Preempted — already closed there, idempotent — and crash) and
         # park it on the context for Result.attempt_log / Result.goodput

@@ -85,6 +85,18 @@ class RematChoice:
     budget_bytes: Optional[int] = None   # None: no limit reported
     limit_bytes: Optional[int] = None
     args_bytes: Optional[int] = None     # what the budget subtracted
+    charged_bytes: int = 0               # what `keep` took of the budget
+
+    def estimate_bytes(self, *, fallback: bool = False) -> Optional[int]:
+        """The peak this arithmetic expects for the step that keeps the
+        choice (with ``fallback``: nothing): the arguments, the working
+        set of a step with nothing kept, and the kept names at what
+        they were charged. None where no limit is reported. XLA's own
+        is the ``step_build`` span's ``xla_memory``, beside it."""
+        if self.budget_bytes is None:
+            return None
+        return (self.limit_bytes - RESERVE_BYTES - self.budget_bytes
+                + (0 if fallback else self.charged_bytes))
 
     def attrs(self, *, fallback: bool = False) -> dict:
         """The ``step_build`` span's ``remat_*`` attributes (and
@@ -95,7 +107,9 @@ class RematChoice:
                 "remat_keep_bytes": self.keep_bytes if keep else 0,
                 "remat_budget_bytes": self.budget_bytes,
                 "remat_args_bytes": self.args_bytes,
-                "remat_keep_fallback": fallback}
+                "remat_keep_fallback": fallback,
+                "remat_estimate_bytes": self.estimate_bytes(
+                    fallback=fallback)}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,8 +157,10 @@ class StepRemat:
         keep = choose_keep(candidates, budget,
                            peak_share=KEPT_PEAK_SHARE)
         sizes = dict(candidates)
-        return RematChoice(keep, sum(sizes[n] for n in keep), budget,
-                           limit, args)
+        return RematChoice(
+            keep, sum(sizes[n] for n in keep), budget, limit, args,
+            # as choose_keep charged them
+            sum(math.ceil(sizes[n] * KEPT_PEAK_SHARE) for n in keep))
 
     def build(self, step: Callable, state, batch, *,
               label: str = "train_step", **build_kw):

@@ -29,12 +29,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.models.transformer import (
     Params, forward, init_params, param_specs)
-from gke_ray_train_tpu.obs.trace import scope
+from gke_ray_train_tpu.obs.trace import region, scope
 from gke_ray_train_tpu.ops.moe import COUNTERS, stats_merge
 from gke_ray_train_tpu.parallel.mesh import BATCH_AXES
 from gke_ray_train_tpu.parallel.sharding import tree_shardings
 from gke_ray_train_tpu.train.lora import LoraConfig, init_lora, lora_specs
-from gke_ray_train_tpu.train.remat import StepRemat
+from gke_ray_train_tpu.train.remat import StepRemat, shard_bytes
 
 Batch = Dict[str, jnp.ndarray]
 
@@ -120,7 +120,20 @@ def make_train_state(cfg: ModelConfig, optimizer: optax.GradientTransformation,
     with ``device_put`` — plain-path-identical by construction, and no
     init program to compile; trees past ``_EAGER_INIT_LIMIT`` (an 8B
     fp32 init must never materialize on one host) take the jitted
-    sharded path under ``sharding_invariant_rng``."""
+    sharded path under ``sharding_invariant_rng``.
+
+    On the record as one ``state_build`` region (obs/trace.py; recorded
+    always: it runs once, before any step), with the bytes one device
+    holds of the state it made."""
+    with region("state_build") as built:
+        state = _make_train_state(cfg, optimizer, key, mesh=mesh,
+                                  lora_cfg=lora_cfg, params=params)
+        built.attrs["args_bytes"] = shard_bytes(state)
+    return state
+
+
+def _make_train_state(cfg, optimizer, key, *, mesh, lora_cfg,
+                      params) -> TrainState:
     from gke_ray_train_tpu.parallel.sharding import (
         shard_tree, sharding_invariant_rng)
 

@@ -495,3 +495,186 @@ def test_run_training_reports_compile_metrics():
     _, metrics = run_training(state, step, batches, epochs=1)
     assert metrics["compile_s"] > 0
     assert metrics["restart_to_first_step_s"] >= metrics["compile_s"]
+
+
+# ---------------------------------------------------------------------------
+# the build on the record (ISSUE 34): jax's own seconds, the cache's
+# verdict and XLA's memory on the build's spans
+# ---------------------------------------------------------------------------
+
+def _build_spans():
+    """{name: span} of the record, which is then emptied."""
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    spans = {s["name"]: s for s in obs_trace.RECORD.spans}
+    obs_trace.RECORD.clear()
+    return spans
+
+
+def _small_jit():
+    def small_step(x):
+        return jnp.tanh(x @ x.T).sum()
+    return jax.jit(small_step)
+
+
+def test_step_compile_reads_miss_then_hit_over_two_builds(cache_sandbox):
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    enable_persistent_cache(str(cache_sandbox / "cache"))
+    obs_trace.RECORD.clear()
+    seen = []
+    for _ in range(2):
+        jax.clear_caches()           # drop the in-memory executable
+        step = build_or_load_step(_small_jit(),
+                                  jnp.ones((8, 8), jnp.float32),
+                                  label="twice built")
+        comp = _build_spans()["step_compile"]
+        seen.append(comp["cache"])
+        # `source` keeps its meaning (no sidecar: compiled both times);
+        # `cache` says which of the two compiles built anything
+        assert step.info["source"] == "compiled"
+        assert step.info["cache"] == comp["cache"]
+        assert comp["retrieval_s"] >= 0.0
+        if comp["cache"] == "hit":
+            assert comp["backend_compile_s"] == 0.0
+        else:
+            assert 0.0 < comp["backend_compile_s"] \
+                <= comp["t1"] - comp["t0"]
+    assert seen == ["miss", "hit"]
+
+
+def test_step_compile_says_no_cache_where_none_is_in_use():
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    obs_trace.RECORD.clear()
+    jax.clear_caches()
+    step = build_or_load_step(_small_jit(), jnp.ones((8, 8), jnp.float32),
+                              label="uncached")
+    comp = _build_spans()["step_compile"]
+    assert comp["cache"] is None and step.info["cache"] is None
+    assert comp["backend_compile_s"] > 0.0
+
+
+def test_step_lower_carries_jax_s_own_seconds_once(fsdp_mesh):
+    """A jitted function traced inside the step reports a trace of its
+    own; the region counts the outermost alone, so the two parts never
+    pass the region's seconds."""
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    inner = jax.jit(lambda x: jnp.tanh(x) * 2.0)
+
+    def outer(x):
+        return inner(inner(x) @ x.T).sum()
+    obs_trace.RECORD.clear()
+    jax.clear_caches()
+    before = cache_stats()
+    build_or_load_step(jax.jit(outer), jnp.ones((8, 8), jnp.float32),
+                       label="nested")
+    low = _build_spans()["step_lower"]
+    assert low["trace_s"] > 0.0 and low["to_mlir_s"] > 0.0
+    assert low["trace_s"] + low["to_mlir_s"] <= \
+        (low["t1"] - low["t0"]) * 1.05 + 1e-3
+    # the process's totals moved by the same seconds
+    after = cache_stats()
+    assert after["trace_s"] - before["trace_s"] >= low["trace_s"] - 1e-9
+
+
+def test_timed_blocks_count_the_outermost_alone():
+    """The listener by hand: jax reports a block's start as a scalar
+    and its end as a duration; a block inside another adds nothing."""
+    trace_ev = "/jax/core/compile/jaxpr_trace_duration"
+    mlir_ev = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    before = cache_stats()
+    perf_cache._on_scalar(trace_ev, 0.0)          # outer opens
+    perf_cache._on_scalar(trace_ev, 0.0)          # inner opens
+    perf_cache._on_duration(trace_ev, 1.0)        # inner ends
+    perf_cache._on_duration(trace_ev, 3.0)        # outer ends
+    perf_cache._on_scalar(mlir_ev, 0.0)
+    perf_cache._on_scalar(trace_ev, 0.0)          # a trace in a lowering
+    perf_cache._on_duration(trace_ev, 0.5)
+    perf_cache._on_duration(mlir_ev, 2.0)
+    after = cache_stats()
+    assert after["trace_s"] - before["trace_s"] == pytest.approx(3.0)
+    assert after["to_mlir_s"] - before["to_mlir_s"] == pytest.approx(2.0)
+
+
+XLA_MEMORY_KEYS = {"peak", "arguments", "outputs", "aliased",
+                   "temporaries", "code", "limit"}
+
+
+@pytest.mark.parametrize("sidecar", [False, True],
+                         ids=["compiled", "deserialized"])
+def test_step_build_carries_xla_memory(tmp_path, fsdp_mesh, sidecar):
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    _, _, state, step, batch = _tiny_setup(fsdp_mesh)
+    path = str(tmp_path / "step.bin") if sidecar else None
+    if sidecar:
+        build_or_load_step(step, state, batch, sidecar=path)
+    obs_trace.RECORD.clear()
+    built = build_or_load_step(step, state, batch, sidecar=path)
+    span = _build_spans()["step_build"]
+    assert span["source"] == ("deserialized" if sidecar else "compiled")
+    memory = span["xla_memory"]
+    assert memory == built.info["xla_memory"]
+    # XLA:CPU may give no analysis: {} then, else all seven
+    assert memory == {} or set(memory) == XLA_MEMORY_KEYS
+    if memory:
+        assert memory["limit"] is None      # XLA:CPU reports no limit
+        stats = built._compiled.memory_analysis()
+        assert memory["peak"] == stats.peak_memory_in_bytes
+        assert memory["arguments"] == stats.argument_size_in_bytes
+        assert memory["temporaries"] == stats.temp_size_in_bytes
+        assert memory["code"] == stats.generated_code_size_in_bytes
+
+
+def test_past_peak_judges_the_span_s_own_peak():
+    class Stats:
+        peak_memory_in_bytes = 5_000
+        argument_size_in_bytes = 3_000
+        output_size_in_bytes = 1_000
+        alias_size_in_bytes = 1_000
+        temp_size_in_bytes = 2_000
+        generated_code_size_in_bytes = 10
+
+    class Compiled:
+        calls = 0
+
+        def memory_analysis(self):
+            Compiled.calls += 1
+            return Stats()
+    memory = perf_cache.xla_memory(Compiled(), 6_000)
+    assert memory == {"peak": 5_000, "arguments": 3_000, "outputs": 1_000,
+                      "aliased": 1_000, "temporaries": 2_000, "code": 10,
+                      "limit": 6_000}
+    # one analysis serves the span and the fallback's judgement
+    assert Compiled.calls == 1
+    assert perf_cache._past_peak(memory, memory["peak"]) is None
+    assert perf_cache._past_peak(memory, None) is None
+    assert "passes" in perf_cache._past_peak(memory, memory["peak"] - 1)
+    # a backend with no analysis: nothing on the span, nothing to judge
+
+    class Silent:
+        def memory_analysis(self):
+            return None
+    assert perf_cache.xla_memory(Silent(), 6_000) == {}
+    assert perf_cache._past_peak({}, 1) is None
+
+
+def test_the_fallback_s_memory_wins_where_a_build_fell_back(monkeypatch):
+    """The span carries the memory of the executable that will run."""
+    from gke_ray_train_tpu.obs import trace as obs_trace
+    from gke_ray_train_tpu.perf.cache import StepFallback
+    first, second = _small_jit(), jax.jit(lambda x: (x * 2.0).sum())
+    peaks = iter([9_000, 4_000])
+    monkeypatch.setattr(
+        perf_cache, "xla_memory",
+        lambda compiled, limit: {"peak": next(peaks), "limit": limit})
+    obs_trace.RECORD.clear()
+    built = build_or_load_step(
+        first, jnp.ones((8, 8), jnp.float32), label="too large",
+        attrs={"remat_keep_fallback": False},
+        fallback=StepFallback(second, {"remat_keep_fallback": True},
+                              peak_limit_bytes=5_000))
+    spans = list(obs_trace.RECORD.spans)
+    obs_trace.RECORD.clear()
+    assert [s["name"] for s in spans].count("step_compile") == 2
+    (build,) = [s for s in spans if s["name"] == "step_build"]
+    assert build["remat_keep_fallback"] is True
+    assert build["xla_memory"]["peak"] == 4_000
+    assert built.info["xla_memory"]["peak"] == 4_000

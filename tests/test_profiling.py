@@ -106,7 +106,10 @@ def test_loss_stream_bitwise_with_a_profiler_attached(tmp_path,
     obs_trace.RECORD.clear()
     try:
         plain, params_plain = run(None)
-        assert list(obs_trace.RECORD.spans) == []
+        # no per-step name without a profiler; what ends once a call is
+        # kept whoever listens (ISSUE 34)
+        assert {s["name"] for s in obs_trace.RECORD.spans} == {
+            "train_loop", "compile"}
         traced, params_traced = run(TraceProfiler(
             str(tmp_path / "prof"), start_step=2, num_steps=2))
         assert any(s["name"] == "step_iter"

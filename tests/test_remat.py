@@ -6,6 +6,7 @@ step, which device is asked for its limit, and the build's fallback
 when the compiler finds no room."""
 
 import dataclasses
+import math
 import types
 
 import jax
@@ -522,7 +523,8 @@ def test_which_steps_carry_a_sizer(devices):
     assert RematChoice() == RematChoice((), 0, None, None, None)
     assert RematChoice(("attn/out",), 7, 9, 20, 4).attrs(fallback=True) == {
         "remat_keep": [], "remat_keep_bytes": 0, "remat_budget_bytes": 9,
-        "remat_args_bytes": 4, "remat_keep_fallback": True}
+        "remat_args_bytes": 4, "remat_keep_fallback": True,
+        "remat_estimate_bytes": 20 - step_remat.RESERVE_BYTES - 9}
 
 
 def test_the_compile_surface_sizes_a_train_step(monkeypatch, devices,
@@ -591,3 +593,80 @@ def test_flash_grid_attribute_of_the_presets(preset, seq, expect):
                        None, 1, seq) == {}
     assert flash_grids(dataclasses.replace(cfg, attn_impl="ring"),
                        None, 1, seq) == {}
+
+
+# ---------------------------------------------------------------------------
+# the chooser's estimate beside XLA's own peak (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+def _charged(names, sizes):
+    return sum(math.ceil(sizes[n] * remat.KEPT_PEAK_SHARE) for n in names)
+
+
+def test_estimate_is_the_limit_less_the_reserve_and_the_unspent_budget(
+        monkeypatch, devices, record):
+    limit = 1 << 30
+    built = _aot_build(monkeypatch, devices, limit=limit)
+    (span,) = _spans(record, "step_build")
+    cfg, _, _, batch, step_kw = _setup("qlora_xla", devices)
+    rows, seq = batch["inputs"].shape
+    sizes = dict(keep_candidates(cfg, rows // step_kw["grad_accum"], seq,
+                                 model=1, flash=False))
+    charged = _charged(span["remat_keep"], sizes)
+    assert 0 < charged < span["remat_keep_bytes"]
+    assert span["remat_estimate_bytes"] == (
+        limit - step_remat.RESERVE_BYTES
+        - (span["remat_budget_bytes"] - charged))
+    assert built.info["remat_estimate_bytes"] == \
+        span["remat_estimate_bytes"]
+    # which is the arguments, the working set and what is kept, charged
+    working_set = (limit - step_remat.RESERVE_BYTES
+                   - span["remat_args_bytes"] - span["remat_budget_bytes"])
+    assert working_set > 0
+    assert span["remat_estimate_bytes"] == (
+        span["remat_args_bytes"] + working_set + charged)
+    # XLA's own beside it, against the limit the chooser was given
+    assert span["xla_memory"] == {} or \
+        span["xla_memory"]["limit"] == limit
+
+
+@pytest.mark.parametrize("limit,expected", [
+    (None, None),                  # no limit reported: no estimate
+    (1 << 16, "args + working set"),    # no room: nothing charged
+])
+def test_estimate_with_nothing_kept(monkeypatch, devices, record, limit,
+                                    expected):
+    built = _aot_build(monkeypatch, devices, limit=limit)
+    (span,) = _spans(record, "step_build")
+    assert span["remat_keep"] == []
+    if expected is None:
+        assert span["remat_estimate_bytes"] is None
+        assert built.info["remat_estimate_bytes"] is None
+    else:
+        assert span["remat_estimate_bytes"] == (
+            limit - step_remat.RESERVE_BYTES - span["remat_budget_bytes"])
+        assert span["remat_estimate_bytes"] > span["remat_args_bytes"]
+
+
+def test_the_fallback_s_estimate_charges_nothing(monkeypatch, devices,
+                                                 record):
+    limit = 1 << 30
+    _aot_build(monkeypatch, devices, limit=limit,
+               stub=_no_room(monkeypatch, "compile_out_of_hbm"))
+    (span,) = _spans(record, "step_build")
+    assert span["remat_keep_fallback"] is True
+    assert span["remat_estimate_bytes"] == (
+        limit - step_remat.RESERVE_BYTES - span["remat_budget_bytes"])
+
+
+def test_remat_choice_arithmetic_by_hand():
+    choice = RematChoice(
+        keep=("a", "b"), keep_bytes=1_000, budget_bytes=2_000,
+        limit_bytes=10_000 + step_remat.RESERVE_BYTES, args_bytes=3_000,
+        charged_bytes=920)
+    assert choice.estimate_bytes() == 10_000 - (2_000 - 920)
+    assert choice.estimate_bytes(fallback=True) == 10_000 - 2_000
+    assert choice.attrs()["remat_estimate_bytes"] == 8_920
+    assert choice.attrs(fallback=True)["remat_estimate_bytes"] == 8_000
+    assert RematChoice().estimate_bytes() is None
+    assert RematChoice().attrs()["remat_estimate_bytes"] is None

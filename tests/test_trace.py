@@ -663,13 +663,18 @@ def test_step_iter_children_nest_and_pipeline_spans_land(
     assert record.scope_tables == {}
 
 
-def test_no_profiler_no_session_records_nothing(record, tiny_train_setup):
+def test_no_profiler_no_session_records_no_per_step_name(
+        record, tiny_train_setup):
     from gke_ray_train_tpu.train.loop import run_training
     from tests.test_obs import _batches
     _, _, state, step = tiny_train_setup
     run_training(state, step, _batches(4), epochs=1, log_every=2,
                  prefetch=2)
-    assert list(record.spans) == [] and record.scope_tables == {}
+    # what ends once a call is kept whoever listens (ISSUE 34), what
+    # ends once a step is not
+    assert sorted(s["name"] for s in record.spans) == [
+        "compile", "train_loop"]
+    assert record.scope_tables == {}
 
 
 def test_session_keeps_per_step_spans_out_of_the_stream(
@@ -715,7 +720,8 @@ def test_aot_step_build_spans_and_scope_table(record, attached):
     # the build's spans are recorded whoever listens: it is over before
     # a profiler could be attached
     built = {s["name"]: s for s in record.spans}
-    assert set(built) == {"step_build", "step_lower", "step_compile"}
+    assert set(built) == {"state_build", "step_build", "step_lower",
+                          "step_compile"}
     assert built["step_build"]["source"] == "compiled"
     assert built["step_lower"]["parent"] == built["step_build"]["id"]
     assert step.info["build_s"] == pytest.approx(
@@ -733,3 +739,114 @@ def test_aot_step_build_spans_and_scope_table(record, attached):
         assert record.scope_tables == {}
         assert not {s["name"] for s in record.spans} \
             & obs_trace.PER_STEP_SPANS
+
+
+# ---------------------------------------------------------------------------
+# set-up on the record (ISSUE 34): what ends once a call is kept always
+# ---------------------------------------------------------------------------
+
+SETUP_NAMES = {"state_build": ("args_bytes",),
+               "train_loop": ("steps", "to_first_step_s"),
+               "step_lower": ("trace_s", "to_mlir_s"),
+               "step_compile": ("cache", "retrieval_s",
+                                "backend_compile_s")}
+
+
+@pytest.mark.parametrize("name", sorted(SETUP_NAMES))
+def test_setup_names_and_attributes_in_code_and_schema(name):
+    attrs = SETUP_NAMES[name]
+    assert obs_trace.SPAN_NAMES[name] == attrs
+    assert tuple(obs_trace.load_schema()["names"][name]) == attrs
+    assert obs_trace.check_schema() == []
+    obs_trace.validate_span(name, dict.fromkeys(attrs, 1))
+    with pytest.raises(obs_trace.SpanError):
+        obs_trace.validate_span(name, {"stray": 1})
+
+
+@pytest.mark.parametrize("attr", ["xla_memory", "remat_estimate_bytes"])
+def test_step_build_declares_the_memory_attributes(attr):
+    assert attr in obs_trace.SPAN_NAMES["step_build"]
+    assert attr in obs_trace.load_schema()["names"]["step_build"]
+
+
+@pytest.mark.parametrize("name", ["compile", "state_build", "train_loop"])
+def test_once_a_call_names_are_always_recorded(name):
+    assert name in obs_trace.ALWAYS_RECORDED
+    assert name not in obs_trace.PER_STEP_SPANS
+    # the vocabulary that rides the compile cache's key is not touched
+    assert obs_trace.SCOPE_VERSION == 4
+
+
+def test_train_loop_is_the_parent_of_compile_and_counts_its_steps(
+        record, tiny_train_setup):
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    _, _, state, step = tiny_train_setup
+    _, last = run_training(state, step, _batches(3), epochs=1,
+                           log_every=1)
+    spans = {s["name"]: s for s in record.spans}
+    loop, first = spans["train_loop"], spans["compile"]
+    # no profiler, no session: the iteration's region between the two
+    # is not kept, so `compile` names the nearest region that is
+    assert first["parent"] == loop["id"] and loop["parent"] is None
+    assert loop["t0"] <= first["t0"] <= first["t1"] <= loop["t1"]
+    assert loop["steps"] == 3 and first["step"] == 1
+    assert loop["to_first_step_s"] == pytest.approx(
+        last["restart_to_first_step_s"])
+    assert loop["to_first_step_s"] <= loop["t1"] - loop["t0"]
+
+
+def test_train_loop_region_closes_on_a_failing_step(record,
+                                                    tiny_train_setup):
+    from gke_ray_train_tpu.train.loop import run_training
+    from tests.test_obs import _batches
+    _, _, state, step = tiny_train_setup
+
+    def failing(st, batch):
+        raise RuntimeError("step failed")
+    with pytest.raises(RuntimeError, match="step failed"):
+        run_training(state, failing, _batches(2), epochs=1)
+    loop = next(s for s in record.spans if s["name"] == "train_loop")
+    assert loop["steps"] == 0 and loop["to_first_step_s"] is None
+    # the thread's stack of open regions is empty again
+    with obs_trace.region("state_build") as r:
+        assert r.parent is None
+
+
+def test_state_build_is_recorded_with_one_device_s_share(record):
+    import jax
+
+    from gke_ray_train_tpu.models import tiny
+    from gke_ray_train_tpu.train import make_optimizer, make_train_state
+    from gke_ray_train_tpu.train.remat import shard_bytes
+    cfg = tiny(vocab_size=64, d_model=32, n_layers=1, n_heads=2,
+               n_kv_heads=2, d_ff=64, dtype="float32",
+               param_dtype="float32")
+    state = make_train_state(cfg, make_optimizer(1e-3), jax.random.key(0))
+    (span,) = [s for s in record.spans if s["name"] == "state_build"]
+    assert span["parent"] is None
+    assert span["args_bytes"] == shard_bytes(state) > 0
+
+
+@pytest.mark.parametrize("name", ["train_loop", "state_build"])
+def test_critical_path_books_no_term_for_the_setup_spans(tmp_path, name):
+    """The reconciliation of the fixture of
+    test_critical_path_reconciles_and_doctored_trips is unchanged by a
+    span of the whole loop, or of the state's build, beside it."""
+    from gke_ray_train_tpu.obs import critical
+    from gke_ray_train_tpu.obs.report import build_report
+    assert name not in critical.SPAN_TERM
+    _fake_attempt(tmp_path, compile_span_s=1.0, ledger=LEDGER)
+    before = build_report(str(tmp_path))["attempts"][0]["critical_path"]
+    spans = obs_trace.SpanLog(obs_trace.spans_path(str(tmp_path), 0),
+                              run_id="runZ", attempt=1, rank=0)
+    spans.emit(name, LEDGER["wall_s"])
+    spans.close()
+    rep = build_report(str(tmp_path))
+    after = rep["attempts"][0]["critical_path"]
+    assert rep["critical_path_ok"] and after["reconciliation"]["ok"]
+    assert after["span_terms"] == before["span_terms"]
+    assert after["reconciliation"] == before["reconciliation"]
+    # the loop's own span holds every other: it is no step of the path
+    on_path = [p["name"] for p in after["path"]]
+    assert (name in on_path) == (name == "state_build")
