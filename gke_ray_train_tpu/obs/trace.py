@@ -143,7 +143,11 @@ SPAN_NAMES: Dict[str, tuple] = {
     # take, `impl`: pallas / xla; chunk, chunks a row, heads, head size,
     # state, groups, heads a grid step or block, grid steps of a kernel
     # call a row, layers; models/transformer.py::ssm_geometry), {}
-    # without such layers. `remat_estimate_bytes`: the peak the
+    # without such layers. `moe_gather`: the routed layer's
+    # gather-and-sum for the step's tokens (the form, `impl`: pallas /
+    # xla; tokens a grid step, the pair buffer's rows, a row's bytes,
+    # picks a token; ops/moe.py::gather_geometry), {} without a dropless
+    # routed layer. `remat_estimate_bytes`: the peak the
     # chooser's arithmetic expects for the step it asked for (None
     # where no limit is reported). `xla_memory`: what XLA laid out for
     # the executable that will run, from `compiled.memory_analysis()`,
@@ -153,7 +157,7 @@ SPAN_NAMES: Dict[str, tuple] = {
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
                    "remat_budget_bytes", "remat_args_bytes",
                    "remat_keep_fallback", "flash_grid", "ssm_scan",
-                   "remat_estimate_bytes", "xla_memory"),
+                   "moe_gather", "remat_estimate_bytes", "xla_memory"),
     # what jax's own events said while the region was open
     # (perf/cache.py's listener): `trace_s` the step's trace to a
     # jaxpr, `to_mlir_s` its lowering to a module, each the time of the
@@ -236,6 +240,7 @@ KERNEL_NAMES = (
     "flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd", "fused_ce_dx",
     "fused_ce_dhead", "fused_rmsnorm", "fused_rope_qk",
     "fused_rmsnorm_rope", "ssd_fwd", "ssd_states", "ssd_bwd",
+    "moe_gather_sum",
     ) + LIBRARY_KERNEL_NAMES
 
 # the profiler's host plane shows a region under this prefix

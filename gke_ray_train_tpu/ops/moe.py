@@ -31,7 +31,10 @@ expert) pairs are sorted by expert into a buffer sized for the worst
 case the held share allows (every one of a token's picks held), and the
 three products run over the real group sizes (:func:`grouped_dot`: a
 kernel that visits only the tiles the groups cover), so imbalance costs
-time and never a token. The
+time and never a token. The combine's forward and the dispatch's
+backward sum a token's K rows of the buffer (:func:`gather_sum`): where
+rows are wide, a kernel that copies them into VMEM and sums them there,
+so that no ``[T, K, D]`` tensor reaches HBM. The
 layer is told which experts it holds (``cfg.experts_held``); it scores
 all ``n_experts``, normalises a token's weights over all it selected,
 and computes the pairs that land in its range, which is what one
@@ -41,13 +44,18 @@ is no exchange, and nothing here stands in for one.
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
-
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from gke_ray_train_tpu.models.config import ModelConfig
 from gke_ray_train_tpu.obs.trace import scope
+from gke_ray_train_tpu.ops.flash_attention import interpret_default
 
 # per-step counters of the dropless layer, in the train step's metrics:
 # pairs dispatched to held experts (summed over layers and
@@ -239,6 +247,22 @@ def pair_buffer_rows(cfg: ModelConfig, tokens: int) -> int:
     return tokens * min(cfg.expert_top_k, cfg.n_experts_held)
 
 
+def gather_geometry(cfg: ModelConfig, tokens: int) -> dict:
+    """The routed layer's gather-and-sum (the combine's forward and the
+    dispatch's backward) for ``tokens`` a micro-batch: the form
+    :func:`gather_plan` picks, its token tile, the pair buffer's rows,
+    the bytes of a row and the picks a token (the ``step_build`` span's
+    ``moe_gather``); ``{}`` without such a layer."""
+    if not cfg.dropless_router or cfg.n_layers <= cfg.n_dense_layers:
+        return {}
+    rows = pair_buffer_rows(cfg, tokens)
+    dtype = jnp.dtype(cfg.dtype)
+    plan = gather_plan(rows, cfg.d_model, cfg.expert_top_k, dtype, tokens)
+    return {"impl": plan.impl, "token_tile": plan.token_tile, "rows": rows,
+            "row_bytes": cfg.d_model * dtype.itemsize,
+            "picks": cfg.expert_top_k}
+
+
 def select_experts(x, router_w, router_bias, cfg: ModelConfig):
     """x [T, D] -> (idx [T, K] int32, weights [T, K] float32).
 
@@ -263,6 +287,156 @@ def select_experts(x, router_w, router_bias, cfg: ModelConfig):
     return idx, w * cfg.router_scale
 
 
+# ---------------------------------------------------------------------------
+# y[t] = sum_k w[t, k] buf[row[t, k]]: the combine's forward and the
+# dispatch's backward, a token's K rows summed in VMEM
+# ---------------------------------------------------------------------------
+
+# read off scripts/moe_gather_sweep.py on the v5e (PERF.md section 6,
+# PR 35): the kernel issues a row's copy in ~21 ns whatever its size, so
+# it wins where a row is 8 KB or more (a hidden of 4096 in bf16) and
+# XLA's form wins at 4 KB; tokens a grid step (16 to 256 read within 1%
+# but at 12 KB rows, where 32 led by 5%); VMEM for the two slots
+GATHER_MIN_ROW_BYTES = 8192
+GATHER_TILE = 32
+GATHER_SLOTS_BYTES = 16 * 2**20
+
+
+class GatherPlan(NamedTuple):
+    """How :func:`gather_sum` runs one shape: ``impl`` ``pallas`` (the
+    kernel ``moe_gather_sum``) or ``xla`` (the sum unrolled over K in
+    ``jax.numpy``); ``token_tile`` the tokens of a grid step (0 under
+    ``xla``)."""
+    impl: str
+    token_tile: int
+
+
+def gather_plan(rows: int, width: int, picks: int, dtype,
+                tokens: Optional[int] = None) -> GatherPlan:
+    """The one rule that picks the form, from shapes alone: the kernel
+    where a row holds :data:`GATHER_MIN_ROW_BYTES` or more and is whole
+    tiles (``width / 128`` a multiple of the sublanes of one: 8, or 16
+    for a type packed two a word), with the largest multiple of 8 tokens
+    up to :data:`GATHER_TILE` that divides ``tokens`` (``rows // picks``,
+    the worst-case buffer's, by default) and keeps both slots within
+    :data:`GATHER_SLOTS_BYTES`. Else the ``jax.numpy`` form."""
+    tokens = rows // picks if tokens is None else tokens
+    item = jnp.dtype(dtype).itemsize
+    sublanes = 8 * 4 // item
+    most = min(GATHER_TILE, tokens,
+               GATHER_SLOTS_BYTES // (2 * picks * width * item))
+    if width * item >= GATHER_MIN_ROW_BYTES \
+            and width % (128 * sublanes) == 0:
+        for tile in range(most - most % 8, 0, -8):
+            if tokens % tile == 0:
+                return GatherPlan("pallas", tile)
+    return GatherPlan("xla", 0)
+
+
+def gather_sum(buf: jnp.ndarray, row: jnp.ndarray, w: jnp.ndarray, *,
+               plan: Optional[GatherPlan] = None,
+               interpret: Optional[bool] = None) -> jnp.ndarray:
+    """buf [P, D], row [T, K] int32, w [T, K] -> [T, D] in buf's dtype:
+    ``y[t] = sum_k w[t, k] buf[row[t, k]]``, each product and the sum in
+    float32 and one rounding on the way out. Every pick's row is read;
+    one whose weight is 0 adds 0 whatever its row holds (a buffer row
+    past the live ones may hold anything). ``plan`` overrides
+    :func:`gather_plan`'s (tests, the sweep); off the chip the kernel
+    runs interpreted."""
+    T, K = row.shape
+    P, D = buf.shape
+    plan = plan or gather_plan(P, D, K, buf.dtype, T)
+    w = w.astype(jnp.float32)
+    if plan.impl == "pallas":
+        out = _gather_sum_pallas(buf.reshape(P, D // 128, 128), row, w,
+                                 tile=plan.token_tile,
+                                 interpret=interpret_default(interpret))
+        return out.reshape(T, D)
+    return _gather_sum_xla(buf, row, w)
+
+
+def _gather_sum_xla(buf, row, w):
+    acc = None
+    for k in range(row.shape[1]):
+        wk = w[:, k:k + 1]
+        term = jnp.where(wk != 0, buf[row[:, k]].astype(jnp.float32) * wk,
+                         0.0)
+        acc = term if acc is None else acc + term
+    return acc.astype(buf.dtype)
+
+
+def _gather_sum_kernel(row_ref, w_ref, buf_ref, out_ref, rows, sem, *,
+                       tile: int, picks: int):
+    """A grid step: the K rows of each of ``tile`` tokens, copied from
+    HBM into one slot and summed there a token at a time. A row is
+    ``[D / 128, 128]``, whole tiles, so that each copy is one aligned
+    block; the next step's copies start before this step's wait, into
+    the other slot."""
+    i = pl.program_id(0)
+    slot = i % 2
+
+    def fetch(step, into):
+        first = step * tile * picks
+
+        def one_token(t, carry):
+            for k in range(picks):
+                pltpu.make_async_copy(
+                    buf_ref.at[row_ref[first + t * picks + k]],
+                    rows.at[into, k, t], sem.at[into]).start()
+            return carry
+        jax.lax.fori_loop(0, tile, one_token, 0)
+
+    @pl.when(i == 0)
+    def _():
+        fetch(0, 0)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _():
+        fetch(i + 1, 1 - slot)
+
+    # one wait for the whole slot: the semaphore counts what has landed
+    pltpu.make_async_copy(rows.at[slot], rows.at[slot], sem.at[slot]).wait()
+
+    def one_sum(t, carry):
+        acc = jnp.zeros(out_ref.shape[1:], jnp.float32)
+        for k in range(picks):
+            wk = w_ref[t, k]
+            acc = acc + jnp.where(
+                wk != 0, rows[slot, k, t].astype(jnp.float32) * wk, 0.0)
+        out_ref[t] = acc.astype(out_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, tile, one_sum, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _gather_sum_pallas(buf, row, w, *, tile: int, interpret: bool):
+    """buf [P, D / 128, 128] (a row is whole tiles, so that each copy is
+    one aligned block), ``row`` [T, K] scalar-prefetched flat (a step
+    starts the next one's copies), ``w`` [T, K] a tile at a time in
+    SMEM -> [T, D / 128, 128]. Jitted: the routed layers of a step share
+    one trace and one lowering a use."""
+    (_, lanes, _), (T, K) = buf.shape, row.shape
+    slots = 2 * K * tile * lanes * 128 * buf.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_gather_sum_kernel, tile=tile, picks=K),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(T // tile,),
+            in_specs=[pl.BlockSpec((tile, K), lambda i, row: (i, 0),
+                                   memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, lanes, 128),
+                                   lambda i, row: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, K, tile, lanes, 128), buf.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((T, lanes, 128), buf.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=slots + 8 * 2**20),
+        interpret=interpret,
+        name="moe_gather_sum",
+    )(row.reshape(T * K), w, buf)
+
+
 @jax.custom_vjp
 def _dispatch(x, tok, row, held):
     """x [T, D] -> x[tok] [P, D]. ``row`` [T, K]: the buffer row of each
@@ -279,9 +453,7 @@ def _dispatch_fwd(x, tok, row, held):
 
 def _dispatch_bwd(res, g):
     row, held = res
-    dx = jnp.sum(jnp.where(held[..., None], g[row], 0), axis=1,
-                 dtype=jnp.float32)
-    return dx.astype(g.dtype), None, None, None
+    return gather_sum(g, row, held), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -296,8 +468,9 @@ def _combine(out, w, tok, row, pair):
 
 
 def _weighted_rows(out, w, row):
-    return jnp.einsum("tkd,tk->td", out[row], w.astype(out.dtype),
-                      preferred_element_type=jnp.float32).astype(out.dtype)
+    # the weights rounded to the compute dtype, as the einsum this
+    # replaced rounded them
+    return gather_sum(out, row, w.astype(out.dtype))
 
 
 def _combine_fwd(out, w, tok, row, pair):
@@ -373,9 +546,9 @@ def routed_experts(x: jnp.ndarray, lp: dict, cfg: ModelConfig, dtype,
             act = jax.nn.gelu(gate, approximate=True)
         else:
             raise ValueError(f"unknown activation {cfg.activation}")
+        # rows past the live ones hold whatever the kernel left there:
+        # their weights are 0, and gather_sum selects, never multiplies
         out = dot(act * up, maybe_dequantize(lp["w_down"], dtype), sizes)
-        # rows past the live ones hold whatever the kernel left there
-        out = jnp.where((jnp.arange(P) < pairs)[:, None], out, 0)
     with scope("moe/combine"):
         y = _combine(out, jnp.where(held, w, 0.0), tok, row, order)
     mean = jnp.maximum(pairs, 1).astype(jnp.float32) / G

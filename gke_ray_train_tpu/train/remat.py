@@ -29,6 +29,7 @@ from gke_ray_train_tpu.models.remat import (
     KEPT_PEAK_SHARE, choose_keep, keep_candidates, working_set_bytes)
 from gke_ray_train_tpu.models.transformer import (
     flash_grids, resolve_seq_impl, ssm_geometry)
+from gke_ray_train_tpu.ops.moe import gather_geometry
 from gke_ray_train_tpu.ops.quant import stored_bits
 from gke_ray_train_tpu.perf.cache import StepFallback, build_or_load_step
 
@@ -171,10 +172,10 @@ class StepRemat:
         the limit, builds ``step`` instead."""
         choice = self.choose(state, batch)
         # the same for either step: the checkpoints move no kernel
-        grid = {"flash_grid": flash_grids(self.cfg, self.mesh,
-                                          *self.micro_shape(batch)),
-                "ssm_scan": ssm_geometry(self.cfg,
-                                         self.micro_shape(batch)[1])}
+        rows, seq = self.micro_shape(batch)
+        grid = {"flash_grid": flash_grids(self.cfg, self.mesh, rows, seq),
+                "ssm_scan": ssm_geometry(self.cfg, seq),
+                "moe_gather": gather_geometry(self.cfg, rows * seq)}
         if choice.keep:
             built = build_or_load_step(
                 self.with_keep(choice.keep), state, batch, label=label,
@@ -215,4 +216,11 @@ class StepRemat:
                 g["impl"], g["chunks_a_row"], g["chunk"], g["head_block"],
                 "grid step" if g["impl"] == "pallas" else "block",
                 g["grid_steps_a_row"])
+        if grid["moe_gather"]:
+            g = grid["moe_gather"]
+            logger.info(
+                "%s: routed gather-and-sum as %s: %d tokens a grid step, "
+                "%d picks a token from %d buffer rows of %d bytes", label,
+                g["impl"], g["token_tile"], g["picks"], g["rows"],
+                g["row_bytes"])
         return built

@@ -30,7 +30,7 @@ from gke_ray_train_tpu.models.config import (
 from gke_ray_train_tpu.models.transformer import (
     SHARED_MLP, _mlp, _moe, block_layout, block_leaves, forward,
     init_params, ssm_geometry)
-from gke_ray_train_tpu.ops import ssm
+from gke_ray_train_tpu.ops import moe, ssm
 
 MIXER_LEAVES = ["in_proj", "conv_w", "conv_b", "dt_bias", "a_log", "d_skip",
                 "ssm_norm", "out_proj"]
@@ -821,6 +821,43 @@ def test_v5e_scan_keeps_a_heads_decay_out_of_hbm(v5e):
     assert not re.findall(rf"f32\[[\d,]*{Q},{Q}\]", hlo)
     # x, y and the two cotangents are 134 MB each; a block's decay was 134
     assert built.memory_analysis().temp_size_in_bytes < 2 * S * H * P * 2
+
+
+def test_v5e_no_pick_row_reaches_hbm(v5e):
+    """What the gain rests on, at the hybrid cell's shape (8192 tokens,
+    10 picks, rows of 4096 in bf16): compiled for the v5e, the combine's
+    forward and the dispatch's backward are one ``moe_gather_sum`` call
+    each, and no ``[T, K, D]`` tensor, bf16 or float32, is among the
+    program's operations (before PR 35 XLA wrote ``f32[8192, 10, 4096]``
+    and the gathered rows in bf16)."""
+    from jax.sharding import SingleDeviceSharding
+    T, K, D = 8192, 10, 4096
+    P = T * K
+    sharding = SingleDeviceSharding(v5e)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def layer(x, tok, row, held, w, order, dy):
+        def f(x):
+            xs = moe._dispatch(x, tok, row, held)
+            return moe._combine(xs * 2, w, tok, row, order)
+        y, vjp = jax.vjp(f, x)
+        return y, vjp(dy)
+    # the suite asks for float32 products (conftest.py); the program's
+    # own default here
+    with jax.default_matmul_precision("default"), \
+            pytest.MonkeyPatch.context() as mp:
+        mp.setattr(moe, "interpret_default", lambda interpret: False)
+        built = jax.jit(layer).lower(
+            spec((T, D)), spec((P,), jnp.int32), spec((T, K), jnp.int32),
+            spec((T, K), jnp.bool_), spec((T, K), jnp.float32),
+            spec((P,), jnp.int32), spec((T, D))).compile()
+    hlo = built.as_text()
+    calls = re.findall(r"%[\w.\-]*?(moe_gather_sum)[\w.\-]* = .*"
+                       r"custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(calls) == 2, calls
+    assert not re.findall(rf"\[{T},{K},{D}\]", hlo)
 
 
 def test_serving_a_pipelined_mesh_and_the_converters_refuse_by_name(devices):
