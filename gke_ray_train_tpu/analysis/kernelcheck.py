@@ -91,7 +91,7 @@ LEDGER_FLOOR = 1e-9
 REQUIRED_KERNELS = frozenset({
     "flash_attention", "ring_attention", "a2a_attention",
     "quant_matmul", "moe_dispatch", "rope", "kvcache_insert",
-    "fused_norm_rope", "fused_cross_entropy", "hier_psum"})
+    "fused_norm_rope", "fused_cross_entropy", "hier_psum", "nf4_matmul"})
 
 # TPU tiling: lane is always 128; sublane depends on dtype
 SUBLANE = {"float32": 8, "bfloat16": 16, "float16": 16,

@@ -284,7 +284,7 @@ def _constrain(x, mesh: Optional[Mesh], *spec):
 
 
 def _proj(x, w, lora_p, lora_scale, dtype, drop_rng=None, drop_rate=0.0,
-          bias=None):
+          bias=None, mesh=None):
     """x @ w (+ bias), plus the low-rank LoRA bypass when adapters are
     present. ``bias``: optional [d_out] projection bias (Qwen-2 q/k/v).
 
@@ -292,7 +292,10 @@ def _proj(x, w, lora_p, lora_scale, dtype, drop_rng=None, drop_rate=0.0,
     the TPU-native replacement for peft's adapter modules (reference:
     ray-jobs/fine_tune_llama_ray.py:245-252, SURVEY.md row D6). ``w``
     may be a quantized QTensor (QLoRA base weights, SURVEY.md row D5) —
-    dequantized here, in-jit, so XLA fuses it into the matmul prologue.
+    decoded here, in-jit: inside the product's kernel where
+    ``ops/quant.py::nf4_matmul_plan`` takes the shapes and ``mesh`` has
+    one device (a kernel's operands are whole), else by ``dequantize``
+    before the product.
 
     ``drop_rng``/``drop_rate``: LoRA dropout (reference LORA_DROPOUT,
     fine_tune_config.json:32) — peft semantics: dropout on the *adapter
@@ -300,9 +303,10 @@ def _proj(x, w, lora_p, lora_scale, dtype, drop_rng=None, drop_rate=0.0,
     """
     # local import: ops.quant -> train.lora -> models.transformer is a
     # module-level chain, so this reverse edge must stay deferred
-    from gke_ray_train_tpu.ops.quant import maybe_dequantize
+    from gke_ray_train_tpu.ops.quant import frozen_matmul
     with scope("base"):
-        y = jnp.einsum("bsd,dh->bsh", x, maybe_dequantize(w, dtype))
+        y = frozen_matmul(x, w, dtype,
+                          whole=mesh is not None and mesh.size == 1)
     if lora_p is not None:
         with scope("lora"):
             y = y + _lora_bypass(x, lora_p, lora_scale, dtype, drop_rng,
@@ -371,16 +375,16 @@ SHARED_MLP = (SHARED_TARGETS, "moe/shared", "moe/shared")
 
 
 def _mlp(x, lp, cfg: ModelConfig, dtype, lora_p=None, lora_scale=1.0,
-         drop_rng=None, drop_rate=0.0, which=DENSE_MLP):
+         drop_rng=None, drop_rate=0.0, which=DENSE_MLP, mesh=None):
     (w_gate, w_up, w_down), gate_up_scope, down_scope = which
 
     def lr(name):
         return _lora_entry(lora_p, name)
     with scope(gate_up_scope):
         gate = _proj(x, lp[w_gate], lr(w_gate), lora_scale, dtype,
-                     _drop_key(drop_rng, 4), drop_rate)
+                     _drop_key(drop_rng, 4), drop_rate, mesh=mesh)
         up = _proj(x, lp[w_up], lr(w_up), lora_scale, dtype,
-                   _drop_key(drop_rng, 5), drop_rate)
+                   _drop_key(drop_rng, 5), drop_rate, mesh=mesh)
         gate, up = (checkpoint_name(t, gate_up_scope) for t in (gate, up))
         if cfg.activation == "silu":
             act = jax.nn.silu(gate)
@@ -391,11 +395,12 @@ def _mlp(x, lp, cfg: ModelConfig, dtype, lora_p=None, lora_scale=1.0,
         h = act * up
     with scope(down_scope):
         return _proj(h, lp[w_down], lr(w_down), lora_scale, dtype,
-                     _drop_key(drop_rng, 6), drop_rate)
+                     _drop_key(drop_rng, 6), drop_rate, mesh=mesh)
 
 
 def _moe(x, lp, cfg: ModelConfig, dtype, segment_ids, token_weights,
-         lora_p=None, lora_scale=1.0, drop_rng=None, drop_rate=0.0):
+         lora_p=None, lora_scale=1.0, drop_rng=None, drop_rate=0.0,
+         mesh=None):
     """The routed MLP of one layer -> (y, stats of ops/moe.py).
 
     "softmax" (Mixtral): LoRA adapts attention only, there being no
@@ -415,7 +420,7 @@ def _moe(x, lp, cfg: ModelConfig, dtype, segment_ids, token_weights,
     if cfg.n_shared_experts:
         y = y + _mlp(x, lp, cfg, dtype, lora_p=lora_p,
                      lora_scale=lora_scale, drop_rng=drop_rng,
-                     drop_rate=drop_rate, which=SHARED_MLP)
+                     drop_rate=drop_rate, which=SHARED_MLP, mesh=mesh)
     return y, counters
 
 
@@ -505,7 +510,8 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
 
     def proj(h, name, tag, bias=None):
         return _proj(h, lp[name], _lora_entry(lora_p, name), lora_scale,
-                     dtype, _drop_key(drop_rng, tag), drop_rate, bias=bias)
+                     dtype, _drop_key(drop_rng, tag), drop_rate, bias=bias,
+                     mesh=mesh)
     if cfg.latent_attention:
         q, k, v = _latent_qkv(x, lp, cfg, rope, positions, mesh, proj)
     else:
@@ -536,7 +542,7 @@ def _attn(x, lp, cfg: ModelConfig, impl, dtype, rope, positions, mask,
 
 
 def _ssm(x, lp, cfg: ModelConfig, dtype, segment_ids, lora_p=None,
-         lora_scale=1.0, drop_rng=None, drop_rate=0.0):
+         lora_scale=1.0, drop_rng=None, drop_rate=0.0, mesh=None):
     """The mixer of a state-space layer (Mamba-2), x [B, S, D] ->
     [B, S, D]: ``[z | xBC | dt] = x W_in``; a causal depthwise conv and
     SiLU over ``xBC``; the selective scan over ``[x | B | C]`` with
@@ -556,7 +562,7 @@ def _ssm(x, lp, cfg: ModelConfig, dtype, segment_ids, lora_p=None,
 
     def proj(h, name, tag):
         return _proj(h, lp[name], _lora_entry(lora_p, name), lora_scale,
-                     dtype, _drop_key(drop_rng, tag), drop_rate)
+                     dtype, _drop_key(drop_rng, tag), drop_rate, mesh=mesh)
     with scope("ssm/in_proj"):
         zxbcdt = checkpoint_name(proj(x, "in_proj", 0), "ssm/in_proj")
         z = zxbcdt[..., :inner]
@@ -657,7 +663,7 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
         if kind == "ssm":
             h = _ssm(h, lp, cfg, dtype, segment_ids, lora_p=lo,
                      lora_scale=lora_scale, drop_rng=_drop_key(drng, 0),
-                     drop_rate=lora_dropout)
+                     drop_rate=lora_dropout, mesh=mesh)
         else:
             h = _attn(h, lp, cfg, impl, dtype,
                       rope if kind in cfg.rope_kinds else None, positions,
@@ -683,13 +689,13 @@ def run_block_stack(x, aux, layer_slice, cfg: ModelConfig, impl, dtype,
             h, a = _moe(h, lp, cfg, dtype, segment_ids, token_weights,
                         lora_p=lo, lora_scale=lora_scale,
                         drop_rng=_drop_key(drng, 1),
-                        drop_rate=lora_dropout)
+                        drop_rate=lora_dropout, mesh=mesh)
             aux = stats_merge(aux, a)
         else:
             h = _mlp(h, lp, cfg, dtype, lora_p=lo,
                      lora_scale=lora_scale,
                      drop_rng=_drop_key(drng, 1),
-                     drop_rate=lora_dropout)
+                     drop_rate=lora_dropout, mesh=mesh)
         with scope("moe/combine" if moe else "mlp/down"):
             if cfg.post_block_norm:
                 h = _rms_norm(h, lp["mlp_post_norm"], eps=eps,

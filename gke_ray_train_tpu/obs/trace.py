@@ -147,7 +147,11 @@ SPAN_NAMES: Dict[str, tuple] = {
     # gather-and-sum for the step's tokens (the form, `impl`: pallas /
     # xla; tokens a grid step, the pair buffer's rows, a row's bytes,
     # picks a token; ops/moe.py::gather_geometry), {} without a dropless
-    # routed layer. `remat_estimate_bytes`: the peak the
+    # routed layer. `nf4_matmul`: the frozen products for the step's
+    # rows, by weight shape the form ops/quant.py::nf4_matmul_plan
+    # picked, its tiles and the calls a micro-pass, then the calls of
+    # each form (ops/quant.py::nf4_geometry), {} without a quantized
+    # projection. `remat_estimate_bytes`: the peak the
     # chooser's arithmetic expects for the step it asked for (None
     # where no limit is reported). `xla_memory`: what XLA laid out for
     # the executable that will run, from `compiled.memory_analysis()`,
@@ -157,7 +161,8 @@ SPAN_NAMES: Dict[str, tuple] = {
     "step_build": ("source", "remat_keep", "remat_keep_bytes",
                    "remat_budget_bytes", "remat_args_bytes",
                    "remat_keep_fallback", "flash_grid", "ssm_scan",
-                   "moe_gather", "remat_estimate_bytes", "xla_memory"),
+                   "moe_gather", "nf4_matmul", "remat_estimate_bytes",
+                   "xla_memory"),
     # what jax's own events said while the region was open
     # (perf/cache.py's listener): `trace_s` the step's trace to a
     # jaxpr, `to_mlir_s` its lowering to a module, each the time of the
@@ -232,7 +237,8 @@ SCOPE_NAMES = (
 SCOPE_VERSION = 4
 
 # pl.pallas_call(name=...) of every kernel (ops/flash_attention.py,
-# ops/fused_ce.py, ops/fused_norm_rope.py, ops/ssm.py), then the
+# ops/fused_ce.py, ops/fused_norm_rope.py, ops/ssm.py, ops/moe.py,
+# ops/quant.py), then the
 # library's kernels the program calls under the names the library gave
 # them: jax's megablox grouped matmul and its transpose (ops/moe.py)
 LIBRARY_KERNEL_NAMES = ("gmm", "tgmm")
@@ -240,7 +246,7 @@ KERNEL_NAMES = (
     "flash_fwd", "flash_dq", "flash_dkv", "fused_ce_fwd", "fused_ce_dx",
     "fused_ce_dhead", "fused_rmsnorm", "fused_rope_qk",
     "fused_rmsnorm_rope", "ssd_fwd", "ssd_states", "ssd_bwd",
-    "moe_gather_sum",
+    "moe_gather_sum", "nf4_matmul", "nf4_matmul_dx",
     ) + LIBRARY_KERNEL_NAMES
 
 # the profiler's host plane shows a region under this prefix

@@ -11,6 +11,9 @@ v5e a decode is one fusion of its own (half a byte a weight in, the
 compute dtype out, 4.5-4.9 ps a weight) and the product after it reads
 the decoded weight; ``dequantize`` says why it is not the product's
 prologue, and what that cost until PR 31 (PERF.md section 6).
+Where a projection's operands are whole on one device and its shapes
+tile, the decode is inside the product instead (:func:`frozen_matmul`:
+the kernel pair ``nf4_matmul`` / ``nf4_matmul_dx``).
 
 - "nf4": 4-bit NormalFloat codebook (the QLoRA data type), absmax-scaled
   per group. The codes are ``jnp.uint4`` of the weight's own shape, on
@@ -31,11 +34,13 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Any
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # NF4 codebook (QLoRA appendix E; public constant) — the 16 values are
 # quantiles of N(0,1) normalized to [-1, 1].
@@ -200,6 +205,273 @@ def maybe_dequantize(w: Any, dtype) -> jnp.ndarray:
     if is_qtensor(w):
         return dequantize(w, dtype)
     return w.astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# x @ dequantize(qt) with the decode inside the product: codes and scales
+# read a tile at a time, decoded in VMEM and fed to the MXU, so that the
+# decoded weight never reaches HBM
+# ---------------------------------------------------------------------------
+
+# read off scripts/nf4_matmul_sweep.py on the v5e (its table is in
+# PERF.md section 6): the rows a call where the kernel beat the decode
+# and the product in turn (32 and 2048; one packed row of 8192 lost in
+# dx, where a weight is decoded once a row tile), and the tiles a grid
+# step at most: forward (rows, columns, contraction), dx (columns,
+# contraction)
+NF4_MIN_ROWS = 32
+NF4_MAX_ROWS = 2048
+NF4_TILE = (2048, 2048, 512)
+NF4_DX_TILE = (1024, 1024)
+
+
+class Nf4Plan(NamedTuple):
+    """How :func:`frozen_matmul` runs one product ``[rows, depth] x
+    [depth, cols]``: ``impl`` ``pallas`` (the kernels ``nf4_matmul``
+    forward, a weight tile of ``[depth, cols]`` and ``rows`` rows a grid
+    step, and ``nf4_matmul_dx`` backward, a weight tile of ``[dx_depth,
+    dx_cols]``) or ``xla`` (:func:`dequantize`, then the product); tiles
+    0 under ``xla``."""
+    impl: str
+    rows: int = 0
+    cols: int = 0
+    depth: int = 0
+    dx_cols: int = 0
+    dx_depth: int = 0
+
+
+def _largest_tile(size: int, step: int, most: int) -> int:
+    """The largest multiple of ``step`` up to ``most`` that divides
+    ``size``; 0 where none does."""
+    return next((t for t in range(most - most % step, 0, -step)
+                 if size % t == 0), 0)
+
+
+def nf4_matmul_plan(rows: int, depth: int, cols: int, kind: str,
+                    group: int = DEFAULT_GROUP) -> Nf4Plan:
+    """The one rule that picks the form of a frozen product, from shapes
+    alone: the kernel pair for an NF4 leaf whose rows a call lie in
+    [:data:`NF4_MIN_ROWS`, :data:`NF4_MAX_ROWS`] and whose weight tiles
+    whole: the contraction in steps of eight groups (a tile of scales is
+    then whole sublanes), the columns in steps of 128 lanes, the rows in
+    steps of 16 (bf16 sublanes), each the largest that divides, up to
+    :data:`NF4_TILE` forward and :data:`NF4_DX_TILE` for dx. Everything
+    else (int8 leaves, widths that do not tile, one packed row of 8192)
+    takes ``dequantize`` + einsum."""
+    if kind != "nf4" or not NF4_MIN_ROWS <= rows <= NF4_MAX_ROWS:
+        return Nf4Plan("xla")
+    tiles = (_largest_tile(rows, 16, NF4_TILE[0]),
+             _largest_tile(cols, 128, NF4_TILE[1]),
+             _largest_tile(depth, 8 * group, NF4_TILE[2]),
+             _largest_tile(cols, 128, NF4_DX_TILE[0]),
+             _largest_tile(depth, 8 * group, NF4_DX_TILE[1]))
+    if not all(tiles):
+        return Nf4Plan("xla")
+    return Nf4Plan("pallas", *tiles)
+
+
+def frozen_matmul(x: jnp.ndarray, w: Any, dtype, *,
+                  whole: bool = False) -> jnp.ndarray:
+    """``x [B, S, depth] @ w [depth, cols]`` in ``dtype``: the frozen
+    base of a projection (``models/transformer.py::_proj``). ``w`` a
+    QTensor or a float weight. ``whole``: the operands are whole on one
+    device (the caller's mesh has one), so that a kernel may take them;
+    a Mosaic kernel cannot be partitioned. Where that holds, ``x`` is
+    traced in ``dtype`` and :func:`nf4_matmul_plan` picks the kernel,
+    :func:`nf4_matmul`; else ``dequantize`` + einsum."""
+    if whole and is_qtensor(w) and isinstance(x, jax.core.Tracer) \
+            and x.dtype == jnp.dtype(dtype):
+        *lead, depth = x.shape
+        rows = int(np.prod(lead))
+        plan = nf4_matmul_plan(rows, depth, w.shape[-1], w.kind, w.group)
+        if plan.impl == "pallas":
+            return nf4_matmul(x.reshape(rows, depth), w, plan=plan
+                              ).reshape(*lead, w.shape[-1])
+    return jnp.einsum("bsd,dh->bsh", x, maybe_dequantize(w, dtype))
+
+
+def nf4_geometry(params: Any, rows: int, whole: bool) -> dict:
+    """The frozen products of a step for ``rows`` a micro-batch (the
+    ``step_build`` span's ``nf4_matmul``): by weight shape
+    ``"depth x cols"``, the form :func:`nf4_matmul_plan` picks, its
+    tiles (rows, columns, contraction; ``dx_tiles`` the same for dx) and
+    the calls a micro-pass (layers stacked on the leaf); then the calls
+    a micro-pass of each form. Stacked projections only (a bank of
+    experts is rank 4 and decoded whole, ``ops/moe.py``); ``{}`` without
+    a quantized one."""
+    shapes: dict = {}
+
+    def visit(node):
+        if is_qtensor(node) and node.codes.ndim == 3:
+            layers, depth, cols = node.codes.shape
+            plan = (nf4_matmul_plan(rows, depth, cols, node.kind, node.group)
+                    if whole else Nf4Plan("xla"))
+            key = f"{depth}x{cols}"
+            entry = shapes.setdefault(key, {
+                "impl": plan.impl, "kind": node.kind,
+                "tiles": [plan.rows, plan.cols, plan.depth],
+                "dx_tiles": [plan.rows, plan.dx_cols, plan.dx_depth],
+                "calls": 0})
+            entry["calls"] += layers
+        elif isinstance(node, dict):
+            for v in node.values():
+                visit(v)
+        elif isinstance(node, (list, tuple)):
+            for v in node:
+                visit(v)
+    visit(params)
+    if not shapes:
+        return {}
+    calls = {impl: sum(s["calls"] for s in shapes.values()
+                       if s["impl"] == impl) for impl in ("pallas", "xla")}
+    return {"rows": rows, "shapes": shapes, **calls}
+
+
+def nf4_matmul(x: jnp.ndarray, qt: QTensor, *,
+               plan: Optional[Nf4Plan] = None,
+               interpret: Optional[bool] = None) -> jnp.ndarray:
+    """``x [rows, depth] @ dequantize(qt) [depth, cols] -> [rows, cols]``
+    in x's dtype, the weights decoded a tile at a time inside the
+    kernel: the same values as ``dequantize``'s, bit for bit (the select
+    tree, ``value * scale`` in float32, one rounding), products summed
+    in float32 and rounded once on the way out. Differentiable in ``x``
+    (the kernel ``nf4_matmul_dx``: ``dy @ dequantize(qt)^T``, the same
+    tiles decoded again); the leaf is frozen and gets no cotangent, and
+    the leaf is all the backward keeps. ``plan`` overrides
+    :func:`nf4_matmul_plan`'s (tests, the sweep); off the chip the
+    kernels run interpreted."""
+    from gke_ray_train_tpu.ops.flash_attention import interpret_default
+    rows, depth = x.shape
+    plan = plan or nf4_matmul_plan(rows, depth, qt.shape[-1], qt.kind,
+                                   qt.group)
+    if plan.impl != "pallas":
+        raise ValueError(f"no kernel tiles {x.shape} x {qt.shape} "
+                         f"({qt.kind}); use frozen_matmul")
+    return _nf4_product(x, qt, plan, interpret_default(interpret))
+
+
+def _decode_tile(codes: jnp.ndarray, scales: jnp.ndarray, group: int,
+                 dtype) -> jnp.ndarray:
+    """codes [rows, cols] uint4, scales [rows / group, cols] -> [rows,
+    cols] in ``dtype``: ``dequantize``'s arithmetic on one tile."""
+    tk, tn = codes.shape
+    vals = _nf4_lookup(codes).reshape(tk // group, group, tn)
+    return (vals * scales[:, None, :]).reshape(tk, tn).astype(dtype)
+
+
+def _nf4_fwd_kernel(x_ref, codes_ref, scales_ref, out_ref, acc_ref, *,
+                    group: int):
+    """Grid (rows, columns, contraction): decode a weight tile, multiply,
+    add into the float32 accumulator; the last contraction step writes."""
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    w = _decode_tile(codes_ref[...], scales_ref[...], group, x_ref.dtype)
+    acc_ref[...] += jnp.dot(x_ref[...], w,
+                            preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _nf4_dx_kernel(dy_ref, codes_ref, scales_ref, out_ref, acc_ref, *,
+                   group: int):
+    """Grid (rows, contraction tiles of the forward, its columns): the
+    same weight tile decoded, ``dy [tm, tn] . w [tk, tn]`` over tn."""
+    n = pl.program_id(2)
+
+    @pl.when(n == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    w = _decode_tile(codes_ref[...], scales_ref[...], group, dy_ref.dtype)
+    acc_ref[...] += jax.lax.dot_general(
+        dy_ref[...], w, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _nf4_specs(a, codes, scales, plan: Nf4Plan, *, dx: bool) -> dict:
+    """One direction's grid, blocks and VMEM: ``a`` is x [rows, depth]
+    forward and dy [rows, cols] for dx; the weight tile is [plan.depth,
+    plan.cols] forward and [plan.dx_depth, plan.dx_cols] for dx."""
+    (rows, _), (depth, cols) = a.shape, codes.shape
+    tm = plan.rows
+    tn, tk = (plan.dx_cols, plan.dx_depth) if dx else (plan.cols,
+                                                       plan.depth)
+    group = depth // scales.shape[0]
+    if dx:     # grid: rows, the forward's contraction tiles, its columns
+        grid = (rows // tm, depth // tk, cols // tn)
+        a_block, out = (tm, tn), (tm, tk, depth)
+        a_at = lambda i, j, n: (i, n)   # noqa: E731
+        w_at = lambda i, j, n: (j, n)   # noqa: E731
+    else:      # grid: rows, columns, contraction
+        grid = (rows // tm, cols // tn, depth // tk)
+        a_block, out = (tm, tk), (tm, tn, cols)
+        a_at = lambda i, j, k: (i, k)   # noqa: E731
+        w_at = lambda i, j, k: (k, j)   # noqa: E731
+    # two buffers of each operand and of the result, the accumulator,
+    # and the decode's float32 temporaries
+    vmem = (2 * (a_block[0] * a_block[1] + out[0] * out[1]) * a.dtype.itemsize
+            + 4 * out[0] * out[1] + (tk * tn // 2 + tk // group * tn * 4) * 2
+            + 4 * 4 * tk * tn)
+    return dict(
+        grid=grid,
+        in_specs=[pl.BlockSpec(a_block, a_at), pl.BlockSpec((tk, tn), w_at),
+                  pl.BlockSpec((tk // group, tn), w_at)],
+        out_specs=pl.BlockSpec(out[:2], lambda i, j, k: (i, j)),
+        scratch_shapes=[pltpu.VMEM(out[:2], jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((rows, out[2]), a.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem + 16 * 2**20))
+
+
+@partial(jax.jit, static_argnames=("plan", "interpret"))
+def _nf4_fwd_pallas(x, codes, scales, *, plan: Nf4Plan, interpret: bool):
+    """Jitted, each direction: the layers of a step share one trace and
+    one lowering of each shape."""
+    return pl.pallas_call(
+        partial(_nf4_fwd_kernel, group=codes.shape[0] // scales.shape[0]),
+        **_nf4_specs(x, codes, scales, plan, dx=False),
+        interpret=interpret,
+        name="nf4_matmul",
+    )(x, codes, scales)
+
+
+@partial(jax.jit, static_argnames=("plan", "interpret"))
+def _nf4_dx_pallas(dy, codes, scales, *, plan: Nf4Plan, interpret: bool):
+    return pl.pallas_call(
+        partial(_nf4_dx_kernel, group=codes.shape[0] // scales.shape[0]),
+        **_nf4_specs(dy, codes, scales, plan, dx=True),
+        interpret=interpret,
+        name="nf4_matmul_dx",
+    )(dy, codes, scales)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _nf4_product(x, qt, plan, interpret):
+    return _nf4_fwd_pallas(x, qt.codes, qt.scales, plan=plan,
+                           interpret=interpret)
+
+
+def _nf4_product_fwd(x, qt, plan, interpret):
+    return _nf4_product(x, qt, plan, interpret), qt
+
+
+def _nf4_product_bwd(plan, interpret, qt, dy):
+    return _nf4_dx_pallas(dy, qt.codes, qt.scales, plan=plan,
+                          interpret=interpret), None
+
+
+_nf4_product.defvjp(_nf4_product_fwd, _nf4_product_bwd)
 
 
 def quantize_params(params: Any, kind: str = "nf4",

@@ -264,6 +264,46 @@ def _quant_oracle(case: KernelCase, mesh, x, w):
                                preferred_element_type=jnp.float32)
 
 
+def _nf4_matmul_inputs(case: KernelCase, key: jax.Array, M=32, D=1024,
+                       F=256):
+    kx, kw_ = jax.random.split(key)
+    x = jax.random.normal(kx, (M, D), jnp.float32)
+    w = jax.random.normal(kw_, (D, F), jnp.float32) * 0.02
+    return (x, w), (0,)
+
+
+def _nf4_matmul_kernel(case: KernelCase, mesh, x, w):
+    from gke_ray_train_tpu.ops.quant import (
+        Nf4Plan, nf4_matmul, quantize_tensor)
+    dtype = jnp.dtype(case.dtype)
+    # the smallest tiles that qualify: two steps on every grid axis
+    return nf4_matmul(x.astype(dtype), quantize_tensor(w, "nf4"),
+                      plan=Nf4Plan("pallas", 16, 128, 512, 128, 512))
+
+
+def _nf4_matmul_oracle(case: KernelCase, mesh, x, w):
+    from gke_ray_train_tpu.ops.quant import dequantize, quantize_tensor
+    # the operands as the kernel sees them, one float32 product
+    dtype = jnp.dtype(case.dtype)
+    deq = dequantize(quantize_tensor(w, "nf4"), dtype)
+    return jax.lax.dot_general(
+        x.astype(dtype).astype(jnp.float32), deq.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32).astype(dtype)
+
+
+register(KernelSpec(
+    name="nf4_matmul",
+    build=_nf4_matmul_inputs,
+    kernel=_nf4_matmul_kernel,
+    oracle=_nf4_matmul_oracle,
+    # float32 only: XLA:CPU executes no bf16 x bf16 = f32 product, which
+    # the interpreted kernel asks for (tests/test_quant.py runs bf16
+    # under the suite's float32 products)
+    cases=(KernelCase("f32", dtype="float32"),),
+))
+
+
 register(KernelSpec(
     name="quant_matmul",
     build=_quant_inputs,

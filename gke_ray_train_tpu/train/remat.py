@@ -30,6 +30,7 @@ from gke_ray_train_tpu.models.remat import (
 from gke_ray_train_tpu.models.transformer import (
     flash_grids, resolve_seq_impl, ssm_geometry)
 from gke_ray_train_tpu.ops.moe import gather_geometry
+from gke_ray_train_tpu.ops.quant import nf4_geometry
 from gke_ray_train_tpu.ops.quant import stored_bits
 from gke_ray_train_tpu.perf.cache import StepFallback, build_or_load_step
 
@@ -175,7 +176,10 @@ class StepRemat:
         rows, seq = self.micro_shape(batch)
         grid = {"flash_grid": flash_grids(self.cfg, self.mesh, rows, seq),
                 "ssm_scan": ssm_geometry(self.cfg, seq),
-                "moe_gather": gather_geometry(self.cfg, rows * seq)}
+                "moe_gather": gather_geometry(self.cfg, rows * seq),
+                "nf4_matmul": nf4_geometry(
+                    state.params, rows * seq,
+                    whole=self.mesh is not None and self.mesh.size == 1)}
         if choice.keep:
             built = build_or_load_step(
                 self.with_keep(choice.keep), state, batch, label=label,
@@ -223,4 +227,12 @@ class StepRemat:
                 "%d picks a token from %d buffer rows of %d bytes", label,
                 g["impl"], g["token_tile"], g["picks"], g["rows"],
                 g["row_bytes"])
+        if grid["nf4_matmul"]:
+            g = grid["nf4_matmul"]
+            logger.info(
+                "%s: frozen products of %d rows, calls a micro-pass as the "
+                "kernel %d, decoded before the product %d: %s", label,
+                g["rows"], g["pallas"], g["xla"], "; ".join(
+                    f"{k} {v['impl']} {v['calls']}"
+                    for k, v in g["shapes"].items()))
         return built

@@ -367,3 +367,196 @@ def test_merge_lora_partial_targets_dequantizes_rest():
     for blk in merged["blocks"]:
         for t in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
             assert not is_qtensor(blk[t]), t
+
+
+# -- the decode inside the product: ops/quant.py::nf4_matmul ----------------
+
+def _small_plan():
+    """The smallest tiles that qualify: two grid steps on every axis."""
+    from gke_ray_train_tpu.ops.quant import Nf4Plan
+    return Nf4Plan("pallas", 16, 128, 512, 128, 512)
+
+
+def _operands(rows, depth, cols, dtype, seed=0):
+    kx, kw, kd = jax.random.split(jax.random.key(seed), 3)
+    w = jax.random.normal(kw, (depth, cols), jnp.float32) * 0.02
+    return (jax.random.normal(kx, (rows, depth), jnp.float32).astype(dtype),
+            quantize_tensor(w, "nf4"),
+            jax.random.normal(kd, (rows, cols), jnp.float32).astype(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_nf4_matmul_is_dequantize_then_the_product(dtype):
+    """The kernel pair (interpreted) against ``dequantize`` + einsum,
+    forward and dx: the same decoded weights and float32 sums, so the
+    results differ only by the order of a sum."""
+    from gke_ray_train_tpu.ops.quant import nf4_matmul
+    x, qt, dy = _operands(32, 1024, 256, dtype)
+
+    def ours(x):
+        return nf4_matmul(x, qt, plan=_small_plan())
+
+    def theirs(x):
+        return jnp.einsum("md,dn->mn", x, dequantize(qt, dtype),
+                          preferred_element_type=jnp.float32).astype(dtype)
+
+    (y, vjp), (y_ref, vjp_ref) = jax.vjp(ours, x), jax.vjp(theirs, x)
+    (dx,), (dx_ref,) = vjp(dy), vjp_ref(dy)
+    assert y.dtype == dx.dtype == dtype and dx.shape == x.shape
+    # bf16: a unit of the last place at the largest magnitude; float32:
+    # a few, a sum of 1024 products in another order
+    ulp = 2.0 ** -7 if dtype == jnp.bfloat16 else 2.0 ** -18
+    for got, want in ((y, y_ref), (dx, dx_ref)):
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.max(np.abs(got - want)) <= ulp * np.max(np.abs(want))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_nf4_decoded_tile_is_dequantize_bit_for_bit(dtype):
+    """What the kernel hands the MXU is ``dequantize``'s weights to the
+    last bit: every code, scales of both signs' groups and a zero
+    group, a tile at a time through the kernel's own decode."""
+    from jax.experimental import pallas as pl
+    from gke_ray_train_tpu.ops.quant import _decode_tile
+    depth, cols, tk, tn = 1024, 256, 512, 128
+    codes = (jnp.arange(depth * cols, dtype=jnp.int32) * 7 % 16).reshape(
+        depth, cols).astype(jnp.uint4)
+    scales = jax.random.uniform(jax.random.key(2), (depth // 64, cols),
+                                jnp.float32, 1e-3, 0.1)
+    qt = QTensor(codes, scales.at[3].set(0.0), "nf4", 64)
+
+    def kernel(c_ref, s_ref, o_ref):
+        o_ref[...] = _decode_tile(c_ref[...], s_ref[...], 64, dtype)
+    decoded = pl.pallas_call(
+        kernel, grid=(depth // tk, cols // tn),
+        in_specs=[pl.BlockSpec((tk, tn), lambda i, j: (i, j)),
+                  pl.BlockSpec((tk // 64, tn), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((tk, tn), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((depth, cols), dtype),
+        interpret=True)(qt.codes, qt.scales)
+    want = jax.jit(lambda q: dequantize(q, dtype))(qt)
+    bits = jnp.uint16 if dtype == jnp.bfloat16 else jnp.uint32
+    np.testing.assert_array_equal(
+        np.asarray(jax.lax.bitcast_convert_type(decoded, bits)),
+        np.asarray(jax.lax.bitcast_convert_type(want, bits)))
+
+
+def test_proj_takes_the_kernel_on_one_device_and_agrees_under_grad():
+    """``_proj`` with adapters through ``jax.grad``: on a mesh of one
+    device, at rows the plan takes, the base is the kernel pair (both in
+    the program, under the ``base`` scope); with no mesh it is the
+    decode and the einsum. The loss and the gradients of x and of both
+    adapter matrices agree within bf16's rounding."""
+    from jax.sharding import Mesh
+    from gke_ray_train_tpu.models.transformer import _proj
+    from gke_ray_train_tpu.ops.quant import NF4_MIN_ROWS
+    rows, depth, cols = NF4_MIN_ROWS, 512, 256
+    x, qt, _ = _operands(rows, depth, cols, jnp.bfloat16, seed=3)
+    x = x.reshape(2, rows // 2, depth)
+    lora = {"a": jax.random.normal(jax.random.key(4), (depth, 8)) * 0.02,
+            "b": jax.random.normal(jax.random.key(5), (8, cols)) * 0.02}
+    one = Mesh(np.array(jax.devices()[:1]), ("data",))
+
+    def loss(x, lora, mesh):
+        y = _proj(x, qt, lora, 2.0, jnp.bfloat16, mesh=mesh)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def grads(mesh):
+        return jax.jit(jax.value_and_grad(
+            lambda x, lo: loss(x, lo, mesh), argnums=(0, 1)))(x, lora)
+
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda x, lo: loss(x, lo, one), argnums=(0, 1)))(x, lora))
+    assert "nf4_matmul" in text and "nf4_matmul_dx" in text
+    assert "nf4_matmul" not in str(jax.make_jaxpr(jax.grad(
+        lambda x, lo: loss(x, lo, None), argnums=(0, 1)))(x, lora))
+    (l1, (gx1, gl1)), (l2, (gx2, gl2)) = grads(one), grads(None)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-2)
+    for a, b in zip(jax.tree.leaves((gx1, gl1)), jax.tree.leaves((gx2, gl2))):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.max(np.abs(a - b)) <= 2e-2 * np.max(np.abs(b))
+
+
+@pytest.mark.parametrize("rows,depth,cols,kind,want", [
+    (2048, 4096, 14336, "nf4", ("pallas", 2048, 2048, 512, 1024, 1024)),
+    (2048, 14336, 4096, "nf4", ("pallas", 2048, 2048, 512, 1024, 1024)),
+    (2048, 4096, 4096, "nf4", ("pallas", 2048, 2048, 512, 1024, 1024)),
+    (2048, 4096, 1024, "nf4", ("pallas", 2048, 1024, 512, 1024, 1024)),
+    (32, 4096, 14336, "nf4", ("pallas", 32, 2048, 512, 1024, 1024)),
+    (2048, 2048, 576, "nf4", ("xla", 0, 0, 0, 0, 0)),  # latent kv down
+    (2048, 4096, 14336, "int8", ("xla", 0, 0, 0, 0, 0)),
+    (16, 4096, 14336, "nf4", ("xla", 0, 0, 0, 0, 0)),   # not measured
+    (8192, 4096, 14336, "nf4", ("xla", 0, 0, 0, 0, 0)),  # one row of 8192
+    (2048, 96, 8, "nf4", ("xla", 0, 0, 0, 0, 0)),       # a tiny preset
+], ids=["dense_gate_up", "dense_down", "dense_q_o", "dense_k_v",
+        "decode_32", "cols_576", "int8", "rows_16", "rows_8192", "tiny"])
+def test_nf4_matmul_plan_reads_shapes(rows, depth, cols, kind, want):
+    from gke_ray_train_tpu.ops.quant import nf4_matmul_plan
+    assert tuple(nf4_matmul_plan(rows, depth, cols, kind)) == want
+
+
+def _abstract_quantized(preset, kind):
+    from gke_ray_train_tpu.models import init_params
+    from gke_ray_train_tpu.models.config import PRESETS
+    cfg = PRESETS[preset]()
+    return jax.eval_shape(lambda: quantize_params(
+        init_params(cfg, jax.random.key(0)), kind))
+
+
+def test_nf4_geometry_of_the_dense_cell():
+    """The ``step_build`` span's ``nf4_matmul`` for Mistral-7B's frozen
+    base at 2048 rows a micro-batch: every projection on the kernel on
+    one device, none on a mesh of several, none for int8 leaves."""
+    from gke_ray_train_tpu.ops.quant import nf4_geometry
+    params = _abstract_quantized("mistral-7b", "nf4")
+    got = nf4_geometry(params, 2048, whole=True)
+    assert got["rows"] == 2048 and (got["pallas"], got["xla"]) == (224, 0)
+    assert {k: v["calls"] for k, v in got["shapes"].items()} == {
+        "4096x4096": 64, "4096x1024": 64, "4096x14336": 64,
+        "14336x4096": 32}
+    assert got["shapes"]["4096x14336"]["tiles"] == [2048, 2048, 512]
+    assert got["shapes"]["4096x14336"]["dx_tiles"] == [2048, 1024, 1024]
+    shared = nf4_geometry(params, 2048, whole=False)
+    assert (shared["pallas"], shared["xla"]) == (0, 224)
+    int8 = nf4_geometry(_abstract_quantized("mistral-7b", "int8"), 2048,
+                        whole=True)
+    assert (int8["pallas"], int8["xla"]) == (0, 224)
+    assert nf4_geometry({"w": jnp.zeros((2, 4, 4))}, 2048, True) == {}
+
+
+def test_v5e_nf4_matmul_pair_compiles_under_base_and_its_phases(v5e):
+    """The kernel pair at the dense gate/up shape, compiled for a
+    described v5e inside a gradient: Mosaic takes the ``uint4`` codes as
+    XLA lays them out (no copy of the codes before either kernel), and
+    each custom call's ``op_name`` carries ``base`` and its phase, which
+    is what ``proj_share.train`` and ``nf4_matmul_roofline.train`` bill
+    by."""
+    from jax.sharding import SingleDeviceSharding
+    from gke_ray_train_tpu.obs.trace import scope_path
+    from gke_ray_train_tpu.ops.quant import nf4_matmul, nf4_matmul_plan
+    rows, D, F = 2048, 4096, 14336
+    sharding = SingleDeviceSharding(v5e)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    qt = QTensor(spec((D, F), jnp.uint4), spec((D // 64, F), jnp.float32))
+    plan = nf4_matmul_plan(rows, D, F, "nf4")
+
+    def loss(x, q):
+        with jax.named_scope("base"):
+            y = nf4_matmul(x, q, plan=plan, interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("default"):
+        hlo = jax.jit(jax.grad(loss)).lower(
+            spec((rows, D), jnp.bfloat16), qt).compile().as_text()
+    calls = dict(re.findall(
+        r"%(nf4_matmul(?:_dx)?)[\w.]* = .*custom-call\(.*"
+        r'op_name="([^"]*)"', hlo))
+    assert set(calls) == {"nf4_matmul", "nf4_matmul_dx"}, calls
+    assert all(scope_path(n).endswith("base") for n in calls.values())
+    assert "jvp(" in calls["nf4_matmul"] \
+        and "transpose(" not in calls["nf4_matmul"]
+    assert "transpose(" in calls["nf4_matmul_dx"]
+    assert not re.search(r"= u4\[\S+ (?:copy|fusion)\(", hlo)
