@@ -1,15 +1,18 @@
-"""The seam to the program: its model configuration from the published
-key names, and the benchmark's seeded weights laid out as the program
-stores them (quantised by the program's own quantiser, one jitted call).
+"""The dense decoder's seam to the program (its model configuration from
+the published key names, the benchmark's seeded weights laid out as the
+program stores them, quantised by the program's own quantiser, one
+jitted call), and what every training family's run reads its leaves,
+gradients and trace slice with.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from benchmark import weights as wts
 
@@ -135,6 +138,74 @@ def named_leaves(tree) -> dict:
             name += "@" + ".".join(idx)
         out[name] = leaf
     return out
+
+
+def by_leaf_name(norms: Dict[str, float]) -> Dict[str, float]:
+    """{"wq.a": norm over all layers}: ``named_leaves`` gives one entry
+    a block dict of the program's tree (``prologue.wq.a@1``), the
+    reference one a leaf name over all its layers."""
+    out: Dict[str, float] = {}
+    for name, v in norms.items():
+        base = name.split("@")[0].removeprefix("prologue.")
+        out[base] = out.get(base, 0.0) + v * v
+    return {k: float(np.sqrt(v)) for k, v in out.items()}
+
+
+def pairs_gap(pairs, reference_pairs) -> float:
+    """Held pairs counted over the followed steps against the
+    reference's count."""
+    return abs(sum(pairs) - sum(reference_pairs)) \
+        / max(sum(reference_pairs), 1)
+
+
+# ---------------------------------------------------------------------------
+# the first gradient, tensor against tensor
+# ---------------------------------------------------------------------------
+
+def gradient_by_layer(cfg, tree, scale: float = 1.0):
+    """A tree of the adapters' layout (stacked over a block's layers) ->
+    one ``{target: {"a", "b"}}`` a layer, as the reference holds its
+    gradient, in float32 on the host."""
+    from gke_ray_train_tpu.models.transformer import block_layout
+    out = [None] * cfg.n_layers
+    for where, i, first, count, stride, _ in block_layout(cfg):
+        for r in range(count):
+            out[first + r * stride] = jax.tree.map(
+                lambda x, r=r: np.asarray(x[r], np.float32) * scale,
+                tree[where][i])
+    return out
+
+
+def gradient_table(got, want) -> Dict[str, np.ndarray]:
+    """{"wq_a.b": [layers, 3]}: a layer's |got|^2, |want|^2 and
+    got . want of that leaf (noughts where a layer has no such leaf).
+    Every number that sets two gradients tensor against tensor comes
+    from these three."""
+    out: Dict[str, np.ndarray] = {}
+    for layer, (g, w) in enumerate(zip(got, want)):
+        for t, ab in w.items():
+            for k, ref in ab.items():
+                mine = np.asarray(g[t][k], np.float64).ravel()
+                ref = np.asarray(ref, np.float64).ravel()
+                row = out.setdefault(f"{t}.{k}", np.zeros((len(want), 3)))
+                row[layer] = mine @ mine, ref @ ref, mine @ ref
+    return out
+
+
+def direction_gap(table: Dict[str, np.ndarray], targets=None) -> float:
+    """Largest |got - want| / |want| over the leaves (of ``targets``,
+    or all), each leaf one vector over all its layers; a leaf whose
+    reference gradient is under the median leaf's is measured against
+    the median, as ``check.worst_leaf_gap`` does. A gap of norms is
+    second order in random rounding and nearly cancels; this one is
+    first order and does not cancel, so it rises with every rounding on
+    the way."""
+    sums = {k: t.sum(0) for k, t in table.items()}
+    floor = float(np.median([s[1] for s in sums.values()]))
+    return max(float(np.sqrt(max(s[0] - 2 * s[2] + s[1], 0.0)
+                             / max(s[1], floor, 1e-300)))
+               for k, s in sums.items()
+               if targets is None or k.split(".")[0] in targets)
 
 
 class TraceSlice:

@@ -1,46 +1,27 @@
-"""A training cell of the latent-attention routed decoder
-(``glm4_moe_lite``: GLM-4.7-Flash): the same job as ``drivers/train.py``
-and ``drivers/train_moe.py`` (``train_eval_save`` wired from the mix's
-job keys, driven through ``train/loop.py::run_training``), with this
-family's seam to the program: its model configuration from the published
-keys (the latent ranks and the three head sizes), the benchmark's seeded
-tree in the program's layout (the five attention matrices, every bank
-quantised expert by expert as it is drawn, one jitted call), adapters.
-What ``drivers/train.py``, ``drivers/train_moe.py`` and
-``drivers/common.py`` export is used as it stands; ``run`` returns the
-facts ``train_moe.run`` returns, so the readers that hold for the routed
-cell serve this one, and reads two numbers for ``correct`` beside
-theirs: the first gradient set tensor against tensor with the
-reference's (``grad_dir_gap``, ``attn_dir_gap``), which tell bfloat16
-from the precision below where the norms' gap does not.
-
-``run`` is ``train_moe.run`` with the same three calls exchanged again
-(model configuration, seeded tree, adapters) and no window in ``work``:
-``train_moe.run`` finds its three by name in its own module, so they
-cannot be handed to it. The next ``benchmark`` issue can give
-``train.run`` those as a seam and fold both copies back (PERF.md
-section 7).
+"""The latent-attention routed decoder's family (``glm4_moe_lite``:
+GLM-4.7-Flash) for ``drivers/train.py::run``: its model configuration
+from the published keys (the latent ranks and the three head sizes), the
+benchmark's seeded tree in the program's layout (the five attention
+matrices, every bank quantised expert by expert as it is drawn, one
+jitted call), adapters, each layer's kind, and two numbers for
+``correct`` beside the norms' gaps: the first gradient set tensor
+against tensor with the reference's (``grad_dir_gap``,
+``attn_dir_gap``), which tell bfloat16 from the precision below where
+the norms' gap does not.
 """
 
 from __future__ import annotations
 
-import gc
-import time
-from typing import Dict, Optional
+import functools
+import sys
+from typing import Dict
 
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from benchmark import check, traffic
-from benchmark import harness as hs
-from benchmark import weights as wts
 from benchmark import weights_mla as wl
-from benchmark.drivers import common
-from benchmark.drivers.train import (
-    StepLog, doc_lengths, find_adam_mu, leaf_norms, optimizer_facts,
-    reference_readings)
-from benchmark.drivers.train_moe import BANK, by_leaf_name
+from benchmark.drivers import train, train_moe
+# the tensor-against-tensor arithmetic is common to every family that
+# reads it; it is named here too, where the tests of this family look
+from benchmark.drivers.common import (  # noqa: F401
+    direction_gap, gradient_by_layer, gradient_table)
 
 
 # ---------------------------------------------------------------------------
@@ -74,143 +55,12 @@ def model_config(config: dict, *, dtype: str, param_dtype: str,
         dtype=dtype, param_dtype=param_dtype, attn_impl=attn_impl,
         remat_policy=remat_policy)
 
-def params_maker(cfg, config: dict, *, quant_kind: Optional[str],
-                 quant_group: int = 64):
-    """``make(key) -> tree`` in the program's layout and the types it is
-    run in: projections and experts quantised slice by slice as they are
-    drawn (never a full-precision tree first), the rest in
-    ``cfg.param_dtype``."""
-    from gke_ray_train_tpu.models.transformer import (
-        block_layout, block_leaves)
-    from gke_ray_train_tpu.ops.quant import QTensor, quantize_tensor
 
-    dims = wl.dims_from_config(config)
-    pdt = jnp.dtype(cfg.param_dtype)
-    quant = quant_kind not in (None, "none")
-    frozen = wl.ATTENTION + wl.DENSE_MLP + wl.SHARED
-
-    def leaf(key, name, layer, kind, expert=None):
-        """One layer's (one expert's) leaf as the program stores it."""
-        bench_name = BANK[name] if kind == "moe" and name in BANK else name
-        args = () if expert is None else (expert,)
-        if not (quant and name in frozen):
-            return wl.stored(dims, key, bench_name, layer, pdt, *args)
-        w = wl.stored(dims, key, bench_name, layer, jnp.bfloat16, *args)
-        qt = quantize_tensor(w[None], quant_kind, quant_group)
-        return qt.codes[0], qt.scales[0]
-
-    def stack(key, name, first, count, stride, kind):
-        bank = kind == "moe" and name in BANK
-        experts = dims["held_lo"] + jnp.arange(dims["held"], dtype=jnp.int32)
-
-        def one(r):
-            layer = first + r * stride
-            if bank:
-                return jax.lax.map(
-                    lambda e: leaf(key, name, layer, kind, e), experts)
-            return leaf(key, name, layer, kind)
-        out = jax.lax.map(one, jnp.arange(count, dtype=jnp.int32))
-        if isinstance(out, tuple):
-            return QTensor(out[0], out[1], quant_kind,
-                           out[0].shape[-2] // out[1].shape[-2])
-        return out
-
-    def make(key):
-        tree = {"embed": wts.stored(dims, key, "embed", 0, pdt),
-                "final_norm": wts.stored(dims, key, "final_norm", 0, pdt),
-                "lm_head": wts.stored(dims, key, "lm_head", 0, pdt)}
-        for where, _, first, count, stride, kind in block_layout(cfg):
-            tree.setdefault(where, []).append(
-                {name: stack(key, name, first, count, stride, kind)
-                 for name in block_leaves(cfg, count, kind)})
-        return tree
-    return make
-
-
-def build_params(cfg, config: dict, seed: int, mesh, *,
-                 quant_kind: Optional[str], quant_group: int = 64):
-    """The whole tree in one jitted call from the seed."""
-    make = params_maker(cfg, config, quant_kind=quant_kind,
-                        quant_group=quant_group)
-    key = wts.seed_key(seed)
-    shardings = common.param_shardings(cfg, jax.eval_shape(make, key), mesh)
-    return jax.jit(make, out_shardings=shardings)(key)
-
-
-def build_lora(cfg, config: dict, seed: int, mesh, lora_cfg):
-    """LoRA adapters in the program's layout: A from the seed, B zero."""
-    from gke_ray_train_tpu.models.transformer import block_layout
-    from gke_ray_train_tpu.parallel.sharding import tree_shardings
-    from gke_ray_train_tpu.train.lora import lora_specs
-
-    dims = wl.dims_from_config(config)
-    specs = lora_specs(cfg, lora_cfg)
-
-    def make(key):
-        tree: Dict[str, list] = {}
-        for where, i, first, count, stride, _ in block_layout(cfg):
-            layers = first + stride * jnp.arange(count, dtype=jnp.int32)
-            tree.setdefault(where, []).append({
-                t: {"a": jax.lax.map(
-                        lambda l, t=t: wl.lora_a(dims, key, t, l,
-                                                 lora_cfg.r), layers),
-                    "b": jnp.zeros((count,) + wl.lora_b_shape(
-                        dims, t, lora_cfg.r), jnp.float32)}
-                for t in specs[where][i]})
-        return tree
-    return jax.jit(make, out_shardings=tree_shardings(mesh, specs))(
-        wts.seed_key(seed))
-
-
-
-# ---------------------------------------------------------------------------
-# the first gradient, tensor against tensor
-# ---------------------------------------------------------------------------
-
-def gradient_by_layer(cfg, tree, scale: float = 1.0):
-    """A tree of the adapters' layout (stacked over a block's layers) ->
-    one ``{target: {"a", "b"}}`` a layer, as the reference holds its
-    gradient, in float32 on the host."""
-    from gke_ray_train_tpu.models.transformer import block_layout
-    out = [None] * cfg.n_layers
-    for where, i, first, count, stride, _ in block_layout(cfg):
-        for r in range(count):
-            out[first + r * stride] = jax.tree.map(
-                lambda x, r=r: np.asarray(x[r], np.float32) * scale,
-                tree[where][i])
-    return out
-
-
-def gradient_table(got, want) -> Dict[str, np.ndarray]:
-    """{"wq_a.b": [layers, 3]}: a layer's |got|^2, |want|^2 and
-    got . want of that leaf (noughts where a layer has no such leaf).
-    Every number that sets two gradients tensor against tensor comes
-    from these three."""
-    out: Dict[str, np.ndarray] = {}
-    for layer, (g, w) in enumerate(zip(got, want)):
-        for t, ab in w.items():
-            for k, ref in ab.items():
-                mine = np.asarray(g[t][k], np.float64).ravel()
-                ref = np.asarray(ref, np.float64).ravel()
-                row = out.setdefault(f"{t}.{k}", np.zeros((len(want), 3)))
-                row[layer] = mine @ mine, ref @ ref, mine @ ref
-    return out
-
-
-def direction_gap(table: Dict[str, np.ndarray], targets=None) -> float:
-    """Largest |got - want| / |want| over the leaves (of ``targets``,
-    or all), each leaf one vector over all its layers; a leaf whose
-    reference gradient is under the median leaf's is measured against
-    the median, as ``check.worst_leaf_gap`` does. A gap of norms is
-    second order in random rounding and nearly cancels; this one is
-    first order and does not cancel, so it rises with every rounding on
-    the way."""
-    sums = {k: t.sum(0) for k, t in table.items()}
-    floor = float(np.median([s[1] for s in sums.values()]))
-    return max(float(np.sqrt(max(s[0] - 2 * s[2] + s[1], 0.0)
-                             / max(s[1], floor, 1e-300)))
-               for k, s in sums.items()
-               if targets is None or k.split(".")[0] in targets)
+# the tree and the adapters as every routed family builds them
+# (``train_moe``), from this family's weights module
+params_maker = functools.partial(train_moe.params_maker, w=wl)
+build_params = functools.partial(train_moe.build_params, w=wl)
+build_lora = functools.partial(train_moe.build_lora, w=wl)
 
 
 def gradient_readings(table) -> Dict[str, float]:
@@ -221,239 +71,6 @@ def gradient_readings(table) -> Dict[str, float]:
             "attn_dir_gap": direction_gap(table, wl.ATTENTION)}
 
 
-def pairs_gap(pairs, reference_pairs) -> float:
-    """Held pairs counted over the followed steps against the
-    reference's count."""
-    return abs(sum(pairs) - sum(reference_pairs)) \
-        / max(sum(reference_pairs), 1)
-
-
-# ---------------------------------------------------------------------------
-# the run
-# ---------------------------------------------------------------------------
-
-def run(ctx: dict) -> dict:
-    """ctx: cell, config, mix, limits, seed, seconds, trace, devices,
-    peaks, t_start, trace_dir. Returns the facts the readers reduce."""
-    from gke_ray_train_tpu.config import (
-        optimizer_from_config, quant_kind_from_config, schedule_from_config)
-    from gke_ray_train_tpu.data.packing import pack_examples
-    from gke_ray_train_tpu.data.sft import sft_epoch_batches
-    from gke_ray_train_tpu.parallel.placement import (
-        host_batch_size, input_shard_layout, make_place_batch)
-    from gke_ray_train_tpu.perf.cache import (
-        enable_persistent_cache, make_abstract_batch)
-    from gke_ray_train_tpu.plan import ExecutionPlan, compile_step_with_plan
-    from gke_ray_train_tpu.train import (
-        LoraConfig, ThroughputMeter, make_train_state, make_train_step)
-    from gke_ray_train_tpu.train.loop import run_training
-
-    config, mix, devices = ctx["config"], ctx["mix"], ctx["devices"]
-    job = dict(mix["job"])
-    if not (job.get("USE_QLORA") and job.get("PACKING")):
-        raise hs.BenchFailure("this driver runs packed QLoRA jobs")
-    family = "v5e" if devices[0].platform == "tpu" else "cpu"
-    job["TOPOLOGY"] = f"{family}-{len(devices)}"
-    plan = ExecutionPlan.resolve(job)
-    enable_persistent_cache(plan=plan)
-    counter = hs.CompileCounter()
-    mesh = plan.build_mesh(devices)
-    seq = plan.max_seq_len
-    train_dtype = job.get("TRAIN_DTYPE", "bfloat16")
-    cfg = model_config(
-        config, dtype=train_dtype,
-        param_dtype=job.get("PARAM_DTYPE", train_dtype),
-        attn_impl=job.get("ATTN_IMPL", "auto"),
-        remat_policy=job.get("REMAT_POLICY", "full"), max_seq_len=seq)
-    quant_kind = quant_kind_from_config(job, True)
-
-    # ---- weights: the benchmark's, in the program's layout -----------
-    t_init0 = time.perf_counter()
-    params = build_params(cfg, config, ctx["seed"], mesh,
-                          quant_kind=quant_kind)
-    jax.block_until_ready(params)
-    init_s = time.perf_counter() - t_init0
-
-    # ---- rows from the seed, packed by the program --------------------
-    data_par = mesh.shape["data"] * mesh.shape["fsdp"]
-    global_batch = plan.per_device_batch * data_par * plan.grad_accum
-    group = int(mix["rows"]["docs_per_row"])
-    examples = traffic.train_examples(
-        mix["rows"], cfg.vocab_size, seq, group, ctx["seed"])
-    # a group of documents at a time: the generator dealt them so that
-    # each group fills one row, and the program's packer lays it out (a
-    # shrunk rehearsal's groups may take more rows than one)
-    packed = [row for i in range(0, len(examples), group)
-              for row in pack_examples(examples[i:i + group], seq)]
-    rows = {k: np.stack([r[k] for r in packed]) for k in packed[0]}
-    total_steps = max(len(packed) // global_batch, 1)
-    in_shards, in_shard_id = input_shard_layout(mesh)
-    host_batch_size(global_batch, num_shards=in_shards)
-
-    # ---- optimizer, state, the compiled step --------------------------
-    t_build0 = time.perf_counter()
-    lora_cfg = LoraConfig.from_dict(job)
-    schedule = schedule_from_config(job, total_steps)
-    opt = optimizer_from_config(job, schedule)
-    state = make_train_state(cfg, opt, jax.random.key(1), mesh=mesh,
-                             lora_cfg=lora_cfg, params=params)
-    state = state._replace(lora=build_lora(cfg, config, ctx["seed"], mesh,
-                                           lora_cfg))
-    del params
-    step_fn = make_train_step(cfg, opt, mesh=mesh, lora_cfg=lora_cfg,
-                              schedule=schedule, plan=plan)
-    step_fn = compile_step_with_plan(
-        plan, mesh, step_fn, state,
-        make_abstract_batch(mesh, global_batch, seq, packed=True,
-                            context_sharded=False),
-        sidecar=None, label="benchmark train_step")
-    warm_build_s = time.perf_counter() - t_build0
-    place = make_place_batch(mesh, context_sharded=False)
-    meter = ThroughputMeter(cfg, seq_len=seq, n_devices=len(devices),
-                            peak_flops=ctx["peaks"]["flops_bf16"],
-                            trainable="lora")
-
-    fed: Dict[int, dict] = {}         # stream index -> host batch
-
-    def stream(first: int, stop_at=None, deadline=None):
-        """epoch_batches for one run_training call. The loop skips the
-        ``first`` batches its step counter says were trained already."""
-        def epoch_batches(epoch):
-            it = iter(sft_epoch_batches(
-                rows, global_batch, num_hosts=in_shards,
-                host_id=in_shard_id, epoch=epoch, shuffle=False))
-            i = 0
-            while stop_at is None or i < stop_at:
-                if deadline is not None and i > first and \
-                        time.perf_counter() >= deadline[0]:
-                    return
-                with jax.profiler.TraceAnnotation("bench:next_batch"):
-                    batch = next(it, None)
-                if batch is None:
-                    return
-                if i >= first:
-                    fed[i] = batch
-                yield batch
-                i += 1
-        return epoch_batches
-
-    def drive(state, batches, writer, log_every=1, profiler=None):
-        """The one call that set-up and the window both make."""
-        return run_training(
-            state, step_fn, batches, epochs=1, place_batch=place,
-            guards=plan.runtime_guards(), prefetch=plan.prefetch,
-            log_every=log_every, meter=meter, tb_writer=writer,
-            profiler=profiler)
-
-    # ---- the first steps, read back for `correct` ---------------------
-    n_check = int(mix["check"]["steps"])
-    trainable0 = jax.device_get(state.lora)
-    first_log = StepLog()
-    state, _ = drive(state, stream(0, stop_at=1), first_log)
-    adam_mu = find_adam_mu(state.opt_state)
-    mu = by_leaf_name(leaf_norms(adam_mu))
-    b1 = optimizer_facts(job, total_steps)["b1"]
-    program = {"grad_norm": {k: v / (1.0 - b1) for k, v in mu.items()},
-               "gradient": gradient_by_layer(cfg, jax.device_get(adam_mu),
-                                             1.0 / (1.0 - b1))}
-    del adam_mu
-    state, _ = drive(state, stream(1, stop_at=n_check), first_log)
-    trainable1 = jax.device_get(state.lora)
-    n0, n1 = common.named_leaves(trainable0), common.named_leaves(trainable1)
-    program["change"] = by_leaf_name({k: float(np.linalg.norm(
-        (np.asarray(n1[k], np.float32) - np.asarray(n0[k], np.float32)
-         ).ravel())) for k in n0})
-    program["loss"] = [s["loss"] for s in first_log.steps]
-    program["pairs"] = [s["moe_pairs"] for s in first_log.steps]
-    del trainable0, trainable1, n0, n1
-    check_batches = [fed[i] for i in range(n_check)]
-    fed.clear()
-
-    # ---- the window ----------------------------------------------------
-    log = StepLog()
-    tracer = None
-    counter.begin()
-    t0 = time.perf_counter()
-    setup_s = t0 - ctx["t_start"]
-    deadline = [t0 + float(ctx["seconds"])]
-    if ctx["trace"]:
-        tracer = common.TraceSlice(ctx["trace_dir"],
-                                   float(ctx["seconds"]) - hs.TRACE_SECONDS)
-        tracer.step()
-    state, last = drive(state, stream(n_check, deadline=deadline), log,
-                        log_every=int(job["LOGGING_STEPS"]), profiler=tracer)
-    t1 = time.perf_counter()
-    if tracer is not None:
-        tracer.finish()
-    compiles = counter.in_window()
-    trained = [fed[i] for i in sorted(fed)]
-    step_docs = [doc_lengths(b) for b in trained]
-    docs = [n for step in step_docs for n in step]
-    device = hs.device_record(devices)
-    step_info = getattr(step_fn, "info", {}) or {}
-    moe = {k: [s[k] for s in log.steps] for k in
-           ("moe_pairs", "moe_max_load", "moe_pairs_dropped")}
-
-    # ---- free the program's state, then the reference -----------------
-    del state, step_fn, place, meter
-    gc.collect()
-    ref_args = (ctx, cfg, job, quant_kind, True, lora_cfg, total_steps,
-                check_batches)
-    t_ref0 = time.perf_counter()
-    reference = reference_readings(*ref_args)
-    reference_s = time.perf_counter() - t_ref0
-    ref_pairs = reference["dims"].pop("held_pairs")
-    reference["gradient"] = reference["dims"].pop("first_gradient")
-    readings = check.train_readings(program, reference)
-    table = gradient_table(program.pop("gradient"), reference["gradient"])
-    readings.update(gradient_readings(table))
-    readings["pairs_gap"] = pairs_gap(program["pairs"], ref_pairs)
-    dropped = sum(moe["moe_pairs_dropped"]) + sum(
-        s["moe_pairs_dropped"] for s in first_log.steps)
-
-    rows_per_call = global_batch // plan.grad_accum // data_par
-    return {
-        "kind": "train", "chips": len(devices), "peaks": ctx["peaks"],
-        "dims": dict(reference["dims"]),
-        # what the kernels' patterns are filled from (benchmark/kernels)
-        "sizes": dict(reference["dims"], seq=seq, rows=rows_per_call),
-        "t0": t0, "t1": t1, "window_s": t1 - t0, "setup_s": setup_s,
-        "spans": {"init_s": init_s, "warm_build_s": warm_build_s},
-        "counters": {"data_stall_frac": last.get("data_stall_frac"),
-                     "compiles_in_window": compiles,
-                     "cache": counter.snapshot(),
-                     "train_step_source": step_info.get("source"),
-                     "moe_pairs_dropped": dropped},
-        "work": {"steps": len(trained), "doc_lengths": docs,
-                 "tokens": int(sum(docs)), "trainable": "lora",
-                 "lora_rank": lora_cfg.r, "lora_targets": list(
-                     lora_cfg.targets),
-                 "rows_per_call": rows_per_call, "seq": seq,
-                 "micro_steps": plan.grad_accum, "step_docs": step_docs,
-                 "step_times": [s["t"] for s in log.steps],
-                 "step_pairs": moe["moe_pairs"],
-                 "layer_kinds": [wl.layer_kinds(config, i)
-                                 for i in range(cfg.n_layers)]},
-        "trace_window": (None if tracer is None
-                         else (tracer.t0, tracer.t1)),
-        "device": device, "readings": readings,
-        # for benchmark/tools/readings.py: the control and the faults
-        # are read from the same first steps
-        "raw": {"program": program, "reference": reference,
-                "reference_args": ref_args, "reference_pairs": ref_pairs,
-                "gradient_table": table},
-        "attempted": len(trained), "failed": int(dropped > 0),
-        "notes": [{"note": "compilations inside the window",
-                   "count": compiles, "cache": counter.snapshot(),
-                   "train_step": step_info},
-                  {"note": "first steps", "program": program["loss"],
-                   "reference": reference["loss"],
-                   "held pairs, program": program["pairs"],
-                   "held pairs, reference": ref_pairs},
-                  {"note": "routed layer, a step of the window",
-                   "moe_pairs": moe["moe_pairs"][:4],
-                   "moe_max_load": max(moe["moe_max_load"], default=None),
-                   "moe_pairs_dropped": dropped},
-                  {"note": "seconds by phase", "setup_s": setup_s,
-                   "window_s": t1 - t0, "reference_s": reference_s}],
-    }
+# ``drivers/train.py::run`` with this module as the family
+layer_kinds = wl.layer_kinds
+run = functools.partial(train.run, family=sys.modules[__name__])

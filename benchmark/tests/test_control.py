@@ -1,13 +1,18 @@
 """The control of each cell, at a size a test run can hold: the plain
 reference computed in the precision below the configuration's, put in
 the program's place, has to come out NOT correct under the cell's own
-committed limits, through the harness's own ``judge``. (On the chip, at
-the cell's own size, benchmark/tools/readings.py judges the same way;
-PERF.md has those readings.)
+committed limits, through the harness's own ``judge``, by every number
+the cell compares (``drivers/train.py::compared``: the first gradient
+tensor against tensor too, where the family reads it). (On the chip, at
+the cell's own size, benchmark/tools/readings.py and
+gradient_readings.py judge the same way; PERF.md has those readings.)
 
 The tiny model runs in bfloat16 as the cells do, so the program's own
-readings here are of the size they have on the chip, and the test also
-shows them inside the limits.
+readings here are near the size they have on the chip, and the test also
+shows them inside the limits. Every cell is shrunk by ``rehearse/
+tiny.py``, which keeps a routed or state-space layer's own widths: at a
+family's own rehearsal widths (``rehearse/*_tiny.py``) bfloat16 reads
+the routed cell's program over its ``grad_gap`` limit (PERF.md).
 """
 
 import pytest
@@ -26,6 +31,8 @@ def test_control_is_not_correct(workload):
     limits = ctx["limits"]
     program = readings.judged(facts["readings"], limits)
     assert program["correct"], program
-    control = readings.control_readings(facts["raw"], [limits["control"]])
-    verdict = readings.judged(control[limits["control"]], limits)
+    control, _ = readings.control_readings(
+        facts["raw"], [limits["control"]])[limits["control"]]
+    assert set(control) == set(facts["readings"])
+    verdict = readings.judged(control, limits)
     assert not verdict["correct"] and verdict["failed"], verdict

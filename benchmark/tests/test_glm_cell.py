@@ -77,14 +77,14 @@ def test_control_and_half_a_batch_are_not_correct():
     """The reference in fp8, and the reference fed half of each batch,
     put in the program's place: the first by the gradient's direction,
     the second by every number."""
-    from benchmark.tools import gradient_readings, readings
+    from benchmark.tools import readings
     ctx = hs.make_ctx(CELL, 2 ** 31 + 7, 0.0, False, require_chip=False,
                       override=shrink)
     facts = hs.driver_of(ctx).run(ctx)
     limits = ctx["limits"]
     assert readings.judged(facts["readings"], limits)["correct"]
     got = {k: readings.judged(r, limits) for k, (r, _) in
-           gradient_readings.control_readings(
+           readings.control_readings(
                facts["raw"], [limits["control"], "half_batch"]).items()}
     # float32 at four layers here: the control reads 0.09, an eighth of
     # what it reads on the chip, where the limits are set (PERF.md);

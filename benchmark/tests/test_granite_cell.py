@@ -72,14 +72,14 @@ def test_conv_reads_the_previous_document(monkeypatch):
 def test_control_and_half_a_batch_are_not_correct():
     """The reference in fp8, and the reference fed half of each batch,
     put in the program's place."""
-    from benchmark.tools import readings, ssm_readings
+    from benchmark.tools import readings
     ctx = hs.make_ctx(CELL, 2 ** 31 + 7, 0.0, False, require_chip=False,
                       override=shrink)
     facts = hs.driver_of(ctx).run(ctx)
     limits = ctx["limits"]
     assert readings.judged(facts["readings"], limits)["correct"]
     got = {k: readings.judged(r, limits) for k, (r, _) in
-           ssm_readings.control_readings(
+           readings.control_readings(
                facts["raw"], [limits["control"], "half_batch"]).items()}
     assert not got["fp8"]["correct"]
     assert {"grad_dir_gap", "ssm_dir_gap"} & set(got["fp8"]["failed"])

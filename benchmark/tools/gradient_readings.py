@@ -1,24 +1,30 @@
-"""``tools/readings.py`` for a cell whose driver also sets the first
-gradient tensor against tensor (``drivers/train_mla.py``: the run's
-``raw`` holds a ``gradient_table``), many seeds in one process:
+"""``tools/readings.py`` for a cell whose family also sets the first
+gradient tensor against tensor (the family gives ``gradient_readings``:
+the run's ``raw`` holds a ``gradient_table``), many seeds in one
+process. The family is the driver the cell's mix names:
 
     python benchmark/tools/gradient_readings.py --workload <cell> \
         --seeds 11,12,... [--control-seeds 11,12] \
-        [--controls fp8,half_batch] [--steps 1] [--out chiprun_out/x.json]
+        [--controls fp8,half_batch] [--steps 1] [--seconds 0] \
+        [--fault <name>] [--out chiprun_out/x.json]
 
-For every seed the cell's own run with the shortest window there is
-(``--seconds 0``: one step), judged through the harness's own ``judge``
-under the committed limits. For the control seeds also the reference in
-the precision below put in the program's place, and the reference fed
-half of each batch: their first gradient goes through the same table
-against the float32 reference's, so every number the cell compares is
-read for them too. ``--steps 1`` follows one optimizer step instead of
-the mix's three: a third of the reference's time, for readings of the
-numbers that the first step alone gives (``grad_gap``,
-``grad_dir_gap``, ``attn_dir_gap``; ``change_gap`` and ``pairs_gap``
-then read another quantity and are left out of the row). The rows keep the table, so a
-number over other leaves or layers can be read off them afterwards.
-On the CPU it only rehearses (--rehearse).
+For every seed the cell's own run with the window given (``--seconds
+0``: one step), judged through the harness's own ``judge`` under the
+committed limits. For the control seeds also the reference in the
+precision below put in the program's place, and the reference fed half
+of each batch: their first gradient goes through the same table against
+the float32 reference's, so every number the cell compares is read for
+them too. ``--steps 1`` follows one optimizer step instead of the mix's
+three: a third of the reference's time, for readings of the numbers
+that the first step alone gives (``grad_gap``, ``loss1`` and the
+``*_dir_gap``; ``change_gap`` and ``pairs_gap`` then read another
+quantity and are left out of the row). ``--fault`` plants one of the
+family's ``FAULTS`` in the PROGRAM for every seed given (the hybrid
+family's ``carry`` and ``conv``: the scan, or the conv, told nothing of
+the documents), to read what the cell's numbers make of it at its own
+size. The rows keep the tables, so a number over other leaves or layers
+can be read off them afterwards. On the CPU it only rehearses
+(--rehearse, at the cell's own rehearsal widths).
 """
 
 from __future__ import annotations
@@ -33,29 +39,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-FIRST_STEP_ONLY = ("grad_gap", "grad_dir_gap", "attn_dir_gap", "loss1")
 
-
-def control_readings(raw: dict, which) -> dict:
-    """{control: (the numbers `correct` compares, its table)}, with the
-    control in the program's place and the float32 reference unchanged."""
-    from benchmark import check
-    from benchmark.drivers import train, train_mla
-    args = raw["reference_args"]
-    n = len(args[-1][0]["inputs"])
-    out = {}
-    for name in which:
-        kw = ({"keep_rows": slice(0, n // 2)} if name == "half_batch"
-              else {"mode": name})
-        low = train.reference_readings(*args, **kw)
-        pairs = low["dims"].pop("held_pairs")
-        table = train_mla.gradient_table(low["dims"].pop("first_gradient"),
-                                         raw["reference"]["gradient"])
-        got = check.train_readings(low, raw["reference"])
-        got.update(train_mla.gradient_readings(table))
-        got["pairs_gap"] = train_mla.pairs_gap(pairs, raw["reference_pairs"])
-        out[name] = got, table
-    return out
+def first_step_only(name: str) -> bool:
+    """A number the first optimizer step alone gives."""
+    return name in ("grad_gap", "loss1") or name.endswith("_dir_gap")
 
 
 def main(argv=None) -> int:
@@ -66,12 +53,25 @@ def main(argv=None) -> int:
     ap.add_argument("--controls", default="fp8,half_batch")
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--seconds", type=float, default=0.0)
+    ap.add_argument("--fault", default=None)
     ap.add_argument("--out", default=None)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
     from benchmark import harness as hs
-    from benchmark.rehearse.glm_tiny import shrink
-    from benchmark.tools.readings import judged
+    from benchmark.tools.readings import control_readings, judged
+
+    bench = hs.load_json(hs.ROOT, "BENCHMARK.json")
+    family = hs.driver_of(hs.cell_files(bench, hs.find_cell(
+        bench, args.workload)))
+    if args.fault:
+        faults = getattr(family, "FAULTS", {})
+        if args.fault not in faults:
+            ap.error(f"--fault: {family.__name__} plants "
+                     f"{sorted(faults) or 'none'}")
+        faults[args.fault]()
+    if args.rehearse:
+        from benchmark.rehearse import shrink_for
+        shrink = shrink_for(args.workload)
 
     def override(files):
         if args.rehearse:
@@ -82,7 +82,7 @@ def main(argv=None) -> int:
     def verdict(readings, limits):
         if args.steps is not None:
             readings = {k: v for k, v in readings.items()
-                        if k in FIRST_STEP_ONLY}
+                        if first_step_only(k)}
         return judged(readings, limits)
 
     def listed(table):
@@ -96,8 +96,12 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         facts = hs.driver_of(ctx).run(ctx)
         raw = facts["raw"]
-        row = {"seed": seed, "steps": ctx["mix"]["check"]["steps"],
+        row = {"seed": seed, "fault": args.fault,
+               "steps": ctx["mix"]["check"]["steps"],
                "program": verdict(facts["readings"], ctx["limits"]),
+               "window_steps": facts["work"]["steps"],
+               "window_s": facts["window_s"], "setup_s": facts["setup_s"],
+               "memory_peak_bytes": facts["device"]["memory_peak_bytes"],
                "run_s": time.perf_counter() - t0}
         tables = {"program": listed(raw["gradient_table"])}
         if seed in with_control:

@@ -34,9 +34,10 @@ CONTROLS = ("int8", "fp8", "half_batch")
 
 
 def control_readings(raw: dict, which=CONTROLS) -> dict:
-    """{control: the numbers `correct` compares}, with the control in
-    the program's place and the float32 reference unchanged."""
-    from benchmark import check
+    """{control: (the numbers `correct` compares, the first gradient's
+    table or None)}, with the control in the program's place and the
+    float32 reference unchanged: every number the cell compares, as the
+    run reads it (``drivers/train.py::compared``)."""
     from benchmark.drivers import train
     args = raw["reference_args"]
     n = len(args[-1][0]["inputs"])
@@ -45,7 +46,7 @@ def control_readings(raw: dict, which=CONTROLS) -> dict:
         kw = ({"keep_rows": slice(0, n // 2)} if name == "half_batch"
               else {"mode": name})
         low = train.reference_readings(*args, **kw)
-        out[name] = check.train_readings(low, raw["reference"])
+        out[name] = train.compared(low, raw["reference"], raw["family"])
     return out
 
 
@@ -83,8 +84,9 @@ def main(argv=None) -> int:
         if seed in with_control:
             t0 = time.perf_counter()
             row["control"] = {
-                k: judged(v, ctx["limits"]) for k, v in control_readings(
-                    facts["raw"], args.controls.split(",")).items()}
+                k: judged(v, ctx["limits"]) for k, (v, _) in
+                control_readings(facts["raw"],
+                                 args.controls.split(",")).items()}
             row["control_s"] = time.perf_counter() - t0
         del facts
         rows.append(row)
